@@ -191,10 +191,7 @@ verbose = false
 def _load_config(path: str | None) -> dict:
     cfg: dict = {}
     if path:
-        try:  # stdlib on 3.11+
-            import tomllib
-        except ImportError:  # 3.10 runtimes ship the identical tomli
-            import tomli as tomllib
+        import tomllib
 
         with open(path, "rb") as f:
             cfg = tomllib.load(f)
@@ -313,6 +310,9 @@ def _parse_csv_values(files):
 
 
 def cmd_server(args) -> int:
+    from pilosa_tpu.utils import compile_cache
+
+    compile_cache.configure()  # before the first compile of the process
     from pilosa_tpu.server import Server, ServerConfig
 
     cfg_dict = _load_config(args.config)

@@ -247,7 +247,7 @@ def _patch_sharded(arr, slot: int, make_patch):
     other piece's buffer is reused as-is. Each process's handle only
     contributes its own addressable data to SPMD execution, so a
     process-local reassembly is all a local write needs (SURVEY.md §7.3
-    hard part #3, multi-host case — VERDICT r3 #6)."""
+    hard part #3, multi-host case)."""
     pieces = list(arr.addressable_shards)
     datas = [p.data for p in pieces]
     for i, p in enumerate(pieces):
@@ -662,9 +662,9 @@ def batched_body(body1, n_leaves: int, n_scalars: int, n_queries: int):
 def local_fn_batched(structure, reduce_kind: str, leaf_ranks: tuple,
                      n_scalars: int, n_queries: int):
     """ONE device program evaluating ``n_queries`` same-shape queries
-    (Executor.submit micro-batching). Each program dispatch on a
-    tunneled/remote backend carries a fixed launch cost comparable to the
-    device compute of a whole 1B-column query; stacking a micro-batch of
+    (Executor.submit micro-batching). Each program dispatch carries a
+    fixed launch cost that can rival the device compute of a whole
+    1B-column query; stacking a micro-batch of
     pipelined queries into one program amortizes it, and the single
     [B, ...] readback serves every query in the batch with one host
     round trip."""

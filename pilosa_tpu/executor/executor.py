@@ -357,7 +357,7 @@ class Executor:
 
         Device streams are ordered, so a serving loop can enqueue a stream
         of queries and resolve them in order — the host↔device round trip
-        (the latency floor on tunneled/remote backends) overlaps with
+        (the latency floor under every blocking readback) overlaps with
         device compute instead of serializing after it. Pipelined
         reductions sharing a program shape — Count, the BSI aggregates
         Sum/Min/Max, AND TopN's phase-2 recount (candidate lists pad to

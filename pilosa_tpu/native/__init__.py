@@ -30,23 +30,6 @@ def _load():
             _lib = False  # cache the miss: this runs in per-container
             return None   # hot loops, a PATH scan per call would bite
         lib = ctypes.CDLL(path)
-        if not hasattr(lib, "union_sorted_u16"):
-            # Stale .so predating the sorted-set symbols. dlopen caches
-            # by path, so re-loading the rebuilt file at the SAME path
-            # returns the stale handle — rebuild to a fresh temp name.
-            import shutil
-            import tempfile
-
-            src = build(force=True)
-            if src is None:
-                _lib = False
-                return None
-            fresh = tempfile.NamedTemporaryFile(
-                suffix=".so", delete=False
-            ).name
-            shutil.copy2(src, fresh)
-            lib = ctypes.CDLL(fresh)
-            os.unlink(fresh)  # mapping survives the unlink (Linux)
         u64p = ctypes.POINTER(ctypes.c_uint64)
         u32p = ctypes.POINTER(ctypes.c_uint32)
         u16p = ctypes.POINTER(ctypes.c_uint16)

@@ -29,28 +29,10 @@ collective reductions.
 
 from __future__ import annotations
 
-import contextlib
-import inspect
-import threading
-import weakref
-
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-
-    SHARD_MAP_NATIVE = True
-except ImportError:  # older runtimes ship it under experimental; on
-    # those, concurrent shard_map programs from SEPARATE executors over
-    # the same forced-CPU device set can deadlock in the cross-module
-    # all-reduce rendezvous — single-mesh use is fine; multi-mesh
-    # in-process dispatches are serialized by _fallback_guard below
-    from jax.experimental.shard_map import shard_map
-
-    SHARD_MAP_NATIVE = False
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from pilosa_tpu.executor import expr
@@ -65,57 +47,13 @@ from pilosa_tpu.utils.cost import current_cost
 
 _DIST_JIT_CACHE: dict = {}
 
-# ---------------------------------------------------------------------------
-# Experimental-fallback dispatch guard.
-#
-# The experimental shard_map can deadlock when programs built over
-# DIFFERENT meshes (separate in-process executors — e.g. a test server's
-# auto-mesh next to a bench's explicit submesh) launch concurrently:
-# both enter the collective rendezvous over the same forced-CPU device
-# set and wait on each other. Native shard_map keys the rendezvous by
-# mesh and doesn't need this. Rather than a comment asking callers not
-# to do that, dispatches take a process-wide lock whenever more than one
-# distinct live mesh exists under the fallback; single-mesh deployments
-# (every production shape) never pay it. tests/test_mesh_reduction.py
-# holds the regression.
-
-_FALLBACK_DISPATCH_LOCK = threading.RLock()
-_LIVE_EXECUTORS: "weakref.WeakSet" = weakref.WeakSet()
-_guard_serialized_count = 0
-
-
-def _multi_mesh_live(mesh) -> bool:
-    meshes = {e.mesh for e in _LIVE_EXECUTORS}
-    meshes.add(mesh)
-    return len(meshes) > 1
-
-
-@contextlib.contextmanager
-def _fallback_guard(mesh):
-    if SHARD_MAP_NATIVE or not _multi_mesh_live(mesh):
-        yield
-        return
-    global _guard_serialized_count
-    with _FALLBACK_DISPATCH_LOCK:
-        _guard_serialized_count += 1
-        yield
-
-
-# hierarchical bodies produce replicated outputs via all_gather + local
-# fold, which the rep checker cannot infer — disable it for those
-# programs only (kwarg name varies across shard_map generations)
-if "check_rep" in inspect.signature(shard_map).parameters:
-    _LOOSE_REP = {"check_rep": False}
-elif "check_vma" in inspect.signature(shard_map).parameters:
-    _LOOSE_REP = {"check_vma": False}
-else:
-    _LOOSE_REP = {}
-
 
 def _smap(body, mesh, in_specs, out_specs, hier):
-    kwargs = _LOOSE_REP if hier is not None else {}
+    # hierarchical bodies produce replicated outputs via all_gather + a
+    # local fold, which the varying-axes checker cannot infer — it is
+    # disabled for those programs only
     return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, **kwargs)
+                     out_specs=out_specs, check_vma=hier is None)
 
 
 def _dist_body(structure, reduce_kind: str, leaf_ranks: tuple, hier=None):
@@ -386,7 +324,6 @@ class DistExecutor(Executor):
         # certification mode, not for serving.
         self.quantized_ranking = bool(quantized_ranking)
         self.verify_quantized = bool(verify_quantized)
-        _LIVE_EXECUTORS.add(self)
 
     def _quant_ranking_active(self) -> bool:
         return self.quantized_ranking
@@ -427,20 +364,6 @@ class DistExecutor(Executor):
             self.mesh, filt_structure, n_filt, n_scalars, n_gather, has_agg,
             quantized,
         )
-
-    # ------------------------------------------------ dispatch wrapping
-
-    def _dispatch(self, node, reduce_kind, leaves, scalars):
-        with _fallback_guard(self.mesh):
-            return super()._dispatch(node, reduce_kind, leaves, scalars)
-
-    def _flush_group_locked(self, key, group):
-        with _fallback_guard(self.mesh):
-            return super()._flush_group_locked(key, group)
-
-    def _groupby_level_enqueue(self, *args, **kwargs):
-        with _fallback_guard(self.mesh):
-            return super()._groupby_level_enqueue(*args, **kwargs)
 
     # ------------------------------------------- wire-byte accounting
 

@@ -913,11 +913,16 @@ class Server:
                 pacer=self.api.cluster.client.pacer,
                 logger=self.logger,
             ).start()
+        import jax
+
+        devices = jax.devices()
         self.logger.info(
-            "listening on %s://%s:%d (data-dir %s, node %s)",
+            "listening on %s://%s:%d (data-dir %s, node %s, devices %d x "
+            "%s %s)",
             "https" if self.config.tls_enabled else "http",
             self.config.bind, self.port, self.holder.data_dir,
-            self.api.cluster.local.id,
+            self.api.cluster.local.id, len(devices), devices[0].platform,
+            devices[0].device_kind,
         )
         self.api.trace_log_dir = self.config.trace_log_dir
         from pilosa_tpu.utils.diagnostics import DiagnosticsCollector

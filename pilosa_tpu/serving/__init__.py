@@ -1,9 +1,8 @@
 """Multi-process serving tier (docs/OPERATIONS.md deployment shapes).
 
 The single-process serving ceiling is the Python interpreter, not the
-device (BENCH_SUITE ``ceiling_note``): ~1.7 ms of single-interpreter
-HTTP + API work per request plateaus one node near ~830 QPS while the
-accelerator idles. This package shatters that ceiling with the standard
+device: the single-interpreter HTTP + API work per request plateaus one
+node while the accelerator idles (rate on the chip: not measured). This package shatters that ceiling with the standard
 deployment shape for Python services, adapted to a device-owning
 backend:
 

@@ -391,9 +391,10 @@ class OwnerRuntime:
         sock = self._new_listen_socket()
         sock.set_inheritable(True)
         env = dict(os.environ)
-        # workers never touch the device; make sure a stray jax import
-        # in a future worker-side module cannot grab the accelerator
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # workers never touch the device: whatever the owner inherited
+        # (JAX_PLATFORMS=tpu included), a stray jax import in a future
+        # worker-side module must not reach for the owner's chip
+        env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.Popen(
             [sys.executable, "-m", "pilosa_tpu", "serve-worker",
              "--handshake-sock", self._sock_path,

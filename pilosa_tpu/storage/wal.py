@@ -3,8 +3,8 @@
 The reference (and rounds 1-5 here) made each acked write pay its own
 op-log append+flush into the fragment's file — never an fsync, so "per
 write durability" was OS-buffer-deep, and making it real would have put
-one fsync on every ACK (the measured drag behind the 4.0× mixed
-read+write ceiling, BENCH_SUITE.readwrite). This module is the classic
+one fsync on every ACK (the drag behind the mixed read+write
+ceiling). This module is the classic
 WAL trade instead: concurrent writers append op records into ONE
 holder-level log, a commit thread issues ONE flush+fsync for the whole
 group, and only then are all the waiting ACKs released — durability at

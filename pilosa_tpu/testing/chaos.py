@@ -22,8 +22,7 @@ requests ride plain urllib, so the observer is never partitioned from
 the nodes — a write acked through a reachable node counts even when
 that node is about to be cut off.
 
-Used by ``bench_suite.py config_chaos`` (the ≥20-schedule gate recorded
-in BENCH_SUITE.json) and the ``slow`` soak in tests/test_partition.py.
+Used by ``bench_suite.py config_chaos`` (the ≥20-schedule gate) and the ``slow`` soak in tests/test_partition.py.
 """
 
 from __future__ import annotations

@@ -49,8 +49,7 @@ def attrs_from_proto(attrs) -> dict:
 
 def encode_results(results, trace: dict | None = None) -> bytes:
     """``trace``: a finished span subtree (dict) from a traced remote
-    sub-query, carried back to the coordinator as QueryResponse.trace_json
-    (silently dropped against a pre-trace generated schema)."""
+    sub-query, carried back to the coordinator as QueryResponse.trace_json."""
     import json as _json
 
     p = pb2()
@@ -59,10 +58,7 @@ def encode_results(results, trace: dict | None = None) -> bytes:
         qr = resp.results.add()
         _encode_result(qr, res)
     if trace is not None:
-        try:
-            resp.trace_json = _json.dumps(trace, separators=(",", ":"))
-        except AttributeError:  # stale internal_pb2 without the field
-            pass
+        resp.trace_json = _json.dumps(trace, separators=(",", ":"))
     return resp.SerializeToString()
 
 
@@ -223,10 +219,7 @@ def encode_batch_request(items) -> bytes:
         unit.query = item[1]
         unit.shards.extend(int(s) for s in item[2])
         if len(item) > 3 and item[3]:
-            try:
-                unit.trace = item[3]
-            except AttributeError:  # stale internal_pb2: hop untraced
-                pass
+            unit.trace = item[3]
     return req.SerializeToString()
 
 
@@ -234,8 +227,7 @@ def decode_batch_request(data: bytes) -> list[tuple]:
     p = pb2()
     req = p.BatchQueryRequest()
     req.ParseFromString(data)
-    return [(u.index, u.query, list(u.shards),
-             getattr(u, "trace", "") or None)
+    return [(u.index, u.query, list(u.shards), u.trace or None)
             for u in req.queries]
 
 
@@ -254,11 +246,8 @@ def encode_batch_responses(outcomes) -> bytes:
             for res in outcome[1]:
                 _encode_result(resp.results.add(), res)
             if len(outcome) > 2 and outcome[2] is not None:
-                try:
-                    resp.trace_json = _json.dumps(outcome[2],
-                                                  separators=(",", ":"))
-                except AttributeError:
-                    pass
+                resp.trace_json = _json.dumps(outcome[2],
+                                              separators=(",", ":"))
         else:
             resp.err = outcome[1]
             resp.status = int(outcome[2])
@@ -391,7 +380,7 @@ def _response_results_json(resp) -> dict:
     import json as _json
 
     trace = None
-    raw_trace = getattr(resp, "trace_json", "")
+    raw_trace = resp.trace_json
     if raw_trace:
         try:
             trace = _json.loads(raw_trace)

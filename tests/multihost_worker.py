@@ -96,8 +96,8 @@ with tempfile.TemporaryDirectory() as tmp:
     # write-through: the contract is that a shard's write is applied on
     # (at least) the process owning that shard's slot; here both
     # replicated holders apply it, which covers the owner. Resident
-    # sharded leaves are PATCHED per addressable piece (VERDICT r3 #6:
-    # batch._patch_sharded, a single-device scatter + handle reassembly,
+    # sharded leaves are PATCHED per addressable piece
+    # (batch._patch_sharded, a single-device scatter + handle reassembly,
     # no collective) — asserted via residency counters: the write must
     # bump `updates` and the re-query must re-decode nothing.
     from pilosa_tpu.storage import residency  # noqa: E402

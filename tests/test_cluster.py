@@ -625,7 +625,7 @@ class TestResizeAndReReplication:
         """Kill a node; after DEAD_HEARTBEATS failed probes the acting
         coordinator removes it and drives coordinator-computed resize
         instructions until every shard is back at full replica count
-        (VERDICT r1 #7: no manual join or anti-entropy pass needed)."""
+        (no manual join or anti-entropy pass needed)."""
         from pilosa_tpu.parallel.cluster import DEAD_HEARTBEATS
 
         servers = make_cluster(tmp_path, 3, replica_n=2)
@@ -952,8 +952,7 @@ class TestResizeAndReReplication:
 class TestEagerShardVisibility:
     def test_new_remote_shard_visible_without_poll(self, tmp_path):
         """A shard created on one node is broadcast (CreateShardMessage)
-        and visible to other nodes' queries immediately — no TTL window
-        (VERDICT r1 weak #6)."""
+        and visible to other nodes' queries immediately — no TTL window."""
         import time as _time
 
         servers = make_cluster(tmp_path, 2)
@@ -1280,7 +1279,7 @@ class TestConcurrentFanout:
         """Cross-node fan-out runs one concurrent sub-query per node
         (reference mapReduce): with two remote nodes each answering in
         ~delay seconds, the query's wall time is ~max(delays), not the
-        sum (VERDICT r3 #2)."""
+        sum."""
         import time
 
         servers = make_cluster(tmp_path, 3)
@@ -1332,7 +1331,7 @@ class TestConcurrentFanout:
 
 class TestAsyncSelfJoin:
     def test_joiner_with_slow_peer_serves_status_and_gates_queries(self, tmp_path):
-        """Self-join fetch runs as a background job (VERDICT r3 #8): while
+        """Self-join fetch runs as a background job: while
         a slow peer drags the fragment fetch out, Server.open has already
         returned, the joiner answers /status as RESIZING, and queries
         gate on wait_until_normal — then complete correctly once the

@@ -10,22 +10,7 @@ repair feeding the mesh executor, and pipelined submit on the topology.
 
 import functools
 
-import pytest
-
 from cluster_helpers import join_node, make_cluster, req, seed, uri
-from pilosa_tpu.parallel import dist
-
-# Multiple in-process servers each running a DistExecutor share ONE
-# forced-CPU device set; on runtimes that only ship the experimental
-# shard_map, their concurrent programs deadlock in the cross-module
-# all-reduce rendezvous (collective_ops_utils "stuck participant").
-# Single-mesh suites (test_distributed, the mesh serving tests) are
-# unaffected; only this multi-server-mesh topology must skip there.
-pytestmark = pytest.mark.skipif(
-    not dist.SHARD_MAP_NATIVE,
-    reason="concurrent multi-server shard_map collectives deadlock on "
-           "the experimental shard_map fallback (old jax CPU runtime)",
-)
 
 make_mesh_cluster = functools.partial(
     make_cluster, use_mesh=True, prefix="mnode"

@@ -108,7 +108,7 @@ def test_statsd_datagrams():
 
 def test_block_repair_is_binary_and_compact(tmp_path):
     """Anti-entropy block repair moves roaring bytes, not JSON int lists:
-    a dense 100-row block transfers ~O(bitmap bytes) (VERDICT r1 #6)."""
+    a dense 100-row block transfers ~O(bitmap bytes)."""
     import numpy as np
 
     servers = make_cluster(tmp_path, 2, replica_n=2)

@@ -1,6 +1,6 @@
 """Hierarchical reduction plane (parallel/reduction.py + the 2-D mesh).
 
-Three contracts, gated here and again (at scale, with records) by the
+Two contracts, gated here and again (at scale, with records) by the
 bench_suite ``mesh`` config:
 
 * bit-exactness — every reduce kind on every mesh factorization returns
@@ -8,13 +8,9 @@ bench_suite ``mesh`` config:
   non-divisible shard counts (padded slots);
 * the wire model — dense-equivalent vs actual reduction-lane bytes are
   recorded per dispatch, actual is smaller on hierarchical meshes, and
-  Row/TopN shapes clear the ≥4x bar the ROADMAP target needs;
-* the experimental-fallback guard — concurrent dispatches from
-  executors over DIFFERENT meshes serialize instead of deadlocking when
-  shard_map comes from jax.experimental.
+  Row/TopN shapes clear the ≥4x bar the ROADMAP target needs.
 """
 
-import threading
 
 import numpy as np
 import pytest
@@ -22,7 +18,6 @@ import pytest
 from pilosa_tpu.executor import Executor
 from pilosa_tpu.executor.result import result_to_json
 from pilosa_tpu.parallel import DistExecutor, make_mesh, mesh_groups
-from pilosa_tpu.parallel import dist as dist_mod
 from pilosa_tpu.parallel import reduction
 from pilosa_tpu.shardwidth import SHARD_WIDTH, WORDS_PER_SHARD
 from pilosa_tpu.storage import FieldOptions, Holder
@@ -322,51 +317,3 @@ class TestQuantizedRanking:
         (want,) = qbase.execute("rank", pql)
         (got,) = dist.execute("rank", pql)
         assert result_to_json(got) == result_to_json(want)
-
-
-class TestFallbackGuard:
-    """Satellite: when shard_map is the experimental fallback, dispatches
-    from executors over DIFFERENT meshes must serialize (the documented
-    cross-module all-reduce rendezvous deadlock) instead of relying on a
-    comment."""
-
-    def test_concurrent_multi_mesh_serializes(self, holder, executors):
-        if dist_mod.SHARD_MAP_NATIVE:
-            pytest.skip("native shard_map keys rendezvous by mesh")
-        a = executors[(8, 2)]
-        b = executors[(4, 2)]
-        # warm both programs single-threaded first (compilation under
-        # the guard is fine but slow inside threads)
-        (want_a,) = a.execute("big", "Count(Row(f=1))")
-        (want_b,) = b.execute("big", "Count(Row(f=1))")
-        before = dist_mod._guard_serialized_count
-        results, errors = {}, []
-
-        def run(name, ex, want):
-            try:
-                for _ in range(5):
-                    (got,) = ex.execute("big", "Count(Row(f=1))")
-                    assert got == want
-                results[name] = True
-            except Exception as e:  # pragma: no cover - failure detail
-                errors.append((name, e))
-
-        threads = [threading.Thread(target=run, args=("a", a, want_a)),
-                   threading.Thread(target=run, args=("b", b, want_b))]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=120)
-        assert not errors
-        assert results == {"a": True, "b": True}
-        assert dist_mod._guard_serialized_count > before
-
-    def test_single_mesh_unaffected_semantics(self, executors):
-        """The guard only engages for multi-mesh: _multi_mesh_live is the
-        predicate, and a lone mesh must not trip it."""
-        if dist_mod.SHARD_MAP_NATIVE:
-            pytest.skip("native shard_map keys rendezvous by mesh")
-        mesh = executors[(8, 2)].mesh
-        live = {e.mesh for e in dist_mod._LIVE_EXECUTORS}
-        # other module-scoped executors exist, so multi-mesh is live now
-        assert dist_mod._multi_mesh_live(mesh) == (len(live | {mesh}) > 1)

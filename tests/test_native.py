@@ -95,3 +95,27 @@ def test_sorted_set_ops_match_numpy():
         np.testing.assert_array_equal(
             got_d, np.setdiff1d(a, b, assume_unique=True)
         )
+
+
+@requires_native
+def test_library_is_keyed_by_source_hash():
+    """Only the file named after the current source's hash is ever
+    loaded: a library under the old fixed name, or under another hash,
+    is not what the loader looks for, whatever its mtime."""
+    import os
+
+    from pilosa_tpu.native import build
+
+    lib_dir = os.path.dirname(build.SRC)
+    planted = [os.path.join(lib_dir, "libfastbits.so"),
+               os.path.join(lib_dir, "libfastbits-0000000000000000.so")]
+    for path in planted:
+        with open(path, "wb") as f:
+            f.write(b"not a shared object")
+    try:
+        assert build.build() == build.lib_path()
+        assert build.lib_path() not in planted
+        assert native._load()._name == build.lib_path()
+    finally:
+        for path in planted:
+            os.unlink(path)

@@ -617,8 +617,7 @@ class TestChaosHarness:
     def test_chaos_soak(self, tmp_path):
         """Long randomized soak (env-tunable): more schedules, more
         events, 5 nodes — the ≥20-schedule acceptance gate also runs in
-        bench_suite's `chaos` config with its record in
-        BENCH_SUITE.json."""
+        bench_suite's `chaos` config."""
         import os
 
         faults.clear()

@@ -116,7 +116,7 @@ class TestHTTPServing:
         return results
 
     def test_concurrent_load_matches_serial_mesh_on(self, tmp_path):
-        """The VERDICT load test: mesh-backed single-node server, N
+        """The load test: mesh-backed single-node server, N
         concurrent clients, per-query results identical to serial."""
         servers = make_cluster(tmp_path, 1, use_mesh=True)
         try:

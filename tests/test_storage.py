@@ -341,7 +341,7 @@ class TestRowCounts:
 
     def test_discovery_paths_avoid_per_row_counts(self, tmp_path, monkeypatch):
         """Rows() discovery and cold-cache TopN phase 1 must not call
-        count_row per row (VERDICT r1 weak #5: multi-second host loops at
+        count_row per row (multi-second host loops at
         50k rows x 1k shards)."""
         from pilosa_tpu.executor import Executor
         from pilosa_tpu.storage import Holder

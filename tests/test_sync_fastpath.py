@@ -8,9 +8,9 @@
 - token-bucket pacer bounds (rate, inflight, the paced-sleep counter);
 - conflict-aware merge rules (mutex/BSI) preserved through the new path;
 - mixed-version cluster: one node forced JSON-only AND old-wire under a
-  randomized workload (VERDICT r5 Next #5);
+  randomized workload;
 - a ≥30-min mixed read+write+churn+repair soak with flat-RSS /
-  flat-residency oracles behind the ``slow`` marker (VERDICT Next #4).
+  flat-residency oracles behind the ``slow`` marker.
 """
 
 import os
@@ -591,9 +591,9 @@ def _force_old_wire(servers, victim):
 
 
 def test_mixed_version_cluster_randomized(tmp_path):
-    """VERDICT r5 Next #5: a 3-node cluster with one node forced
-    JSON-only AND old-wire (no manifest/delta routes) under the
-    randomized property workload — manifest/delta negotiation and the r4
+    """A 3-node cluster with one node forced JSON-only AND old-wire (no
+    manifest/delta routes) under the randomized property workload —
+    manifest/delta negotiation and the r4
     proto renumbering cannot corrupt a mixed deployment. Every node must
     answer the full oracle after writes routed through ALL nodes and
     repair passes run from every node."""
@@ -674,8 +674,8 @@ def _rss_kb() -> int:
 
 @pytest.mark.slow
 def test_maintenance_soak_flat_rss_and_residency(tmp_path):
-    """≥30-min (env-tunable) mixed read+write+churn+repair soak
-    (VERDICT r5 Next #4): a replicated cluster serves queries and writes
+    """≥30-min (env-tunable) mixed read+write+churn+repair soak:
+    a replicated cluster serves queries and writes
     while a third node joins and leaves repeatedly and anti-entropy
     passes run throughout. Oracles: zero errors, exact counts at every
     checkpoint, flat RSS (the median of the last quarter within 25% + a

@@ -1,4 +1,4 @@
-"""Adversarial TopN approximation tests (VERDICT r5 Next #7).
+"""Adversarial TopN approximation tests.
 
 TopN's phase-1 candidate set comes from the per-fragment RANKED CACHES,
 ordered by UNFILTERED row counts; phase 2 recounts candidates exactly.

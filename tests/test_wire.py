@@ -276,3 +276,35 @@ def test_protobuf_request_carries_result_options(node):
     assert out["attrs"] == {}  # excludeRowAttrs
     assert out["columns"] == [1]
     assert out["columnAttrs"] == [{"id": 1, "attrs": {"city": "nyc"}}]
+
+
+def test_stale_generated_module_is_regenerated_not_imported():
+    """internal_pb2.py is keyed by a hash of internal.proto on its first
+    line. A planted file without the current stamp must never be
+    imported: with protoc it is regenerated, without protoc the layer
+    reports unavailable (JSON only)."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+
+    gen = os.path.join(os.path.dirname(wire.__file__), "internal_pb2.py")
+    with open(gen, "w") as f:
+        f.write("# source-sha256: 0000\nraise RuntimeError('stale module')\n")
+    code = (
+        "from pilosa_tpu import wire\n"
+        "p = wire.pb2()\n"
+        "print('none' if p is None else p.QueryRequest.__name__)\n"
+    )
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        timeout=120,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    if shutil.which("protoc"):
+        assert proc.stdout.strip() == "QueryRequest"
+        with open(gen, "rb") as f:
+            assert f.readline() == wire._stamp()
+    else:
+        assert proc.stdout.strip() == "none"
