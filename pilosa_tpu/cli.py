@@ -156,7 +156,6 @@ qos-hedge-delay = 0.25        # hedge trigger before the p95 tracker warms up
 qos-hedge-budget = 0.05       # max hedges as a fraction of reads; 0 disables
 qos-breaker-threshold = 5     # consecutive faults before a breaker opens
 qos-breaker-cooldown = 5.0    # open -> half-open probe interval (seconds)
-tracing = false               # legacy always-on switch (= sample rate 1.0)
 trace-sample-rate = 0.0       # probabilistic trace sampling: 0 = off
                               # (zero overhead), 0.01 = 1% of requests
                               # root a cross-node span tree on
@@ -701,6 +700,21 @@ def cmd_check(args) -> int:
     return 1 if bad or quarantined else 0
 
 
+def cmd_trace_report(args) -> int:
+    """Read a device capture back (docs/OBSERVABILITY.md "Reading a
+    capture"): busy share, time per XLA module and operation, and the
+    longest idle gaps of each device by what the host was doing."""
+    from pilosa_tpu.utils.tracing import format_trace_report, trace_report
+
+    try:
+        report = trace_report(args.log_dir, gaps_n=args.gaps)
+    except FileNotFoundError as e:
+        print(f"trace-report: {e}", file=sys.stderr)
+        return 1
+    print(json.dumps(report) if args.json else format_trace_report(report))
+    return 0
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog="pilosa-tpu", description="TPU-native distributed bitmap index"
@@ -802,6 +816,16 @@ def main(argv=None) -> int:
                         "generation + change-feed replay (needs backups "
                         "taken from a group-durability WAL)")
     p.set_defaults(fn=cmd_restore)
+
+    p = sub.add_parser(
+        "trace-report",
+        help="summarize the newest POST /debug/trace-device capture",
+    )
+    p.add_argument("log_dir", help="trace-log-dir (or one .xplane.pb)")
+    p.add_argument("--gaps", type=int, default=5,
+                   help="longest idle gaps to label, per device")
+    p.add_argument("--json", action="store_true")
+    p.set_defaults(fn=cmd_trace_report)
 
     p = sub.add_parser("version", help="print version")
     p.set_defaults(fn=lambda a: (print(__version__), 0)[1])

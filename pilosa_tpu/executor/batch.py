@@ -48,6 +48,7 @@ from jax import lax
 from pilosa_tpu.executor import expr
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD, next_pow2
 from pilosa_tpu.storage import residency
+from pilosa_tpu.utils.compile_cache import named_jit
 
 INT32_MIN = -(1 << 31)
 INT32_MAX = (1 << 31) - 1
@@ -192,7 +193,6 @@ def host_planes(idx, spec, shard: int, depth: int) -> np.ndarray:
 # the next query to re-decode and re-upload its whole working set.
 
 
-@jax.jit
 def _or_delta(arr, slot, word_idx, masks):
     """OR sparse word masks into one shard slot of a [S, W] leaf.
     word_idx is host-deduplicated; padding repeats (0, mask 0), which
@@ -201,23 +201,26 @@ def _or_delta(arr, slot, word_idx, masks):
     return arr.at[slot].set(arr[slot] | delta)
 
 
-@jax.jit
 def _andnot_delta(arr, slot, word_idx, masks):
     delta = jnp.zeros((arr.shape[-1],), jnp.uint32).at[word_idx].max(masks)
     return arr.at[slot].set(arr[slot] & ~delta)
 
 
-@jax.jit
 def _or_delta_row(arr, slot, row, word_idx, masks):
     """Same for one row of a [S, R, W] matrix leaf."""
     delta = jnp.zeros((arr.shape[-1],), jnp.uint32).at[word_idx].max(masks)
     return arr.at[slot, row].set(arr[slot, row] | delta)
 
 
-@jax.jit
 def _andnot_delta_row(arr, slot, row, word_idx, masks):
     delta = jnp.zeros((arr.shape[-1],), jnp.uint32).at[word_idx].max(masks)
     return arr.at[slot, row].set(arr[slot, row] & ~delta)
+
+
+_or_delta = named_jit("or_delta", _or_delta)
+_andnot_delta = named_jit("andnot_delta", _andnot_delta)
+_or_delta_row = named_jit("or_delta_row", _or_delta_row)
+_andnot_delta_row = named_jit("andnot_delta_row", _andnot_delta_row)
 
 
 def _word_masks(positions) -> tuple[np.ndarray, np.ndarray]:
@@ -627,7 +630,8 @@ def local_fn(structure, reduce_kind: str, leaf_ranks: tuple, n_scalars: int):
     key = ("local", structure, reduce_kind, leaf_ranks, n_scalars)
     fn = _LOCAL_JIT_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(_local_body(structure, reduce_kind, leaf_ranks))
+        fn = named_jit(reduce_kind,
+                       _local_body(structure, reduce_kind, leaf_ranks))
         _LOCAL_JIT_CACHE[key] = fn
     return fn
 
@@ -675,7 +679,9 @@ def local_fn_batched(structure, reduce_kind: str, leaf_ranks: tuple,
         return fn
 
     body1 = _local_body(structure, reduce_kind, leaf_ranks)
-    fn = jax.jit(batched_body(body1, len(leaf_ranks), n_scalars, n_queries))
+    fn = named_jit(
+        f"{reduce_kind}_b{n_queries}",
+        batched_body(body1, len(leaf_ranks), n_scalars, n_queries))
     _LOCAL_JIT_CACHE[key] = fn
     return fn
 
@@ -702,23 +708,28 @@ def groupby_level_body(ls, idxs, scalars, filt_structure, n_filt: int,
     per group)."""
     filt_leaves = ls[:n_filt]
     dim_mats = ls[n_filt:n_filt + n_gather]
-    mask = jnp.take(dim_mats[0], idxs[0], axis=0)  # [C, W]
-    for d, ii in zip(dim_mats[1:], idxs[1:]):
-        mask = mask & jnp.take(d, ii, axis=0)
+    with jax.named_scope("groupby_gather"):
+        mask = jnp.take(dim_mats[0], idxs[0], axis=0)  # [C, W]
+        for d, ii in zip(dim_mats[1:], idxs[1:]):
+            mask = mask & jnp.take(d, ii, axis=0)
     if filt_structure is not None:
-        f = expr._go(filt_structure, filt_leaves, scalars)
-        mask = mask & f[None, :]
-    counts = jnp.sum(lax.population_count(mask).astype(jnp.int32), axis=-1)
-    if not has_agg:
-        return counts
-    planes = ls[n_filt + n_gather]
-    gmask = mask & planes[expr.PLANES_EXISTS][None, :]
-    n_g = jnp.sum(lax.population_count(gmask).astype(jnp.int32), axis=-1)
-    plane_counts = jnp.stack([
-        jnp.sum(lax.population_count(planes[b][None, :] & gmask)
-                .astype(jnp.int32), axis=-1)
-        for b in range(expr.PLANES_OFFSET, planes.shape[0])
-    ])  # [depth, C]
+        with jax.named_scope("groupby_filter"):
+            f = expr._go(filt_structure, filt_leaves, scalars)
+            mask = mask & f[None, :]
+    with jax.named_scope("groupby_reduce"):
+        counts = jnp.sum(lax.population_count(mask).astype(jnp.int32),
+                         axis=-1)
+        if not has_agg:
+            return counts
+        planes = ls[n_filt + n_gather]
+        gmask = mask & planes[expr.PLANES_EXISTS][None, :]
+        n_g = jnp.sum(lax.population_count(gmask).astype(jnp.int32),
+                      axis=-1)
+        plane_counts = jnp.stack([
+            jnp.sum(lax.population_count(planes[b][None, :] & gmask)
+                    .astype(jnp.int32), axis=-1)
+            for b in range(expr.PLANES_OFFSET, planes.shape[0])
+        ])  # [depth, C]
     return counts, n_g, plane_counts
 
 
@@ -757,6 +768,6 @@ def local_groupby_level_fn(filt_structure, n_filt: int, n_scalars: int,
             [counts.ravel(), n_g.ravel(), plane_counts.ravel()]
         )
 
-    fn = jax.jit(body)
+    fn = named_jit("groupby_level", body)
     _LOCAL_JIT_CACHE[key] = fn
     return fn

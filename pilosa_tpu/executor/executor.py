@@ -35,6 +35,7 @@ from pilosa_tpu.shardwidth import WORDS_PER_SHARD, next_pow2, position, shard_of
 from pilosa_tpu.storage import residency
 from pilosa_tpu.storage.heat import global_heat
 from pilosa_tpu.utils.cost import current_cost, use_node
+from pilosa_tpu.utils.tracing import stage, staged
 from pilosa_tpu.storage.field import (
     BSI_EXISTS_ROW,
     TYPE_INT,
@@ -263,6 +264,12 @@ def instrument_calls(index_name: str, calls, run_one) -> list:
             out.append(res)
             stats.count("queries", 1, {"call": call.name})
     return out
+
+
+@staged("device.readback")
+def _readback(device_array) -> np.ndarray:
+    """The blocking host copy of a program's result."""
+    return np.asarray(device_array)
 
 
 def _result_cardinality(res) -> int:
@@ -568,7 +575,7 @@ class Executor:
         """Row-gather readback hook: device [padded, words] result →
         host array. DistExecutor's hierarchical mesh routes this through
         the roaring wire simulation (parallel/reduction.py)."""
-        return np.asarray(stacked)
+        return _readback(stacked)
 
     def _program(self, structure, reduce_kind: str, leaf_ranks: tuple,
                  n_scalars: int):
@@ -592,6 +599,7 @@ class Executor:
     def _quant_ranking_active(self) -> bool:
         return False
 
+    @staged("executor.operands")
     def _eval_operands(self, idx: Index, compiled: _Compiled, block,
                        extra_leaves=(), memoize: bool = True):
         """Resolve a compiled query's device leaves; scalars stay host
@@ -670,19 +678,17 @@ class Executor:
     def _dispatch(self, node, reduce_kind: str, leaves, scalars):
         import jax.numpy as jnp
 
-        from pilosa_tpu.utils.tracing import global_tracer
-
         fn = self._program(
             node, reduce_kind, tuple(l.ndim - 1 for l in leaves), len(scalars)
         )
-        cost = current_cost()
-        with global_tracer().span("device.dispatch", reduce=reduce_kind):
-            # same boundaries as the span: enqueue time on the device
-            # stream, attributed to the active request/call node
-            t0 = time.perf_counter()
+        # enqueue time on the device stream; the cost plane attributes
+        # the stage's own elapsed time to the active request/call node
+        site = stage("device.dispatch", reduce=reduce_kind)
+        with site:
             out = fn(*leaves, *(jnp.asarray(s, jnp.int32) for s in scalars))
-            if cost is not None:
-                cost.note_dispatch(time.perf_counter() - t0)
+        cost = current_cost()
+        if cost is not None:
+            cost.note_dispatch(site.elapsed)
         self._note_reduce(reduce_kind, out.shape, leaves[0].shape[0])
         return out
 
@@ -800,7 +806,7 @@ class Executor:
                     self._flush_group_locked(key, group)
                 out = group["out"]
             if not isinstance(out, np.ndarray):
-                out = np.asarray(out)  # blocking readback, outside the lock
+                out = _readback(out)  # blocking, outside the lock
                 with self._mb_lock:
                     group["out"] = out
             return out[i]
@@ -838,22 +844,16 @@ class Executor:
         args = [leaf for leaves, _ in padded for leaf in leaves]
         if n_scalars:
             args.append(np.asarray([s for _, s in padded], np.int32))
-        from pilosa_tpu.utils.tracing import global_tracer
-
-        # the span lands in the trace of whichever request flushed the
+        # the stage lands in the trace of whichever request flushed the
         # group — truthful attribution: that request paid the dispatch,
         # its batchmates ride for free (tagged with the shared size);
         # the cost plane attributes the dispatch the same way
+        site = stage("device.dispatch", reduce=reduce_kind, batch=len(rows))
+        with site:
+            group["out"] = fn(*args)
         cost = current_cost()
-        with global_tracer().span("device.dispatch", reduce=reduce_kind,
-                                  batch=len(rows)):
-            if cost is None:
-                group["out"] = fn(*args)
-            else:
-                t0 = time.perf_counter()
-                group["out"] = fn(*args)
-                cost.note_dispatch(time.perf_counter() - t0,
-                                   batch=len(rows))
+        if cost is not None:
+            cost.note_dispatch(site.elapsed, batch=len(rows))
         self._note_reduce(reduce_kind, group["out"].shape, shapes[0][0])
         if self._pending.get(key) is group:
             del self._pending[key]
@@ -961,7 +961,7 @@ class Executor:
                                     scalars)
         else:
             packed = self._batched_eval(idx, compiled, block, reduce_kind)
-        return Deferred(lambda: finish(np.asarray(packed)))
+        return Deferred(lambda: finish(_readback(packed)))
 
     def includes_target(self, idx: Index, call: Call, shards=None):
         """Resolve IncludesColumn's target: (numeric column, shard), or
@@ -990,7 +990,7 @@ class Executor:
         col, shard = target
         pos = position(col)
         compiled = self._compile_cached(idx, call.children[0])
-        words = np.asarray(compiled.eval(idx, shard))
+        words = _readback(compiled.eval(idx, shard))
         return bool((words[pos // 32] >> np.uint32(pos % 32)) & np.uint32(1))
 
     def _execute_options(self, idx: Index, call: Call, shards=None):
@@ -1001,6 +1001,7 @@ class Executor:
 
     # -------------------------------------------------------------- compile
 
+    @staged("executor.plan")
     def _compile_cached(self, idx: Index, call: Call,
                         wrap: str | None = None,
                         build: Callable | None = None) -> _Compiled:
@@ -1288,44 +1289,47 @@ class Executor:
             raise PQLError(f"field {field_name!r} not found")
         n = call.arg("n", 10)
         filt_call = call.children[0] if call.children else None
-        shard_list = self._shards(idx, shards)
-        if not shard_list:
-            return Deferred(value=[])
-        view = field.view(VIEW_STANDARD)
+        # TopN plans per request: phase 1 reads the ranked caches of the
+        # live fragments, then the filter compiles
+        with stage("executor.plan"):
+            shard_list = self._shards(idx, shards)
+            if not shard_list:
+                return Deferred(value=[])
+            view = field.view(VIEW_STANDARD)
 
-        explicit_ids = call.arg("ids")
-        if explicit_ids is not None:
-            candidates = sorted(int(i) for i in explicit_ids)
-        else:
-            # phase 1: per-shard candidates from the ranked caches
-            overfetch = max(n * TOPN_CANDIDATE_FACTOR, n + 10)
-            cand: set[int] = set()
-            for shard in shard_list:
-                frag = view.fragment(shard) if view else None
-                if frag is None:
-                    continue
-                cand.update(r for r, _ in frag.top(overfetch))
-            candidates = sorted(cand)
-        candidates = self._filter_topn_candidates(field, call, candidates)
-        if not candidates:
-            return Deferred(value=[])
+            explicit_ids = call.arg("ids")
+            if explicit_ids is not None:
+                candidates = sorted(int(i) for i in explicit_ids)
+            else:
+                # phase 1: per-shard candidates from the ranked caches
+                overfetch = max(n * TOPN_CANDIDATE_FACTOR, n + 10)
+                cand: set[int] = set()
+                for shard in shard_list:
+                    frag = view.fragment(shard) if view else None
+                    if frag is None:
+                        continue
+                    cand.update(r for r, _ in frag.top(overfetch))
+                candidates = sorted(cand)
+            candidates = self._filter_topn_candidates(field, call, candidates)
+            if not candidates:
+                return Deferred(value=[])
 
-        # phase 2: exact recount of every candidate across all shards —
-        # countrows programs over stacked candidate matrices. The
-        # candidate axis is CHUNKED to the per-device matrix byte budget
-        # (a candidate row costs shards×128KiB; see
-        # TOPN_MATRIX_BUDGET_BYTES) and each chunk pads to the chunk's
-        # power-of-two size with ZERO rows (zeros match no write event,
-        # so the residency patch routing stays exact) — so chunks of one
-        # query AND pipelined TopN streams bucket into shared shapes and
-        # micro-batch together.
-        n_real = len(candidates)
-        specs: list = []
-        scalars: list = []
-        filt_node = (
-            self._compile_node(idx, filt_call, specs, scalars) if filt_call else None
-        )
-        node = ("countrows", len(specs), filt_node)
+            # phase 2: exact recount of every candidate across all shards —
+            # countrows programs over stacked candidate matrices. The
+            # candidate axis is CHUNKED to the per-device matrix byte budget
+            # (a candidate row costs shards×128KiB; see
+            # TOPN_MATRIX_BUDGET_BYTES) and each chunk pads to the chunk's
+            # power-of-two size with ZERO rows (zeros match no write event,
+            # so the residency patch routing stays exact) — so chunks of one
+            # query AND pipelined TopN streams bucket into shared shapes and
+            # micro-batch together.
+            n_real = len(candidates)
+            specs: list = []
+            scalars: list = []
+            filt_node = (
+                self._compile_node(idx, filt_call, specs, scalars) if filt_call else None
+            )
+            node = ("countrows", len(specs), filt_node)
         block = self._shard_block(shard_list)
         bytes_per_cand = (
             block.padded * WORDS_PER_SHARD * 4 // self.arg_shard_factor
@@ -1353,10 +1357,11 @@ class Executor:
             chunk_reads = []
             for lo in range(0, len(cand_list), rows):
                 chunk = cand_list[lo:lo + rows]
-                matrix = batch.stacked_matrix(
-                    idx, field_name, view, chunk, block, put,
-                    pad_rows=rows - len(chunk),
-                )
+                with stage("executor.operands"):
+                    matrix = batch.stacked_matrix(
+                        idx, field_name, view, chunk, block, put,
+                        pad_rows=rows - len(chunk),
+                    )
                 leaves = base_leaves + [matrix]
                 read = (self._microbatch_enqueue(node, kind, leaves,
                                                  scalar_ints)
@@ -1364,7 +1369,7 @@ class Executor:
                 if read is None:
                     packed = self._dispatch(node, kind, leaves,
                                             scalar_ints)
-                    read = (lambda p: lambda: np.asarray(p))(packed)
+                    read = (lambda p: lambda: _readback(p))(packed)
                 chunk_reads.append((chunk, read))
             return chunk_reads
 
@@ -1658,41 +1663,48 @@ class Executor:
         needs a readback per level to choose the next level's
         candidates, so it defers the whole evaluation to ``result()``.
         """
-        limit, filt_call, agg_field, dims, having = self._groupby_prelude(
-            idx, call, shards
-        )
-        if not dims:
-            return Deferred(value=[])
-        shard_list = self._shards(idx, shards)
-        if not shard_list:
-            return Deferred(value=[])
+        # GroupBy plans per request (its dimensions' row ids are read
+        # from the live fragments), so its plan stage is the prelude and
+        # the filter's compile, and its operand stage the stacked
+        # filter leaves, dimension matrices and aggregate planes
+        with stage("executor.plan"):
+            limit, filt_call, agg_field, dims, having = \
+                self._groupby_prelude(idx, call, shards)
+            if not dims:
+                return Deferred(value=[])
+            shard_list = self._shards(idx, shards)
+            if not shard_list:
+                return Deferred(value=[])
 
-        specs: list = []
-        scalars: list = []
-        filt_node = (
-            self._compile_node(idx, filt_call, specs, scalars)
-            if filt_call is not None
-            else None
-        )
-        block = self._shard_block(shard_list)
-        put = self._leaf_put(block)
-        filt_leaves = [batch.stacked_leaf(idx, s, block, put) for s in specs]
-        dim_mats = []
-        for fname, row_ids in dims:
-            field = idx.field(fname)
-            view = field.view(VIEW_STANDARD) if field else None
-            dim_mats.append(
-                batch.stacked_matrix(idx, fname, view, row_ids, block, put)
+            specs: list = []
+            scalars: list = []
+            filt_node = (
+                self._compile_node(idx, filt_call, specs, scalars)
+                if filt_call is not None
+                else None
             )
-        planes = (
-            batch.stacked_leaf(
-                idx,
-                _PlanesSpec(agg_field.name, agg_field.options.bit_depth),
-                block, put,
+            block = self._shard_block(shard_list)
+        with stage("executor.operands"):
+            put = self._leaf_put(block)
+            filt_leaves = [batch.stacked_leaf(idx, s, block, put)
+                           for s in specs]
+            dim_mats = []
+            for fname, row_ids in dims:
+                field = idx.field(fname)
+                view = field.view(VIEW_STANDARD) if field else None
+                dim_mats.append(
+                    batch.stacked_matrix(idx, fname, view, row_ids, block,
+                                         put)
+                )
+            planes = (
+                batch.stacked_leaf(
+                    idx,
+                    _PlanesSpec(agg_field.name, agg_field.options.bit_depth),
+                    block, put,
+                )
+                if agg_field is not None
+                else None
             )
-            if agg_field is not None
-            else None
-        )
 
         sizes = [len(row_ids) for _, row_ids in dims]
         total_groups = 1
@@ -1736,7 +1748,7 @@ class Executor:
 
             def finish() -> list[GroupCount]:
                 counts_arr, agg_arrs = _groupby_level_unpack(
-                    np.asarray(packed), layout, cand.shape[0], has_agg,
+                    _readback(packed), layout, cand.shape[0], has_agg,
                     depth,
                 )
                 return collect(cand, counts_arr, agg_arrs)
@@ -1795,7 +1807,7 @@ class Executor:
         has_agg = planes is not None
         depth = agg_field.options.bit_depth if has_agg else 0
         return _groupby_level_unpack(
-            np.asarray(packed), layout, cand.shape[0], has_agg, depth,
+            _readback(packed), layout, cand.shape[0], has_agg, depth,
             quantized=quantized,
         )
 
@@ -1822,7 +1834,8 @@ class Executor:
             filt_node, len(filt_leaves), len(scalars), n_gather, has_agg,
             quantized=quantized,
         )
-        jscalars = tuple(jnp.asarray(s, jnp.int32) for s in scalars)
+        with stage("device.upload"):
+            jscalars = tuple(jnp.asarray(s, jnp.int32) for s in scalars)
 
         packs = []
         layout = []  # (padded, actual) per chunk
@@ -1834,19 +1847,28 @@ class Executor:
                 ci = np.concatenate(
                     [ci, np.zeros((padded - actual, n_gather), np.int32)]
                 )
-            idx_arrays = tuple(
-                jnp.asarray(ci[:, d], jnp.int32) for d in range(n_gather)
-            )
+            with stage("device.upload"):
+                idx_arrays = tuple(
+                    jnp.asarray(ci[:, d], jnp.int32) for d in range(n_gather)
+                )
             args = list(filt_leaves) + list(dim_mats)
             if has_agg:
                 args.append(planes)
             args.extend(idx_arrays)
-            packs.append(fn(*args, *jscalars))
+            site = stage("device.dispatch", reduce="groupby")
+            with site:
+                packs.append(fn(*args, *jscalars))
+            cost = current_cost()
+            if cost is not None:
+                cost.note_dispatch(site.elapsed)
             self._note_reduce("groupby_q" if quantized else "groupby",
                               packs[-1].shape, block.padded)
             layout.append((padded, actual))
 
-        packed = jnp.concatenate(packs) if len(packs) > 1 else packs[0]
+        if len(packs) == 1:
+            return packs[0], layout
+        with stage("device.dispatch", reduce="concat"):
+            packed = jnp.concatenate(packs)
         return packed, layout
 
     # ---------------------------------------------------------------- writes
@@ -1864,7 +1886,8 @@ class Executor:
             raise PQLError(f"field {field_name!r} not found")
         if field.options.type == TYPE_INT:
             try:
-                changed = field.set_value(col, int(row))
+                with stage("fragment.write"):
+                    changed = field.set_value(col, int(row))
             except ValueError as e:
                 raise PQLError(str(e)) from e
         else:
@@ -1872,8 +1895,10 @@ class Executor:
             _check_row(row)
             ts = call.arg("timestamp")
             timestamp = _parse_time(ts) if ts is not None else None
-            changed = field.set_bit(int(row), col, timestamp=timestamp)
-        idx.mark_columns_exist([col])
+            with stage("fragment.write"):
+                changed = field.set_bit(int(row), col, timestamp=timestamp)
+        with stage("fragment.write"):
+            idx.mark_columns_exist([col])
         return changed
 
     def _execute_clear(self, idx: Index, call: Call) -> bool:
@@ -1890,12 +1915,14 @@ class Executor:
         if field is None:
             raise PQLError(f"field {field_name!r} not found")
         if field.options.type == TYPE_INT:
-            return field.clear_value(col)
+            with stage("fragment.write"):
+                return field.clear_value(col)
         row = self._translate_row(idx, field, row, create=False)
         if row is None:
             return False
         _check_row(row)
-        return field.clear_bit(int(row), col)
+        with stage("fragment.write"):
+            return field.clear_bit(int(row), col)
 
     def _execute_clear_row(self, idx: Index, call: Call, shards=None) -> bool:
         field_name, row = self._row_field_and_value(call)
@@ -1961,7 +1988,7 @@ class Executor:
             return True
         compiled = self._compile_cached(idx, call.children[0])
         block = self._shard_block(shard_list)
-        host = np.asarray(self._batched_eval(idx, compiled, block, "row"))
+        host = _readback(self._batched_eval(idx, compiled, block, "row"))
         for i, shard in enumerate(block.shards):
             frag = field.view(VIEW_STANDARD, create=True).fragment(shard, create=True)
             frag.write_row_words(int(row), host[i])

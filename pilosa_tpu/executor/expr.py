@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from pilosa_tpu.utils.compile_cache import named_jit
+
 _U32 = jnp.uint32
 
 # BSI plane-matrix row layout (matches storage.field BSI_* constants).
@@ -56,7 +58,7 @@ def _build(structure):
     def eval_fn(leaves, scalars):
         return _go(structure, leaves, scalars)
 
-    return jax.jit(eval_fn)
+    return named_jit("expr", eval_fn)
 
 
 def _go(node, leaves, scalars):

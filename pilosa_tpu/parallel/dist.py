@@ -43,6 +43,7 @@ from pilosa_tpu.parallel.mesh import (
     GROUPS_AXIS, SHARDS_AXIS, ShardAssignment, make_mesh, mesh_groups,
     shards_spec,
 )
+from pilosa_tpu.utils.compile_cache import named_jit
 from pilosa_tpu.utils.cost import current_cost
 
 _DIST_JIT_CACHE: dict = {}
@@ -160,7 +161,8 @@ def _dist_fn(mesh, structure, reduce_kind: str, leaf_ranks: tuple,
     scalar_specs = tuple(P() for _ in range(n_scalars))
     out_specs = spec if reduce_kind == "row" else P()
 
-    fn = jax.jit(
+    fn = named_jit(
+        f"dist_{reduce_kind}",
         _smap(
             _dist_body(structure, reduce_kind, leaf_ranks, hier),
             mesh=mesh,
@@ -199,7 +201,8 @@ def _dist_fn_batched(mesh, structure, reduce_kind: str, leaf_ranks: tuple,
         + ((P(),) if n_scalars else ())
     )
 
-    fn = jax.jit(
+    fn = named_jit(
+        f"dist_{reduce_kind}_b{n_queries}",
         _smap(
             batch.batched_body(body1, n_leaves, n_scalars, n_queries),
             mesh=mesh,
@@ -267,7 +270,8 @@ def _dist_groupby_level_fn(mesh, filt_structure, n_filt: int, n_scalars: int,
             reduce_split(batch.split_sum(o, axis=0)).ravel() for o in out
         ])
 
-    fn = jax.jit(
+    fn = named_jit(
+        "dist_groupby_level",
         _smap(body, mesh=mesh, in_specs=in_specs, out_specs=P(), hier=hier)
     )
     _DIST_JIT_CACHE[key] = fn
@@ -431,7 +435,7 @@ class DistExecutor(Executor):
         per-slot roaring containers in block frames — the result is
         decoded FROM those frames, so the compression is load-bearing,
         not just counted."""
-        host = np.asarray(stacked)
+        host = super()._row_host(stacked, block)  # stage device.readback
         if self._hier is None or jax.process_count() > 1:
             return host
         frames, actual = reduction.encode_row_frames(host)

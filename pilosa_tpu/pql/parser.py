@@ -149,11 +149,17 @@ class _Lexer:
 # only costs the next parse.
 _PARSE_CACHE: dict[str, Query] = {}
 _PARSE_CACHE_MAX = 4096
+# texts answered from the memo (a plain counter, not locked: stage
+# pql.parse counts every call exactly, this says how many of them cost
+# a dict lookup; /metrics pql_parse_memo_hits_total)
+memo_hits = 0
 
 
 def parse(src: str) -> Query:
+    global memo_hits
     cached = _PARSE_CACHE.get(src)
     if cached is not None:
+        memo_hits += 1
         return cached
     lex = _Lexer(src)
     calls = []

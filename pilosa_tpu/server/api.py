@@ -32,6 +32,7 @@ from pilosa_tpu.utils.cost import (
     deactivate_cost,
     new_cost_context,
 )
+from pilosa_tpu.utils.tracing import stage
 
 
 class ApiError(Exception):
@@ -218,12 +219,8 @@ class API:
         from pilosa_tpu.executor.executor import PQLError
         from pilosa_tpu.pql import ParseError
         from pilosa_tpu.qos import AdmissionError, DeadlineExceeded
-        from pilosa_tpu.utils.tracing import (
-            global_query_tracker,
-            global_tracer,
-        )
+        from pilosa_tpu.utils.tracing import global_query_tracker
 
-        tracer = global_tracer()
         tracker = global_query_tracker()
         inflight = tracker.start(index, pql, tenant=tenant, remote=remote)
         inflight_token = (tracker.activate(inflight)
@@ -246,10 +243,8 @@ class API:
                 # this request before it crossed the shared-memory ring
                 # (serving/worker.py) — double-gating would shed
                 # requests the node as a whole has capacity for
-                if inflight is not None:
-                    inflight.stage = "admission"
                 try:
-                    with tracer.span("qos.admit", tenant=tenant):
+                    with stage("qos.admit", tenant=tenant):
                         slot = self.qos.admission.admit(tenant)
                 except AdmissionError as e:
                     err = ApiError(str(e), 429)
@@ -257,7 +252,7 @@ class API:
                     raise err from e
             return self._query_raw_admitted(
                 index, pql, shards, remote, opts, tenant, deadline,
-                slot, inflight, tracer, on_submitted,
+                slot, on_submitted,
             )
         except ApiError as e:
             err_status = e.status
@@ -286,24 +281,21 @@ class API:
             tracker.finish(inflight, inflight_token)
 
     def _query_raw_admitted(self, index, pql, shards, remote, opts,
-                            tenant, deadline, slot, inflight, tracer,
-                            on_submitted=None):
+                            tenant, deadline, slot, on_submitted=None):
         import time
 
         from pilosa_tpu.executor.executor import PQLError
         from pilosa_tpu.parallel.cluster import ClusterDegradedError
         from pilosa_tpu.pql import ParseError
         from pilosa_tpu.qos import DeadlineExceeded
-
         t0 = time.perf_counter()
         try:
-            if inflight is not None:
-                inflight.stage = "parse"
             query = pql
             if isinstance(pql, str):
                 from pilosa_tpu.pql import parse
 
-                query = parse(pql)
+                with stage("pql.parse"):
+                    query = parse(pql)
             writes = (len(query.write_calls())
                       if hasattr(query, "write_calls") else 1)
             if 0 < self.max_writes_per_request < writes:
@@ -355,8 +347,6 @@ class API:
                     # leader's execution); the leader's profile carries
                     # the full tree (server/pipeline.py tags both)
                     key = (index, pql)
-                if inflight is not None:
-                    inflight.stage = "pipeline.wave"
                 deferreds = self._pipeline.run(index, query, kwargs,
                                                key=key)
                 if on_submitted is not None:
@@ -372,19 +362,17 @@ class API:
                 # i.e. what this request actually waited for.
                 from pilosa_tpu.executor.executor import instrument_calls
 
-                if inflight is not None:
-                    inflight.stage = "executor.resolve"
                 handles = iter(deferreds)
-                results = instrument_calls(
-                    index, query.calls,
-                    lambda call: next(handles).result(),
-                )
+                with stage("executor.resolve"):
+                    results = instrument_calls(
+                        index, query.calls,
+                        lambda call: next(handles).result(),
+                    )
             else:
-                if inflight is not None:
-                    inflight.stage = "executor.execute"
                 if on_submitted is not None:
                     on_submitted()  # eager path: executing right now
-                results = self.executor.execute(index, query, **kwargs)
+                with stage("executor.execute"):
+                    results = self.executor.execute(index, query, **kwargs)
             if opts:
                 results = self._apply_request_opts(index, results, opts)
             if writes:
@@ -414,8 +402,6 @@ class API:
                 # the whole wave of concurrent writers — storage/wal.py);
                 # per-op already fsynced inline, flush-only promises
                 # nothing, and both make this a no-op.
-                if inflight is not None:
-                    inflight.stage = "wal.barrier"
                 self._ack_durable()
             return results
         except DeadlineExceeded as e:
@@ -490,7 +476,6 @@ class API:
         and fills afterwards, so a write group-committing concurrently
         with the fill invalidates it (the insert refuses to land)."""
         from pilosa_tpu.executor.result import results_json_bytes
-
         scope = None
         snap = None
         if (not remote and shards is None and deadline is None and not opts
@@ -538,7 +523,8 @@ class API:
                                  profile_out=profile_out,
                                  pre_admitted=pre_admitted,
                                  on_submitted=on_submitted)
-        payload = results_json_bytes(results)
+        with stage("result.encode"):
+            payload = results_json_bytes(results)
         if snap is not None and scope is not None:
             from pilosa_tpu.pql import parse
             from pilosa_tpu.serving.rescache import query_field_deps
@@ -580,7 +566,6 @@ class API:
             global_tracer,
         )
 
-        tracer = global_tracer()
         tracker = global_query_tracker()
         inflight = tracker.start(index, pql, tenant=tenant, remote=False)
         inflight_token = (tracker.activate(inflight)
@@ -591,18 +576,14 @@ class API:
         slot = None
         try:
             if not pre_admitted:
-                if inflight is not None:
-                    inflight.stage = "admission"
                 try:
-                    with tracer.span("qos.admit", tenant=tenant):
+                    with stage("qos.admit", tenant=tenant):
                         slot = self.qos.admission.admit(tenant)
                 except AdmissionError as e:
                     err = ApiError(str(e), 429)
                     err.retry_after = e.retry_after
                     raise err from e
-            if inflight is not None:
-                inflight.stage = "rescache"
-            with tracer.span("rescache.hit", index=index):
+            with global_tracer().span("rescache.hit", index=index):
                 cache.record_hit(scope, index, pql)
             if on_submitted is not None:
                 # the dedupe-join cutoff (serving/mpserve.py): a cache
@@ -863,9 +844,7 @@ class API:
         wal = getattr(self.holder, "wal", None)
         if wal is None or wal.mode == MODE_FLUSH_ONLY:
             return
-        from pilosa_tpu.utils.tracing import global_tracer
-
-        with global_tracer().span("wal.barrier"):
+        with stage("wal.barrier"):
             translate = getattr(self.holder, "translate", None)
             if translate is not None:
                 translate.sync()
@@ -1663,7 +1642,10 @@ class API:
             global_tracer,
         )
 
-        out = {"slow_queries_total": self.slow_queries_total}
+        from pilosa_tpu.pql import parser
+
+        out = {"slow_queries_total": self.slow_queries_total,
+               "pql_parse_memo_hits_total": parser.memo_hits}
         out.update(global_tracer().metrics())
         out.update(global_query_tracker().metrics())
         return out

@@ -32,6 +32,7 @@ from urllib.parse import parse_qs, urlparse
 
 from pilosa_tpu.server.api import API, ApiError
 from pilosa_tpu.utils.cost import cost_enabled
+from pilosa_tpu.utils.tracing import TRACE_HEADER, global_tracer, stage
 
 _ROUTES: list[tuple[str, re.Pattern, str]] = [
     ("POST", re.compile(r"^/index/([^/]+)/query$"), "post_query"),
@@ -370,68 +371,17 @@ class HTTPHandler(BaseHTTPRequestHandler):
     # --------------------------------------------------------------- routes
 
     def post_query(self, index, query=None):
-        raw = self._body()
-        content_type = self.headers.get("Content-Type", "")
-        accept = self.headers.get("Accept", "")
-        proto_in = "application/x-protobuf" in content_type
-        proto_out = "application/x-protobuf" in accept
-        want_profile = bool(
-            query and query.get("profile", ["false"])[0] == "true"
-        )
-        if want_profile and proto_out:
-            # the profile rides only the JSON envelope; silently paying
-            # the profiling overhead and dropping the tree would send a
-            # debugger down a false trail (checked before the wire-
-            # availability 406 so the answer is deterministic)
-            raise ApiError(
-                "profile=true requires a JSON response (drop the "
-                "application/x-protobuf Accept header)"
-            )
-
-        if proto_in or proto_out:
-            from pilosa_tpu import wire
-
-            if not wire.available():
-                raise ApiError("protobuf wire format unavailable", 406)
-
-        if proto_in:
-            from pilosa_tpu.wire.serializer import decode_query_request
-
-            pql, shards, remote, opts = decode_query_request(raw)
-        else:
-            pql = raw.decode()
-            shards = None
-            if query and "shards" in query:
-                shards = [
-                    _int_param(s, "shards") for s in query["shards"][0].split(",")
-                ]
-            remote = bool(query and query.get("remote", ["false"])[0] == "true")
-            opts = {}
-        # request-level result options also ride URL params for either
-        # body encoding (reference handler query args)
-        opts.update({
-            k: True for k in ("columnAttrs", "excludeColumns",
-                              "excludeRowAttrs")
-            if query and query.get(k, ["false"])[0] == "true"
-        })
-
-        tenant, deadline = self._qos_envelope(remote=remote)
-        self._staleness_gate()
-        # PQL PROFILE (docs/OBSERVABILITY.md): ?profile=true returns a
-        # per-AST-node execution profile beside the results; remote hops
-        # carry the flag so the coordinator's envelope holds one
-        # stitched per-node tree (the trace-graft pattern below)
-        profile_out: list | None = [] if want_profile else None
-
-        # Tracing roots (utils/tracing.py): an EDGE request makes the
-        # sampling decision here (one tree per request, or a suppressed
-        # context so inner sites can't root their own); a REMOTE
-        # sub-query carrying X-Pilosa-Trace joins the coordinator's
-        # trace and returns its finished span subtree in the response so
-        # the caller renders ONE cluster-wide tree.
-        from pilosa_tpu.utils.tracing import TRACE_HEADER, global_tracer
-
+        # Tracing roots (utils/tracing.py): the root stage http.query
+        # spans body read to last byte written. An EDGE request makes
+        # the sampling decision here (one tree per request, or a
+        # suppressed context so inner sites can't root their own); a
+        # REMOTE sub-query carrying X-Pilosa-Trace joins the
+        # coordinator's trace and returns its finished span subtree in
+        # the response so the caller renders ONE cluster-wide tree. The
+        # decision needs only the URL and the headers (every internal
+        # client sends remote=true there), so it precedes the body read.
         tracer = global_tracer()
+        remote = bool(query and query.get("remote", ["false"])[0] == "true")
         trace_hdr = self.headers.get(TRACE_HEADER) if remote else None
         if remote:
             root_cm = tracer.remote_root(
@@ -439,80 +389,146 @@ class HTTPHandler(BaseHTTPRequestHandler):
                 index=index,
             )
         else:
-            root_cm = tracer.request_root("http.query", index=index,
-                                          tenant=tenant)
-        with root_cm as root:
-            if not proto_out:
-                if self.api.serve_fastlane:
-                    # fast lane: the response envelope arrives
-                    # pre-serialized (hot shapes encode straight to
-                    # bytes; identical deduped wavemates share one
-                    # encoding — executor/result.py)
-                    payload = self.api.query_json_bytes(
-                        index, pql, shards=shards, remote=remote,
-                        opts=opts, tenant=tenant, deadline=deadline,
-                        profile_out=profile_out)
-                    if root is not None and trace_hdr:
-                        # splice the finished subtree into the closing
-                        # brace of the pre-serialized envelope — sampled
-                        # remote hops are rare (rate-bounded), so the
-                        # fast lane's zero-build path is untouched
-                        root.finish()
-                        payload = (payload[:-1] + b',"trace":'
-                                   + json.dumps(
-                                       root.to_json(),
-                                       separators=(",", ":")).encode()
-                                   + b"}")
-                    if profile_out:
-                        # same splice as the trace graft: profiled
-                        # requests are rare debugging traffic, the
-                        # zero-build fast lane stays untouched
-                        payload = (payload[:-1] + b',"profile":'
-                                   + json.dumps(
-                                       profile_out[0],
-                                       separators=(",", ":")).encode()
-                                   + b"}")
-                    self._note_egress(tenant, index, len(payload), remote)
-                    self._raw(payload)
-                else:  # r5-shaped legacy path (serve_fastlane = False)
-                    out = self.api.query(index, pql, shards=shards,
-                                         remote=remote, opts=opts,
-                                         tenant=tenant, deadline=deadline,
-                                         profile_out=profile_out)
-                    if root is not None and trace_hdr:
-                        root.finish()
-                        out["trace"] = root.to_json()
-                    if profile_out:
-                        out["profile"] = profile_out[0]
-                    # encode here (not via _json) so the legacy path
-                    # bills egress like the fast lane does
-                    data = json.dumps(out).encode()
-                    self._note_egress(tenant, index, len(data), remote)
-                    self._raw(data)
-                return
-            from pilosa_tpu.wire.serializer import (
-                encode_error,
-                encode_results,
-            )
+            root_cm = tracer.request_root("http.query", index=index)
+        with stage("http.query", root_cm) as root:
+            self._post_query(index, query, remote, trace_hdr, root)
 
-            retry_after = None
-            try:
-                results = self.api.query_raw(index, pql, shards=shards,
-                                             remote=remote, opts=opts,
-                                             tenant=tenant,
-                                             deadline=deadline,
-                                             profile_out=profile_out)
-                trace_json = None
+    def _post_query(self, index, query, remote, trace_hdr, root):
+        with stage("http.read"):
+            raw = self._body()
+            content_type = self.headers.get("Content-Type", "")
+            accept = self.headers.get("Accept", "")
+            proto_in = "application/x-protobuf" in content_type
+            proto_out = "application/x-protobuf" in accept
+            want_profile = bool(
+                query and query.get("profile", ["false"])[0] == "true"
+            )
+            if want_profile and proto_out:
+                # the profile rides only the JSON envelope; silently
+                # paying the profiling overhead and dropping the tree
+                # would send a debugger down a false trail (checked
+                # before the wire-availability 406 so the answer is
+                # deterministic)
+                raise ApiError(
+                    "profile=true requires a JSON response (drop the "
+                    "application/x-protobuf Accept header)"
+                )
+
+            if proto_in or proto_out:
+                from pilosa_tpu import wire
+
+                if not wire.available():
+                    raise ApiError("protobuf wire format unavailable", 406)
+
+            if proto_in:
+                from pilosa_tpu.wire.serializer import decode_query_request
+
+                pql, shards, body_remote, opts = decode_query_request(raw)
+                remote = remote or body_remote
+            else:
+                pql = raw.decode()
+                shards = None
+                if query and "shards" in query:
+                    shards = [
+                        _int_param(s, "shards")
+                        for s in query["shards"][0].split(",")
+                    ]
+                opts = {}
+            # request-level result options also ride URL params for
+            # either body encoding (reference handler query args)
+            opts.update({
+                k: True for k in ("columnAttrs", "excludeColumns",
+                                  "excludeRowAttrs")
+                if query and query.get(k, ["false"])[0] == "true"
+            })
+
+            tenant, deadline = self._qos_envelope(remote=remote)
+            if root is not None and not remote:
+                root.tags["tenant"] = tenant
+            self._staleness_gate()
+        # PQL PROFILE (docs/OBSERVABILITY.md): ?profile=true returns a
+        # per-AST-node execution profile beside the results; remote hops
+        # carry the flag so the coordinator's envelope holds one
+        # stitched per-node tree (the trace-graft pattern below)
+        profile_out: list | None = [] if want_profile else None
+
+        if not proto_out:
+            if self.api.serve_fastlane:
+                # fast lane: the response envelope arrives
+                # pre-serialized (hot shapes encode straight to
+                # bytes; identical deduped wavemates share one
+                # encoding — executor/result.py)
+                payload = self.api.query_json_bytes(
+                    index, pql, shards=shards, remote=remote,
+                    opts=opts, tenant=tenant, deadline=deadline,
+                    profile_out=profile_out)
+                if root is not None and trace_hdr:
+                    # splice the finished subtree into the closing
+                    # brace of the pre-serialized envelope — sampled
+                    # remote hops are rare (rate-bounded), so the
+                    # fast lane's zero-build path is untouched
+                    root.finish()
+                    payload = (payload[:-1] + b',"trace":'
+                               + json.dumps(
+                                   root.to_json(),
+                                   separators=(",", ":")).encode()
+                               + b"}")
+                if profile_out:
+                    # same splice as the trace graft: profiled
+                    # requests are rare debugging traffic, the
+                    # zero-build fast lane stays untouched
+                    payload = (payload[:-1] + b',"profile":'
+                               + json.dumps(
+                                   profile_out[0],
+                                   separators=(",", ":")).encode()
+                               + b"}")
+            else:  # r5-shaped legacy path (serve_fastlane = False)
+                out = self.api.query(index, pql, shards=shards,
+                                     remote=remote, opts=opts,
+                                     tenant=tenant, deadline=deadline,
+                                     profile_out=profile_out)
                 if root is not None and trace_hdr:
                     root.finish()
-                    trace_json = root.to_json()
-                payload = encode_results(results, trace=trace_json)
-                status = 200
-            except ApiError as e:
-                payload = encode_error(str(e))
-                status = e.status
-                retry_after = getattr(e, "retry_after", None)
+                    out["trace"] = root.to_json()
+                if profile_out:
+                    out["profile"] = profile_out[0]
+                # encode here (not via _json) so the legacy path
+                # bills egress like the fast lane does
+                with stage("result.encode"):
+                    payload = json.dumps(out).encode()
             self._note_egress(tenant, index, len(payload), remote)
+            # into the connection's write buffer; the one send happens
+            # when the handler returns (wbufsize), after the root span
+            # is recorded, so /debug/traces already holds a request's
+            # tree when its client reads the answer
+            with stage("http.write"):
+                self._raw(payload)
+            return
+        from pilosa_tpu.wire.serializer import (
+            encode_error,
+            encode_results,
+        )
+
+        retry_after = None
+        try:
+            results = self.api.query_raw(index, pql, shards=shards,
+                                         remote=remote, opts=opts,
+                                         tenant=tenant,
+                                         deadline=deadline,
+                                         profile_out=profile_out)
+            trace_json = None
+            if root is not None and trace_hdr:
+                root.finish()
+                trace_json = root.to_json()
+            with stage("result.encode"):
+                payload = encode_results(results, trace=trace_json)
+            status = 200
+        except ApiError as e:
+            payload = encode_error(str(e))
+            status = e.status
+            retry_after = getattr(e, "retry_after", None)
+        self._note_egress(tenant, index, len(payload), remote)
+        with stage("http.write"):
             self.send_response(status)
             self.send_header("Content-Type", "application/x-protobuf")
             self.send_header("Content-Length", str(len(payload)))
@@ -819,6 +835,28 @@ class HTTPHandler(BaseHTTPRequestHandler):
         # inspector gauges, and the slow-query ring's counter
         text += prometheus_block(self.api.observability_metrics(), prefix,
                                   seen=seen)
+        # the stage sites' always-on counters (docs/OBSERVABILITY.md
+        # "Stages"): rate(stage_<x>_seconds_total) / rate(stage_<x>_total)
+        # is the mean time a request spends in layer x; every stage
+        # present from scrape one. Then the device block: programs made
+        # executable (compiled or loaded from the persistent cache) and
+        # device memory as memory_stats() gives it now.
+        from pilosa_tpu.utils.tracing import (
+            device_memory_by_device,
+            device_metrics,
+            stage_metrics,
+        )
+
+        text += prometheus_block(stage_metrics(), prefix, "stage",
+                                 seen=seen)
+        text += prometheus_block(device_metrics(), prefix, "device",
+                                 seen=seen)
+        for d in device_memory_by_device():
+            tag = f'{{device="{d["device"]}"}}'
+            text += (f"{prefix}_device_memory_bytes_in_use{tag} "
+                     f"{d['bytes_in_use']}\n"
+                     f"{prefix}_device_memory_peak_bytes{tag} "
+                     f"{d['peak_bytes']}\n")
         # partition-tolerance plane (docs/OPERATIONS.md failure model):
         # epoch, quorum/degraded gauges, heartbeat + fencing counters
         text += prometheus_block(self.api.cluster_metrics(), prefix,
@@ -901,7 +939,7 @@ class HTTPHandler(BaseHTTPRequestHandler):
         from pilosa_tpu.utils.tracing import global_tracer
 
         tracer = global_tracer()
-        self._json({"enabled": tracer.enabled,
+        self._json({"enabled": tracer.sample_rate > 0.0,
                     "sampleRate": tracer.sample_rate,
                     "traces": tracer.recent()})
 
@@ -1088,6 +1126,10 @@ class HTTPHandler(BaseHTTPRequestHandler):
         snap["cdc"] = self.api.cdc_metrics()
         snap["integrity"] = self.api.integrity_metrics()
         snap["observability"] = self.api.observability_metrics()
+        from pilosa_tpu.utils.tracing import device_metrics, stage_metrics
+
+        snap["stages"] = stage_metrics()
+        snap["device"] = device_metrics()
         from pilosa_tpu.parallel.reduction import global_reduce_stats
 
         snap["dist_reduce"] = global_reduce_stats().snapshot()

@@ -30,6 +30,7 @@ import time
 from concurrent.futures import Future
 
 from pilosa_tpu.utils.cost import current_cost
+from pilosa_tpu.utils.tracing import stage, staged
 
 
 class _SharedDeferred:
@@ -119,8 +120,6 @@ class QueryPipeline:
         race-free). The API façade only passes a key for plain edge reads
         — no explicit shards, no deadline, no result options — where
         identical PQL strings are guaranteed identical requests."""
-        from pilosa_tpu.utils.tracing import global_tracer
-
         self._ensure_thread()
         now = time.monotonic()
         # benign races: both fields are plain floats read heuristically
@@ -133,7 +132,7 @@ class QueryPipeline:
         # instead of being orphaned on the pipeline thread
         ctx = contextvars.copy_context()
         self._q.put((index, query, kwargs, fut, key, ctx))
-        with global_tracer().span("pipeline.wave") as span:
+        with stage("pipeline.wave") as span:
             defs = fut.result()
             if span is not None:
                 span.tags["wave"] = getattr(fut, "wave_size", 1)
@@ -195,7 +194,8 @@ class QueryPipeline:
                     # submit under the REQUEST's captured context: spans
                     # and inspector updates started inside land in that
                     # request's trace, not on the dispatcher thread
-                    defs = ctx.run(executor.submit, index, q, **kwargs)
+                    defs = ctx.run(_submit_staged, executor, index, q,
+                                   kwargs)
                 except BaseException as e:
                     fut.set_exception(e)
                     continue
@@ -278,15 +278,28 @@ class QueryPipeline:
             note(item)
         deadline = time.monotonic() + self.GATHER_WINDOW_S
         try:
-            while unique < cap:
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    return
-                try:
-                    item = self._q.get(timeout=left)
-                except queue.Empty:
-                    return
-                wave.append(item)
-                note(item)
+            # the stage covers only the wait in the window: the greedy
+            # drain above costs nothing, this is what a wave pays for
+            # its wavemates
+            with stage("pipeline.gather"):
+                while unique < cap:
+                    left = deadline - time.monotonic()
+                    if left <= 0:
+                        return
+                    try:
+                        item = self._q.get(timeout=left)
+                    except queue.Empty:
+                        return
+                    wave.append(item)
+                    note(item)
         finally:
             self._last_wave_size = len(wave)
+
+
+@staged("pipeline.submit")
+def _submit_staged(executor, index, query, kwargs):
+    """One request's submit on the dispatcher thread, run under that
+    request's captured context so the stage (and everything nested in
+    it: plan, operands, dispatch) carries the request's id and joins
+    its trace."""
+    return executor.submit(index, query, **kwargs)

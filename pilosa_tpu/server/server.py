@@ -35,7 +35,6 @@ class ServerConfig:
         use_mesh: bool | None = None,
         mesh_groups: int = 0,
         topn_quantized_ranking: bool = False,
-        tracing: bool = False,
         trace_sample_rate: float = 0.0,
         trace_log_dir: str = "",
         diagnostics_endpoint: str = "",
@@ -128,12 +127,11 @@ class ServerConfig:
         # mesh"). Only meaningful with the mesh executor; harmless
         # (lossless pass-through) on a flat mesh.
         self.topn_quantized_ranking = bool(topn_quantized_ranking)
-        # Distributed tracing (docs/OBSERVABILITY.md): `tracing = true`
-        # is the legacy always-on switch (rate 1.0); `trace-sample-rate`
-        # sets probabilistic sampling directly (0 = off, zero-overhead).
-        # `trace-log-dir` is where POST /debug/trace-device writes live
-        # JAX profiler captures (default: <data-dir>/jax-traces).
-        self.tracing = tracing
+        # Distributed tracing (docs/OBSERVABILITY.md): `trace-sample-rate`
+        # sets probabilistic sampling of the span tree (0 = off; the
+        # stage counters are always on). `trace-log-dir` is where POST
+        # /debug/trace-device writes live JAX profiler captures
+        # (default: <data-dir>/jax-traces).
         self.trace_sample_rate = float(trace_sample_rate)
         if not 0.0 <= self.trace_sample_rate <= 1.0:
             raise ValueError(
@@ -414,7 +412,6 @@ class ServerConfig:
             heartbeat_timeout=_parse_duration(
                 d.get("heartbeat-timeout", d.get("heartbeat_timeout", 2.0))
             ),
-            tracing=_parse_bool(d.get("tracing", False)),
             trace_sample_rate=float(
                 d.get("trace-sample-rate", d.get("trace_sample_rate", 0.0))
             ),
@@ -592,7 +589,6 @@ class ServerConfig:
             "seeds": self.seeds,
             "heartbeat-interval": self.heartbeat_interval,
             "heartbeat-timeout": self.heartbeat_timeout,
-            "tracing": self.tracing,
             "trace-sample-rate": self.trace_sample_rate,
             "trace-log-dir": self.trace_log_dir,
             "diagnostics-endpoint": self.diagnostics_endpoint,
@@ -822,12 +818,14 @@ class Server:
         # tracer BEFORE the serving workers: each worker copies the
         # sample rate out of the handshake cfg, which reads the live
         # global tracer
-        from pilosa_tpu.utils.tracing import global_tracer
+        from pilosa_tpu.utils.tracing import (
+            global_tracer,
+            install_compile_listener,
+        )
 
-        rate = self.config.trace_sample_rate
-        if rate <= 0 and self.config.tracing:
-            rate = 1.0  # legacy `tracing = true`: always-on
-        global_tracer().sample_rate = rate
+        global_tracer().sample_rate = self.config.trace_sample_rate
+        # count compiles from the first query on (device_compiles_total)
+        install_compile_listener()
         if mp_workers:
             from pilosa_tpu.serving.mpserve import OwnerRuntime
 

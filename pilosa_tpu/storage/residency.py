@@ -43,7 +43,6 @@ from __future__ import annotations
 import threading
 import weakref
 from collections import OrderedDict
-from functools import partial
 from typing import Callable
 
 import jax
@@ -51,7 +50,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD, next_pow2
+from pilosa_tpu.utils.compile_cache import named_jit
 from pilosa_tpu.utils.cost import current_cost
+from pilosa_tpu.utils.tracing import stage, staged
 
 ROW_BYTES = WORDS_PER_SHARD * 4  # 128 KiB per resident row
 
@@ -76,18 +77,22 @@ PURGE = object()
 COMPRESS_MAX_OCCUPANCY = 0.5
 
 
-@partial(jax.jit, static_argnames=("block_words",))
 def _gather_blocks(arr, idx, block_words: int):
     """Compact the nonzero blocks of a flattened array: uint32[nb, bw]."""
     return arr.reshape(-1, block_words)[idx]
 
 
-@partial(jax.jit, static_argnames=("n_blocks", "block_words"))
 def _scatter_blocks(blocks, idx, n_blocks: int, block_words: int):
     """Inverse of _gather_blocks. ``idx`` may contain duplicates (padding
     repeats a real index with its real data — identical writes are safe)."""
     out = jnp.zeros((n_blocks, block_words), jnp.uint32)
     return out.at[idx].set(blocks).reshape(-1)
+
+
+_gather_blocks = named_jit("gather_blocks", _gather_blocks,
+                           static_argnames=("block_words",))
+_scatter_blocks = named_jit("scatter_blocks", _scatter_blocks,
+                            static_argnames=("n_blocks", "block_words"))
 
 
 class WriteEvent:
@@ -164,6 +169,30 @@ class _HostEntry:
         return n
 
 
+class _ContendedLock:
+    """The row cache's one lock, with its waiting measured: an
+    uncontended acquire costs what it did; only when the non-blocking
+    try fails is the blocking acquire timed, as stage
+    ``residency.lock_wait`` — so the stage's seconds are the time threads
+    spent queued behind the lock, and its count the contended
+    acquisitions. ``inner`` is the re-entrant lock itself (the build
+    condition waits on it)."""
+
+    __slots__ = ("inner",)
+
+    def __init__(self):
+        self.inner = threading.RLock()
+
+    def __enter__(self):
+        if not self.inner.acquire(blocking=False):
+            with stage("residency.lock_wait"):
+                self.inner.acquire()
+
+    def __exit__(self, *exc):
+        self.inner.release()
+        return False
+
+
 class DeviceRowCache:
     """Byte-budgeted two-tier LRU of device-resident arrays (dense rows,
     BSI plane matrices, mesh-sharded shard stacks — sized by actual
@@ -215,12 +244,12 @@ class DeviceRowCache:
         # of one field can't lose each other's read-modify-write of the
         # same leaf. Host decodes happen OUTSIDE the lock (see
         # get_or_build) so query misses don't serialize behind it.
-        self._lock = threading.RLock()
+        self._lock = _ContendedLock()
         # in-flight builds: key -> buffered write events, replayed onto
         # the entry after its unlocked decode (see get_or_build); the
         # condition lets concurrent builders of one key wait for the first
         self._pending_builds: dict[tuple, list] = {}
-        self._build_done = threading.Condition(self._lock)
+        self._build_done = threading.Condition(self._lock.inner)
 
     def __len__(self) -> int:
         return len(self._rows) + len(self._compressed)
@@ -349,7 +378,8 @@ class DeviceRowCache:
             # decode under the lock: plain get_row keys are per-fragment
             # (invalidated by their writers), so staleness isn't possible,
             # and single-row decodes are cheap
-            return self._put_locked(key, decode(), device_put)
+            with stage("residency.miss"):
+                return self._put_locked(key, decode(), device_put)
 
     def get_or_build(self, key: tuple, tag: tuple | None,
                      probe: Callable | None,
@@ -390,6 +420,12 @@ class DeviceRowCache:
                 # route this tag's writes into the buffer from now on
                 self._updaters[key] = (tag, probe())
                 self._tag_index.setdefault(tag, set()).add(key)
+        return self._build_missing(key, tag, decode, device_put, buf)
+
+    @staged("residency.miss")
+    def _build_missing(self, key, tag, decode, device_put, buf):
+        """The miss half of get_or_build: decode outside the lock,
+        upload and replay the buffered writes under it."""
         try:
             host = decode()  # slow host work, outside the lock
         except BaseException:
@@ -424,7 +460,8 @@ class DeviceRowCache:
                         break
                     entry = self._rows.get(key)
                     if entry is not None:
-                        entry.arr = apply(entry.arr)
+                        with stage("residency.patch"):
+                            entry.arr = apply(entry.arr)
                         entry.block_idx = None
                         arr = entry.arr
                 return arr
@@ -546,7 +583,8 @@ class DeviceRowCache:
                     continue
                 entry = self._rows.get(key)
                 if entry is not None:
-                    entry.arr = apply(entry.arr)
+                    with stage("residency.patch"):
+                        entry.arr = apply(entry.arr)
                     # occupancy may have changed; don't demote later
                     entry.block_idx = None
                     self.updates += 1

@@ -26,6 +26,18 @@ def cache_dir() -> str:
     return os.environ.get(ENV_VAR) or DEFAULT_DIR
 
 
+def named_jit(name: str, fn, **jit_kwargs):
+    """``jax.jit(fn)`` under a stable program name: XLA calls the module
+    ``jit_<name>``, which is what a profiler capture's ``XLA Modules``
+    line, a compile log and the lowered text show. Every program of the
+    served path goes through here so none of them reads ``jit_body``.
+    The name is part of the persistent cache's key."""
+    import jax
+
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
+
+
 def configure() -> str:
     """Point JAX's persistent compile cache at ``cache_dir()`` and cache
     every program, however quickly it compiled: the default 1 s

@@ -99,8 +99,9 @@ def prometheus_block(pairs: dict, prefix: str, subsystem: str = "",
         # ints emit exactly — %g would quantize large counters (byte
         # totals, request counts) to 6 significant digits and make
         # rate() stair-step (the residency exporter documented this
-        # hazard first)
-        rendered = value if isinstance(value, int) else f"{value:g}"
+        # hazard first); floats keep 12 digits for the same reason (a
+        # stage's cumulative seconds after a day of uptime)
+        rendered = value if isinstance(value, int) else f"{value:.12g}"
         lines.append(f"{family} {rendered}")
     return "\n".join(lines) + ("\n" if lines else "")
 

@@ -22,16 +22,27 @@ rewrite is the real thing, sized for the serving planes PRs 1-6 built:
 - **In-flight inspector**: ``QueryTracker`` (always on, lock-free stage
   updates) backs ``GET /debug/queries`` — upstream's long-running-query
   view: trace id, PQL, index, age, current stage, shards outstanding.
+- **One stage site, four sinks**: ``stage(name)`` is the context manager
+  every layer boundary of the served path uses (``STAGES`` is the list).
+  One pair of clock reads feeds the always-on cumulative counters
+  (``/metrics`` ``pilosa_tpu_stage_*``, ``/debug/vars`` ``stages``), the
+  sampled span tree, a ``jax.profiler.TraceAnnotation`` while a device
+  capture runs, and the inspector's ``stage``.
 
 On TPU the device-side story stays the JAX profiler; ``start_jax_trace``
-wraps ``jax.profiler`` and is exposed live at ``POST /debug/trace-device``.
+wraps ``jax.profiler`` (Python tracer off, so the capture leaves the host
+alone) and is exposed live at ``POST /debug/trace-device``;
+``trace_report`` reads a capture back (``python -m pilosa_tpu
+trace-report``).
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import random
+import re
 import threading
 import time
 from collections import deque
@@ -63,13 +74,13 @@ class Span:
 
     def __init__(self, name: str, tags: dict | None = None,
                  trace_id: str | None = None, parent: "Span | None" = None,
-                 parent_id: str | None = None):
+                 parent_id: str | None = None, start: float | None = None):
         self.name = name
         self.trace_id = trace_id or _new_trace_id()
         self.span_id = _new_span_id()
         self.parent = parent
         self.parent_id = parent.span_id if parent is not None else parent_id
-        self.start = time.perf_counter()
+        self.start = time.perf_counter() if start is None else start
         self.end = None
         self.tags = tags if tags is not None else {}
         self.children: list[Span] = []
@@ -209,25 +220,13 @@ def use_span(span: Span):
 class Tracer:
     """Sampled, context-propagating tracer; keeps the last N root trees."""
 
-    def __init__(self, enabled: bool = False, keep: int = 64,
-                 sample_rate: float | None = None):
-        # legacy constructor surface: enabled=True meant always-on
-        self.sample_rate = (sample_rate if sample_rate is not None
-                            else (1.0 if enabled else 0.0))
+    def __init__(self, keep: int = 64, sample_rate: float = 0.0):
+        self.sample_rate = sample_rate
         self.keep = keep
         self._lock = threading.Lock()
         self.finished: deque = deque(maxlen=keep)
         self.sampled_traces = 0
         self.spans_started = 0
-
-    # legacy boolean surface (server config `tracing = true`, old tests)
-    @property
-    def enabled(self) -> bool:
-        return self.sample_rate > 0.0
-
-    @enabled.setter
-    def enabled(self, value: bool) -> None:
-        self.sample_rate = 1.0 if value else 0.0
 
     # ------------------------------------------------------------ span sites
 
@@ -239,11 +238,15 @@ class Tracer:
         never root standalone trees off background traffic — only the
         designated root sites (``request_root``, ``remote_root``,
         ``root_span``) start traces."""
+        return self._join(name, tags, None)
+
+    def _join(self, name: str, tags: dict, start: float | None):
         cur = _current_span.get()
         if cur is None or cur is _NOT_SAMPLED:
             return _NOP
         self.spans_started += 1
-        span = Span(name, tags, trace_id=cur.trace_id, parent=cur)
+        span = Span(name, tags, trace_id=cur.trace_id, parent=cur,
+                    start=start)
         cur.children.append(span)
         return _SpanHandle(self, span)
 
@@ -457,12 +460,22 @@ class QueryTracker:
             (pql[:1024] if isinstance(pql, str) else str(pql)[:1024]),
             tenant, remote, cur.trace_id if cur is not None else None,
         )
+        rid = _request_id.get()
+        with self._lock:
+            if not rid or rid in self._live:
+                self._next += 1
+                rid = self._next
+            q.qid = rid
+            self.started_total += 1
+            self._live[rid] = q
+        return q
+
+    def reserve_id(self) -> int:
+        """An id for a request whose record does not exist yet (the
+        root stage reserves it; ``start`` adopts it as the qid)."""
         with self._lock:
             self._next += 1
-            q.qid = self._next
-            self.started_total += 1
-            self._live[q.qid] = q
-        return q
+            return self._next
 
     def activate(self, q: InflightQuery):
         """Bind ``q`` to the current context; returns a reset token."""
@@ -500,18 +513,493 @@ def global_query_tracker() -> QueryTracker:
     return _global_query_tracker
 
 
+# --------------------------------------------------------------- stage site
+#
+# The contract of names (docs/OBSERVABILITY.md "Stages"): PERF.md and
+# the benchmark's metric files read the series by these names.
+
+# The flat partition of a request on its handler thread ...
+TOP_LEVEL_STAGES = (
+    "http.read", "qos.admit", "pql.parse", "pipeline.wave",
+    "executor.execute", "executor.resolve", "result.encode", "wal.barrier",
+    "http.write",
+)
+# ... under the root, and the stages nested in them or on other threads.
+STAGES = ("http.query",) + TOP_LEVEL_STAGES + (
+    "pipeline.gather", "pipeline.submit", "executor.plan",
+    "executor.operands", "residency.miss", "residency.patch",
+    "residency.lock_wait", "device.upload", "device.dispatch",
+    "device.readback", "fragment.write",
+)
+
+
+class _StageCounter:
+    """Entries and nanoseconds of one stage, exact under threads (both
+    are only ever changed under ``_lock``)."""
+
+    __slots__ = ("count", "ns", "_lock")
+
+    def __init__(self):
+        self.count = 0
+        self.ns = 0
+        self._lock = threading.Lock()
+
+
+_clock_ns = time.perf_counter_ns
+
+_stage_counters: dict[str, _StageCounter] = {n: _StageCounter()
+                                             for n in STAGES}
+_stage_registry_lock = threading.Lock()
+
+# jax.profiler.TraceAnnotation while a device capture runs, else None:
+# start_jax_trace sets it before start_trace and clears it after
+# stop_trace, so a site pays for an annotation only inside a capture.
+_annotation = None
+
+# The id every stage of one HTTP request carries into the capture
+# (``rid``): reserved by the root stage, adopted by QueryTracker.start
+# as the inspector's qid, so /debug/queries and the xplane agree.
+_request_id: contextvars.ContextVar = contextvars.ContextVar(
+    "pilosa_tpu_request_id", default=0
+)
+
+
+class _Stage:
+    """One entry of a stage; see ``stage``."""
+
+    __slots__ = ("name", "elapsed", "_counter", "_tags", "_root", "_cm",
+                 "_t0", "_ann", "_query", "_prev", "_rid_token")
+
+    def __init__(self, name: str, counter: _StageCounter, root, tags: dict):
+        self.name = name
+        self.elapsed = 0.0  # seconds, set on exit
+        self._counter = counter
+        self._tags = tags
+        self._root = root
+        self._ann = None
+        self._rid_token = None
+
+    def __enter__(self) -> Span | None:
+        name = self.name
+        cm = self._root
+        if cm is not None:
+            self._rid_token = _request_id.set(
+                global_query_tracker().reserve_id())
+        q = self._query = _current_query.get()
+        if q is not None:
+            self._prev = q.stage
+            q.stage = name
+        annotate = _annotation
+        if annotate is not None:
+            self._ann = annotate(
+                name, rid=q.qid if q is not None else _request_id.get())
+            self._ann.__enter__()
+        self._t0 = t0 = _clock_ns()
+        if cm is None:
+            # join-only, checked here so that the common case (no sampled
+            # trace) costs one contextvar read and no call
+            cur = _current_span.get()
+            cm = (_NOP if cur is None or cur is _NOT_SAMPLED
+                  else global_tracer()._join(name, self._tags, t0 * 1e-9))
+        self._cm = cm
+        if cm is _NOP:
+            return None
+        span = cm.__enter__()
+        if span is not None:
+            span.start = t0 * 1e-9
+        return span
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = _clock_ns()
+        ns = t1 - self._t0
+        self.elapsed = ns * 1e-9
+        counter = self._counter
+        with counter._lock:
+            counter.count += 1
+            counter.ns += ns
+        cm = self._cm
+        if cm is not _NOP:
+            span = getattr(cm, "_span", None)
+            if span is not None and span.end is None:
+                span.end = t1 * 1e-9
+            cm.__exit__(exc_type, exc, tb)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        q = self._query
+        if q is not None:
+            q.stage = self._prev
+        if self._rid_token is not None:
+            _request_id.reset(self._rid_token)
+        return False
+
+
+def stage(name: str, _root=None, /, **tags) -> _Stage:
+    """The one site every layer boundary of the served path uses.
+
+    ``with stage("device.dispatch", reduce=kind) as span:`` reads the
+    clock twice and feeds four sinks: (1) the always-on cumulative
+    counters of ``name`` (entries and seconds, exact under threads);
+    (2) the sampled span tree — join-only, ``span`` is the child Span
+    or None outside a sampled trace; (3) a
+    ``jax.profiler.TraceAnnotation(name, rid=<request id>)`` while a
+    device capture runs, which puts the stage on the profiler's own
+    clock, on the line of the thread that did the work; (4) the
+    in-flight inspector's ``stage`` (restored to the enclosing stage on
+    exit). With no sampled trace and no capture it allocates neither a
+    Span nor an annotation. ``handle.elapsed`` holds the seconds after
+    exit (the cost plane's dispatch timer reads it).
+
+    ``_root`` is a root handle (``Tracer.request_root`` /
+    ``remote_root``) for the one stage that is also the trace's root:
+    it makes the sampling decision and reserves the request id."""
+    counter = _stage_counters.get(name)
+    if counter is None:
+        with _stage_registry_lock:
+            counter = _stage_counters.setdefault(name, _StageCounter())
+    return _Stage(name, counter, _root, tags)
+
+
+def staged(name: str):
+    """Decorator form of ``stage`` for a function that is one stage."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with stage(name):
+                return fn(*args, **kwargs)
+
+        return wrapper
+
+    return deco
+
+
+def stage_metrics() -> dict:
+    """``<stage>_total`` and ``<stage>_seconds_total`` (dots to
+    underscores) for every stage, zeros included: the ``stage`` block of
+    /metrics and group ``stages`` of /debug/vars."""
+    out: dict = {}
+    for name, c in list(_stage_counters.items()):
+        key = name.replace(".", "_")
+        with c._lock:
+            out[f"{key}_total"] = c.count
+            out[f"{key}_seconds_total"] = c.ns * 1e-9
+    return out
+
+
+# ------------------------------------------------- device compiles, memory
+
+_compile_lock = threading.Lock()
+_compile_stats = {"compiles": 0, "compile_ns": 0, "cache_loads": 0}
+_compile_listener_installed = False
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+def _on_jax_duration(event: str, duration: float, **_kw) -> None:
+    if event == _BACKEND_COMPILE_EVENT:
+        with _compile_lock:
+            _compile_stats["compiles"] += 1
+            _compile_stats["compile_ns"] += int(duration * 1e9)
+    elif event == _CACHE_LOAD_EVENT:
+        with _compile_lock:
+            _compile_stats["cache_loads"] += 1
+
+
+def install_compile_listener() -> None:
+    """Count every program JAX makes executable from here on (a backend
+    compile or a persistent-cache load; loads are counted apart too).
+    Idempotent; Server.open calls it before the first query."""
+    global _compile_listener_installed
+    with _compile_lock:
+        if _compile_listener_installed:
+            return
+        _compile_listener_installed = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def device_metrics() -> dict:
+    """The ``device`` block of /metrics and /debug/vars: programs made
+    executable (and the seconds that took, and how many came from the
+    persistent cache), and device memory summed over the local devices
+    as ``memory_stats()`` gives it at scrape (0 where the backend gives
+    none, as the CPU does)."""
+    install_compile_listener()
+    memory = device_memory_by_device()
+    with _compile_lock:
+        return {
+            "compiles_total": _compile_stats["compiles"],
+            "compile_seconds_total": _compile_stats["compile_ns"] * 1e-9,
+            "compile_cache_loads_total": _compile_stats["cache_loads"],
+            "memory_bytes_in_use": sum(d["bytes_in_use"] for d in memory),
+            "memory_peak_bytes": sum(d["peak_bytes"] for d in memory),
+        }
+
+
+def device_memory_by_device() -> list[dict]:
+    """Per local device: id, bytes in use and peak (the labelled
+    gauges beside the unlabelled sums)."""
+    import jax
+
+    out = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        out.append({"device": str(d.id),
+                    "bytes_in_use": int(stats.get("bytes_in_use", 0)),
+                    "peak_bytes": int(stats.get("peak_bytes_in_use", 0))})
+    return out
+
+
 # ----------------------------------------------------------- device tracing
 
 
 @contextlib.contextmanager
 def start_jax_trace(log_dir: str):
     """Capture an XLA/JAX profiler trace around a block (TPU-side tracing;
-    view with xprof/tensorboard). Live capture around real traffic is
-    exposed at ``POST /debug/trace-device?secs=N`` (server/http.py)."""
+    view with xprof/tensorboard, or ``python -m pilosa_tpu trace-report``).
+    Live capture around real traffic is exposed at
+    ``POST /debug/trace-device?secs=N`` (server/http.py).
+
+    The Python tracer is off (``python_tracer_level = 0``): at its
+    default it hooks every Python call of every thread and halves the
+    rate of the server it observes. The host tracer keeps its level, so
+    the stage sites' ``TraceAnnotation``s are recorded; they are
+    switched on for exactly the capture's span."""
+    global _annotation
     import jax
 
-    jax.profiler.start_trace(log_dir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    _annotation = jax.profiler.TraceAnnotation
     try:
-        yield
+        jax.profiler.start_trace(log_dir, profiler_options=options)
+        try:
+            yield
+        finally:
+            jax.profiler.stop_trace()
     finally:
-        jax.profiler.stop_trace()
+        _annotation = None
+
+
+# ------------------------------------------------------------- trace report
+#
+# The operator's reading of a capture (``python -m pilosa_tpu
+# trace-report <trace-log-dir>``); the labelling rule is written down in
+# docs/OBSERVABILITY.md "Reading a capture".
+
+_DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+_HOST_PLANE = "/host:CPU"
+_CPU_OPS_LINE = re.compile(r"^tf_XLA|^XLA")  # a CPU capture's stand-in
+_HLO = re.compile(r"^%?([\w.\-]+) = (\(?)([a-z0-9]+\[[0-9,]*\])")
+# A stage that only waits for another thread of this process; it labels
+# a gap only when nothing else does (the thread it waits for says why).
+WAIT_STAGES = ("pipeline.wave",)
+LABEL_MIN_SHARE = 0.10
+
+
+def _op_name(text: str) -> str:
+    """An operation's XLA name and result shape from the HLO text the
+    TPU's trace carries as the event name: ``fusion.36 u32[128,12,2048]``."""
+    m = _HLO.match(text)
+    if not m:
+        return text[:80]
+    name, is_tuple, shape = m.groups()
+    if is_tuple:
+        shape += f"x{text.split(') ', 1)[0].count('[')}"
+    return f"{name} {shape}"
+
+
+def _merged(intervals) -> list[tuple[float, float]]:
+    out: list[list[float]] = []
+    for a, b in sorted(intervals):
+        if out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        else:
+            out.append([a, b])
+    return [(a, b) for a, b in out]
+
+
+def _innermost(events) -> list[tuple[float, float, str]]:
+    """One thread's nested stage events [(start, end, name)] as a flat
+    timeline in which every instant belongs to the innermost stage."""
+    out: list[tuple[float, float, str]] = []
+    stack: list[tuple[float, str]] = []
+    cursor = 0.0
+    for start, end, name in sorted(events, key=lambda e: (e[0], -e[1])):
+        while stack and stack[-1][0] <= start:
+            e_end, e_name = stack.pop()
+            if cursor < e_end:
+                out.append((cursor, e_end, e_name))
+                cursor = e_end
+        if stack and cursor < start:
+            out.append((cursor, start, stack[-1][1]))
+        cursor = start
+        stack.append((end, name))
+    while stack:
+        e_end, e_name = stack.pop()
+        if cursor < e_end:
+            out.append((cursor, e_end, e_name))
+            cursor = e_end
+    return out
+
+
+def label_gap(gap: tuple[float, float], timelines) -> tuple[str, float, list]:
+    """What the host was doing in one device idle gap: per stage, the
+    union over all host threads of the time the stage was the innermost
+    one on its thread inside the gap, as a share of the gap. The label
+    is the stage with the largest share (among equal shares, the one
+    more threads sat in); a wait stage (WAIT_STAGES) labels the gap only
+    when no other stage reaches LABEL_MIN_SHARE; ``no-request`` when
+    none does. Returns (label, share, every stage over the threshold as
+    [name, share], largest first)."""
+    a, b = gap
+    length = b - a
+    if length <= 0:
+        return "no-request", 0.0, []
+    per_stage: dict[str, list] = {}
+    for timeline in timelines:
+        for s, e, name in timeline:
+            if e > a and s < b:
+                per_stage.setdefault(name, []).append((max(s, a), min(e, b)))
+    shares = sorted(
+        ((round(sum(y - x for x, y in _merged(iv)) / length, 4),
+          sum(y - x for x, y in iv), name)
+         for name, iv in per_stage.items()), reverse=True)
+    shares = [(sh, n) for sh, _, n in shares if sh >= LABEL_MIN_SHARE]
+    ranked = [[n, sh] for sh, n in shares]
+    active = [(sh, n) for sh, n in shares if n not in WAIT_STAGES] or shares
+    if not active:
+        return "no-request", 0.0, ranked
+    share, name = active[0]
+    return name, share, ranked
+
+
+def trace_report(log_dir: str, gaps_n: int = 5, top_n: int = 10) -> dict:
+    """Reduce the newest ``.xplane.pb`` under ``log_dir`` (or the file
+    itself): per device the busy share of its traced extent, seconds per
+    XLA module and per operation, and its ``gaps_n`` longest idle gaps,
+    each labelled by what the host was doing (``label_gap``); and the
+    host threads' seconds by innermost stage, summed over threads."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = log_dir
+    if os.path.isdir(log_dir):
+        paths = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                          recursive=True)
+        if not paths:
+            raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
+        path = max(paths, key=os.path.getmtime)
+    data = ProfileData.from_file(path)
+    planes = list(data.planes)
+    known = set(_stage_counters)
+
+    timelines = []
+    python_events = 0
+    for plane in planes:
+        if plane.name != _HOST_PLANE:
+            continue
+        for line in plane.lines:
+            events = []
+            for e in line.events:
+                if e.name in known:
+                    events.append((e.start_ns * 1e-9,
+                                   (e.start_ns + e.duration_ns) * 1e-9,
+                                   e.name))
+                elif e.name.startswith("$"):
+                    python_events += 1  # the Python tracer's frames
+            if events:
+                timelines.append(_innermost(events))
+
+    def device_lines(plane, cpu: bool):
+        ops, modules = [], []
+        for line in plane.lines:
+            is_ops = (bool(_CPU_OPS_LINE.match(line.name)) if cpu
+                      else line.name == "XLA Ops")
+            is_mod = not cpu and line.name == "XLA Modules"
+            if not (is_ops or is_mod):
+                continue
+            for e in line.events:
+                if cpu and not e.duration_ns:
+                    continue  # thread-pool markers, not operations
+                rec = (e.start_ns * 1e-9,
+                       (e.start_ns + e.duration_ns) * 1e-9, e.name)
+                (ops if is_ops else modules).append(rec)
+        return ops, modules
+
+    devices = [(p.name, *device_lines(p, False)) for p in planes
+               if _DEVICE_PLANE.match(p.name)]
+    if not devices:
+        devices = [(p.name, *device_lines(p, True)) for p in planes
+                   if p.name == _HOST_PLANE]
+    out_devices = []
+    for name, ops, modules in devices:
+        if not ops:
+            continue
+        busy = _merged((s, e) for s, e, _ in ops)
+        first, last = busy[0][0], busy[-1][1]
+        busy_s = sum(b - a for a, b in busy)
+        by_op: dict[str, float] = {}
+        for s, e, n in ops:
+            n = _op_name(n)
+            by_op[n] = by_op.get(n, 0.0) + (e - s)
+        by_module: dict[str, float] = {}
+        for s, e, n in modules:
+            n = n.split("(", 1)[0]
+            by_module[n] = by_module.get(n, 0.0) + (e - s)
+        gaps = sorted(((b0, a1) for (_, b0), (a1, _) in zip(busy, busy[1:])),
+                      key=lambda g: g[0] - g[1])[:gaps_n]
+        labelled = []
+        for gap in gaps:
+            label, share, ranked = label_gap(gap, timelines)
+            labelled.append({"seconds": gap[1] - gap[0],
+                             "at_s": gap[0] - first, "label": label,
+                             "share": share, "stages": ranked})
+        out_devices.append({
+            "device": name,
+            "extent_s": last - first,
+            "busy_s": busy_s,
+            "busy_share": busy_s / (last - first) if last > first else 0.0,
+            "modules": sorted(by_module.items(), key=lambda kv: -kv[1])[:top_n],
+            "ops": sorted(by_op.items(), key=lambda kv: -kv[1])[:top_n],
+            "idle_gaps": labelled,
+        })
+    host: dict[str, float] = {}
+    for timeline in timelines:
+        for s, e, n in timeline:
+            host[n] = host.get(n, 0.0) + (e - s)
+    return {"file": path, "host_threads_with_stages": len(timelines),
+            "python_tracer_events": python_events,
+            "host_stage_thread_s": sorted(host.items(),
+                                          key=lambda kv: -kv[1]),
+            "devices": out_devices}
+
+
+def format_trace_report(report: dict) -> str:
+    lines = [f"capture {report['file']}",
+             f"host threads with stage annotations: "
+             f"{report['host_threads_with_stages']}; Python-tracer events: "
+             f"{report['python_tracer_events']}"]
+    if report["host_stage_thread_s"]:
+        lines.append("host thread-seconds by innermost stage: " + ", ".join(
+            f"{n} {s:.3f}" for n, s in report["host_stage_thread_s"]))
+    if not report["devices"]:
+        lines.append("no operation ran on any device in this capture")
+    for d in report["devices"]:
+        lines.append(f"{d['device']}: busy {100 * d['busy_share']:.1f} % of "
+                     f"{d['extent_s']:.3f} s ({d['busy_s']:.3f} s)")
+        for title, key in (("XLA module", "modules"), ("operation", "ops")):
+            for name, seconds in d[key]:
+                lines.append(f"  {title} {name}: {seconds:.4f} s")
+        for g in d["idle_gaps"]:
+            also = ", ".join(f"{n} {100 * sh:.0f}%" for n, sh in g["stages"]
+                             if n != g["label"])
+            label = (g["label"] if g["label"] == "no-request"
+                     else f"{g['label']} {100 * g['share']:.0f}%")
+            lines.append(f"  idle gap {g['seconds']:.4f} s at "
+                         f"+{g['at_s']:.3f} s: {label}"
+                         + (f" (also {also})" if also else ""))
+    return "\n".join(lines)

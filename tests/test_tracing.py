@@ -254,7 +254,7 @@ class TestSingleNode:
             entry = q["queries"][0]
             assert entry["pql"] == "Count(Row(f=1))"
             assert entry["index"] == "i"
-            assert entry["stage"] == "admission"
+            assert entry["stage"] == "qos.admit"  # the stage site's name
             assert entry["ageSeconds"] >= 0
             release.set()
             worker.join(30)
@@ -545,11 +545,14 @@ class TestConfigKnobs:
         with pytest.raises(ValueError):
             ServerConfig(trace_sample_rate=-0.1)
 
-    def test_legacy_tracing_bool_means_rate_one(self, tmp_path):
+    def test_sample_rate_one_means_always_on(self, tmp_path):
+        # the legacy `tracing` boolean is gone (ROADMAP D3): rate 1.0 is
+        # how a config says always-on
         from pilosa_tpu.server import Server, ServerConfig
 
+        assert "tracing" not in ServerConfig().to_dict()
         s = Server(ServerConfig(
-            data_dir=str(tmp_path / "n"), port=0, tracing=True,
+            data_dir=str(tmp_path / "n"), port=0, trace_sample_rate=1.0,
             anti_entropy_interval=0, heartbeat_interval=0,
         )).open()
         try:
