@@ -193,53 +193,56 @@ def host_planes(idx, spec, shard: int, depth: int) -> np.ndarray:
 # the next query to re-decode and re-upload its whole working set.
 
 
-def _or_delta(arr, slot, word_idx, masks):
-    """OR sparse word masks into one shard slot of a [S, W] leaf.
-    word_idx is host-deduplicated; padding repeats (0, mask 0), which
-    .at[].max resolves correctly against any real mask for word 0."""
-    delta = jnp.zeros((arr.shape[-1],), jnp.uint32).at[word_idx].max(masks)
-    return arr.at[slot].set(arr[slot] | delta)
+def _delta_patch(combine):
+    """Body of the four delta programs: combine sparse word masks into
+    one row of a leaf — row (slot,) of a [S, W] leaf, (slot, row) of a
+    [S, R, W] matrix. ``args`` is _delta_args' one array: the row index,
+    then n word indices, then their n masks. Padding repeats (word 0,
+    mask 0), which .at[].max resolves correctly against any real mask
+    for word 0."""
+
+    def patch(arr, args):
+        n_at = arr.ndim - 1
+        n = (args.shape[0] - n_at) // 2
+        at = tuple(args[i].astype(jnp.int32) for i in range(n_at))
+        word_idx = args[n_at:n_at + n].astype(jnp.int32)
+        delta = jnp.zeros((arr.shape[-1],), jnp.uint32).at[word_idx].max(
+            args[n_at + n:])
+        return arr.at[at].set(combine(arr[at], delta))
+
+    return patch
 
 
-def _andnot_delta(arr, slot, word_idx, masks):
-    delta = jnp.zeros((arr.shape[-1],), jnp.uint32).at[word_idx].max(masks)
-    return arr.at[slot].set(arr[slot] & ~delta)
+def _andnot(words, delta):
+    return words & ~delta
 
 
-def _or_delta_row(arr, slot, row, word_idx, masks):
-    """Same for one row of a [S, R, W] matrix leaf."""
-    delta = jnp.zeros((arr.shape[-1],), jnp.uint32).at[word_idx].max(masks)
-    return arr.at[slot, row].set(arr[slot, row] | delta)
+_or_delta = named_jit("or_delta", _delta_patch(jnp.bitwise_or))
+_andnot_delta = named_jit("andnot_delta", _delta_patch(_andnot))
+_or_delta_row = named_jit("or_delta_row", _delta_patch(jnp.bitwise_or))
+_andnot_delta_row = named_jit("andnot_delta_row", _delta_patch(_andnot))
 
 
-def _andnot_delta_row(arr, slot, row, word_idx, masks):
-    delta = jnp.zeros((arr.shape[-1],), jnp.uint32).at[word_idx].max(masks)
-    return arr.at[slot, row].set(arr[slot, row] & ~delta)
-
-
-_or_delta = named_jit("or_delta", _or_delta)
-_andnot_delta = named_jit("andnot_delta", _andnot_delta)
-_or_delta_row = named_jit("or_delta_row", _or_delta_row)
-_andnot_delta_row = named_jit("andnot_delta_row", _andnot_delta_row)
-
-
-def _word_masks(positions) -> tuple[np.ndarray, np.ndarray]:
-    """In-shard positions → (unique word indices, OR-combined masks),
-    padded to the next power of two so delta scatters compile O(log n)
-    distinct shapes."""
+def _delta_args(at: tuple, positions) -> np.ndarray:
+    """One patch's arguments as ONE host array, uint32[len(at) + 2n]:
+    the row index ``at``, then the unique word indices of the in-shard
+    ``positions`` and their OR-combined masks, each padded to the next
+    power of two so delta scatters compile O(log n) distinct shapes.
+    One array because every host argument of a program call is a
+    transfer of its own, and the calling thread gives up the interpreter
+    for each: measured on the chip beside one busy thread, a call with
+    a python int and two arrays waits four times for it, a call with
+    one array twice (PERF.md, PR 26)."""
     positions = np.asarray(positions, np.uint32)
-    words = (positions >> 5).astype(np.int32)
+    words = positions >> 5
     bits = np.uint32(1) << (positions & np.uint32(31))
     uw = np.unique(words)
-    masks = np.zeros(uw.size, np.uint32)
-    idx = np.searchsorted(uw, words)
-    np.bitwise_or.at(masks, idx, bits)
     n = next_pow2(max(uw.size, 1))
-    out_w = np.zeros(n, np.int32)
-    out_m = np.zeros(n, np.uint32)
-    out_w[: uw.size] = uw
-    out_m[: uw.size] = masks
-    return out_w, out_m
+    out = np.zeros(len(at) + 2 * n, np.uint32)
+    out[: len(at)] = at
+    out[len(at): len(at) + uw.size] = uw
+    np.bitwise_or.at(out[len(at) + n:], np.searchsorted(uw, words), bits)
+    return out
 
 
 def _patch_sharded(arr, slot: int, make_patch):
@@ -288,6 +291,11 @@ def _make_probe(block: ShardBlock, match, row_pos_of, decode_row,
     writes to fragment owners, which the slot layout mirrors; a process
     observing a foreign shard's write has nothing local to patch (its
     pieces don't contain that slot) and leaves its handle untouched.
+
+    The probe itself runs under the row cache's lock and only looks
+    things up; the closure it returns does the numpy (word masks, row
+    decode) and the device work when the cache applies it, with the lock
+    free (residency._patch_routed), possibly more than once.
     """
     slot_of = {s: i for i, s in enumerate(block.shards)}
     per_piece = not block.patchable
@@ -302,35 +310,30 @@ def _make_probe(block: ShardBlock, match, row_pos_of, decode_row,
             # addressable pieces contain that slot — nothing local to do
             return None
         row_pos = row_pos_of(ev) if row_pos_of is not None else None
-        if ev.added or (ev.added is False and delta_on_clear):
-            if ev.positions is not None:
-                word_idx, masks = _word_masks(ev.positions)
-                if row_pos is None:
-                    fn = _or_delta if ev.added else _andnot_delta
-                    if per_piece:
-                        return lambda arr: _patch_sharded(
-                            arr, slot,
-                            lambda piece, r: fn(piece, r, word_idx, masks),
-                        )
-                    return lambda arr: fn(arr, slot, word_idx, masks)
-                fn = _or_delta_row if ev.added else _andnot_delta_row
-                if per_piece:
-                    return lambda arr: _patch_sharded(
-                        arr, slot,
-                        lambda piece, r: fn(piece, r, row_pos, word_idx,
-                                            masks),
-                    )
-                return lambda arr: fn(arr, slot, row_pos, word_idx, masks)
-
-        def set_row(arr_or_piece, r):
-            new = jnp.asarray(decode_row(ev))
+        if ev.positions is not None and (
+                ev.added or (ev.added is False and delta_on_clear)):
             if row_pos is None:
-                return arr_or_piece.at[r].set(new)
-            return arr_or_piece.at[r, row_pos].set(new)
+                fn = _or_delta if ev.added else _andnot_delta
+                at = ()
+            else:
+                fn = _or_delta_row if ev.added else _andnot_delta_row
+                at = (row_pos,)
+            args = {}  # built when first applied, kept for a retry
+
+            def patch(arr_or_piece, r):
+                if r not in args:
+                    args[r] = _delta_args((r, *at), ev.positions)
+                return fn(arr_or_piece, args[r])
+        else:
+            def patch(arr_or_piece, r):
+                new = jnp.asarray(decode_row(ev))
+                if row_pos is None:
+                    return arr_or_piece.at[r].set(new)
+                return arr_or_piece.at[r, row_pos].set(new)
 
         if per_piece:
-            return lambda arr: _patch_sharded(arr, slot, set_row)
-        return lambda arr: set_row(arr, slot)
+            return lambda arr: _patch_sharded(arr, slot, patch)
+        return lambda arr: patch(arr, slot)
 
     return probe
 

@@ -119,6 +119,10 @@ class Fragment:
         # clusters, embedded multi-server) — same-named fragments on
         # different holders hold DIFFERENT replicas' data
         self.frag_id = (scope, index, field, view, shard)
+        # view.view_name_bsi(field): plane matrices are only ever cached
+        # for a BSI view's fragments (_PlanesSpec.resolve), so only
+        # their writes look for any (_after_row_write)
+        self._bsi_view = view == f"bsig_{field}"
         self.bitmap = RoaringBitmap()
         self.op_n = 0
         # monotonic content version: bumped on every mutation (see
@@ -810,16 +814,18 @@ class Fragment:
         """Invalidate this fragment's own device entries and route the
         write to dependent stacked leaves for in-place patching (instead
         of the old global generation purge — one Set() must not evict
-        unrelated resident leaves). Batch paths pass ``row_count`` from
-        one shared ``row_counts()`` metadata pass; point writes leave it
-        None and pay one ``count_row``."""
-        cache = residency.global_row_cache()
-        cache.invalidate(self.frag_id + (row,))
-        cache.invalidate_fragment(self.frag_id + ("__planes__",))
-        cache.apply_write(residency.WriteEvent(
-            self.index, self.field, self.view, self.shard, row,
-            positions=positions, added=added, scope=self.scope,
-        ))
+        unrelated resident leaves), in one call of the row cache: the
+        patched leaves are swapped in when it returns. Batch paths pass
+        ``row_count`` from one shared ``row_counts()`` metadata pass;
+        point writes leave it None and pay one ``count_row``."""
+        residency.global_row_cache().row_written(
+            self.frag_id,
+            residency.WriteEvent(
+                self.index, self.field, self.view, self.shard, row,
+                positions=positions, added=added, scope=self.scope,
+            ),
+            planes=self._bsi_view,
+        )
         if row_count is None:
             row_count = self.count_row(row)
         self.row_cache.add(row, row_count)

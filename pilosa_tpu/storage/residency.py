@@ -221,6 +221,9 @@ class DeviceRowCache:
         self.tier_promotions = 0  # host -> dense (lookup or pass)
         self.tier_demotions = 0  # dense/compressed -> host
         self.updates = 0  # in-place scatter updates of derived entries
+        # patches dispatched again because another writer swapped the
+        # same leaf first (_patch_routed)
+        self.patch_retries = 0
         self.write_events = 0  # fragment mutations routed through apply_write
         # Snapshot validity counter: bumped whenever an entry is removed
         # or a dense array replaced (write patch, invalidate, evict,
@@ -239,11 +242,13 @@ class DeviceRowCache:
         # fragment mutation to exactly the tagged entries
         self._updaters: dict[tuple, tuple[tuple, Callable]] = {}
         self._tag_index: dict[tuple, set[tuple]] = {}
-        # One lock for all bookkeeping. Writers patch entries under it
-        # (apply_write), so two concurrent writes to different fragments
-        # of one field can't lose each other's read-modify-write of the
-        # same leaf. Host decodes happen OUTSIDE the lock (see
-        # get_or_build) so query misses don't serialize behind it.
+        # One lock, for dictionary bookkeeping. Neither a write's device
+        # patch nor a miss's host decode runs under it: a writer
+        # dispatches its patch on the array it saw and swaps the result
+        # in only if the entry still holds that array (_patch_routed),
+        # so two concurrent writes to different fragments of one field
+        # can't lose each other's read-modify-write of the same leaf;
+        # a miss decodes first and replays what it missed (get_or_build).
         self._lock = _ContendedLock()
         # in-flight builds: key -> buffered write events, replayed onto
         # the entry after its unlocked decode (see get_or_build); the
@@ -491,29 +496,36 @@ class DeviceRowCache:
 
     def invalidate(self, key: tuple) -> None:
         with self._lock:
-            entry = self._rows.pop(key, None)
-            if entry is not None:
-                self._bytes -= entry.arr.nbytes
-            centry = self._compressed.pop(key, None)
-            if centry is not None:
-                self._compressed_bytes -= centry.nbytes
-            # host copies invalidate like compressed ones: decompress+
-            # patch costs more than the re-decode they were demoted to
-            # avoid (apply_write's missing-dense branch lands here)
-            hentry = self._host.pop(key, None)
-            if hentry is not None:
-                self._host_bytes -= hentry.nbytes
-            if entry is not None or centry is not None \
-                    or hentry is not None:
-                self._bump_generation()
-            self._drop_updater(key)
+            self._invalidate_locked(key)
+
+    def _invalidate_locked(self, key: tuple) -> None:
+        entry = self._rows.pop(key, None)
+        if entry is not None:
+            self._bytes -= entry.arr.nbytes
+        centry = self._compressed.pop(key, None)
+        if centry is not None:
+            self._compressed_bytes -= centry.nbytes
+        # host copies invalidate like compressed ones: decompress+
+        # patch costs more than the re-decode they were demoted to
+        # avoid (a routed write's missing-dense branch lands here)
+        hentry = self._host.pop(key, None)
+        if hentry is not None:
+            self._host_bytes -= hentry.nbytes
+        if entry is not None or centry is not None or hentry is not None:
+            self._bump_generation()
+        self._drop_updater(key)
 
     def invalidate_fragment(self, frag_id: tuple) -> None:
         with self._lock:
-            for store in (self._rows, self._compressed, self._host):
-                doomed = [k for k in store if k[: len(frag_id)] == frag_id]
-                for k in doomed:
-                    self.invalidate(k)
+            self._invalidate_prefix_locked(frag_id)
+
+    def _invalidate_prefix_locked(self, prefix: tuple) -> None:
+        """Every key of every tier that starts with ``prefix``: a scan
+        of all three stores, so not for the per-write path."""
+        n = len(prefix)
+        for store in (self._rows, self._compressed, self._host):
+            for k in [k for k in store if k[:n] == prefix]:
+                self._invalidate_locked(k)
 
     # --------------------------------------------------- derived-entry updates
 
@@ -544,7 +556,7 @@ class DeviceRowCache:
         (field close/delete: the durable files are no longer ours)."""
         with self._lock:
             for key in list(self._tag_index.get(tag, ())):
-                self.invalidate(key)
+                self._invalidate_locked(key)
 
     def _drop_updater(self, key: tuple) -> None:
         reg = self._updaters.pop(key, None)
@@ -557,40 +569,109 @@ class DeviceRowCache:
 
     def apply_write(self, event: WriteEvent) -> None:
         """Route one fragment mutation to the derived entries that depend
-        on it: dense entries are patched on device, compressed copies are
-        invalidated, everything else is untouched (this replaces the old
-        global write-generation purge, which evicted EVERY stacked leaf on
-        any write). Runs fully under the lock so concurrent writers can't
-        lose each other's read-modify-write of a shared leaf."""
-        tag = (event.scope, event.index, event.field)
+        on it: dense entries are patched on device, compressed and host
+        copies are invalidated, everything else is untouched (this
+        replaces the old global write-generation purge, which evicted
+        EVERY stacked leaf on any write). The lock is held to find the
+        affected entries and again to swap the patched arrays in; the
+        patches themselves are dispatched between the two, outside it
+        (_patch_routed)."""
         with self._lock:
-            self.write_events += 1
-            for key in list(self._tag_index.get(tag, ())):
-                reg = self._updaters.get(key)
-                if reg is None:
-                    continue
-                pending = self._pending_builds.get(key)
-                if pending is not None:
-                    # key is mid-build: its decode may or may not see this
-                    # write — buffer it for replay after the upload
-                    pending.append(event)
-                    continue
-                apply = reg[1](event)
-                if apply is None:
-                    continue  # unaffected (different row/view/shard)
-                if apply is PURGE:
-                    self.invalidate(key)
-                    continue
-                entry = self._rows.get(key)
-                if entry is not None:
+            todo = self._route_locked(event)
+        self._patch_routed(event, todo)
+
+    def row_written(self, frag_id: tuple, event: WriteEvent,
+                    planes: bool = False) -> None:
+        """A fragment's whole per-row write bookkeeping in one
+        acquisition: drop the fragment's own row entry (and, for a
+        fragment of a BSI view, ``planes``, its plane matrices — the
+        only fragments that have any, so only they pay the key scan),
+        then route the event as apply_write does."""
+        with self._lock:
+            self._invalidate_locked(frag_id + (event.row,))
+            if planes:
+                self._invalidate_prefix_locked(frag_id + ("__planes__",))
+            todo = self._route_locked(event)
+        self._patch_routed(event, todo)
+
+    def _route_locked(self, event: WriteEvent) -> list:
+        """The bookkeeping half of a routed write (caller holds the
+        lock): buffer the event for keys that are mid-build, invalidate
+        what cannot be patched (PURGE, no dense entry), and return
+        ``(key, entry, apply, entry.arr)`` for each dense entry to
+        patch. Only the probes' cheap part runs here (slot lookup and
+        match); the closures they return do their numpy and device work
+        when _patch_routed applies them."""
+        self.write_events += 1
+        tag = (event.scope, event.index, event.field)
+        todo = []
+        for key in list(self._tag_index.get(tag, ())):
+            reg = self._updaters.get(key)
+            if reg is None:
+                continue
+            pending = self._pending_builds.get(key)
+            if pending is not None:
+                # key is mid-build: its decode may or may not see this
+                # write — buffer it for replay after the upload
+                pending.append(event)
+                continue
+            apply = reg[1](event)
+            if apply is None:
+                continue  # unaffected (different row/view/shard)
+            entry = None if apply is PURGE else self._rows.get(key)
+            if entry is None:
+                self._invalidate_locked(key)
+            else:
+                todo.append((key, entry, apply, entry.arr))
+        return todo
+
+    def _patch_routed(self, event: WriteEvent, todo: list) -> None:
+        """Dispatch each patch on the array its entry held when the
+        write was routed, with the lock free, then take the lock once
+        to swap the results in. An entry whose array is no longer the
+        one patched (another writer swapped the same leaf first) is
+        patched again on the array it holds now: every patch is an
+        idempotent delta or a set-to-current-truth of its own shard
+        slot, so racing patches of one leaf commute. An entry that left
+        the dense tier meanwhile (evicted, invalidated, demoted) is
+        never put back: the key is invalidated like any copy that
+        cannot be patched, or, if a build of it has started, the event
+        joins that build's buffer. Device arrays are immutable, so a
+        reader holding the old array keeps a consistent snapshot; the
+        swap is done when this returns, so before the write is
+        acknowledged."""
+        while todo:
+            try:
+                patched = []
+                for _key, _entry, apply, seen in todo:
                     with stage("residency.patch"):
-                        entry.arr = apply(entry.arr)
-                    # occupancy may have changed; don't demote later
-                    entry.block_idx = None
-                    self.updates += 1
-                    self._bump_generation()
-                else:
-                    self.invalidate(key)
+                        patched.append(apply(seen))
+            except BaseException:
+                # the fragment already holds the write: a leaf that
+                # could not be patched must not outlive it
+                with self._lock:
+                    for key, *_ in todo:
+                        self._invalidate_locked(key)
+                raise
+            retry = []
+            with self._lock:
+                for (key, entry, apply, seen), new in zip(todo, patched):
+                    if self._rows.get(key) is not entry:
+                        pending = self._pending_builds.get(key)
+                        if pending is not None:
+                            pending.append(event)
+                        else:
+                            self._invalidate_locked(key)
+                    elif entry.arr is not seen:
+                        self.patch_retries += 1
+                        retry.append((key, entry, apply, entry.arr))
+                    else:
+                        entry.arr = new
+                        # occupancy may have changed; don't demote later
+                        entry.block_idx = None
+                        self.updates += 1
+                        self._bump_generation()
+            todo = retry
 
 # ---------------------------------------------------- host tier (tiering)
 
@@ -833,7 +914,8 @@ class DeviceRowCache:
     _MONOTONIC_METRICS = frozenset({
         "residency_hits", "residency_misses", "residency_evictions",
         "residency_compressions", "residency_decompressions",
-        "residency_updates", "residency_write_events",
+        "residency_updates", "residency_patch_retries",
+        "residency_write_events",
         "residency_host_hits", "residency_tier_promotions",
         "residency_tier_demotions",
     })
@@ -855,6 +937,7 @@ class DeviceRowCache:
                 "residency_compressions": self.compressions,
                 "residency_decompressions": self.decompressions,
                 "residency_updates": self.updates,
+                "residency_patch_retries": self.patch_retries,
                 "residency_write_events": self.write_events,
                 "residency_entries_host": len(self._host),
                 "residency_bytes_host": self._host_bytes,

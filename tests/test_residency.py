@@ -1,14 +1,18 @@
 """Two-tier HBM residency: demote-compress on eviction, scatter-promote
 on hit (storage/residency.py; SURVEY.md §7.3 hard part #1)."""
 
+import threading
+
 import numpy as np
 import pytest
 
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD
 from pilosa_tpu.storage.residency import (
     COMPRESS_BLOCK_WORDS,
+    PURGE,
     ROW_BYTES,
     DeviceRowCache,
+    WriteEvent,
 )
 
 
@@ -330,3 +334,312 @@ def test_executor_memo_rehomes_on_cache_swap(tmp_path):
     finally:
         res_mod.set_global_row_cache(old)
         holder.close()
+
+
+# ---------------------------------------------------------------------------
+# A routed write holds the lock for bookkeeping only: the patch is
+# dispatched on the array the entry held, outside the lock, and swapped
+# in if the entry still holds that array (DeviceRowCache._patch_routed).
+# Every interleaving below is forced with Events; nothing sleeps.
+
+WAIT = 20  # seconds; a wait that times out fails its assert
+
+LEAF = ("stack", "", "i", "f", ("standard",), 1, 0)
+TAG = ("", "i", "f")
+
+
+class GatedPatch:
+    """Probe whose closure ORs ``bit`` into the leaf; its first call
+    announces itself and waits to be let go, later calls (a retry) run
+    straight through."""
+
+    def __init__(self, bit, row=1):
+        self.bit, self.row = np.uint32(bit), row
+        self.entered = threading.Event()
+        self.go = threading.Event()
+        self.seen = []  # the array each call was given
+        self.lock_owned = []
+
+    def on(self, cache):
+        self.cache = cache
+        return self
+
+    def __call__(self, ev):
+        if ev.row != self.row:
+            return None
+
+        def apply(arr):
+            self.seen.append(arr)
+            self.lock_owned.append(self.cache._lock.inner._is_owned())
+            if len(self.seen) == 1:
+                self.entered.set()
+                assert self.go.wait(WAIT)
+            return arr | self.bit
+
+        return apply
+
+
+def started(fn, *args):
+    out = {}
+
+    def run():
+        try:
+            out["value"] = fn(*args)
+        except BaseException as e:  # read by the test, never swallowed
+            out["error"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.out = out
+    t.start()
+    return t
+
+
+def finished(t):
+    t.join(WAIT)
+    assert not t.is_alive()
+    assert "error" not in t.out, t.out.get("error")
+    return t.out.get("value")
+
+
+def resident_leaf(budget=4 << 20, seed=21, key=LEAF, blocks=2):
+    cache = DeviceRowCache(budget_bytes=budget)
+    dec = CountingDecoder(sparse_row(np.random.default_rng(seed), blocks))
+    cache.get_row(key, dec)
+    return cache, dec
+
+
+def test_patch_runs_outside_the_lock():
+    """(a) While the patch closure runs, the writer does not own the
+    cache's lock, and a hit on another key by another thread returns."""
+    cache, _ = resident_leaf()
+    other = CountingDecoder(sparse_row(np.random.default_rng(22), 2))
+    cache.get_row(("other",), other)
+    patch = GatedPatch(1).on(cache)
+    cache.register_updater(LEAF, TAG, patch)
+    w = started(cache.apply_write, WriteEvent("i", "f", "standard", 0, 1))
+    assert patch.entered.wait(WAIT)
+    hit = started(cache.get_or_build, ("other",), None, None, other)
+    got = finished(hit)  # would hang here if the patch held the lock
+    np.testing.assert_array_equal(np.asarray(got), other.host)
+    assert other.calls == 1 and cache.updates == 0
+    patch.go.set()
+    finished(w)
+    assert patch.lock_owned == [False]
+    assert cache.updates == 1 and cache.patch_retries == 0
+
+
+def test_racing_patches_of_one_leaf_retry_and_commute():
+    """(b), cache level: the second writer's dispatch is held until the
+    first has swapped; its patch of the stale array is thrown away and
+    made again on the array the entry holds now."""
+    cache, dec = resident_leaf()
+    first, second = GatedPatch(1, row=1), GatedPatch(2, row=2)
+    first.on(cache).go.set()
+    second.on(cache)
+    cache.register_updater(
+        LEAF, TAG, lambda ev: first(ev) or second(ev))
+    gen0 = cache.generation
+    w2 = started(cache.apply_write, WriteEvent("i", "f", "standard", 0, 2))
+    assert second.entered.wait(WAIT)
+    cache.apply_write(WriteEvent("i", "f", "standard", 0, 1))  # swaps
+    assert cache.updates == 1
+    swapped = cache._rows[LEAF].arr
+    second.go.set()
+    finished(w2)
+    assert cache.updates == 2 and cache.patch_retries == 1
+    assert cache.generation == gen0 + 2  # one bump a swap, none a retry
+    assert second.seen[0] is first.seen[0]  # both saw the original
+    # the retry, on the first's array
+    assert len(second.seen) == 2 and second.seen[1] is swapped
+    got = np.asarray(cache.get_row(LEAF, dec))
+    np.testing.assert_array_equal(got, dec.host | np.uint32(3))
+    assert dec.calls == 1 and second.lock_owned == [False, False]
+
+
+def _invalidate(cache):
+    cache.invalidate(LEAF)
+
+
+def _evict_to_compressed(cache):
+    # one more row than the budget holds: the leaf, LRU-oldest and
+    # sparse, is demoted to the compressed tier
+    cache.get_row(("filler",), CountingDecoder(
+        sparse_row(np.random.default_rng(23), 2)))
+    assert LEAF in cache._compressed
+
+
+def _evict_and_promote(cache):
+    # demoted and promoted again: a dense entry, but another object,
+    # built from the array the patch never reached
+    _evict_to_compressed(cache)
+    assert cache._lookup_locked(LEAF) is not None
+    assert LEAF in cache._rows
+
+
+def _demote_to_host(cache):
+    assert cache.demote_field_stacks_to_host("", "i", "f") == (1, ROW_BYTES)
+    assert LEAF in cache._host
+
+
+def _clear(cache):
+    cache.clear()
+
+
+@pytest.mark.parametrize("leave", [
+    _invalidate, _evict_to_compressed, _evict_and_promote,
+    _demote_to_host, _clear,
+], ids=lambda f: f.__name__.strip("_"))
+def test_entry_that_left_the_dense_tier_is_not_resurrected(leave):
+    """(c) Between dispatch and swap the entry is invalidated, evicted,
+    demoted or cleared: the patched array is dropped, no copy of the
+    unpatched one survives in any tier, and the next read decodes the
+    row again, written bit included."""
+    cache, dec = resident_leaf(budget=200 << 10)  # one dense row fits
+    patch = GatedPatch(1).on(cache)
+    cache.register_updater(LEAF, TAG, patch)
+    dec.host = dec.host | np.uint32(1)  # the fragment holds the write
+    w = started(cache.apply_write, WriteEvent("i", "f", "standard", 0, 1))
+    assert patch.entered.wait(WAIT)
+    leave(cache)
+    patch.go.set()
+    finished(w)
+    for tier in (cache._rows, cache._compressed, cache._host):
+        assert LEAF not in tier
+    assert LEAF not in cache._updaters
+    assert cache.updates == 0 and cache.patch_retries == 0
+    got = np.asarray(cache.get_row(LEAF, dec))
+    assert dec.calls == 2
+    np.testing.assert_array_equal(got, dec.host)
+
+
+def test_build_started_between_dispatch_and_swap_gets_the_event():
+    """(d) The entry is dropped and a build of the same key begins while
+    the patch is in flight: the writer hands its event to that build's
+    buffer (and does not wait for the build), which replays it."""
+    cache, dec = resident_leaf()
+    patch = GatedPatch(1).on(cache)
+    cache.register_updater(LEAF, TAG, patch)
+    ev = WriteEvent("i", "f", "standard", 0, 1)
+    w = started(cache.apply_write, ev)
+    assert patch.entered.wait(WAIT)
+    cache.invalidate(LEAF)
+    decoding, decoded = threading.Event(), threading.Event()
+    stale = dec.host.copy()  # a decode that did not see the write
+
+    def slow_decode():
+        decoding.set()
+        assert decoded.wait(WAIT)
+        return stale
+
+    replayed = []
+
+    def build_probe(e):
+        replayed.append(e)
+        return lambda arr: arr | np.uint32(1)
+
+    b = started(cache.get_or_build, LEAF, TAG, lambda: build_probe,
+                slow_decode)
+    assert decoding.wait(WAIT)
+    patch.go.set()
+    finished(w)  # the writer is done while the build still decodes
+    assert cache._pending_builds[LEAF] == [ev]
+    assert cache.updates == 0
+    decoded.set()
+    got = np.asarray(finished(b))
+    assert replayed == [ev]
+    np.testing.assert_array_equal(got, stale | np.uint32(1))
+    np.testing.assert_array_equal(
+        np.asarray(cache._rows[LEAF].arr), stale | np.uint32(1))
+
+
+def test_purge_invalidates_without_dispatch():
+    """(e) A probe that answers PURGE drops the entry under the lock, as
+    before; nothing is dispatched."""
+    cache, dec = resident_leaf()
+    cache.register_updater(LEAF, TAG, lambda ev: PURGE)
+    cache.apply_write(WriteEvent("i", "f", "standard", 0, 1))
+    assert LEAF not in cache._rows and LEAF not in cache._updaters
+    assert cache.updates == 0 and cache.write_events == 1
+    cache.get_row(LEAF, dec)
+    assert dec.calls == 2
+
+
+def test_write_during_a_build_is_buffered_not_dispatched():
+    """(e) A write routed while its key is mid-build joins the build's
+    buffer at once (step 1); the probe is first asked at the replay."""
+    cache = DeviceRowCache(budget_bytes=4 << 20)
+    host = sparse_row(np.random.default_rng(24), 2)
+    decoding, decoded = threading.Event(), threading.Event()
+
+    def slow_decode():
+        decoding.set()
+        assert decoded.wait(WAIT)
+        return host
+
+    asked = []
+
+    def probe(e):
+        asked.append(e)
+        return lambda arr: arr | np.uint32(4)
+
+    b = started(cache.get_or_build, LEAF, TAG, lambda: probe, slow_decode)
+    assert decoding.wait(WAIT)
+    ev = WriteEvent("i", "f", "standard", 0, 1)
+    cache.apply_write(ev)
+    assert asked == [] and cache._pending_builds[LEAF] == [ev]
+    decoded.set()
+    got = np.asarray(finished(b))
+    assert asked == [ev]
+    np.testing.assert_array_equal(got, host | np.uint32(4))
+
+
+def test_failed_patch_invalidates_the_leaf():
+    """A patch that raises leaves no unpatched copy behind (the fragment
+    already holds the write), and the writer sees the error."""
+    cache, dec = resident_leaf()
+
+    def probe(ev):
+        def apply(arr):
+            raise RuntimeError("device fell over")
+        return apply
+
+    cache.register_updater(LEAF, TAG, probe)
+    with pytest.raises(RuntimeError, match="fell over"):
+        cache.apply_write(WriteEvent("i", "f", "standard", 0, 1))
+    assert LEAF not in cache._rows and cache.updates == 0
+    assert not cache._lock.inner._is_owned()
+    cache.get_row(LEAF, dec)
+    assert dec.calls == 2
+
+
+@pytest.mark.parametrize("planes", [False, True])
+def test_row_written_is_one_entry_point(planes):
+    """Fragment._after_row_write's three steps in one call: the
+    fragment's own row entry goes, its plane matrices go only when the
+    caller says it can have any, and the event is routed."""
+    rng = np.random.default_rng(25)
+    cache = DeviceRowCache(budget_bytes=4 << 20)
+    frag = ("", "i", "f", "bsig_f" if planes else "standard", 0)
+    rows = {k: CountingDecoder(sparse_row(rng, 2)) for k in (
+        frag + (1,), frag + (2,), frag + ("__planes__", 6), LEAF)}
+    for k, d in rows.items():
+        cache.get_row(k, d)
+    cache.register_updater(
+        LEAF, TAG, lambda ev: (lambda arr: arr | np.uint32(1)))
+    cache.row_written(frag, WriteEvent("i", "f", frag[3], 0, 1),
+                      planes=planes)
+    assert frag + (1,) not in cache._rows
+    assert frag + (2,) in cache._rows
+    assert (frag + ("__planes__", 6) in cache._rows) is (not planes)
+    assert cache.updates == 1 and cache.write_events == 1
+    np.testing.assert_array_equal(
+        np.asarray(cache._rows[LEAF].arr), rows[LEAF].host | np.uint32(1))
+
+
+def test_patch_retries_is_exported():
+    cache, _ = resident_leaf()
+    cache.patch_retries = 3
+    assert cache.metrics()["residency_patch_retries"] == 3
+    text = cache.prometheus_lines()
+    assert "pilosa_tpu_residency_patch_retries_total 3" in text
+    assert "# TYPE pilosa_tpu_residency_patch_retries_total counter" in text

@@ -536,13 +536,13 @@ PROGRAMS = {
         ("leaf", 0), 1, 0, 1, False
     ).lower(_u32(2, 64), _u32(2, 4, 64), np.zeros(4, np.int32)),
     "jit_or_delta": lambda b, e, r: b._or_delta.lower(
-        _u32(2, 64), 0, np.zeros(1, np.int32), np.zeros(1, np.uint32)),
+        _u32(2, 64), b._delta_args((0,), [5])),
     "jit_andnot_delta": lambda b, e, r: b._andnot_delta.lower(
-        _u32(2, 64), 0, np.zeros(1, np.int32), np.zeros(1, np.uint32)),
+        _u32(2, 64), b._delta_args((0,), [5])),
     "jit_or_delta_row": lambda b, e, r: b._or_delta_row.lower(
-        _u32(2, 4, 64), 0, 1, np.zeros(1, np.int32), np.zeros(1, np.uint32)),
+        _u32(2, 4, 64), b._delta_args((0, 1), [5])),
     "jit_andnot_delta_row": lambda b, e, r: b._andnot_delta_row.lower(
-        _u32(2, 4, 64), 0, 1, np.zeros(1, np.int32), np.zeros(1, np.uint32)),
+        _u32(2, 4, 64), b._delta_args((0, 1), [5])),
     "jit_expr": lambda b, e, r: e._build(("leaf", 0)).lower(
         (_u32(64),), ()),
     "jit_gather_blocks": lambda b, e, r: r._gather_blocks.lower(
