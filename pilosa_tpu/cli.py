@@ -46,7 +46,10 @@ heartbeat-timeout = 2.0       # tight per-probe timeout for liveness
                               # results stay byte-identical (exact
                               # recount on the error-bound-widened
                               # window)
-# device-budget-bytes = 0     # HBM residency budget; 0 = auto
+# device-budget-bytes = 0     # HBM residency budget PER CHIP (an entry is
+                              # charged what it holds on the fullest
+                              # chip: a leaf sharded over a mesh costs its
+                              # shard); 0 = auto (4 GiB of a chip's 16)
 long-query-time = 0.0         # log queries slower than this; 0 = off
 max-writes-per-request = 5000 # reject larger write batches; 0 = unlimited
 ingest-workers = 1            # local shard-group apply pool per import

@@ -591,6 +591,27 @@ class Executor:
             filt_structure, n_filt, n_scalars, n_gather, has_agg
         )
 
+    # stage that times _groupby_operand_put's placements
+    _operand_stage = "device.upload"
+
+    def _groupby_operand_put(self, scalars):
+        """Placement hook beside _leaf_put for the small per-dispatch
+        operands of a GroupBy level: returns put(ci), which takes one
+        chunk's candidate indices int32[C, n_gather] to the trailing
+        arguments of _groupby_level_program's program. Here: one index
+        array per gathered dimension and then the scalars, each made on
+        the default device. DistExecutor packs them into one array
+        replicated over the mesh."""
+        import jax.numpy as jnp
+
+        jscalars = tuple(jnp.asarray(s, jnp.int32) for s in scalars)
+
+        def put(ci):
+            return tuple(jnp.asarray(ci[:, d], jnp.int32)
+                         for d in range(ci.shape[1])) + jscalars
+
+        return put
+
     # EQuARX quantized candidate-ranking lane: inert on the base
     # executor (no inter-group wire to shrink); DistExecutor overrides
     # the predicate behind the topn-quantized-ranking knob.
@@ -1834,8 +1855,8 @@ class Executor:
             filt_node, len(filt_leaves), len(scalars), n_gather, has_agg,
             quantized=quantized,
         )
-        with stage("device.upload"):
-            jscalars = tuple(jnp.asarray(s, jnp.int32) for s in scalars)
+        with stage(self._operand_stage):
+            put = self._groupby_operand_put(scalars)
 
         packs = []
         layout = []  # (padded, actual) per chunk
@@ -1847,17 +1868,14 @@ class Executor:
                 ci = np.concatenate(
                     [ci, np.zeros((padded - actual, n_gather), np.int32)]
                 )
-            with stage("device.upload"):
-                idx_arrays = tuple(
-                    jnp.asarray(ci[:, d], jnp.int32) for d in range(n_gather)
-                )
+            with stage(self._operand_stage):
+                operands = put(ci)
             args = list(filt_leaves) + list(dim_mats)
             if has_agg:
                 args.append(planes)
-            args.extend(idx_arrays)
             site = stage("device.dispatch", reduce="groupby")
             with site:
-                packs.append(fn(*args, *jscalars))
+                packs.append(fn(*args, *operands))
             cost = current_cost()
             if cost is not None:
                 cost.note_dispatch(site.elapsed)

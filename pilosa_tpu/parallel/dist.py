@@ -41,7 +41,7 @@ from pilosa_tpu.executor import batch
 from pilosa_tpu.parallel import reduction
 from pilosa_tpu.parallel.mesh import (
     GROUPS_AXIS, SHARDS_AXIS, ShardAssignment, make_mesh, mesh_groups,
-    shards_spec,
+    replicated, shards_spec,
 )
 from pilosa_tpu.utils.compile_cache import named_jit
 from pilosa_tpu.utils.cost import current_cost
@@ -232,16 +232,18 @@ def _dist_groupby_level_fn(mesh, filt_structure, n_filt: int, n_scalars: int,
 
     hier = mesh_groups(mesh)
     n_leaves = n_filt + n_gather + (1 if has_agg else 0)
-    in_specs = (
-        tuple(shards_spec(mesh) for _ in range(n_leaves))
-        + tuple(P() for _ in range(n_gather))  # candidate index arrays
-        + tuple(P() for _ in range(n_scalars))
-    )
+    # the leaves, then ONE replicated int32 array: the candidate index
+    # arrays end to end, then the scalars (DistExecutor.
+    # _groupby_operand_put packs it; every host argument of a mesh
+    # program is a placement on every chip, so there is one)
+    in_specs = tuple(shards_spec(mesh) for _ in range(n_leaves)) + (P(),)
 
     def body(*args):
         leaves = args[:n_leaves]
-        idxs = args[n_leaves:n_leaves + n_gather]
-        scalars = args[n_leaves + n_gather:]
+        packed = args[n_leaves]
+        c = (packed.shape[0] - n_scalars) // n_gather
+        idxs = tuple(packed[d * c:(d + 1) * c] for d in range(n_gather))
+        scalars = tuple(packed[n_gather * c + i] for i in range(n_scalars))
         group_slots = leaves[0].shape[0] * (hier[1] if hier else 1)
 
         def reduce_split(packed_local):
@@ -350,6 +352,25 @@ class DistExecutor(Executor):
             return jax.make_array_from_process_local_data(
                 sharding, host, (padded,) + host.shape[1:]
             )
+
+        return put
+
+    _operand_stage = "device.replicate"
+
+    def _groupby_operand_put(self, scalars):
+        """One packed int32 array a dispatch, placed on every chip of
+        the mesh at once: the chunk's candidate index arrays end to end,
+        then the scalars (_dist_groupby_level_fn unpacks it). Made one
+        by one on the default device, as the base executor makes them,
+        each was a host transfer and then a copy to every other chip at
+        the program call."""
+        sharding = replicated(self.mesh)
+        tail = np.asarray(scalars, np.int32).reshape(-1)
+
+        def put(ci):
+            packed = np.concatenate(
+                [np.asarray(ci, np.int32).T.reshape(-1), tail])
+            return (jax.device_put(packed, sharding),)
 
         return put
 

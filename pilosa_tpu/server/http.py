@@ -1106,7 +1106,9 @@ class HTTPHandler(BaseHTTPRequestHandler):
         from pilosa_tpu.utils.stats import global_stats
 
         snap = global_stats().snapshot()
-        snap["residency"] = global_row_cache().metrics()
+        cache = global_row_cache()
+        snap["residency"] = dict(cache.metrics(),
+                                 residency_device_bytes=cache.device_bytes())
         snap["serving_pipeline"] = self.api.pipeline_metrics()
         snap["qos"] = self.api.qos.metrics()
         fastlane = self.api.fastlane_metrics()

@@ -40,6 +40,7 @@ re-decode they were demoted to avoid).
 
 from __future__ import annotations
 
+import math
 import threading
 import weakref
 from collections import OrderedDict
@@ -56,8 +57,12 @@ from pilosa_tpu.utils.tracing import stage, staged
 
 ROW_BYTES = WORDS_PER_SHARD * 4  # 128 KiB per resident row
 
-# Default budget: 4 GiB of HBM for row residency (v5e has 16 GiB; the rest
-# is headroom for query intermediates + XLA workspace). Tests override.
+# Default budget: 4 GiB of ONE chip's HBM for row residency (v5e has
+# 16 GiB a chip; the rest is headroom for query intermediates + XLA
+# workspace). Per chip: an entry is charged what it holds on the fullest
+# chip (chip_bytes), so a leaf sharded over a four-chip mesh costs a
+# quarter of its nbytes and the mesh as a whole holds four budgets.
+# Tests override.
 DEFAULT_BUDGET_BYTES = 4 << 30
 
 # Default compressed host-tier budget (residency-host-tier-bytes knob):
@@ -75,6 +80,20 @@ PURGE = object()
 # Demote-as-compressed only when it actually saves memory; denser entries
 # are simply dropped (host re-decode is the fallback, as before).
 COMPRESS_MAX_OCCUPANCY = 0.5
+
+
+def chip_bytes(arr) -> int:
+    """What ``arr`` holds on the fullest chip: the bytes of its largest
+    addressable shard. A leaf that DistExecutor._leaf_put placed with a
+    NamedSharding over the mesh is split evenly over the chips, so that
+    is ``nbytes / mesh.size``; for a single-device array, a replicated
+    one and a host array it is ``nbytes``. The dense tier's budget, its
+    ``_bytes`` and every figure derived from them are in these bytes,
+    the unit Executor.arg_shard_factor already reckons in."""
+    sharding = getattr(arr, "sharding", None)
+    if sharding is None or len(sharding.device_set) == 1:
+        return int(arr.nbytes)
+    return math.prod(sharding.shard_shape(arr.shape)) * arr.dtype.itemsize
 
 
 def _gather_blocks(arr, idx, block_words: int):
@@ -195,8 +214,13 @@ class _ContendedLock:
 
 class DeviceRowCache:
     """Byte-budgeted two-tier LRU of device-resident arrays (dense rows,
-    BSI plane matrices, mesh-sharded shard stacks — sized by actual
-    nbytes). Sparse entries compress on demotion instead of dropping."""
+    BSI plane matrices, mesh-sharded shard stacks). ``budget_bytes`` is
+    a budget PER CHIP: a dense entry is charged chip_bytes, what it
+    holds on the fullest chip, so on one chip its nbytes and on a mesh
+    its shard. The compressed tier lives on ``device`` alone (small
+    single-device arrays, nbytes = chip bytes) and shares that budget;
+    the host tier is host RAM under its own. Sparse entries compress on
+    demotion instead of dropping."""
 
     def __init__(self, budget_bytes: int = DEFAULT_BUDGET_BYTES, device=None,
                  host_budget_bytes: int = DEFAULT_HOST_BUDGET_BYTES):
@@ -460,7 +484,7 @@ class DeviceRowCache:
                         # held off
                         old = self._rows.pop(key, None)
                         if old is not None:
-                            self._bytes -= old.arr.nbytes
+                            self._bytes -= chip_bytes(old.arr)
                         arr = self._put_locked(key, decode(), device_put)
                         break
                     entry = self._rows.get(key)
@@ -491,7 +515,7 @@ class DeviceRowCache:
     def _insert_dense(self, key: tuple, arr, block_idx,
                       custom: bool = False) -> None:
         self._rows[key] = _DenseEntry(arr, block_idx, custom)
-        self._bytes += arr.nbytes
+        self._bytes += chip_bytes(arr)
         self._evict()
 
     def invalidate(self, key: tuple) -> None:
@@ -501,7 +525,7 @@ class DeviceRowCache:
     def _invalidate_locked(self, key: tuple) -> None:
         entry = self._rows.pop(key, None)
         if entry is not None:
-            self._bytes -= entry.arr.nbytes
+            self._bytes -= chip_bytes(entry.arr)
         centry = self._compressed.pop(key, None)
         if centry is not None:
             self._compressed_bytes -= centry.nbytes
@@ -724,8 +748,9 @@ class DeviceRowCache:
         for key in [k for k, e in self._rows.items()
                     if not e.custom and match(k)]:
             entry = self._rows.pop(key)
-            self._bytes -= entry.arr.nbytes
-            freed += entry.arr.nbytes
+            charge = chip_bytes(entry.arr)
+            self._bytes -= charge
+            freed += charge
             self._bump_generation()
             host = np.asarray(entry.arr).reshape(-1)
             block_idx = entry.block_idx
@@ -843,7 +868,7 @@ class DeviceRowCache:
         zero leaves are excluded (never tiered)."""
         with self._lock:
             stores = (("dense", self._rows,
-                       lambda e: 0 if e.custom else e.arr.nbytes),
+                       lambda e: 0 if e.custom else chip_bytes(e.arr)),
                       ("compressed", self._compressed,
                        lambda e: e.nbytes),
                       ("host", self._host, lambda e: e.nbytes))
@@ -888,7 +913,7 @@ class DeviceRowCache:
         multi-holder setups never conflate replicas. Key shapes are
         pinned by executor/batch.leaf_key and Fragment.frag_id."""
         with self._lock:
-            items = [(k, e.arr.nbytes) for k, e in self._rows.items()]
+            items = [(k, chip_bytes(e.arr)) for k, e in self._rows.items()]
             items += [(k, e.nbytes) for k, e in self._compressed.items()]
         per_frag: dict[tuple, int] = {}
         per_field: dict[tuple, int] = {}
@@ -920,10 +945,30 @@ class DeviceRowCache:
         "residency_tier_demotions",
     })
 
+    def device_bytes(self) -> dict[str, int]:
+        """Resident bytes of each chip, keyed by device id: every dense
+        entry's shard on each device that holds a piece of it, and the
+        compressed tier on its one device. An uneven mesh shows here;
+        ``residency_bytes_used`` is the figure the budget is held to
+        (the sum of the entries' charges, never less than the fullest
+        chip's bytes here)."""
+        out: dict[str, int] = {}
+        with self._lock:
+            arrays = [e.arr for e in self._rows.values()]
+            for c in self._compressed.values():
+                arrays += (c.blocks, c.idx)
+        for arr in arrays:
+            n = chip_bytes(arr)
+            for d in arr.sharding.addressable_devices:
+                out[str(d.id)] = out.get(str(d.id), 0) + n
+        return out
+
     def metrics(self) -> dict:
         """Operational gauges/counters for /metrics and /debug/vars (the
         HBM LRU is the system's central capacity mechanism — reference
-        analog: syswrap's mmap-count limits, SURVEY.md §2 #26)."""
+        analog: syswrap's mmap-count limits, SURVEY.md §2 #26).
+        ``residency_bytes_used`` and ``residency_budget_bytes`` are per
+        chip (chip_bytes); device_bytes() has each chip's own figure."""
         with self._lock:
             return {
                 "residency_entries": len(self._rows) + len(self._compressed),
@@ -957,9 +1002,10 @@ class DeviceRowCache:
         scrape ingests the block (docs/OBSERVABILITY.md); ``seen``
         shares the page-wide family-metadata dedupe. One renderer for
         the whole exposition page — stats.prometheus_block."""
-        from pilosa_tpu.utils.stats import prometheus_block
+        from pilosa_tpu.utils.stats import _meta_lines, prometheus_block
 
-        return prometheus_block(
+        seen = seen if seen is not None else set()
+        text = prometheus_block(
             {
                 (f"{name}_total" if name in self._MONOTONIC_METRICS
                  else name): v
@@ -967,6 +1013,14 @@ class DeviceRowCache:
             },
             prefix, seen=seen,
         )
+        family = f"{prefix}_residency_device_bytes"
+        lines = _meta_lines(
+            family, "gauge",
+            "resident bytes on each chip (residency_bytes_used is the "
+            "per-chip figure the budget is held to)", seen)
+        lines += [f'{family}{{device="{d}"}} {n}'
+                  for d, n in sorted(self.device_bytes().items())]
+        return text + "\n".join(lines) + "\n"
 
     def clear(self) -> None:
         with self._lock:
@@ -988,7 +1042,7 @@ class DeviceRowCache:
         # then LRU compressed entries drop.
         while self.bytes_used > self.budget_bytes and len(self._rows) > 1:
             key, entry = self._rows.popitem(last=False)
-            self._bytes -= entry.arr.nbytes
+            self._bytes -= chip_bytes(entry.arr)
             self._bump_generation()
             if entry.block_idx is not None:
                 self._demote(key, entry)  # key stays resident (compressed)

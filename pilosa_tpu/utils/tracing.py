@@ -528,8 +528,8 @@ TOP_LEVEL_STAGES = (
 STAGES = ("http.query",) + TOP_LEVEL_STAGES + (
     "pipeline.gather", "pipeline.submit", "executor.plan",
     "executor.operands", "residency.miss", "residency.patch",
-    "residency.lock_wait", "device.upload", "device.dispatch",
-    "device.readback", "fragment.write",
+    "residency.lock_wait", "device.upload", "device.replicate",
+    "device.dispatch", "device.readback", "fragment.write",
 )
 
 

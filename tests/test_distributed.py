@@ -162,6 +162,46 @@ class TestDistGroupBy:
         )
         assert self.groups_json(r1) == self.groups_json(r2)
 
+    @pytest.mark.parametrize("pql", [
+        "GroupBy(Rows(f), Rows(g))",
+        'GroupBy(Rows(f), filter=Row(fare > 100), aggregate=Sum(field="fare"))',
+    ])
+    def test_level_operands_reach_the_mesh_as_one_replicated_array(
+            self, env, pql):
+        """A level's candidate indices and scalars are one packed array
+        placed on every chip at once (stage device.replicate); the base
+        executor makes them one by one on its device (device.upload)."""
+        from pilosa_tpu.utils.tracing import stage_metrics
+
+        def entered():
+            m = stage_metrics()
+            return np.array([m["device_upload_total"],
+                             m["device_replicate_total"]])
+
+        holder, base, dist = env
+        t0 = entered()
+        (r1,) = base.execute("big", pql)
+        t1 = entered()
+        (r2,) = dist.execute("big", pql)
+        t2 = entered()
+        assert self.groups_json(r1) == self.groups_json(r2) and r2
+        assert (t1 - t0)[0] > 0 and (t1 - t0)[1] == 0
+        assert (t2 - t1)[0] == 0 and (t2 - t1)[1] > 0
+
+    def test_packed_operand_layout(self, env, mesh):
+        holder, base, dist = env
+        (packed,) = dist._groupby_operand_put((7, 9))(
+            np.array([[1, 4], [2, 5], [3, 6]]))
+        assert packed.dtype == np.int32
+        assert packed.sharding.is_fully_replicated
+        assert packed.sharding.device_set == set(mesh.devices.ravel())
+        assert np.asarray(packed).tolist() == [1, 2, 3, 4, 5, 6, 7, 9]
+        one, two, s0, s1 = base._groupby_operand_put((7, 9))(
+            np.array([[1, 4], [2, 5], [3, 6]]))
+        assert np.asarray(one).tolist() == [1, 2, 3]
+        assert np.asarray(two).tolist() == [4, 5, 6]
+        assert (int(s0), int(s1)) == (7, 9)
+
     def test_groupby_limit(self, env):
         r1, r2 = both(env, "GroupBy(Rows(f), Rows(g), limit=1)")
         assert self.groups_json(r1) == self.groups_json(r2)

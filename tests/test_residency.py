@@ -643,3 +643,144 @@ def test_patch_retries_is_exported():
     text = cache.prometheus_lines()
     assert "pilosa_tpu_residency_patch_retries_total 3" in text
     assert "# TYPE pilosa_tpu_residency_patch_retries_total counter" in text
+
+
+# ---------------------------------------------------------------- per chip
+#
+# The budget is bytes on the fullest chip: an entry is charged what its
+# largest shard holds (residency.chip_bytes), so a leaf that the mesh
+# executor shards over four chips costs a quarter of its nbytes and a
+# single-device entry what it always did.
+
+
+def _mesh4():
+    from pilosa_tpu.parallel import make_mesh
+
+    return make_mesh(n_devices=4)
+
+
+def _placement(kind):
+    """A device_put override as DistExecutor._leaf_put makes them (None:
+    the cache's own single-device put) and the divisor it earns."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pilosa_tpu.parallel.mesh import shards_sharding
+
+    if kind == "single":
+        return None, 1
+    mesh = _mesh4()
+    if kind == "sharded":
+        sharding, div = shards_sharding(mesh), 4
+    else:  # replicated: every chip holds all of it
+        sharding, div = NamedSharding(mesh, P()), 1
+    return (lambda host: jax.device_put(host, sharding)), div
+
+
+def _leaf(rng, slots=8):
+    return rng.integers(1, 1 << 32, (slots, WORDS_PER_SHARD), dtype=np.uint32)
+
+
+@pytest.mark.parametrize("kind", ["single", "sharded", "replicated"])
+def test_entry_is_charged_what_it_holds_on_the_fullest_chip(kind):
+    from pilosa_tpu.storage.residency import chip_bytes
+
+    put, div = _placement(kind)
+    host = _leaf(np.random.default_rng(3))
+    cache = DeviceRowCache(budget_bytes=64 << 20)
+    arr = cache.get_row(("leaf",), lambda: host, device_put=put)
+    assert chip_bytes(arr) == host.nbytes // div
+    assert cache.bytes_used == host.nbytes // div
+    assert chip_bytes(host) == host.nbytes  # a host array: its nbytes
+    cache.invalidate(("leaf",))
+    assert cache.bytes_used == 0
+
+
+def test_budget_that_holds_the_per_chip_working_set_evicts_nothing():
+    """Five 1 MiB leaves over four chips are 1.25 MiB a chip: they fit a
+    2 MiB budget that their 5 MiB of global nbytes, the old reckoning,
+    overflowed."""
+    put, _ = _placement("sharded")
+    rng = np.random.default_rng(4)
+    cache = DeviceRowCache(budget_bytes=2 << 20)
+    leaves = {("leaf", i): _leaf(rng) for i in range(5)}
+    for key, host in leaves.items():
+        cache.get_row(key, lambda host=host: host, device_put=put)
+    assert sum(h.nbytes for h in leaves.values()) > cache.budget_bytes
+    assert cache.evictions == 0 and cache.generation == 0
+    assert cache.bytes_used == 5 * (1 << 20) // 4
+    for key, host in leaves.items():  # all still resident: hits only
+        got = cache.get_row(key, lambda: pytest.fail("re-decoded"),
+                            device_put=put)
+        np.testing.assert_array_equal(np.asarray(got), host)
+    assert cache.misses == 5 and cache.hits == 5
+
+
+def test_single_device_entries_evict_exactly_as_before():
+    """One chip: chip_bytes is nbytes, so the same five leaves against
+    the same budget keep two resident and drop three."""
+    rng = np.random.default_rng(4)
+    cache = DeviceRowCache(budget_bytes=2 << 20)
+    for i in range(5):
+        host = _leaf(rng)
+        cache.get_row(("leaf", i), lambda host=host: host)
+    assert cache.bytes_used == 2 << 20 and len(cache) == 2
+    assert cache.evictions == 3
+
+
+def test_patch_sharded_reassembly_keeps_the_charge():
+    """A write to a mesh leaf patches the one piece that holds the slot
+    and reassembles the global handle (batch._patch_sharded); the entry
+    that is swapped in costs what the one it replaces did."""
+    from pilosa_tpu.executor.batch import _patch_sharded
+    from pilosa_tpu.storage.residency import chip_bytes
+
+    put, _ = _placement("sharded")
+    host = _leaf(np.random.default_rng(5))
+    cache = DeviceRowCache(budget_bytes=64 << 20)
+    arr = cache.get_row(("leaf",), lambda: host, device_put=put)
+    new_row = np.full(WORDS_PER_SHARD, 7, np.uint32)
+
+    def probe(ev):
+        return lambda a: _patch_sharded(
+            a, 5, lambda piece, r: piece.at[r].set(new_row))
+
+    cache.register_updater(("leaf",), ("", "i", "f"), probe)
+    cache.apply_write(WriteEvent("i", "f", "standard", 5, 0))
+    patched = cache.get_row(("leaf",), lambda: pytest.fail("re-decoded"))
+    assert patched is not arr and cache.updates == 1
+    assert patched.sharding == arr.sharding
+    assert chip_bytes(patched) == host.nbytes // 4
+    assert cache.bytes_used == host.nbytes // 4
+    want = host.copy()
+    want[5] = new_row
+    np.testing.assert_array_equal(np.asarray(patched), want)
+    cache.invalidate(("leaf",))
+    assert cache.bytes_used == 0
+
+
+def test_metrics_report_the_per_chip_figure_and_each_chips_own():
+    put4, _ = _placement("sharded")
+    rng = np.random.default_rng(6)
+    sharded, single = _leaf(rng), sparse_row(rng, 2)
+    cache = DeviceRowCache(budget_bytes=64 << 20)
+    cache.get_row(("stackm", "", "i", "f", "standard", 0), lambda: sharded,
+                  device_put=put4)
+    cache.get_row(("", "i", "f", "standard", 0, 3), lambda: single)
+    shard = sharded.nbytes // 4
+    assert cache.metrics()["residency_bytes_used"] == shard + single.nbytes
+    per_chip = cache.device_bytes()
+    first = str(_mesh4().devices.ravel()[0].id)
+    assert per_chip == {
+        str(d.id): shard + (single.nbytes if str(d.id) == first else 0)
+        for d in _mesh4().devices.ravel()}
+    text = cache.prometheus_lines()
+    assert f"pilosa_tpu_residency_bytes_used {shard + single.nbytes}\n" in text
+    assert "# TYPE pilosa_tpu_residency_device_bytes gauge\n" in text
+    for d, n in per_chip.items():
+        assert f'pilosa_tpu_residency_device_bytes{{device="{d}"}} {n}\n' \
+            in text
+    # the heat map's overlay adds up to the same figure
+    per_frag, per_field = cache.residency_overlay()
+    assert per_field == {("", "i", "f"): shard}
+    assert per_frag == {("", "i", "f", 0): single.nbytes}
