@@ -360,7 +360,7 @@ def leaf_key(idx, spec, block: ShardBlock) -> tuple:
                 spec.row, block.key())
     if isinstance(spec, _PlanesSpec):
         return ("stackp", idx.scope, idx.name, spec.field, 2 + spec.depth,
-                block.key())
+                spec.pad_rows, block.key())
     if isinstance(spec, _ZeroSpec):
         return ("stackz", block.key())
     raise PQLError(f"unknown leaf spec {type(spec).__name__}")
@@ -406,11 +406,13 @@ def stacked_leaf(idx, spec, block: ShardBlock, device_put=None):
         depth = 2 + spec.depth
         bsi_view = view_name_bsi(spec.field)
         key = leaf_key(idx, spec, block)
+        pad = ((0, spec.pad_rows), (0, 0))   # zero rows after the planes
 
         def decode():
             return block.stack(
-                lambda shard: host_planes(idx, spec, shard, depth),
-                inner=(depth, WORDS_PER_SHARD),
+                lambda shard: np.pad(host_planes(idx, spec, shard, depth),
+                                     pad),
+                inner=(depth + spec.pad_rows, WORDS_PER_SHARD),
             )
 
         def decode_row(ev):
@@ -546,36 +548,35 @@ def minmax_merge(values, counts, want_max: bool):
 COUNT_CHUNK_WORDS = 1 << 18
 
 
+def elementwise_words(node) -> bool:
+    """True for a tree of and/or/xor/diff over word leaves
+    (leaf/const0): bit position never matters, so it can be evaluated
+    on any flat chunk or tile of its leaves. No shift (its bit motion
+    is per shard), no BSI ops, and no ``flipall``: the stacked block
+    pads its shard axis with zero slots, and an unmasked NOT turns those
+    into all-ones words. The compiler never emits it (Not lowers to
+    diff(exists, x), masked by construction), so excluding it costs
+    nothing and removes the latent hazard for hand-built trees
+    (ADVICE r4)."""
+    if node[0] in ("leaf", "const0"):
+        return True
+    if node[0] in ("and", "or", "xor", "diff"):
+        return all(elementwise_words(c) for c in node[1:])
+    return False
+
+
 def count_elementwise_sub(structure, leaf_ranks: tuple):
     """For a ('count', sub) structure whose tree is purely elementwise
-    over rank-1 word leaves (and/or/xor/diff/leaf/const0 — no shift,
-    whose bit motion is per-shard, and no BSI ops), return ``sub``; else
-    None. Such counts need no per-shard vmap: bit position never
-    matters, so the whole stacked block reduces as one flat array in
-    wider chunks (COUNT_CHUNK_WORDS) — the per-shard row width of 2^15
-    words costs measurable reduction overhead on TPU.
-
-    ``flipall`` deliberately DISQUALIFIES: the stacked block pads its
-    shard axis to a power of two with zero slots, and an unmasked NOT
-    turns those into all-ones words that the flat reduction would count.
-    The compiler never emits it (Not lowers to diff(exists, x), masked
-    by construction), so excluding it costs nothing and removes the
-    latent hazard for hand-built trees (ADVICE r4)."""
+    over rank-1 word leaves (elementwise_words), return ``sub``; else
+    None. Such counts need no per-shard vmap: the whole stacked block
+    reduces as one flat array in wider chunks (COUNT_CHUNK_WORDS) — the
+    per-shard row width of 2^15 words costs measurable reduction
+    overhead on TPU."""
     if not structure or structure[0] != "count":
         return None
     if any(r != 1 for r in leaf_ranks):
         return None
-
-    def ok(n):
-        if not isinstance(n, tuple):
-            return True
-        if n[0] in ("leaf", "const0"):
-            return True
-        if n[0] in ("and", "or", "xor", "diff"):
-            return all(ok(c) for c in n[1:])
-        return False
-
-    return structure[1] if ok(structure[1]) else None
+    return structure[1] if elementwise_words(structure[1]) else None
 
 
 def count_flat(sub, leaves, scalars):
@@ -689,87 +690,342 @@ def local_fn_batched(structure, reduce_kind: str, leaf_ranks: tuple,
     return fn
 
 
-# HBM budget for the materialized per-level group masks ([C, words] per
-# gathered dimension per shard block). Chunks are sized so the gathered
-# intermediates stay under this even at full shard counts.
-GROUPBY_MASK_BUDGET_BYTES = 256 << 20
+# ----------------------------------------------------- GroupBy level kernel
+#
+# A level is computed word tile by word tile with every candidate's
+# accumulators held on-chip: each operand row is read from HBM once a
+# level and no [C, words] mask is ever written to it. The device's own
+# layout of a stacked matrix u32[S, n, W] keeps the shard slots on the
+# sublanes unless n is 1, 2 or a multiple of eight (XLA puts the small
+# dimension outermost rather than pad it), so the kernel is handed the
+# [n, S, W] view (a bitcast there) and a candidate's row for eight slots
+# is one dense (8, tile) block picked by a dynamic index on the leading
+# dimension. The caller keeps a dimension's and the planes' row count off
+# those values with zero rows (groupby_pad_rows).
+
+_LANES = 128
+_SUBLANES = 8
+
+# The one allowance everything a level program holds in VMEM is sized
+# against, from static shapes: an eighth of it the accumulator block
+# (one 128-lane int32 row of lane partials per candidate and counted
+# quantity, resident for the whole program: this is what bounds the
+# candidates a program, not anything in HBM), three eighths the operand
+# tiles; the rest is the pipeline's second output buffer and the
+# compiler's own.
+GROUPBY_VMEM_BYTES = 32 << 20
+
+# A candidate walks a tile eight vregs an operand row at a time (one
+# load with one dynamic address), and the walk is unrolled to one basic
+# block of up to 64 vregs, the register file, for the scheduler to
+# overlap; a Sum's many quantities already fill an iteration.
+_CHUNK_WORDS = 8 * _LANES
+_CHUNK_UNROLL = 8
 
 
-def groupby_chunk_groups(block: ShardBlock, n_gather: int, depth: int) -> int:
-    """Max candidate groups per level chunk under the mask byte budget."""
-    s_per_dev = -(-block.padded // block.n_devices)
-    bytes_per_group = s_per_dev * WORDS_PER_SHARD * 4 * (n_gather + depth)
-    return max(1, GROUPBY_MASK_BUDGET_BYTES // max(bytes_per_group, 1))
+def groupby_chunk_groups(n_planes: int) -> int:
+    """Max candidates per level program (n_planes: the aggregate's
+    depth + 2, 0 without one): the accumulator block against its eighth
+    of GROUPBY_VMEM_BYTES, a power of two (8192 count-only, 256 with a
+    16-bit Sum). From static shapes only."""
+    quantities = max(n_planes, 1)
+    fit = GROUPBY_VMEM_BYTES // 8 // (quantities * _LANES * 4)
+    return max(_SUBLANES, next_pow2(fit + 1) >> 1)
 
 
-def groupby_level_body(ls, idxs, scalars, filt_structure, n_filt: int,
-                       n_gather: int, has_agg: bool):
-    """Per-shard GroupBy level kernel shared by the local and SPMD
-    builders: gather each candidate's row from every dimension matrix,
-    AND them into [C, words] group masks, popcount per candidate; with an
-    aggregate also per-candidate BSI plane counts (expr 'bsisum' semantics
-    per group)."""
-    filt_leaves = ls[:n_filt]
-    dim_mats = ls[n_filt:n_filt + n_gather]
-    with jax.named_scope("groupby_gather"):
-        mask = jnp.take(dim_mats[0], idxs[0], axis=0)  # [C, W]
-        for d, ii in zip(dim_mats[1:], idxs[1:]):
-            mask = mask & jnp.take(d, ii, axis=0)
-    if filt_structure is not None:
+def groupby_pad_rows(n_rows: int) -> int:
+    """Zero rows a GroupBy appends to a dimension or plane matrix of
+    n_rows (stacked_matrix's and the planes spec's pad_rows) so that XLA
+    keeps its shard slots on the sublanes and the kernel's [n, S, W]
+    view is never a copy."""
+    pad = 0
+    while n_rows + pad <= 2 or (n_rows + pad) % _SUBLANES == 0:
+        pad += 1
+    return pad
+
+
+def groupby_tile_plan(dim_rows: tuple, other_rows: int, slots: int,
+                      words: int):
+    """(word-tile width, which dimensions are paged) of a level program.
+
+    A resident dimension has every row of its tile in VMEM (read from
+    HBM once a level); filter leaves and planes (other_rows) always are.
+    The tile is as wide as twice those rows (the pipeline's two buffers)
+    allow for one block of shard slots. Where that would leave less than
+    one chunk of the walk, the dimension with the most rows is paged
+    instead: it stays in HBM and each candidate's row tile is copied in
+    by its index (two tiles a paged dimension, whatever its row count).
+    """
+    budget = 3 * GROUPBY_VMEM_BYTES // 8
+    paged = [False] * len(dim_rows)
+    while True:
+        rows = other_rows + sum(1 if p else n
+                                for n, p in zip(dim_rows, paged))
+        fit = budget // (2 * max(rows, 1) * slots * 4)
+        tw = min(words, next_pow2(fit + 1) >> 1)
+        if tw >= min(_CHUNK_WORDS, words) or all(paged):
+            return max(_LANES, tw), tuple(paged)
+        resident = [n if not p else -1 for n, p in zip(dim_rows, paged)]
+        paged[resident.index(max(resident))] = True
+
+
+def _pallas_interpret() -> bool:
+    # off the TPU the same kernel body runs through Pallas' interpreter
+    return jax.default_backend() != "tpu"
+
+
+def groupby_level_body(leaves, idxs, scalars, filt_structure, n_filt: int,
+                       n_gather: int, n_planes: int):
+    """GroupBy level over whole stacked leaves (leading axis: this
+    device's shard slots), shared by the local and SPMD builders.
+
+    leaves: filt leaves ++ dim matrices [S, n_i, W] ++ (planes
+    [S, n_planes + pad, W] if n_planes, which is the aggregate's
+    depth + 2), W a multiple of 128; a matrix's rows past those the
+    candidates and n_planes name are padding (groupby_pad_rows). idxs:
+    int32[n_gather, C] candidate rows; a negative entry in idxs[0] marks
+    padding, after the real candidates. Returns split sums over the
+    slots: counts [2, C], and with an aggregate (counts, n_g [2, C],
+    plane_counts [2, depth, C]) (expr 'bsisum' semantics per
+    candidate)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    filt_leaves = list(leaves[:n_filt])
+    dim_mats = leaves[n_filt:n_filt + n_gather]
+    has_agg = n_planes > 0
+    planes = leaves[n_filt + n_gather] if has_agg else None
+    slots, _, words = dim_mats[0].shape
+    c_pad = idxs.shape[1]
+
+    if filt_structure is not None and not (
+            n_filt and all(f.ndim == 2 for f in filt_leaves)
+            and elementwise_words(filt_structure)):
+        # shifts, BSI comparisons and empty trees are evaluated once by
+        # XLA into one row a slot; the kernel sees a single leaf
         with jax.named_scope("groupby_filter"):
-            f = expr._go(filt_structure, filt_leaves, scalars)
-            mask = mask & f[None, :]
-    with jax.named_scope("groupby_reduce"):
-        counts = jnp.sum(lax.population_count(mask).astype(jnp.int32),
-                         axis=-1)
-        if not has_agg:
-            return counts
-        planes = ls[n_filt + n_gather]
-        gmask = mask & planes[expr.PLANES_EXISTS][None, :]
-        n_g = jnp.sum(lax.population_count(gmask).astype(jnp.int32),
-                      axis=-1)
-        plane_counts = jnp.stack([
-            jnp.sum(lax.population_count(planes[b][None, :] & gmask)
-                    .astype(jnp.int32), axis=-1)
-            for b in range(expr.PLANES_OFFSET, planes.shape[0])
-        ])  # [depth, C]
-    return counts, n_g, plane_counts
+            if n_filt:
+                f = jax.vmap(lambda *ls: expr._go(filt_structure, ls,
+                                                  scalars))(*filt_leaves)
+            else:
+                f = jnp.zeros((slots, words), jnp.uint32)
+        filt_leaves, filt_structure = [f], ("leaf", 0)
+    n_filt = len(filt_leaves)
+
+    quantities = max(n_planes, 1)   # count, n_g, depth planes
+    sb = min(_SUBLANES, slots)
+    tw, paged = groupby_tile_plan(tuple(m.shape[1] for m in dim_mats),
+                                  n_filt + n_planes, sb, words)
+    paged_dims = [d for d in range(n_gather) if paged[d]]
+    cw = min(_CHUNK_WORDS, tw)
+    unroll = min(tw // cw, max(1, _CHUNK_UNROLL // quantities))
+    own_filter = filt_structure is not None and filt_structure[0] != "leaf"
+    n_real = jnp.sum((idxs[0] >= 0).astype(jnp.int32))
+    prefetch = jnp.concatenate(
+        [jnp.maximum(idxs, 0).reshape(-1), n_real[None]])
+
+    def kernel(idx_ref, *refs):
+        filt_refs = refs[:n_filt]
+        dim_refs = refs[n_filt:n_filt + n_gather]
+        planes_ref = refs[n_filt + n_gather] if has_agg else None
+        n_in = n_filt + n_gather + (1 if has_agg else 0)
+        out_ref = refs[n_in]
+        scratch = list(refs[n_in + 1:])
+        f_scratch = scratch.pop(0) if own_filter else None
+        page_refs = {d: (scratch[2 * k], scratch[2 * k + 1])
+                     for k, d in enumerate(paged_dims)}
+
+        def chunk(k):
+            return pl.ds(pl.multiple_of(k * cw, cw), cw)
+
+        f_ref = None
+        if filt_structure is not None:
+            if filt_structure[0] == "leaf":
+                f_ref = filt_refs[filt_structure[1]]
+            else:
+                # the filter expression once a tile, not once a candidate
+                f_ref = f_scratch
+
+                def filter_chunk(k, _):
+                    f_ref[:, chunk(k)] = expr._go(
+                        filt_structure, [r[:, chunk(k)] for r in filt_refs],
+                        ())
+                    return 0
+
+                lax.fori_loop(0, tw // cw, filter_chunk, 0)
+
+        slot_block, word_tile = pl.program_id(0), pl.program_id(1)
+
+        @pl.when((slot_block == 0) & (word_tile == 0))
+        def _():
+            out_ref[...] = jnp.zeros_like(out_ref)
+
+        def page(d, c, half):
+            """The copy of candidate c's row tile of paged dimension d
+            into one half of its buffer."""
+            buf, sem = page_refs[d]
+            return pltpu.make_async_copy(
+                dim_refs[d].at[idx_ref[d * c_pad + c],
+                               pl.ds(slot_block * sb, sb),
+                               pl.ds(word_tile * tw, tw)],
+                buf.at[half], sem.at[half])
+
+        def lanes(x):
+            """Popcounts of an (sb, cw) chunk folded to (sb, 128): the
+            columns meet pairwise, not in one chain of adds."""
+            pc = lax.population_count(x).astype(jnp.int32)
+            cols = [pc[:, k * _LANES:(k + 1) * _LANES]
+                    for k in range(cw // _LANES)]
+            while len(cols) > 1:
+                cols = [a + b for a, b in zip(cols[::2], cols[1::2])]
+            return cols[0]
+
+        n_cand = idx_ref[n_gather * c_pad]
+
+        def candidate(c, _):
+            at = [idx_ref[d * c_pad + c] for d in range(n_gather)]
+            if paged_dims:
+                half = lax.rem(c, 2)
+                for d in paged_dims:
+                    page(d, c, half).wait()
+
+                # the next candidate's rows arrive while this one counts
+                @pl.when(c + 1 < n_cand)
+                def _():
+                    for d in paged_dims:
+                        page(d, c + 1, 1 - half).start()
+
+            def row(d, k):
+                if paged[d]:
+                    return page_refs[d][0][half, :, chunk(k)]
+                return dim_refs[d][at[d], :, chunk(k)]
+
+            def counted(k):
+                """The words whose bits a chunk adds to each quantity, one
+                at a time: made all at once they would not fit the
+                vector registers."""
+                m = row(0, k)
+                for d in range(1, n_gather):
+                    m = m & row(d, k)
+                if f_ref is not None:
+                    m = m & f_ref[:, chunk(k)]
+                yield m
+                if has_agg:
+                    gm = m & planes_ref[expr.PLANES_EXISTS, :, chunk(k)]
+                    yield gm
+                    for b in range(expr.PLANES_OFFSET, n_planes):
+                        yield planes_ref[b, :, chunk(k)] & gm
+
+            def chunks(i, acc):
+                for u in range(unroll):
+                    acc = tuple(a + lanes(x) for a, x in
+                                zip(acc, counted(i * unroll + u)))
+                return acc
+
+            acc = lax.fori_loop(
+                0, tw // (cw * unroll), chunks,
+                tuple(jnp.zeros((sb, _LANES), jnp.int32)
+                      for _ in range(quantities)))
+            for q, a in enumerate(acc):
+                out_row = pl.ds(q * c_pad + c, 1)
+                out_ref[out_row, :] = out_ref[out_row, :] + jnp.sum(
+                    a, axis=0, keepdims=True)
+            return 0
+
+        if paged_dims:
+            @pl.when(n_cand > 0)
+            def _():
+                for d in paged_dims:
+                    page(d, 0, 0).start()
+
+        # the candidates after the last real one are padding
+        lax.fori_loop(0, n_cand, candidate, 0)
+
+    def by_row(n_rows):
+        return pl.BlockSpec((n_rows, sb, tw), lambda i, j, *_: (0, i, j))
+
+    in_specs = [pl.BlockSpec((sb, tw), lambda i, j, *_: (i, j))] * n_filt
+    scratch_shapes = ([pltpu.VMEM((sb, tw), jnp.uint32)]
+                      if own_filter else [])
+    for m, p in zip(dim_mats, paged):
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY) if p
+                        else by_row(m.shape[1]))
+        if p:
+            scratch_shapes += [pltpu.VMEM((2, sb, tw), jnp.uint32),
+                               pltpu.SemaphoreType.DMA((2,))]
+    operands = filt_leaves + [jnp.swapaxes(m, 0, 1) for m in dim_mats]
+    if has_agg:
+        in_specs.append(by_row(n_planes))   # the pad rows are never read
+        operands.append(jnp.swapaxes(planes, 0, 1))
+    # the partials vary over whatever mesh axes the leaves do
+    vma = jax.typeof(dim_mats[0]).vma
+    with jax.named_scope("groupby_level"):
+        partials = pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct((quantities * c_pad, _LANES),
+                                           jnp.int32, vma=vma),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(slots // sb, words // tw),
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec((quantities * c_pad, _LANES),
+                                       lambda i, j, *_: (0, 0)),
+                scratch_shapes=scratch_shapes,
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=GROUPBY_VMEM_BYTES,
+            ),
+            interpret=_pallas_interpret(),
+            name="groupby_level",
+        )(prefetch, *operands)
+    # a lane partial is at most 2^13 a slot, so it cannot wrap before
+    # 2^18 slots; the lanes are summed in the two split channels and the
+    # carry moved up, which leaves both inside the bounds a sum of
+    # per-slot splits has (reduction.split_channel_bounds)
+    lo, hi = split_sum(partials.reshape(quantities, c_pad, _LANES), axis=-1)
+    out = jnp.stack([lo & SPLIT_MASK, hi + (lo >> SPLIT_SHIFT)])
+    if not has_agg:
+        return out[:, 0]
+    return out[:, 0], out[:, 1], out[:, expr.PLANES_OFFSET:]
+
+
+def unpack_groupby_operand(packed, n_gather: int, n_scalars: int):
+    """The one int32 operand a level program takes after its leaves
+    (Executor._groupby_operand_put packs it): the candidate index arrays
+    end to end, then the scalars. Returns (idxs [n_gather, C], scalars)."""
+    c = (packed.shape[0] - n_scalars) // n_gather
+    idxs = packed[:n_gather * c].reshape(n_gather, c)
+    return idxs, tuple(packed[n_gather * c + i] for i in range(n_scalars))
 
 
 def local_groupby_level_fn(filt_structure, n_filt: int, n_scalars: int,
-                           n_gather: int, has_agg: bool):
-    """Single-device GroupBy level program.
+                           n_gather: int, n_planes: int):
+    """Single-device GroupBy level program (n_planes: the aggregate's
+    depth + 2, 0 without one).
 
     Args: filt leaves ++ dim matrices [S, n_i, W] ++ (planes
-    [S, depth+2, W] if agg) ++ candidate index arrays int32[C] (one per
-    gathered dim) ++ scalars. Packed result (split sums, [2, ·] raveled):
-    counts [2·C] without agg, else counts [2·C] ++ n_g [2·C] ++
-    plane_counts [2·depth·C].
+    [S, n_planes + pad, W] if agg) ++ ONE int32 array
+    (unpack_groupby_operand).
+    Packed result (split sums, [2, ·] raveled): counts [2·C] without
+    agg, else counts [2·C] ++ n_g [2·C] ++ plane_counts [2·depth·C].
     """
-    key = ("localgbl", filt_structure, n_filt, n_scalars, n_gather, has_agg)
+    key = ("localgbl", filt_structure, n_filt, n_scalars, n_gather, n_planes)
     fn = _LOCAL_JIT_CACHE.get(key)
     if fn is not None:
         return fn
 
-    n_leaves = n_filt + n_gather + (1 if has_agg else 0)
+    n_leaves = n_filt + n_gather + (1 if n_planes else 0)
 
     def body(*args):
-        leaves = args[:n_leaves]
-        idxs = args[n_leaves:n_leaves + n_gather]
-        scalars = args[n_leaves + n_gather:]
-
-        def per_shard(*ls):
-            return groupby_level_body(
-                ls, idxs, scalars, filt_structure, n_filt, n_gather, has_agg
-            )
-
-        out = jax.vmap(per_shard)(*leaves)
-        if not has_agg:
-            return split_sum(out, axis=0).ravel()
-        counts, n_g, plane_counts = (split_sum(o, axis=0) for o in out)
-        return jnp.concatenate(
-            [counts.ravel(), n_g.ravel(), plane_counts.ravel()]
-        )
+        idxs, scalars = unpack_groupby_operand(
+            args[n_leaves], n_gather, n_scalars)
+        out = groupby_level_body(
+            args[:n_leaves], idxs, scalars, filt_structure, n_filt,
+            n_gather, n_planes)
+        if not n_planes:
+            return out.ravel()
+        return jnp.concatenate([o.ravel() for o in out])
 
     fn = named_jit("groupby_level", body)
     _LOCAL_JIT_CACHE[key] = fn

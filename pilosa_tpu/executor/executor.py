@@ -35,7 +35,7 @@ from pilosa_tpu.shardwidth import WORDS_PER_SHARD, next_pow2, position, shard_of
 from pilosa_tpu.storage import residency
 from pilosa_tpu.storage.heat import global_heat
 from pilosa_tpu.utils.cost import current_cost, use_node
-from pilosa_tpu.utils.tracing import stage, staged
+from pilosa_tpu.utils.tracing import note_groupby_level, stage, staged
 from pilosa_tpu.storage.field import (
     BSI_EXISTS_ROW,
     TYPE_INT,
@@ -58,8 +58,8 @@ TOPN_MATRIX_BUDGET_BYTES = 1 << 30
 
 # GroupBy cross-products at or below this size are evaluated in a single
 # level (one device sync); larger ones use per-dimension prefix pruning
-# (one sync per dimension). Memory is bounded separately, by
-# batch.GROUPBY_MASK_BUDGET_BYTES-based chunking, at any size.
+# (one sync per dimension). No level holds its group masks in HBM
+# (batch.groupby_level_body), so memory does not bound either path.
 GROUPBY_DENSE_MAX_GROUPS = 4096
 
 _RESERVED_ARGS = {"_field", "_col", "from", "to", "n", "limit", "offset",
@@ -104,11 +104,14 @@ class _PlanesSpec:
     ``depth`` is captured at compile time so a delete_field racing the
     query resolves to correctly-shaped zeros, not a dead dereference."""
 
-    __slots__ = ("field", "depth")
+    __slots__ = ("field", "depth", "pad_rows")
 
-    def __init__(self, field: str, depth: int):
+    def __init__(self, field: str, depth: int, pad_rows: int = 0):
         self.field = field
         self.depth = depth
+        # zero rows after the planes of the STACKED leaf (a GroupBy's
+        # level kernel: batch.groupby_pad_rows); resolve() has none
+        self.pad_rows = pad_rows
 
     def resolve(self, idx: Index, shard: int):
         # compile-time depth throughout: the node's clamped scalars were
@@ -582,35 +585,38 @@ class Executor:
         return batch.local_fn(structure, reduce_kind, leaf_ranks, n_scalars)
 
     def _groupby_level_program(self, filt_structure, n_filt: int,
-                               n_scalars: int, n_gather: int, has_agg: bool,
+                               n_scalars: int, n_gather: int, n_planes: int,
                                quantized: bool = False):
         # single-device execution never quantizes (there is no wire);
         # DistExecutor routes quantized=True pruning levels through the
         # 8-bit ranking lane
         return batch.local_groupby_level_fn(
-            filt_structure, n_filt, n_scalars, n_gather, has_agg
+            filt_structure, n_filt, n_scalars, n_gather, n_planes
         )
 
     # stage that times _groupby_operand_put's placements
     _operand_stage = "device.upload"
 
     def _groupby_operand_put(self, scalars):
-        """Placement hook beside _leaf_put for the small per-dispatch
-        operands of a GroupBy level: returns put(ci), which takes one
-        chunk's candidate indices int32[C, n_gather] to the trailing
-        arguments of _groupby_level_program's program. Here: one index
-        array per gathered dimension and then the scalars, each made on
-        the default device. DistExecutor packs them into one array
-        replicated over the mesh."""
-        import jax.numpy as jnp
-
-        jscalars = tuple(jnp.asarray(s, jnp.int32) for s in scalars)
+        """Placement beside _leaf_put for the small per-dispatch operand
+        of a GroupBy level: returns put(ci), which takes one chunk's
+        candidate indices int32[C, n_gather] to the ONE int32 array the
+        level program takes after its leaves: the index arrays end to
+        end, then the scalars (batch.unpack_groupby_operand). One array,
+        because every host argument of a program call is a transfer of
+        its own."""
+        tail = np.asarray(scalars, np.int32).reshape(-1)
 
         def put(ci):
-            return tuple(jnp.asarray(ci[:, d], jnp.int32)
-                         for d in range(ci.shape[1])) + jscalars
+            return self._operand_place(np.concatenate(
+                [np.asarray(ci, np.int32).T.reshape(-1), tail]))
 
         return put
+
+    def _operand_place(self, packed):
+        import jax.numpy as jnp
+
+        return jnp.asarray(packed)
 
     # EQuARX quantized candidate-ranking lane: inert on the base
     # executor (no inter-group wire to shrink); DistExecutor overrides
@@ -1673,9 +1679,9 @@ class Executor:
         stacked dimension matrices, counted per shard, and reduced on
         device — so the whole GroupBy costs one device sync per dimension
         (and exactly one when the cross-product is small enough to skip
-        pruning). Chunking inside a level is byte-budgeted
-        (batch.GROUPBY_MASK_BUDGET_BYTES) so the dense group masks never
-        outgrow HBM.
+        pruning). A level reads each operand row once and keeps every
+        candidate's accumulators on-chip (batch.groupby_level_body); it
+        is chunked only past batch.groupby_chunk_groups candidates.
 
         Pipelined (submit): the common dense single-level case enqueues
         its level program WITHOUT the blocking readback — the host sync
@@ -1713,14 +1719,20 @@ class Executor:
             for fname, row_ids in dims:
                 field = idx.field(fname)
                 view = field.view(VIEW_STANDARD) if field else None
+                # zero rows keep the matrix in the layout whose
+                # [n, S, W] view the level kernel reads in place
                 dim_mats.append(
-                    batch.stacked_matrix(idx, fname, view, row_ids, block,
-                                         put)
+                    batch.stacked_matrix(
+                        idx, fname, view, row_ids, block, put,
+                        pad_rows=batch.groupby_pad_rows(len(row_ids)))
                 )
             planes = (
                 batch.stacked_leaf(
                     idx,
-                    _PlanesSpec(agg_field.name, agg_field.options.bit_depth),
+                    _PlanesSpec(
+                        agg_field.name, agg_field.options.bit_depth,
+                        pad_rows=batch.groupby_pad_rows(
+                            2 + agg_field.options.bit_depth)),
                     block, put,
                 )
                 if agg_field is not None
@@ -1836,52 +1848,58 @@ class Executor:
                                scalars, dim_mats, cand: np.ndarray, planes,
                                agg_field, quantized: bool = False):
         """Dispatch one level's per-candidate counts (plus BSI aggregate
-        partials on the final level), chunked to the mask byte budget,
-        all chunks concatenated on device. Returns (device packed array,
-        chunk layout) — no host sync."""
+        partials on the final level): one program, unless the level has
+        more candidates than the kernel's accumulator block holds
+        (batch.groupby_chunk_groups), when the chunks' results are
+        concatenated on device. Returns (device packed array, chunk
+        layout) — no host sync."""
         import jax.numpy as jnp
 
         n_gather = len(dim_mats)
         has_agg = planes is not None
         depth = agg_field.options.bit_depth if has_agg else 0
         c_total = cand.shape[0]
-        chunk = batch.groupby_chunk_groups(block, n_gather, depth)
+        n_planes = 2 + depth if has_agg else 0
+        chunk = batch.groupby_chunk_groups(n_planes)
         if quantized and has_agg:
             raise AssertionError(
                 "quantized GroupBy levels never carry aggregates "
                 "(the final level is always lossless)"
             )
         fn = self._groupby_level_program(
-            filt_node, len(filt_leaves), len(scalars), n_gather, has_agg,
+            filt_node, len(filt_leaves), len(scalars), n_gather, n_planes,
             quantized=quantized,
         )
-        with stage(self._operand_stage):
-            put = self._groupby_operand_put(scalars)
+        put = self._groupby_operand_put(scalars)
+        args = list(filt_leaves) + list(dim_mats)
+        if has_agg:
+            args.append(planes)
 
         packs = []
         layout = []  # (padded, actual) per chunk
         for lo in range(0, c_total, chunk):
             ci = cand[lo: lo + chunk]
             actual = ci.shape[0]
-            padded = min(chunk, next_pow2(actual))
+            # padding is marked negative: the kernel stops at the last
+            # real candidate (at least 8 wide, so that the smallest
+            # levels share one compiled shape)
+            padded = max(8, next_pow2(actual))
             if padded > actual:
                 ci = np.concatenate(
-                    [ci, np.zeros((padded - actual, n_gather), np.int32)]
+                    [ci, np.full((padded - actual, n_gather), -1, np.int32)]
                 )
             with stage(self._operand_stage):
-                operands = put(ci)
-            args = list(filt_leaves) + list(dim_mats)
-            if has_agg:
-                args.append(planes)
+                operand = put(ci)
             site = stage("device.dispatch", reduce="groupby")
             with site:
-                packs.append(fn(*args, *operands))
+                packs.append(fn(*args, operand))
             cost = current_cost()
             if cost is not None:
                 cost.note_dispatch(site.elapsed)
             self._note_reduce("groupby_q" if quantized else "groupby",
                               packs[-1].shape, block.padded)
             layout.append((padded, actual))
+        note_groupby_level(len(packs))
 
         if len(packs) == 1:
             return packs[0], layout

@@ -31,7 +31,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -216,7 +215,7 @@ def _dist_fn_batched(mesh, structure, reduce_kind: str, leaf_ranks: tuple,
 
 
 def _dist_groupby_level_fn(mesh, filt_structure, n_filt: int, n_scalars: int,
-                           n_gather: int, has_agg: bool,
+                           n_gather: int, n_planes: int,
                            quantized: bool = False):
     """SPMD GroupBy level program (same per-shard body as the local
     builder, reduced over the mesh — hierarchically on a 2-D mesh, like
@@ -224,26 +223,23 @@ def _dist_groupby_level_fn(mesh, filt_structure, n_filt: int, n_scalars: int,
     counts through the 8-bit ranking lane — only intermediate PRUNING
     levels use it (their counts merely gate candidate survival); the
     final level always stays lossless, so reported counts are exact."""
-    key = ("gbl", mesh, filt_structure, n_filt, n_scalars, n_gather, has_agg,
+    key = ("gbl", mesh, filt_structure, n_filt, n_scalars, n_gather, n_planes,
            quantized)
     fn = _DIST_JIT_CACHE.get(key)
     if fn is not None:
         return fn
 
     hier = mesh_groups(mesh)
-    n_leaves = n_filt + n_gather + (1 if has_agg else 0)
-    # the leaves, then ONE replicated int32 array: the candidate index
-    # arrays end to end, then the scalars (DistExecutor.
-    # _groupby_operand_put packs it; every host argument of a mesh
+    n_leaves = n_filt + n_gather + (1 if n_planes else 0)
+    # the leaves, then ONE replicated int32 array
+    # (batch.unpack_groupby_operand; every host argument of a mesh
     # program is a placement on every chip, so there is one)
     in_specs = tuple(shards_spec(mesh) for _ in range(n_leaves)) + (P(),)
 
     def body(*args):
         leaves = args[:n_leaves]
-        packed = args[n_leaves]
-        c = (packed.shape[0] - n_scalars) // n_gather
-        idxs = tuple(packed[d * c:(d + 1) * c] for d in range(n_gather))
-        scalars = tuple(packed[n_gather * c + i] for i in range(n_scalars))
+        idxs, scalars = batch.unpack_groupby_operand(
+            args[n_leaves], n_gather, n_scalars)
         group_slots = leaves[0].shape[0] * (hier[1] if hier else 1)
 
         def reduce_split(packed_local):
@@ -254,27 +250,26 @@ def _dist_groupby_level_fn(mesh, filt_structure, n_filt: int, n_scalars: int,
                 part, GROUPS_AXIS, group_slots
             )
 
-        def per_shard(*ls):
-            return batch.groupby_level_body(
-                ls, idxs, scalars, filt_structure, n_filt, n_gather, has_agg
-            )
-
-        out = jax.vmap(per_shard)(*leaves)
-        if not has_agg:
-            packed = batch.split_sum(out, axis=0)
+        out = batch.groupby_level_body(
+            leaves, idxs, scalars, filt_structure, n_filt, n_gather, n_planes
+        )
+        if not n_planes:
             if quantized:
-                part = lax.psum(packed, SHARDS_AXIS)
+                part = lax.psum(out, SHARDS_AXIS)
                 return reduction.hier_quantized_counts(
                     part, GROUPS_AXIS if hier is not None else None
                 ).ravel()
-            return reduce_split(packed).ravel()
-        return jnp.concatenate([
-            reduce_split(batch.split_sum(o, axis=0)).ravel() for o in out
-        ])
+            return reduce_split(out).ravel()
+        return jnp.concatenate([reduce_split(o).ravel() for o in out])
 
+    # the kernel's partials vary over the mesh like its leaves, so the
+    # flat mesh keeps its varying-axes check; Pallas' interpreter carries
+    # the kernel's scratch through its grid loop without them, so the
+    # check is off where the interpreter runs the body
     fn = named_jit(
         "dist_groupby_level",
-        _smap(body, mesh=mesh, in_specs=in_specs, out_specs=P(), hier=hier)
+        shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                  check_vma=hier is None and not batch._pallas_interpret())
     )
     _DIST_JIT_CACHE[key] = fn
     return fn
@@ -357,22 +352,11 @@ class DistExecutor(Executor):
 
     _operand_stage = "device.replicate"
 
-    def _groupby_operand_put(self, scalars):
-        """One packed int32 array a dispatch, placed on every chip of
-        the mesh at once: the chunk's candidate index arrays end to end,
-        then the scalars (_dist_groupby_level_fn unpacks it). Made one
-        by one on the default device, as the base executor makes them,
-        each was a host transfer and then a copy to every other chip at
-        the program call."""
-        sharding = replicated(self.mesh)
-        tail = np.asarray(scalars, np.int32).reshape(-1)
-
-        def put(ci):
-            packed = np.concatenate(
-                [np.asarray(ci, np.int32).T.reshape(-1), tail])
-            return (jax.device_put(packed, sharding),)
-
-        return put
+    def _operand_place(self, packed):
+        # one placement on every chip of the mesh at once; made on the
+        # default device it would be a host transfer and then a copy to
+        # every other chip at the program call
+        return jax.device_put(packed, replicated(self.mesh))
 
     def _program(self, structure, reduce_kind, leaf_ranks, n_scalars):
         return _dist_fn(self.mesh, structure, reduce_kind, leaf_ranks,
@@ -384,9 +368,9 @@ class DistExecutor(Executor):
                                 n_scalars, n_queries)
 
     def _groupby_level_program(self, filt_structure, n_filt, n_scalars,
-                               n_gather, has_agg, quantized=False):
+                               n_gather, n_planes, quantized=False):
         return _dist_groupby_level_fn(
-            self.mesh, filt_structure, n_filt, n_scalars, n_gather, has_agg,
+            self.mesh, filt_structure, n_filt, n_scalars, n_gather, n_planes,
             quantized,
         )
 

@@ -844,10 +844,13 @@ class HTTPHandler(BaseHTTPRequestHandler):
         from pilosa_tpu.utils.tracing import (
             device_memory_by_device,
             device_metrics,
+            groupby_metrics,
             stage_metrics,
         )
 
         text += prometheus_block(stage_metrics(), prefix, "stage",
+                                 seen=seen)
+        text += prometheus_block(groupby_metrics(), prefix, "groupby",
                                  seen=seen)
         text += prometheus_block(device_metrics(), prefix, "device",
                                  seen=seen)
@@ -1128,9 +1131,14 @@ class HTTPHandler(BaseHTTPRequestHandler):
         snap["cdc"] = self.api.cdc_metrics()
         snap["integrity"] = self.api.integrity_metrics()
         snap["observability"] = self.api.observability_metrics()
-        from pilosa_tpu.utils.tracing import device_metrics, stage_metrics
+        from pilosa_tpu.utils.tracing import (
+            device_metrics,
+            groupby_metrics,
+            stage_metrics,
+        )
 
         snap["stages"] = stage_metrics()
+        snap["groupby"] = groupby_metrics()
         snap["device"] = device_metrics()
         from pilosa_tpu.parallel.reduction import global_reduce_stats
 

@@ -883,8 +883,8 @@ class DeviceRowCache:
                     if isinstance(tag, str) and tag.startswith("stack"):
                         # the stack test runs FIRST (residency_overlay's
                         # order): a plane-stack key ("stackp", scope,
-                        # index, field, 2+depth, block) is len 6 with an
-                        # int at [4] and would otherwise masquerade as a
+                        # index, field, 2+depth, pad, block) has an int
+                        # at [4] and would otherwise masquerade as a
                         # fragment entry under a bogus key with heat 0 —
                         # demoted every pass no matter how hot the field
                         if tag not in ("stack", "stackp") or len(key) < 4:

@@ -686,6 +686,30 @@ def stage_metrics() -> dict:
     return out
 
 
+# --------------------------------------------------------- GroupBy levels
+#
+# The stage site's tags reach a span and the capture, not /metrics, so
+# "how many device programs does a GroupBy level take" has two series of
+# its own beside the stages: one program a level unless a level exceeds
+# batch.groupby_chunk_groups candidates.
+
+_groupby_lock = threading.Lock()
+_groupby_stats = {"levels": 0, "programs": 0}
+
+
+def note_groupby_level(programs: int) -> None:
+    with _groupby_lock:
+        _groupby_stats["levels"] += 1
+        _groupby_stats["programs"] += programs
+
+
+def groupby_metrics() -> dict:
+    """The ``groupby`` block of /metrics and /debug/vars."""
+    with _groupby_lock:
+        return {"levels_total": _groupby_stats["levels"],
+                "level_programs_total": _groupby_stats["programs"]}
+
+
 # ------------------------------------------------- device compiles, memory
 
 _compile_lock = threading.Lock()

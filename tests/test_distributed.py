@@ -170,7 +170,7 @@ class TestDistGroupBy:
             self, env, pql):
         """A level's candidate indices and scalars are one packed array
         placed on every chip at once (stage device.replicate); the base
-        executor makes them one by one on its device (device.upload)."""
+        executor places the same array on its device (device.upload)."""
         from pilosa_tpu.utils.tracing import stage_metrics
 
         def entered():
@@ -190,17 +190,21 @@ class TestDistGroupBy:
 
     def test_packed_operand_layout(self, env, mesh):
         holder, base, dist = env
-        (packed,) = dist._groupby_operand_put((7, 9))(
-            np.array([[1, 4], [2, 5], [3, 6]]))
+        cand = np.array([[1, 4], [2, 5], [3, 6]])
+        packed = dist._groupby_operand_put((7, 9))(cand)
         assert packed.dtype == np.int32
         assert packed.sharding.is_fully_replicated
         assert packed.sharding.device_set == set(mesh.devices.ravel())
         assert np.asarray(packed).tolist() == [1, 2, 3, 4, 5, 6, 7, 9]
-        one, two, s0, s1 = base._groupby_operand_put((7, 9))(
-            np.array([[1, 4], [2, 5], [3, 6]]))
-        assert np.asarray(one).tolist() == [1, 2, 3]
-        assert np.asarray(two).tolist() == [4, 5, 6]
-        assert (int(s0), int(s1)) == (7, 9)
+        # the same one array on the base executor's one device
+        local = base._groupby_operand_put((7, 9))(cand)
+        assert len(local.sharding.device_set) == 1
+        assert np.asarray(local).tolist() == [1, 2, 3, 4, 5, 6, 7, 9]
+        from pilosa_tpu.executor import batch
+
+        idxs, scalars = batch.unpack_groupby_operand(np.asarray(local), 2, 2)
+        assert idxs.tolist() == [[1, 2, 3], [4, 5, 6]]
+        assert [int(x) for x in scalars] == [7, 9]
 
     def test_groupby_limit(self, env):
         r1, r2 = both(env, "GroupBy(Rows(f), Rows(g), limit=1)")
@@ -233,11 +237,13 @@ class TestDistGroupBy:
         assert self.groups_json(r1) == self.groups_json(r2)
 
     def test_groupby_tiny_chunk_budget(self, env, monkeypatch):
-        """A mask byte budget so small every level runs one candidate per
-        chunk must still produce identical results (chunk concat + unpack)."""
+        """A candidate bound of one, so that every level runs one
+        candidate a program, must still produce identical results (chunk
+        concat + unpack)."""
         from pilosa_tpu.executor import batch as batch_mod
 
-        monkeypatch.setattr(batch_mod, "GROUPBY_MASK_BUDGET_BYTES", 1)
+        monkeypatch.setattr(batch_mod, "groupby_chunk_groups",
+                            lambda n_planes: 1)
         r1, r2 = both(
             env,
             'GroupBy(Rows(f), Rows(g), aggregate=Sum(field="fare"))',

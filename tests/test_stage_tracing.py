@@ -533,8 +533,8 @@ PROGRAMS = {
         ("or", ("leaf", 0), ("leaf", 1)), "row", (1, 1), 0
     ).lower(_u32(2, 64), _u32(2, 64)),
     "jit_groupby_level": lambda b, e, r: b.local_groupby_level_fn(
-        ("leaf", 0), 1, 0, 1, False
-    ).lower(_u32(2, 64), _u32(2, 4, 64), np.zeros(4, np.int32)),
+        ("leaf", 0), 1, 0, 1, 0
+    ).lower(_u32(2, 128), _u32(2, 4, 128), np.zeros(8, np.int32)),
     "jit_or_delta": lambda b, e, r: b._or_delta.lower(
         _u32(2, 64), b._delta_args((0,), [5])),
     "jit_andnot_delta": lambda b, e, r: b._andnot_delta.lower(
@@ -568,7 +568,7 @@ DIST_PROGRAMS = {
     "jit_dist_count_b4": lambda d, mesh: d._dist_fn_batched(
         mesh, ("count", ("leaf", 0)), "count", (1,), 0, 4),
     "jit_dist_groupby_level": lambda d, mesh: d._dist_groupby_level_fn(
-        mesh, ("leaf", 0), 1, 0, 1, False),
+        mesh, ("leaf", 0), 1, 0, 1, 0),
 }
 
 
@@ -585,17 +585,21 @@ def test_a_mesh_program_builder_names_its_module(want):
     elif want == "jit_dist_count_b4":
         args = [_u32(n, 64)] * 4
     else:
-        args = [_u32(n, 64), _u32(n, 4, 64), np.zeros(4, np.int32)]
+        args = [_u32(n, 128), _u32(n, 4, 128), np.zeros(8, np.int32)]
     assert _module_name(fn.lower(*args).as_text()) == want
 
 
-def test_groupby_level_scopes_its_phases():
+def test_groupby_level_scopes_its_kernel_and_a_filter_made_outside_it():
     from pilosa_tpu.executor import batch
 
-    lowered = PROGRAMS["jit_groupby_level"](batch, None, None)
-    text = lowered.as_text(debug_info=True)
-    for scope in ("groupby_gather", "groupby_filter", "groupby_reduce"):
-        assert scope in text, scope
+    text = PROGRAMS["jit_groupby_level"](batch, None, None).as_text(
+        debug_info=True)
+    assert "groupby_level" in text and "groupby_filter" not in text
+    # a Shift cannot be taken tile by tile: XLA evaluates it, in a scope
+    shifted = batch.local_groupby_level_fn(
+        ("shift", ("leaf", 0), 0), 1, 1, 1, 0
+    ).lower(_u32(2, 128), _u32(2, 4, 128), np.zeros(9, np.int32))
+    assert "groupby_filter" in shifted.as_text(debug_info=True)
 
 
 # ------------------------------------------------- compiles and device memory
