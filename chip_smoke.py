@@ -11,7 +11,7 @@ server`` child with default knobs — the only process that holds the chip
 1. ``GET /info`` must list only ``--expect-platform`` devices, before
    anything is loaded.
 2. Data from ``--seed``: set fields ``a``/``b`` (rows 1-8, 512 random
-   bits per row and shard — bench.py's data) and int field ``v`` (0-1000
+   bits per row and shard) and int field ``v`` (0-1000
    on ~512 columns per shard), loaded over ``/import`` and
    ``/import-value`` in batches under max-writes-per-request.
 3. Count-Intersect (cold, then warm), TopN, Sum, BSI range, filtered Sum,
@@ -59,7 +59,7 @@ from pilosa_tpu.shardwidth import SHARD_WIDTH  # noqa: E402
 from pilosa_tpu.utils import compile_cache  # noqa: E402
 
 K_ROWS = 8                # rows 1..8 in each of fields a and b
-BITS_PER_ROW_SHARD = 512  # bench.py's density
+BITS_PER_ROW_SHARD = 512
 VALUES_PER_SHARD = 512
 V_MAX = 1000
 ROW_BYTES = SHARD_WIDTH // 8  # one dense row of one shard on the device
@@ -231,8 +231,8 @@ class Reference:
         rng = np.random.default_rng(seed)
         self.n_shards = n_shards
         base = (np.arange(n_shards, dtype=np.int64) * SHARD_WIDTH)
-        # [field][shard, row, bit] -> global column (duplicates possible,
-        # as in bench.py; the reference dedupes)
+        # [field][shard, row, bit] -> global column (duplicates possible;
+        # the reference dedupes)
         self.set_cols = {
             f: base[:, None, None] + rng.integers(
                 0, SHARD_WIDTH, (n_shards, K_ROWS, BITS_PER_ROW_SHARD),

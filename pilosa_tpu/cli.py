@@ -711,7 +711,7 @@ def cmd_trace_report(args) -> int:
 
     try:
         report = trace_report(args.log_dir, gaps_n=args.gaps)
-    except FileNotFoundError as e:
+    except (FileNotFoundError, ValueError) as e:
         print(f"trace-report: {e}", file=sys.stderr)
         return 1
     print(json.dumps(report) if args.json else format_trace_report(report))

@@ -556,8 +556,7 @@ def elementwise_words(node) -> bool:
     pads its shard axis with zero slots, and an unmasked NOT turns those
     into all-ones words. The compiler never emits it (Not lowers to
     diff(exists, x), masked by construction), so excluding it costs
-    nothing and removes the latent hazard for hand-built trees
-    (ADVICE r4)."""
+    nothing and removes the latent hazard for hand-built trees."""
     if node[0] in ("leaf", "const0"):
         return True
     if node[0] in ("and", "or", "xor", "diff"):

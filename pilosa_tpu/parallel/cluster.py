@@ -963,7 +963,7 @@ class Cluster:
 
         The node RING is snapshotted under _lock at the same moment the
         membership is verified, and every per-shard ownership decision
-        below walks that frozen snapshot (ADVICE r5 TOCTOU): a
+        below walks that frozen snapshot (TOCTOU): a
         node-join/leave message landing mid-loop would otherwise swing
         shard_nodes() to the NEW ring before the new ring's resize has
         copied anything — at replica_n=1 deleting by the new ring
@@ -2228,7 +2228,7 @@ class Cluster:
         def one(item):
             src, frag = item
             for source_uri in [src["from"], *src.get("fallbacks", [])]:
-                # Block-checksum probe first (ADVICE r4 #4): a
+                # Block-checksum probe first: a
                 # legitimately-empty fragment — advertised by the peer
                 # catalog but holding no bits — would otherwise be
                 # re-fetched as a full payload from EVERY replica on
@@ -2341,7 +2341,8 @@ class Cluster:
                 pass  # coordinator's straggler timeout covers lost acks
 
     def _spawn_resize(self) -> None:
-        threading.Thread(target=self.coordinate_resize, daemon=True).start()
+        threading.Thread(target=self.coordinate_resize, daemon=True,
+                         name="coordinate-resize").start()
 
     def coordinate_resize(self) -> dict:
         """Coordinator-computed resize (reference ResizeInstruction —

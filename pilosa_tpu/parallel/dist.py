@@ -321,7 +321,7 @@ class DistExecutor(Executor):
         # byte-identical via the widened-window exact recount. On a flat
         # 1-D mesh the lane is a lossless pass-through (same code path,
         # zero error bound). verify_quantized additionally runs the
-        # lossless path per TopN and asserts identity — the bench/dryrun
+        # lossless path per TopN and asserts identity — the dryrun
         # certification mode, not for serving.
         self.quantized_ranking = bool(quantized_ranking)
         self.verify_quantized = bool(verify_quantized)

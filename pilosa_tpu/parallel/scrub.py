@@ -27,8 +27,7 @@ On confirmed corruption the fragment is handled by replica topology:
 
 Budget: ``scrub-interval`` seconds between passes (0 = disabled) and a
 ``scrub-max-bytes-per-sec`` token bucket (parallel/pacer.py RepairPacer
-— the PR-4 shape), so a scrub storm cannot starve serving I/O; the
-bench gate holds the serving plateau at >= 0.97x with the scrubber on.
+— the PR-4 shape), so a scrub storm cannot starve serving I/O.
 
 A racing snapshot can swap file+sidecar mid-read and fake a mismatch:
 every corruption verdict is re-derived under the fragment lock before

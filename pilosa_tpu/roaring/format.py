@@ -146,7 +146,7 @@ def replay_ops(bitmap: RoaringBitmap, buf: bytes | memoryview, offset: int) -> i
 #           run: run_count uint16, then run_count×(start,last) uint16
 #   ops     records: type uint8 (0=add 1=remove), value uint64,
 #           fnv1a32(first 9 bytes) uint32   (upstream uses fnv.New32a,
-#           NOT CRC-32 — ADVICE r1)
+#           NOT CRC-32)
 # import-roaring sniffs this cookie and falls back to our own layout.
 
 PILOSA_MAGIC = 12348

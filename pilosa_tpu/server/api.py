@@ -91,7 +91,7 @@ class API:
         # slower than the threshold are logged and kept in a ring buffer.
         self.long_query_time: float = 0.0  # seconds; 0 = off
         # deque(maxlen): append is atomic and bounded, so concurrent HTTP
-        # handler threads can't interleave an append/trim pair (ADVICE r1)
+        # handler threads can't interleave an append/trim pair
         self.long_queries: collections.deque[dict] = collections.deque(maxlen=100)
         # exported from scrape one (/metrics); lock: += from concurrent
         # handler threads would lose increments (same hazard the deque
@@ -108,24 +108,11 @@ class API:
         self.max_writes_per_request: int = 5000
         # Parallel ingest (docs/INGEST.md): local shard groups of one
         # import apply on a bounded pool (ingest-workers knob), and
-        # routed batches fan out to owner nodes concurrently. The
-        # fan-out width is attribute-only (benches pin it to 1 for a
-        # serialized baseline).
+        # routed batches fan out to owner nodes concurrently.
         self.ingest_workers: int = INGEST_WORKERS_DEFAULT
-        from pilosa_tpu.utils.pool import MAX_FANOUT
-
-        self.ingest_fanout_workers: int = MAX_FANOUT
         # Coalescing serving pipeline (server/pipeline.py): read-only
         # requests ride Executor.submit through a wave-forming queue so
-        # concurrent HTTP clients share micro-batched dispatches. Set
-        # False to serve every request through blocking execute().
-        self.serve_pipelined: bool = True
-        # Host-path fast lane (docs/OPERATIONS.md): pre-serialized
-        # response bytes + identical-query wave dedupe. False restores
-        # the round-5 serving path (dict building + json.dumps per
-        # request, no dedupe) — the bisection/baseline switch the
-        # serving bench uses for its r5-shaped legacy mode.
-        self.serve_fastlane: bool = True
+        # concurrent HTTP clients share micro-batched dispatches.
         self._pipeline = None  # created lazily on first pipelined query
         self._pipeline_lock = threading.Lock()
         # Serving QoS (pilosa_tpu.qos): admission gate + hedge policy +
@@ -322,8 +309,7 @@ class API:
             # eager path so request-thread concurrency is unchanged.
             from pilosa_tpu.executor.executor import pipeline_coalescable
 
-            if (writes == 0 and self.serve_pipelined
-                    and pipeline_coalescable(query)
+            if (writes == 0 and pipeline_coalescable(query)
                     and hasattr(self.executor, "submit")):
                 if self._pipeline is None:
                     with self._pipeline_lock:
@@ -338,9 +324,8 @@ class API:
                 # identical queries landing in one wave submit once and
                 # share results + pre-serialized response bytes
                 key = None
-                if (self.serve_fastlane and isinstance(pql, str)
-                        and shards is None and deadline is None
-                        and not remote and not opts):
+                if (isinstance(pql, str) and shards is None
+                        and deadline is None and not remote and not opts):
                     # PROFILE requests stay dedupe-eligible: a deduped
                     # follower reports dedupeHit=true with near-zero
                     # measured cost — which is the truth (it rode the
@@ -479,7 +464,7 @@ class API:
         scope = None
         snap = None
         if (not remote and shards is None and deadline is None and not opts
-                and self.serve_fastlane and isinstance(pql, str)):
+                and isinstance(pql, str)):
             from pilosa_tpu.serving.rescache import global_result_cache
 
             cache = global_result_cache()
@@ -1256,9 +1241,7 @@ class API:
 
         t0 = time.perf_counter()
         outcomes = concurrent_map(
-            lambda fn: fn(), tasks,
-            max_workers=max(1, self.ingest_fanout_workers),
-            return_exceptions=True,
+            lambda fn: fn(), tasks, return_exceptions=True,
         )
         stats.timing("ingest_route_wall", time.perf_counter() - t0)
         stats.observe("ingest_fanout_width", len(tasks))
@@ -1318,8 +1301,8 @@ class API:
             ids = (rows_arr[lo:hi] * np.uint64(SHARD_WIDTH)
                    + (cols[lo:hi] & np.uint64(SHARD_WIDTH - 1)))
             data = serialize(RoaringBitmap.from_ids(np.unique(ids)))
-            # per-acked-write wire accounting: the elastic bench's
-            # write-amplification gate reads this before/after a split
+            # per-acked-write wire accounting
+            # (routing_range_wire_bytes_total)
             route_stats.wire_bytes += len(data)
             changed += self.cluster.client.import_roaring(
                 node.uri, index, field, int(shards_sorted[lo]), data
@@ -1962,7 +1945,7 @@ class API:
         broadcast to peers, then recount locally). ``remote=True`` marks
         a peer-originated message: apply locally only, no re-broadcast.
 
-        The local recount runs in a BACKGROUND worker (ADVICE r5): on a
+        The local recount runs in a BACKGROUND worker: on a
         large holder the per-fragment row_counts() scans each take the
         fragment lock, so a synchronous recount in the cluster
         message-delivery path stalls heartbeats and message handling for

@@ -453,49 +453,32 @@ class HTTPHandler(BaseHTTPRequestHandler):
         profile_out: list | None = [] if want_profile else None
 
         if not proto_out:
-            if self.api.serve_fastlane:
-                # fast lane: the response envelope arrives
-                # pre-serialized (hot shapes encode straight to
-                # bytes; identical deduped wavemates share one
-                # encoding — executor/result.py)
-                payload = self.api.query_json_bytes(
-                    index, pql, shards=shards, remote=remote,
-                    opts=opts, tenant=tenant, deadline=deadline,
-                    profile_out=profile_out)
-                if root is not None and trace_hdr:
-                    # splice the finished subtree into the closing
-                    # brace of the pre-serialized envelope — sampled
-                    # remote hops are rare (rate-bounded), so the
-                    # fast lane's zero-build path is untouched
-                    root.finish()
-                    payload = (payload[:-1] + b',"trace":'
-                               + json.dumps(
-                                   root.to_json(),
-                                   separators=(",", ":")).encode()
-                               + b"}")
-                if profile_out:
-                    # same splice as the trace graft: profiled
-                    # requests are rare debugging traffic, the
-                    # zero-build fast lane stays untouched
-                    payload = (payload[:-1] + b',"profile":'
-                               + json.dumps(
-                                   profile_out[0],
-                                   separators=(",", ":")).encode()
-                               + b"}")
-            else:  # r5-shaped legacy path (serve_fastlane = False)
-                out = self.api.query(index, pql, shards=shards,
-                                     remote=remote, opts=opts,
-                                     tenant=tenant, deadline=deadline,
-                                     profile_out=profile_out)
-                if root is not None and trace_hdr:
-                    root.finish()
-                    out["trace"] = root.to_json()
-                if profile_out:
-                    out["profile"] = profile_out[0]
-                # encode here (not via _json) so the legacy path
-                # bills egress like the fast lane does
-                with stage("result.encode"):
-                    payload = json.dumps(out).encode()
+            # the response envelope arrives pre-serialized (hot shapes
+            # encode straight to bytes; identical deduped wavemates
+            # share one encoding — executor/result.py)
+            payload = self.api.query_json_bytes(
+                index, pql, shards=shards, remote=remote,
+                opts=opts, tenant=tenant, deadline=deadline,
+                profile_out=profile_out)
+            if root is not None and trace_hdr:
+                # splice the finished subtree into the closing brace
+                # of the pre-serialized envelope — sampled remote hops
+                # are rare (rate-bounded), so the zero-build path is
+                # untouched
+                root.finish()
+                payload = (payload[:-1] + b',"trace":'
+                           + json.dumps(
+                               root.to_json(),
+                               separators=(",", ":")).encode()
+                           + b"}")
+            if profile_out:
+                # same splice as the trace graft: profiled requests
+                # are rare debugging traffic
+                payload = (payload[:-1] + b',"profile":'
+                           + json.dumps(
+                               profile_out[0],
+                               separators=(",", ":")).encode()
+                           + b"}")
             self._note_egress(tenant, index, len(payload), remote)
             # into the connection's write buffer; the one send happens
             # when the handler returns (wbufsize), after the root span

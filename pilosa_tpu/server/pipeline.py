@@ -233,7 +233,7 @@ class QueryPipeline:
         if self._recent_gap >= self.PRESSURE_GAP_S:
             self._last_wave_size = len(wave)
             return
-        # Latch breaker (ADVICE r5): a single fast closed-loop client
+        # Latch breaker: a single fast closed-loop client
         # keeps _recent_gap ≈ window + service < PRESSURE_GAP_S, so the
         # gap signal alone holds the window open forever while every
         # wave dispatches at size 1 — the window buys nothing and costs

@@ -19,8 +19,7 @@ Three durability modes (``durability-mode`` ServerConfig knob):
   checkpoint, clean close) make WAL segments garbage-collectable.
 - ``per-op``: every op record fsyncs the fragment's own file before the
   mutator returns — true per-write durability, the honest version of
-  what round 5 only claimed. The baselining mode for the group-commit
-  bench.
+  what round 5 only claimed.
 - ``flush-only``: the round-5 behavior, byte for byte — append+flush,
   no fsync anywhere on the write path. Survives SIGKILL (the OS buffer
   outlives the process) but not power loss. Kept for back-compat
@@ -83,23 +82,9 @@ REC_OP = 1
 REC_TOMBSTONE = 2
 _REC_HEADER = struct.Struct("<HHHII")
 
-# Bench/test instrumentation: serialize op-log fsyncs behind one lock
-# and add a fixed delay, modeling a single disk journal — tmpfs/9p
-# under-prices the very fsync group commit amortizes (the config_sync
-# injected-RTT precedent, applied to the disk). Applied identically to
-# group AND per-op fsyncs so mode comparisons stay honest.
-_FSYNC_DELAY_S = float(os.environ.get("PILOSA_TPU_FSYNC_DELAY_MS", "0") or 0) / 1e3
-_FSYNC_LOCK = threading.Lock()
-
-
 def wal_fsync(fd: int) -> None:
-    """Op-log fsync (group WAL segments and per-op fragment files both
-    route here so injected journal latency hits every mode equally)."""
-    if _FSYNC_DELAY_S > 0:
-        with _FSYNC_LOCK:
-            time.sleep(_FSYNC_DELAY_S)
-            os.fsync(fd)
-        return
+    """Op-log fsync: group WAL segments and per-op fragment files both
+    route here (the one name a test replaces to count or fail them)."""
     os.fsync(fd)
 
 

@@ -22,7 +22,8 @@ requests ride plain urllib, so the observer is never partitioned from
 the nodes — a write acked through a reachable node counts even when
 that node is about to be cut off.
 
-Used by ``bench_suite.py config_chaos`` (the ≥20-schedule gate) and the ``slow`` soak in tests/test_partition.py.
+Used by tests/test_partition.py: one quick schedule a variant in
+tier-1, and the ``slow`` soak.
 """
 
 from __future__ import annotations
@@ -590,7 +591,7 @@ class ChaosHarness:
                 break
             time.sleep(0.2)
         else:
-            # capture WHY for the bench record — unconverged runs are
+            # capture WHY for the record — unconverged runs are
             # otherwise undebuggable after the fact
             self.converge_diag = {
                 s.config.name: {
@@ -1017,47 +1018,6 @@ class MpServingChaos:
         }
 
 
-def run_mp_chaos(tmp_dir, n_schedules: int = 2, n_workers: int = 2,
-                 seed: int = 0, n_kills: int = 3,
-                 log=lambda msg: None) -> dict:
-    """Run ``n_schedules`` independent kill-a-worker schedules (fresh
-    server each) and fold the two mp-serving oracles; part of the
-    default chaos config (bench_suite config_chaos) and the
-    ``mp_serving`` gate."""
-    records = []
-    for i in range(n_schedules):
-        schedule_seed = seed * 1000 + i
-        log(f"mp chaos schedule {i + 1}/{n_schedules} "
-            f"(seed {schedule_seed})")
-        harness = MpServingChaos(
-            f"{tmp_dir}/mpsched{i}", n_workers=n_workers,
-            seed=schedule_seed, n_kills=n_kills, log=log,
-        )
-        try:
-            harness.boot()
-            record = harness.run_schedule()
-        finally:
-            harness.close()
-        record["seed"] = schedule_seed
-        records.append(record)
-        log(f"  -> ok={record['ok']} acked={record['acked_writes']} "
-            f"kills={len(record['events'])} wall={record['wall_s']}s")
-    failed = [r for r in records if not r["ok"]]
-    return {
-        "schedules": n_schedules,
-        "n_workers": n_workers,
-        "kills_total": sum(len(r["events"]) for r in records),
-        "acked_writes_total": sum(r["acked_writes"] for r in records),
-        "lost_acked_writes": sum(r["lost_acked_writes"] for r in records),
-        "owner_wedges": [w for r in records for w in r["owner_wedges"]],
-        "respawns_total": sum(r["respawns"] for r in records),
-        "dropped_inflight_total": sum(r["dropped_inflight"]
-                                      for r in records),
-        "failed_seeds": [r["seed"] for r in failed],
-        "ok": not failed,
-    }
-
-
 def run_chaos(tmp_dir, n_schedules: int = 20, n_nodes: int = 3,
               replica_n: int = 2, seed: int = 0, n_events: int = 6,
               event_gap_s: float = 0.3, with_storage_faults: bool = False,
@@ -1068,15 +1028,13 @@ def run_chaos(tmp_dir, n_schedules: int = 20, n_nodes: int = 3,
     each — a schedule's damage must not leak into the next) and fold
     the oracle verdicts. Any failing schedule reports its seed so the
     run replays deterministically. ``with_storage_faults`` adds
-    bit-flip and disk-full events plus the disk-integrity oracle
-    (bench_suite config_scrub); ``with_autopilot`` runs the placement
-    plane live (fast tickers + forced-pass events) so the same oracles
-    gate autopilot-minted resizes (bench_suite config_autopilot);
-    ``with_cdc`` runs an out-of-cluster CDC mirror tailing n0 for the
-    whole schedule, gated on the byte-identical mirror oracle
-    (bench_suite config_cdc); ``with_elastic`` adds graceful-drain
-    events so kills and partitions land mid-drain (bench_suite
-    config_elastic), gated on all of the above."""
+    bit-flip and disk-full events plus the disk-integrity oracle;
+    ``with_autopilot`` runs the placement plane live (fast tickers +
+    forced-pass events) so the same oracles gate autopilot-minted
+    resizes; ``with_cdc`` runs an out-of-cluster CDC mirror tailing n0
+    for the whole schedule, gated on the byte-identical mirror oracle;
+    ``with_elastic`` adds graceful-drain events so kills and partitions
+    land mid-drain, gated on all of the above."""
     records = []
     for i in range(n_schedules):
         schedule_seed = seed * 1000 + i

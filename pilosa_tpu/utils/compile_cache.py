@@ -1,9 +1,9 @@
 """Persistent XLA compile cache placement.
 
 Every entry point that will touch a device calls ``configure()`` before
-its first compile (``cli.cmd_server``, ``bench.py``, ``bench_suite.py``,
-``__graft_entry__.py``). The directory is part of JAX's cache key, so it
-must be the same path on every start: ``JAX_COMPILATION_CACHE_DIR`` when
+its first compile (``cli.cmd_server``, ``__graft_entry__.py``). The
+directory is part of JAX's cache key, so it must be the same path on
+every start: ``JAX_COMPILATION_CACHE_DIR`` when
 the operator set it (JAX reads that itself — nothing is set in code),
 else ``<checkout>/.jax_cache`` beside the package. Never a temp name, a
 pid, a port, the data dir or the time.

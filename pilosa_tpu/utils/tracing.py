@@ -917,7 +917,10 @@ def trace_report(log_dir: str, gaps_n: int = 5, top_n: int = 10) -> dict:
         if not paths:
             raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
         path = max(paths, key=os.path.getmtime)
-    data = ProfileData.from_file(path)
+    try:
+        data = ProfileData.from_file(path)
+    except RuntimeError as e:  # a capture cut short, or not a capture
+        raise ValueError(f"{path} is not a readable .xplane.pb: {e}") from e
     planes = list(data.planes)
     known = set(_stage_counters)
 

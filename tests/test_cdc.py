@@ -35,6 +35,20 @@ def _frag(holder, index="i", field="f", shard=0):
     return fld.view(VIEW_STANDARD, create=True).fragment(shard, create=True)
 
 
+def _set_bits(holder, frag, cols, group=40):
+    """Set row 1's ``cols`` a commit group of at most ``group`` records
+    at a time. A segment rotates when a group leaves it over
+    SEGMENT_MAX_BYTES, so how often it rotates follows from how the
+    commit thread happens to batch: on a loaded host one group took 256
+    of 300 records and the log rotated once. A barrier a batch makes
+    the rotations a matter of bytes."""
+    for k, col in enumerate(cols, 1):
+        frag.set_bit(1, col)
+        if k % group == 0:
+            holder.wal.barrier()
+    holder.wal.barrier()
+
+
 def _wait(pred, timeout=10.0, msg="condition"):
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
@@ -113,9 +127,7 @@ class TestWalTail:
         try:
             h.wal.register_cursor("lagger", 0)
             frag = _frag(h)
-            for i in range(300):
-                frag.set_bit(1, i)
-            h.wal.barrier()
+            _set_bits(h, frag, range(300))
             assert len(h.wal._segments) > 2, "rotation never happened"
             got = []
             pos = 0
@@ -140,16 +152,12 @@ class TestWalTail:
         try:
             h.wal.register_cursor("c", 0)
             frag = _frag(h)
-            for i in range(150):
-                frag.set_bit(1, i)
-            h.wal.barrier()
+            _set_bits(h, frag, range(150))
             # pinned: the full feed is still readable
             events, _, _ = h.wal.read_tail(0, max_bytes=1 << 20)
             assert events and events[0][0] == 1
             h.wal.drop_cursor("c")
-            for i in range(150, 300):
-                frag.set_bit(1, i)
-            h.wal.barrier()
+            _set_bits(h, frag, range(150, 300))
             assert h.wal.tail_floor() > 0, "GC never advanced the floor"
             with pytest.raises(TailGone) as ei:
                 h.wal.read_tail(0)
@@ -172,9 +180,7 @@ class TestWalTail:
             h.wal.cdc_retention_bytes = 4096
             h.wal.register_cursor("stalled", 0)
             frag = _frag(h)
-            for i in range(400):
-                frag.set_bit(1, i)
-            h.wal.barrier()
+            _set_bits(h, frag, range(400))
             assert h.wal.metrics()["cdc_forced_reclaims_total"] > 0
             with pytest.raises(TailGone):
                 h.wal.read_tail(0)

@@ -137,8 +137,8 @@ class TestMembership:
         with urllib.request.urlopen(r) as resp:
             assert resp.status == 204
         # 204 = queued: every node recounts in a background worker so
-        # message delivery/heartbeats never stall on the scan (ADVICE
-        # r5); join each node's worker before asserting
+        # message delivery/heartbeats never stall on the scan; join
+        # each node's worker before asserting
         for s in cluster3:
             t = s.api._recalc_thread
             if t is not None:
@@ -989,7 +989,7 @@ class TestEagerShardVisibility:
 
 class TestClusterRaces:
     def test_known_shards_read_during_create_shard_broadcasts(self, tmp_path):
-        """ADVICE r2 (medium): _all_shards used to iterate the raw
+        """_all_shards used to iterate the raw
         known_shards set while handle_message('create-shard') resized it
         from HTTP threads — set.update over a set being resized raises
         RuntimeError mid-query. Hammer both sides concurrently."""
@@ -1038,7 +1038,7 @@ class TestClusterRaces:
                 s.close()
 
     def test_failover_coordinator_ungates_stuck_resizing(self, tmp_path):
-        """ADVICE r2 (medium): coordinator dies between broadcasting
+        """Coordinator dies between broadcasting
         RESIZING and NORMAL; the failover coordinator finds nothing to
         move (replica_n=1 left no live source) and must STILL broadcast
         NORMAL or peers stay gated forever."""

@@ -131,21 +131,6 @@ def test_profile_absent_without_param(server):
     assert "profile" not in out
 
 
-def test_profile_legacy_serving_path(tmp_path):
-    s = Server(ServerConfig(
-        data_dir=str(tmp_path / "d"), port=0, anti_entropy_interval=0,
-        heartbeat_interval=0,
-    )).open()
-    try:
-        s.api.serve_fastlane = False
-        _seed_one(s)
-        out = _post(s, "/index/i/query?profile=true", b"Count(Row(f=1))")
-        assert out["results"] == [3 * 2]
-        assert out["profile"]["calls"][0]["name"] == "Count"
-    finally:
-        s.close()
-
-
 def test_profile_error_requests_carry_no_profile(server):
     _seed_one(server)
     with pytest.raises(urllib.error.HTTPError) as ei:
@@ -667,24 +652,6 @@ def test_heat_scope_separates_holders():
     assert len(rows) == 2
     assert rows[0]["scope"] == "/data/a" and rows[0]["access"] == 5.0
     assert rows[1]["scope"] == "/data/b" and rows[1]["access"] == 1.0
-
-
-def test_legacy_path_bills_egress(tmp_path):
-    """serve_fastlane=False responses must feed egress_bytes like the
-    fast lane (review finding: the legacy JSON path skipped the
-    ledger, under-billing that node's tenants forever)."""
-    s = Server(ServerConfig(
-        data_dir=str(tmp_path / "d"), port=0, anti_entropy_interval=0,
-        heartbeat_interval=0,
-    )).open()
-    try:
-        s.api.serve_fastlane = False
-        _seed_one(s)
-        _post(s, "/index/i/query", b"Count(Row(f=1))")
-        (row,) = s.api.cost.snapshot()
-        assert row["egress_bytes"] > 0
-    finally:
-        s.close()
 
 
 def test_profile_disabled_plane_is_marked(server):

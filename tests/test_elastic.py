@@ -297,6 +297,103 @@ class TestRefusals:
                 s.close()
 
 
+class TestSplitEndToEnd:
+    def test_hot_shard_is_split_adopted_read_and_written_through(
+            self, tmp_path):
+        """One index, one shard, every byte of its heat on one owner:
+        placement moves cannot help. With ``autopilot-split-threshold``
+        armed a planner pass mints a sub-shard split across two nodes,
+        every peer adopts the range table, reads stay exact, reads
+        entering through a non-owner rotate over the span owners, a
+        plain Set through it narrows to its column's span owner while a
+        Clear keeps the union fan-out, and every acknowledged write is
+        readable on every node after one anti-entropy pass. (Before
+        that pass a read of the split shard goes to ONE span owner and
+        sees only the narrowed writes that owner took: ROADMAP D11.)"""
+        from pilosa_tpu.parallel.cluster import global_route_stats
+
+        servers = make_cluster(tmp_path, 3, replica_n=1,
+                               autopilot_enabled=True,
+                               autopilot_interval=3600,
+                               autopilot_split_threshold=1.5,
+                               autopilot_split_ways=2)
+        try:
+            for s in servers:
+                assert s.api.cluster.wait_until_normal(30)
+            entry = uri(servers[0])
+            req("POST", f"{entry}/index/hot", {})
+            req("POST", f"{entry}/index/hot/field/f", {})
+            # columns on both halves of the shard, so that both spans
+            # of a 2-way split hold some
+            cols = [k * (SHARD_WIDTH // 16) + 3 for k in range(16)]
+            req("POST", f"{entry}/index/hot/field/f/import",
+                {"rows": [1] * len(cols), "columns": cols})
+
+            def count(server, row):
+                return req("POST", f"{uri(server)}/index/hot/query",
+                           f"Count(Row(f={row}))".encode())["results"][0]
+
+            for _ in range(30):  # all of the cluster's heat on hot/0
+                assert count(servers[0], 1) == len(cols)
+            coord = _coordinator(servers)
+            c = coord.api.cluster
+            assert _wait(lambda: bool(
+                coord.api.autopilot.run_pass().get("splits")), timeout=20)
+            spans = c.placement.get_ranges("hot", 0)
+            assert len(spans) == 2
+            owners = sorted({i for _lo, _hi, ids in spans for i in ids})
+            assert len(owners) == 2
+            assert _wait(lambda: all(
+                s.api.cluster.placement.range_count >= len(spans)
+                for s in servers), timeout=10)
+            assert coord.api.autopilot_metrics()[
+                "autopilot_splits_total"] == 1
+
+            # reads stay exact through every entry, and one entering
+            # through the node that owns no span reaches both owners
+            for s in servers:
+                assert count(s, 1) == len(cols)
+            by_name = {s.config.name: s for s in servers}
+            (outsider,) = [s for s in servers
+                           if s.config.name not in owners]
+
+            def served(name):
+                return req("GET", f"{uri(by_name[name])}/debug/vars")[
+                    "serving_fastlane"]["http_requests_total"]
+
+            before = {n: served(n) for n in owners}
+            for _ in range(8):
+                assert count(outsider, 1) == len(cols)
+            # successive reads rotate over the span owners: each served
+            # some (and one of the two /debug/vars requests around them)
+            delta = {n: served(n) - before[n] for n in owners}
+            assert all(d >= 2 for d in delta.values()), delta
+
+            rs = global_route_stats()
+            sliced, union = rs.range_slices, rs.union_writes
+            for col in cols:
+                out = req("POST", f"{uri(outsider)}/index/hot/query",
+                          f"Set({col + 1}, f=2)".encode())
+                assert out["results"] == [True]
+            assert rs.range_slices - sliced >= len(cols)
+            assert rs.union_writes == union
+            for col in cols[:4]:
+                req("POST", f"{uri(outsider)}/index/hot/query",
+                    f"Clear({col}, f=1)".encode())
+            assert rs.union_writes - union >= 4
+            # each narrowed write is held by its span's owner, and by
+            # every union owner after a repair pass
+            assert sum(count(by_name[n], 2) for n in owners) >= len(cols)
+            for s in servers:
+                s.api.cluster.sync_holder()
+            for s in servers:
+                assert count(s, 2) == len(cols)
+                assert count(s, 1) == len(cols) - 4
+        finally:
+            for s in servers:
+                s.close()
+
+
 class TestResume:
     def test_departed_target_record_is_stamped_done(self):
         c = _bare_cluster(["n0"])

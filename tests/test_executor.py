@@ -386,7 +386,7 @@ class TestTopNRowsGroupBy:
         )
         assert [(g.group[0]["rowID"], g.count) for g in groups] == [(4, 18)]
         # float thresholds must not truncate: count < 3.5 keeps the
-        # count==3 group (int(3.5) → "< 3" would drop it) — ADVICE r4
+        # count==3 group (int(3.5) → "< 3" would drop it)
         (groups,) = ex.execute(
             "r", "GroupBy(Rows(f), Rows(g), having=Condition(count < 3.5))"
         )
@@ -526,9 +526,8 @@ class TestSubmitPipelined:
 
     def test_submit_pipeline_resolves_in_order(self, env):
         """Enqueue several salted Shift queries without blocking, then
-        resolve; each matches its eager counterpart (the bench.py method:
-        scalars are runtime args, so one compiled program serves every
-        salt)."""
+        resolve; each matches its eager counterpart (scalars are runtime
+        args, so one compiled program serves every salt)."""
         holder, ex = env
         setup_stars(holder)
         pqls = [

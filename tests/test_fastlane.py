@@ -642,7 +642,7 @@ class TestWaveBatcher:
 
 class TestEmptyFragmentProbe:
     def test_fetch_skips_payload_when_all_replicas_empty(self, tmp_path):
-        """ADVICE r4 #4: a legitimately-empty fragment is probed via the
+        """A legitimately-empty fragment is probed via the
         cheap block-checksum list, never re-fetched as a full payload."""
         from pilosa_tpu.parallel.cluster import Cluster, Node
 

@@ -142,7 +142,7 @@ def test_recalculate_caches_repairs_drift(node_api):
         assert resp.status == 204
         assert resp.headers.get("Content-Length") is None  # RFC 7230 204
     # 204 means QUEUED: the recount runs in a background worker so the
-    # cluster message-delivery path can't stall on it (ADVICE r5) — join
+    # cluster message-delivery path can't stall on it — join
     # the worker before asserting on the repaired cache
     t = api._recalc_thread
     if t is not None:
@@ -350,7 +350,7 @@ def test_config_to_dict_round_trips_new_keys():
 
 def test_insecure_tls_is_per_client():
     # One skip-verify client must not disable verification for others in
-    # the same process (ADVICE r1: scope the SSL context to the instance).
+    # the same process (scope the SSL context to the instance).
     from pilosa_tpu.parallel.client import InternalClient
 
     insecure = InternalClient(insecure_tls=True)

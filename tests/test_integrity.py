@@ -10,6 +10,7 @@ import errno
 import glob
 import json
 import os
+import threading
 import time
 import urllib.error
 
@@ -233,6 +234,43 @@ class TestScrubber:
         h2 = _mk_holder(tmp_path)
         assert _frag(h2).count_row(1) == live
         h2.close()
+
+    def test_ticking_scrubber_heals_a_flip_under_a_live_server(
+            self, tmp_path):
+        """The background thread, not an on-demand pass: a byte flipped
+        in a live fragment's snapshot is quarantined and healed by the
+        next tick, and the node answers as before."""
+        from tests.cluster_helpers import make_cluster, req, uri
+
+        from pilosa_tpu.parallel.scrub import Scrubber
+
+        (s,) = make_cluster(tmp_path, 1)
+        scrubber = None
+        try:
+            req("POST", f"{uri(s)}/index/i", {})
+            req("POST", f"{uri(s)}/index/i/field/f", {})
+            cols = list(range(0, 900, 3))
+            req("POST", f"{uri(s)}/index/i/field/f/import",
+                {"rows": [1] * len(cols), "columns": cols})
+            frag = (s.holder.index("i").field("f").view(VIEW_STANDARD)
+                    .fragment(0))
+            frag.snapshot()
+            scrubber = s.api.scrubber = Scrubber(
+                s.holder, cluster=s.api.cluster, interval_s=0.05).start()
+            _flip(frag.path, 64, 0x20)
+            deadline = time.monotonic() + 30
+            while not (scrubber.corruptions >= 1
+                       and scrubber.self_healed + scrubber.repaired >= 1):
+                assert time.monotonic() < deadline, scrubber.passes
+                time.sleep(0.02)
+            assert glob.glob(frag.path + ".quarantine-*")
+            got = req("POST", f"{uri(s)}/index/i/query",
+                      b"Count(Row(f=1))")["results"][0]
+            assert got == len(cols)
+        finally:
+            if scrubber is not None:
+                scrubber.close()
+            s.close()
 
     def test_clean_pass_touches_nothing(self, tmp_path):
         from pilosa_tpu.parallel.scrub import Scrubber
@@ -627,8 +665,29 @@ class TestReadRepair:
             frag_b = (b.holder.index("i").field("f").view(VIEW_STANDARD)
                       .fragment(0))
             want = frag_b.serialize_snapshot()
-            _flip(frag_b.path, 50, 0x08)
-            rec = Scrubber(b.holder, cluster=b.api.cluster).scrub_pass()
+            # reads are served from the node with the rotten file for
+            # the whole window: every answer must be the truth
+            reads, wrong = [], []
+            stop = threading.Event()
+
+            def reader():
+                while not stop.is_set() or not reads:
+                    got = req("POST", f"{uri(b)}/index/i/query",
+                              b"Count(Row(f=3))")["results"][0]
+                    reads.append(got)
+                    if got != len(acked):
+                        wrong.append(got)
+
+            t = threading.Thread(target=reader)
+            t.start()
+            try:
+                _flip(frag_b.path, 50, 0x08)
+                rec = Scrubber(b.holder,
+                               cluster=b.api.cluster).scrub_pass()
+            finally:
+                stop.set()
+                t.join(30)
+            assert not t.is_alive() and reads and not wrong, wrong
             assert rec["corrupt"] == 1 and rec["repaired"] == 1, rec
             healed = (b.holder.index("i").field("f").view(VIEW_STANDARD)
                       .fragment(0))

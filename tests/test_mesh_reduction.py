@@ -1,7 +1,6 @@
 """Hierarchical reduction plane (parallel/reduction.py + the 2-D mesh).
 
-Two contracts, gated here and again (at scale, with records) by the
-bench_suite ``mesh`` config:
+Two contracts, gated here:
 
 * bit-exactness — every reduce kind on every mesh factorization returns
   byte-identical results to the single-device Executor, including
@@ -145,9 +144,8 @@ class TestWireAccounting:
         assert snap["row_gathers"] == 0
 
     def test_hier_row_topn_4x(self, executors):
-        """The bench gate's core assertion, in miniature: Row and TopN
-        shapes move >=4x fewer reduction-lane bytes than the dense
-        equivalent on the hierarchical mesh."""
+        """Row and TopN shapes move >=4x fewer reduction-lane bytes than
+        the dense equivalent on the hierarchical mesh."""
         dist = executors[(8, 2)]
         stats = reduction.global_reduce_stats()
         stats.reset()

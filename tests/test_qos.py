@@ -682,7 +682,7 @@ class TestCircuitBreakerIntegration:
 
 class TestGatherLatch:
     def test_single_fast_client_does_not_latch_window(self):
-        """ADVICE r5: a lone closed-loop client with sub-window service
+        """A lone closed-loop client with sub-window service
         time keeps _recent_gap under the pressure threshold forever; the
         latch breaker must keep it on the zero-wait path (its waves are
         size 1, so the window buys nothing)."""
@@ -731,7 +731,7 @@ class TestGatherLatch:
 
 class TestCleanupRingSnapshot:
     def test_cleanup_ownership_frozen_against_midloop_join(self, tmp_path):
-        """ADVICE r5 TOCTOU: a node-join landing while cleanup_unowned
+        """TOCTOU: a node-join landing while cleanup_unowned
         walks fragments must not swing ownership to the NEW ring — with
         one node and replica_n=1 every fragment is owned locally, and a
         join injected mid-walk must not delete any of them."""

@@ -438,18 +438,6 @@ class TestHTTPServing:
         finally:
             servers[0].close()
 
-    def test_pipeline_disabled_fallback(self, tmp_path):
-        servers = make_cluster(tmp_path, 1, use_mesh=False)
-        try:
-            seed(servers[0])
-            servers[0].api.serve_pipelined = False
-            url = f"{uri(servers[0])}/index/i/query"
-            out = req("POST", url, b"Count(Row(f=1))")
-            assert out == {"results": [24]}
-            assert servers[0].api._pipeline is None
-        finally:
-            servers[0].close()
-
     def test_bad_query_in_wave_does_not_poison_wavemates(self, tmp_path):
         """One request erroring at submit time (unknown field) must fail
         ALONE; the other requests coalesced into the same wave still

@@ -488,6 +488,69 @@ def test_trace_report_labels_gaps_by_the_rule():
     assert "busy 19.0 %" in cli.stdout
 
 
+def _capture_dir(tmp_path, payload: bytes | None):
+    d = tmp_path / "plugins" / "profile" / "x"
+    d.mkdir(parents=True)
+    if payload is not None:
+        (d / "t.xplane.pb").write_bytes(payload)
+    return str(tmp_path)
+
+
+def _cpu_only_capture():
+    from xplane_writer import xspace
+
+    us = 1000
+    return xspace([("/host:CPU", [
+        # a CPU capture's stand-in for device lanes; its zero-length
+        # thread-pool markers are not operations
+        ("tf_XLA_worker_0", [("fusion.7", 0, 25 * us),
+                             ("ThreadpoolListener::Record", 30 * us, 0),
+                             ("fusion.7", 50 * us, 25 * us)]),
+        ("python/1", [("pipeline.submit", 10 * us, 30 * us)]),
+    ])])
+
+
+def _stages_only_capture():
+    from xplane_writer import xspace
+
+    us = 1000
+    return xspace([("/host:CPU", [
+        ("python/1", [("http.query", 0, 900 * us),
+                      ("pql.parse", 100 * us, 200 * us)]),
+    ])])
+
+
+@pytest.mark.parametrize("payload,said,busy_s", [
+    (None, "no .xplane.pb under", None),
+    (lambda: b"not a protobuf at all", "is not a readable .xplane.pb", None),
+    (lambda: b"", "no operation ran on any device in this capture", 0),
+    (_stages_only_capture,
+     "no operation ran on any device in this capture", 0),
+    (_cpu_only_capture, "/host:CPU: busy 66.7 % of 0.000 s", 50e-6),
+], ids=["empty-directory", "corrupt-file", "empty-file",
+        "no-device-operation", "cpu-only-host"])
+def test_trace_report_on_a_malformed_or_poor_capture(tmp_path, capsys,
+                                                     payload, said, busy_s):
+    """What an operator can point ``trace-report`` at after a capture
+    went wrong: a directory with no capture in it, a file that is not
+    one or was cut short, a capture in which nothing ran on a device, a
+    CPU host's. Each is a line that says so and an exit code, never a
+    traceback; and a CPU host's worker threads stand in for the device,
+    their zero-length markers left out."""
+    from pilosa_tpu import cli
+
+    d = _capture_dir(tmp_path, payload() if payload else None)
+    rc = cli.main(["trace-report", d])
+    out = capsys.readouterr()
+    if busy_s is None:  # unreadable: said on stderr, exit code 1
+        assert rc == 1 and said in out.err, out
+        return
+    assert rc == 0 and said in out.out, out
+    report = tracing.trace_report(d)
+    assert [dev["busy_s"] for dev in report["devices"]] == (
+        [pytest.approx(busy_s)] if busy_s else [])
+
+
 def test_an_uncovered_gap_reads_no_request():
     timelines = [tracing._innermost([(0.0, 1.0, "http.query"),
                                      (0.2, 0.4, "pql.parse")])]
