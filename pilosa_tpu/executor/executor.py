@@ -23,15 +23,22 @@ import math
 import threading
 import time
 import weakref
+from itertools import repeat
 from typing import Callable
 
 import numpy as np
 
 from pilosa_tpu.executor import batch, expr
-from pilosa_tpu.executor.result import GroupCount, Pair, RowResult, ValCount
+from pilosa_tpu.executor.result import GroupCounts, Pair, RowResult, ValCount
 from pilosa_tpu.pql import Call, Condition, parse
 from pilosa_tpu.pql.ast import Query
-from pilosa_tpu.shardwidth import WORDS_PER_SHARD, next_pow2, position, shard_of
+from pilosa_tpu.shardwidth import (
+    SHARD_WIDTH,
+    WORDS_PER_SHARD,
+    next_pow2,
+    position,
+    shard_of,
+)
 from pilosa_tpu.storage import residency
 from pilosa_tpu.storage.heat import global_heat
 from pilosa_tpu.utils.cost import current_cost, use_node
@@ -282,7 +289,7 @@ def _result_cardinality(res) -> int:
     popcount is not free."""
     if isinstance(res, RowResult):
         return int(res.count())
-    if isinstance(res, list):
+    if isinstance(res, (list, GroupCounts)):
         return len(res)
     return 0
 
@@ -1616,56 +1623,76 @@ class Executor:
             dims.append((fname, row_ids))
         return limit, filt_call, agg_field, dims, having
 
-    def _groupby_result(
-        self, idx: Index, dims, counts: dict, sums: dict, agg_field, limit,
-        having=None,
-    ) -> list[GroupCount]:
-        """Shared GroupBy result construction: rowID→rowKey translation for
-        keyed dimension fields (reference GroupBy FieldRow carries RowKey
-        when the field has keys), having filter, ordering, limit."""
+    def _groupby_counts(self, idx: Index, dims, cand: np.ndarray,
+                        counts: np.ndarray, agg_arrs, agg_field,
+                        columns: int, limit, having=None) -> GroupCounts:
+        """The answer's columns from a final level's candidates (index
+        tuples into ``dims``), their counts and aggregate partials:
+        count > 0, having, order and limit as array operations, the
+        rowID→rowKey translation of a keyed dimension field carried
+        beside the ids (reference GroupBy FieldRow carries RowKey when
+        the field has keys). ``columns`` bounds a plane count."""
+        sums = None
+        if agg_arrs is not None:
+            sums = _groupby_sums(
+                *agg_arrs, agg_field.options.base,
+                agg_field.options.bit_depth, columns,
+            )
+
+        def take(ix) -> None:
+            nonlocal cand, counts, sums
+            cand, counts = cand[ix], counts[ix]
+            if sums is not None:
+                sums = sums[ix]
+
+        keep = counts > 0
+        if not keep.all():
+            take(keep)
         if having is not None:
-            counts = {
-                k: c for k, c in counts.items() if having(c, sums.get(k))
-            }
-        dim_keys: list[dict[int, str] | None] = []
-        for fname, row_ids in dims:
-            field = idx.field(fname)
-            if field is not None and field.options.keys:
-                translated = self._row_keys(idx, field, row_ids)
-                dim_keys.append(dict(zip(row_ids, translated)))
-            else:
-                dim_keys.append(None)
-
-        def field_row(i: int, row: int) -> dict:
-            keys = dim_keys[i]
-            if keys is not None and keys.get(row) is not None:
-                return {"field": dims[i][0], "rowKey": keys[row]}
-            return {"field": dims[i][0], "rowID": row}
-
+            take(np.array([
+                having(c, s) for c, s in zip(
+                    counts.tolist(),
+                    sums.tolist() if sums is not None else repeat(None),
+                )
+            ], bool))
         # Order by the emitted representation — numeric rowIDs first
         # (numerically), then rowKeys (lexicographically) — so every
         # execution path (single-node, SPMD, cluster merge) agrees on
-        # ordering and limit truncation.
-        def order(key: tuple) -> tuple:
-            return tuple(
-                (1, keys[row]) if (keys := dim_keys[i]) is not None
-                and keys.get(row) is not None else (0, row)
-                for i, row in enumerate(key)
-            )
-
-        out = [
-            GroupCount(
-                [field_row(i, row) for i, row in enumerate(key)],
-                c,
-                sum=sums.get(key) if agg_field is not None else None,
-            )
-            for key, c in sorted(counts.items(), key=lambda kv: order(kv[0]))
-        ]
+        # ordering and limit truncation. A level's candidates are in
+        # lexicographic order of row INDEX, so they are in order already
+        # unless some dimension's rows are not: then its indexes are
+        # ranked once, by what they emit, and the groups sorted by rank.
+        fields, ids, row_keys, ranks = [], [], [], []
+        for fname, row_ids in dims:
+            field = idx.field(fname)
+            keys, emitted = None, row_ids
+            if field is not None and field.options.keys:
+                keys = dict(zip(row_ids, self._row_keys(idx, field, row_ids)))
+                emitted = [(0, r) if keys[r] is None else (1, keys[r])
+                           for r in row_ids]
+            rank = None
+            if emitted != sorted(emitted):
+                order = sorted(range(len(emitted)), key=emitted.__getitem__)
+                rank = np.empty(len(order), np.intp)
+                rank[order] = np.arange(len(order))
+            fields.append(fname)
+            ids.append(np.asarray(row_ids, np.int64))
+            row_keys.append(keys)
+            ranks.append(rank)
+        if any(r is not None for r in ranks):
+            # lexsort's LAST key is the primary one
+            take(np.lexsort([
+                cand[:, d] if ranks[d] is None else ranks[d][cand[:, d]]
+                for d in reversed(range(len(dims)))
+            ]))
         if limit:
-            out = out[: int(limit)]
-        return out
+            take(slice(int(limit)))
+        rows = np.empty(cand.shape, np.int64)
+        for d, dim_ids in enumerate(ids):
+            rows[:, d] = dim_ids[cand[:, d]]
+        return GroupCounts(fields, rows, counts, sums, row_keys)
 
-    def _execute_groupby(self, idx: Index, call: Call, shards=None) -> list[GroupCount]:
+    def _execute_groupby(self, idx: Index, call: Call, shards=None) -> GroupCounts:
         return self._submit_groupby(idx, call, shards).result()
 
     def _submit_groupby(self, idx: Index, call: Call, shards=None,
@@ -1689,6 +1716,12 @@ class Executor:
         with whatever the serving loop enqueues next. The pruning path
         needs a readback per level to choose the next level's
         candidates, so it defers the whole evaluation to ``result()``.
+
+        Either way the Deferred resolves to ONE ``GroupCounts``
+        (executor/result.py): the final level's kept candidates as
+        columns, which the JSON route renders to bytes as they are and
+        which equals the list of GroupCount it makes for a consumer that
+        walks it.
         """
         # GroupBy plans per request (its dimensions' row ids are read
         # from the live fragments), so its plan stage is the prelude and
@@ -1698,10 +1731,10 @@ class Executor:
             limit, filt_call, agg_field, dims, having = \
                 self._groupby_prelude(idx, call, shards)
             if not dims:
-                return Deferred(value=[])
+                return Deferred(value=GroupCounts())
             shard_list = self._shards(idx, shards)
             if not shard_list:
-                return Deferred(value=[])
+                return Deferred(value=GroupCounts())
 
             specs: list = []
             scalars: list = []
@@ -1744,26 +1777,10 @@ class Executor:
         for n in sizes:
             total_groups *= n
 
-        def collect(cand, counts_arr, agg_arrs) -> list[GroupCount]:
-            counts: dict[tuple, int] = {}
-            sums: dict[tuple, int] = {}
-            base = agg_field.options.base if agg_field is not None else 0
-            for j in range(cand.shape[0]):
-                c = int(counts_arr[j])
-                if c <= 0:
-                    continue
-                gkey = tuple(
-                    dims[d][1][int(cand[j, d])] for d in range(cand.shape[1])
-                )
-                counts[gkey] = c
-                if agg_arrs is not None:
-                    n = int(agg_arrs[0][j])
-                    pc = agg_arrs[1][:, j].tolist()
-                    sums[gkey] = (
-                        sum(int(v) << b for b, v in enumerate(pc)) + base * n
-                    )
-            return self._groupby_result(
-                idx, dims, counts, sums, agg_field, limit, having=having,
+        def collect(cand, counts_arr, agg_arrs) -> GroupCounts:
+            return self._groupby_counts(
+                idx, dims, cand, counts_arr, agg_arrs, agg_field,
+                len(block.shards) * SHARD_WIDTH, limit, having=having,
             )
 
         if total_groups <= GROUPBY_DENSE_MAX_GROUPS:
@@ -1779,7 +1796,7 @@ class Executor:
             has_agg = planes is not None
             depth = agg_field.options.bit_depth if has_agg else 0
 
-            def finish() -> list[GroupCount]:
+            def finish() -> GroupCounts:
                 counts_arr, agg_arrs = _groupby_level_unpack(
                     _readback(packed), layout, cand.shape[0], has_agg,
                     depth,
@@ -1790,7 +1807,7 @@ class Executor:
                 return Deferred(finish)
             return Deferred(value=finish())
 
-        def run_pruned() -> list[GroupCount]:
+        def run_pruned() -> GroupCounts:
             # prefix pruning: extend one dimension at a time, dropping
             # empty prefixes after each level (AND only shrinks groups);
             # each level's readback gates the next level's candidates.
@@ -1819,7 +1836,7 @@ class Executor:
                 if agg_arrs is not None:
                     agg_arrs = (agg_arrs[0][keep], agg_arrs[1][:, keep])
                 if cand.shape[0] == 0:
-                    return []
+                    return GroupCounts()
             return collect(cand, counts_arr, agg_arrs)
 
         if pipeline:
@@ -2080,6 +2097,19 @@ def _groupby_level_unpack(host: np.ndarray, layout, c_total: int,
             off += 2 * padded
         out_off += actual
     return counts, (n_g, pc) if has_agg else None
+
+
+def _groupby_sums(n: np.ndarray, pc: np.ndarray, base: int, depth: int,
+                  columns: int) -> np.ndarray:
+    """Each group's BSI Sum from its plane counts ``pc[depth, G]`` and
+    its count of non-null values ``n[G]``: sum(pc[b] << b) + base * n,
+    exact. A plane count is at most ``columns``, so where depth and base
+    leave every partial sum inside 62 bits the arithmetic is int64;
+    otherwise it is done in Python integers (an object array)."""
+    if (((1 << depth) - 1) + abs(base)) * columns < 1 << 62:
+        return (1 << np.arange(depth, dtype=np.int64)) @ pc + base * n
+    weights = np.array([1 << b for b in range(depth)], dtype=object)
+    return np.dot(weights, pc.astype(object)) + base * n.astype(object)
 
 
 def options_child(call: Call) -> Call:

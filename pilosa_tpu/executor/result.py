@@ -2,18 +2,24 @@
 
 Reference: row.go (SURVEY.md §2 #2) — a Row is per-shard segments each
 wrapping a bitmap, so cross-shard merges are cheap concatenation; plus the
-pair/group shapes the executor reduces (Pairs for TopN, GroupCounts for
-GroupBy).
+pair/group shapes the executor reduces (a list of Pair for TopN; for
+GroupBy one GroupCounts, the groups as columns, which renders itself to
+JSON and makes a GroupCount per group only for a consumer that walks it).
 """
 
 from __future__ import annotations
 
 import json
+from collections.abc import Sequence
 
 import numpy as np
 
 from pilosa_tpu.ops.packing import popcount_words, unpack_bits
 from pilosa_tpu.shardwidth import SHARD_WIDTH
+from pilosa_tpu.utils.tracing import (
+    note_groupby_materialized,
+    note_groupby_result,
+)
 
 
 class RowResult:
@@ -145,9 +151,108 @@ class GroupCount:
                 f"sum={self.sum})")
 
 
+class GroupCounts(Sequence):
+    """A GroupBy's whole answer as columns, in result order: group g is
+    row ``rows[g, d]`` of field ``fields[d]`` for every dimension d, with
+    ``counts[g]`` and, under aggregate=Sum(...), ``sums[g]``.
+    ``row_keys[d]`` maps a keyed dimension's row ids to their keys (a row
+    it lacks is emitted by id), None for an un-keyed dimension.
+
+    ``json_bytes`` goes from the columns to the response bytes; as a
+    sequence it equals the list of GroupCount it stands for and builds
+    that list once, for the first consumer that indexes, iterates or
+    compares it (the cluster merge, the internal wire, tests)."""
+
+    __slots__ = ("fields", "rows", "counts", "sums", "row_keys", "_groups")
+
+    def __init__(self, fields=(), rows=None, counts=None, sums=None,
+                 row_keys=None):
+        self.fields = list(fields)
+        self.rows = (np.zeros((0, len(self.fields)), np.int64)
+                     if rows is None else rows)
+        self.counts = np.zeros(0, np.int64) if counts is None else counts
+        self.sums = sums
+        self.row_keys = row_keys or [None] * len(self.fields)
+        self._groups: list[GroupCount] | None = None
+        note_groupby_result()
+
+    def groups(self) -> list[GroupCount]:
+        """The per-group objects, built on first use."""
+        if self._groups is None:
+            note_groupby_materialized()
+            dims = list(zip(self.fields, self.row_keys))
+            counts = self.counts.tolist()
+            sums = ([None] * len(counts) if self.sums is None
+                    else self.sums.tolist())
+            self._groups = [
+                GroupCount(
+                    [{"field": f, "rowID": r}
+                     if keys is None or keys.get(r) is None
+                     else {"field": f, "rowKey": keys[r]}
+                     for (f, keys), r in zip(dims, row)],
+                    c, sum=s)
+                for row, c, s in zip(self.rows.tolist(), counts, sums)
+            ]
+        return self._groups
+
+    def to_json(self) -> list[dict]:
+        return [g.to_json() for g in self.groups()]
+
+    def json_bytes(self) -> bytes:
+        """``_dumps(self.to_json())`` byte for byte, without the objects:
+        a ``{"field", "rowID"|"rowKey"}`` entry is rendered once a
+        distinct row of its dimension, and a group is one string built
+        from its entries, count and sum."""
+        if not len(self.counts):
+            return b"[]"
+        columns = []
+        for field, keys, column in zip(self.fields, self.row_keys,
+                                       self.rows.T.tolist()):
+            name = json.dumps(field)
+            keys = keys or {}
+            entries = {
+                row: f'{{"field":{name},"rowID":{row}}}'
+                if keys.get(row) is None else
+                f'{{"field":{name},"rowKey":{json.dumps(keys[row])}}}'
+                for row in set(column)
+            }
+            columns.append([entries[row] for row in column])
+        groups = (columns[0] if len(columns) == 1
+                  else map(",".join, zip(*columns)))
+        counts = self.counts.tolist()
+        if self.sums is None:
+            out = [f'{{"group":[{g}],"count":{c}}}'
+                   for g, c in zip(groups, counts)]
+        else:
+            out = [f'{{"group":[{g}],"count":{c},"sum":{s}}}'
+                   for g, c, s in zip(groups, counts, self.sums.tolist())]
+        return ("[" + ",".join(out) + "]").encode()
+
+    def __len__(self) -> int:
+        return len(self.counts)
+
+    def __getitem__(self, i):
+        return self.groups()[i]
+
+    def __iter__(self):
+        return iter(self.groups())
+
+    def __eq__(self, other):
+        if isinstance(other, GroupCounts):
+            other = other.groups()
+        if not isinstance(other, list):
+            return NotImplemented
+        return self.groups() == other
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return f"GroupCounts({self.groups()!r})"
+
+
 def result_to_json(res):
     """Serialize any executor result for the HTTP response envelope."""
-    if isinstance(res, (RowResult, Pair, ValCount, GroupCount)):
+    if isinstance(res, (RowResult, Pair, ValCount, GroupCount, GroupCounts)):
         return res.to_json()
     if isinstance(res, list):
         return [result_to_json(r) for r in res]
@@ -158,9 +263,10 @@ def result_to_json(res):
 
 # ------------------------------------------------- pre-serialized responses
 #
-# The serving fast lane encodes hot result shapes (Count, Row, TopN pairs,
-# ValCount) straight to compact-JSON bytes once, instead of dict-building
-# then json.dumps per request. RowResult encodings memoize ON the result
+# The serving fast lane encodes hot result shapes (Count, Row, ValCount,
+# GroupBy's GroupCounts) straight to compact-JSON bytes once, instead of
+# dict-building then json.dumps per request (TopN's pairs still do
+# that). RowResult encodings memoize ON the result
 # object — the encoded-bytes cache keyed by result identity — so a wave of
 # identical coalesced queries (server/pipeline.py dedupe) pays the
 # segment-unpack + encode exactly once however many clients asked.
@@ -184,6 +290,8 @@ def result_json_bytes(res) -> bytes:
         return cached
     if isinstance(res, ValCount):
         return b'{"value":%d,"count":%d}' % (res.value, res.count)
+    if isinstance(res, GroupCounts):
+        return res.json_bytes()
     return _dumps(result_to_json(res))
 
 

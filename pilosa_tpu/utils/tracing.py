@@ -691,10 +691,14 @@ def stage_metrics() -> dict:
 # The stage site's tags reach a span and the capture, not /metrics, so
 # "how many device programs does a GroupBy level take" has two series of
 # its own beside the stages: one program a level unless a level exceeds
-# batch.groupby_chunk_groups candidates.
+# batch.groupby_chunk_groups candidates. Two more say whether an answer
+# stayed columnar to the response bytes: every GroupBy result built, and
+# those a consumer walked group by group (executor/result.py GroupCounts:
+# the cluster merge and the internal wire do; the JSON route does not).
 
 _groupby_lock = threading.Lock()
-_groupby_stats = {"levels": 0, "programs": 0}
+_groupby_stats = {"levels": 0, "programs": 0, "results": 0,
+                  "materialized": 0}
 
 
 def note_groupby_level(programs: int) -> None:
@@ -703,11 +707,23 @@ def note_groupby_level(programs: int) -> None:
         _groupby_stats["programs"] += programs
 
 
+def note_groupby_result() -> None:
+    with _groupby_lock:
+        _groupby_stats["results"] += 1
+
+
+def note_groupby_materialized() -> None:
+    with _groupby_lock:
+        _groupby_stats["materialized"] += 1
+
+
 def groupby_metrics() -> dict:
     """The ``groupby`` block of /metrics and /debug/vars."""
     with _groupby_lock:
         return {"levels_total": _groupby_stats["levels"],
-                "level_programs_total": _groupby_stats["programs"]}
+                "level_programs_total": _groupby_stats["programs"],
+                "results_total": _groupby_stats["results"],
+                "results_materialized_total": _groupby_stats["materialized"]}
 
 
 # ------------------------------------------------- device compiles, memory

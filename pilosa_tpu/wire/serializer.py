@@ -9,7 +9,13 @@ from __future__ import annotations
 
 import numpy as np
 
-from pilosa_tpu.executor.result import GroupCount, Pair, RowResult, ValCount
+from pilosa_tpu.executor.result import (
+    GroupCount,
+    GroupCounts,
+    Pair,
+    RowResult,
+    ValCount,
+)
 from pilosa_tpu.utils import as_int_list
 from pilosa_tpu.wire import pb2
 
@@ -95,7 +101,8 @@ def _encode_result(qr, res) -> None:
             pp.count = pair.count
             if pair.key is not None:
                 pp.key = pair.key
-    elif isinstance(res, list) and res and isinstance(res[0], GroupCount):
+    elif (isinstance(res, (list, GroupCounts)) and res
+          and isinstance(res[0], GroupCount)):
         qr.type = RESULT_GROUPS
         for g in res:
             gg = qr.groups.add()
@@ -113,7 +120,7 @@ def _encode_result(qr, res) -> None:
     elif isinstance(res, list) and res and isinstance(res[0], str):
         qr.type = RESULT_ROW_KEYS
         qr.row_keys.extend(res)
-    elif isinstance(res, list):
+    elif isinstance(res, (list, GroupCounts)):  # an empty GroupBy too
         qr.type = RESULT_ROW_IDS
         qr.row_ids.extend(int(r) for r in res)
     else:

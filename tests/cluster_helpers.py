@@ -4,6 +4,8 @@ cluster-of-meshes, and randomized-churn suites so the request encoding,
 ServerConfig surface, and seed layout live in ONE place."""
 
 import json
+import threading
+import time
 import urllib.request
 
 from pilosa_tpu.server import Server, ServerConfig
@@ -37,6 +39,28 @@ def make_cluster(tmp_path, n, replica_n=1, use_mesh=False, prefix="node",
             use_mesh=use_mesh, **config_kw,
         )).open())
     return servers
+
+
+def settle(servers, timeout=60.0):
+    """Wait out what a membership change leaves running: a join is
+    relayed and resized for on background threads (``join-relay``, the
+    coordinator's ``coordinate-resize`` runs, one a node-join it hears),
+    and ``Server.open`` returns before they end. A partition that lands
+    while one of them is still queued is a different scenario from the
+    one a test here sets up: the late resize passes quorum and acts, or
+    stalls RESIZING with its peers cut off."""
+    deadline = time.monotonic() + timeout
+    while True:
+        busy = [t for t in threading.enumerate()
+                if t.name in ("join-relay", "coordinate-resize")]
+        if not busy:
+            break
+        for t in busy:
+            t.join(max(0.0, deadline - time.monotonic()))
+        assert time.monotonic() < deadline, [t.name for t in busy]
+    for s in servers:
+        assert s.api.cluster.wait_until_normal(
+            max(0.0, deadline - time.monotonic())), s.config.name
 
 
 def join_node(tmp_path, seed_server, use_mesh=False, replica_n=1,

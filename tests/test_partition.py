@@ -21,14 +21,13 @@ internal wire), so the observer is never partitioned from the nodes.
 
 import json
 import socket
-import threading
 import time
 import urllib.error
 import urllib.request
 
 import pytest
 
-from cluster_helpers import make_cluster, req, uri
+from cluster_helpers import make_cluster, req, settle, uri
 from pilosa_tpu.parallel.cluster import (
     Cluster,
     DEAD_HEARTBEATS,
@@ -47,28 +46,6 @@ def _fast_and_clean(monkeypatch):
     monkeypatch.setattr(Cluster, "CLEANUP_DRAIN_TIMEOUT", 1.0)
     yield
     faults.clear()
-
-
-def settle(servers, timeout=60.0):
-    """Wait out what a membership change leaves running: a join is
-    relayed and resized for on background threads (``join-relay``, the
-    coordinator's ``coordinate-resize`` runs, one a node-join it hears),
-    and ``Server.open`` returns before they end. A partition that lands
-    while one of them is still queued is a different scenario from the
-    one a test here sets up: the late resize passes quorum and acts, or
-    stalls RESIZING with its peers cut off."""
-    deadline = time.monotonic() + timeout
-    while True:
-        busy = [t for t in threading.enumerate()
-                if t.name in ("join-relay", "coordinate-resize")]
-        if not busy:
-            break
-        for t in busy:
-            t.join(max(0.0, deadline - time.monotonic()))
-        assert time.monotonic() < deadline, [t.name for t in busy]
-    for s in servers:
-        assert s.api.cluster.wait_until_normal(
-            max(0.0, deadline - time.monotonic())), s.config.name
 
 
 def boot(tmp_path, n, replica_n=1, **kw):
