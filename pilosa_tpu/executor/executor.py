@@ -42,7 +42,12 @@ from pilosa_tpu.shardwidth import (
 from pilosa_tpu.storage import residency
 from pilosa_tpu.storage.heat import global_heat
 from pilosa_tpu.utils.cost import current_cost, use_node
-from pilosa_tpu.utils.tracing import note_groupby_level, stage, staged
+from pilosa_tpu.utils.tracing import (
+    note_groupby_level,
+    note_groupby_range_dims,
+    stage,
+    staged,
+)
 from pilosa_tpu.storage.field import (
     BSI_EXISTS_ROW,
     TYPE_INT,
@@ -1615,12 +1620,17 @@ class Executor:
         having = having_predicate(call, has_agg=agg_field is not None)
 
         dims = []
+        ranges = 0
         for child in call.children:
             fname = child.arg("_field") or child.arg("field")
             row_ids = self._rows_ids(idx, child, shards)
             if not row_ids:
                 return limit, filt_call, agg_field, [], having
             dims.append((fname, row_ids))
+            ranges += any(child.arg(k) is not None
+                          for k in ("previous", "limit", "column"))
+        if ranges:
+            note_groupby_range_dims(ranges)
         return limit, filt_call, agg_field, dims, having
 
     def _groupby_counts(self, idx: Index, dims, cand: np.ndarray,
@@ -1916,7 +1926,7 @@ class Executor:
             self._note_reduce("groupby_q" if quantized else "groupby",
                               packs[-1].shape, block.padded)
             layout.append((padded, actual))
-        note_groupby_level(len(packs))
+        note_groupby_level(len(packs), c_total)
 
         if len(packs) == 1:
             return packs[0], layout

@@ -695,16 +695,26 @@ def stage_metrics() -> dict:
 # stayed columnar to the response bytes: every GroupBy result built, and
 # those a consumer walked group by group (executor/result.py GroupCounts:
 # the cluster merge and the internal wire do; the JSON route does not).
+# Two say how large the levels are and where their rows came from: the
+# real candidates summed over levels (padding left out), and the
+# dimensions whose Rows() named a previous, limit or column (a range of
+# a field, which owns a stacked matrix of its own in the row cache).
 
 _groupby_lock = threading.Lock()
-_groupby_stats = {"levels": 0, "programs": 0, "results": 0,
-                  "materialized": 0}
+_groupby_stats = {"levels": 0, "programs": 0, "candidates": 0,
+                  "range_dims": 0, "results": 0, "materialized": 0}
 
 
-def note_groupby_level(programs: int) -> None:
+def note_groupby_level(programs: int, candidates: int) -> None:
     with _groupby_lock:
         _groupby_stats["levels"] += 1
         _groupby_stats["programs"] += programs
+        _groupby_stats["candidates"] += candidates
+
+
+def note_groupby_range_dims(dims: int) -> None:
+    with _groupby_lock:
+        _groupby_stats["range_dims"] += dims
 
 
 def note_groupby_result() -> None:
@@ -722,6 +732,8 @@ def groupby_metrics() -> dict:
     with _groupby_lock:
         return {"levels_total": _groupby_stats["levels"],
                 "level_programs_total": _groupby_stats["programs"],
+                "level_candidates_total": _groupby_stats["candidates"],
+                "range_dims_total": _groupby_stats["range_dims"],
                 "results_total": _groupby_stats["results"],
                 "results_materialized_total": _groupby_stats["materialized"]}
 
