@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import collections
 import datetime as dt
+import functools
 import math
 import threading
 import time
@@ -44,6 +45,7 @@ from pilosa_tpu.storage.heat import global_heat
 from pilosa_tpu.utils.cost import current_cost, use_node
 from pilosa_tpu.utils.tracing import (
     note_groupby_level,
+    note_groupby_operand_placement,
     note_groupby_range_dims,
     stage,
     staged,
@@ -342,6 +344,11 @@ class Executor:
         self._operand_memo: dict = {}
         self._operand_memo_gen = -1
         self._listened_cache = None
+        # what determines a GroupBy level's packed operand -> the array
+        # as placed on the device(s); see _level_operand. Indices and
+        # scalars only, nothing read from a fragment, so no write,
+        # eviction or residency generation makes an entry stale
+        self._placed_operands: dict = {}
         # guards the re-home check-then-register below: two serving
         # threads racing it would both register the clear listener
         self._rehome_lock = threading.Lock()
@@ -629,6 +636,40 @@ class Executor:
         import jax.numpy as jnp
 
         return jnp.asarray(packed)
+
+    # entries of _placed_operands before it is cleared whole (a plain
+    # dict under the interpreter's lock, as _operand_memo is: a racing
+    # double placement of one key leaves either array, both right)
+    PLACED_OPERANDS_MAX = 512
+
+    def _level_operand(self, cand: np.ndarray, lo: int, hi: int,
+                       padded: int, scalars, cand_key):
+        """The placed operand of one chunk of a level: candidates
+        cand[lo:hi] padded to ``padded`` rows of -1, then the scalars
+        (_groupby_operand_put). With a ``cand_key`` (whatever determines
+        ``cand``) the array placed for the same chunk and scalars before
+        is handed to any number of calls (no program donates an
+        argument), and only a miss builds, places and enters the operand
+        stage."""
+        if cand_key is not None:
+            key = (cand_key, lo, hi, padded, tuple(scalars))
+            operand = self._placed_operands.get(key)
+            if operand is not None:
+                return operand
+        ci = cand[lo:hi]
+        if padded > hi - lo:
+            ci = np.concatenate(
+                [ci, np.full((padded - (hi - lo), cand.shape[1]), -1,
+                             np.int32)]
+            )
+        with stage(self._operand_stage):
+            operand = self._groupby_operand_put(scalars)(ci)
+        note_groupby_operand_placement()
+        if cand_key is not None:
+            if len(self._placed_operands) >= self.PLACED_OPERANDS_MAX:
+                self._placed_operands.clear()
+            self._placed_operands[key] = operand
+        return operand
 
     # EQuARX quantized candidate-ranking lane: inert on the base
     # executor (no inter-group wire to shrink); DistExecutor overrides
@@ -1782,7 +1823,7 @@ class Executor:
                 else None
             )
 
-        sizes = [len(row_ids) for _, row_ids in dims]
+        sizes = tuple(len(row_ids) for _, row_ids in dims)
         total_groups = 1
         for n in sizes:
             total_groups *= n
@@ -1796,12 +1837,10 @@ class Executor:
         if total_groups <= GROUPBY_DENSE_MAX_GROUPS:
             # small cross-product: every group in one level; the level
             # program is enqueued NOW, the readback waits for result()
-            cand = np.zeros((1, 0), np.int32)
-            for n in sizes:
-                cand = _index_cross(cand, n)
+            cand = _dense_candidates(sizes)
             packed, layout = self._groupby_level_enqueue(
                 block, filt_leaves, filt_node, scalars, dim_mats, cand,
-                planes, agg_field,
+                planes, agg_field, cand_key=sizes,
             )
             has_agg = planes is not None
             depth = agg_field.options.bit_depth if has_agg else 0
@@ -1839,6 +1878,10 @@ class Executor:
                     planes if last else None,
                     agg_field if last else None,
                     quantized=quant and not last,
+                    # the first level is every row of its dimension, as
+                    # a dense level of one; the later ones are whatever
+                    # survived the readback
+                    cand_key=(sizes[0],) if k == 0 else None,
                 )
                 keep = counts_arr > 0
                 cand = cand[keep]
@@ -1855,14 +1898,15 @@ class Executor:
 
     def _groupby_eval_level(self, block, filt_leaves, filt_node,
                             scalars, dim_mats, cand: np.ndarray, planes,
-                            agg_field, quantized: bool = False):
+                            agg_field, quantized: bool = False,
+                            cand_key=None):
         """Evaluate one pruning level: enqueue + blocking readback.
         ``quantized`` levels return per-candidate count UPPER BOUNDS
         (approx + error bound) — valid only for gating survival, never
         for reported counts."""
         packed, layout = self._groupby_level_enqueue(
             block, filt_leaves, filt_node, scalars, dim_mats, cand,
-            planes, agg_field, quantized=quantized,
+            planes, agg_field, quantized=quantized, cand_key=cand_key,
         )
         has_agg = planes is not None
         depth = agg_field.options.bit_depth if has_agg else 0
@@ -1873,13 +1917,18 @@ class Executor:
 
     def _groupby_level_enqueue(self, block, filt_leaves, filt_node,
                                scalars, dim_mats, cand: np.ndarray, planes,
-                               agg_field, quantized: bool = False):
+                               agg_field, quantized: bool = False,
+                               cand_key=None):
         """Dispatch one level's per-candidate counts (plus BSI aggregate
         partials on the final level): one program, unless the level has
         more candidates than the kernel's accumulator block holds
         (batch.groupby_chunk_groups), when the chunks' results are
-        concatenated on device. Returns (device packed array, chunk
-        layout) — no host sync."""
+        concatenated on device. ``cand_key`` is a hashable that
+        determines ``cand``'s content (a dense level: its dimensions'
+        sizes), so that the level's operands are placed once and found
+        again (_level_operand); None for candidates chosen from a
+        readback, which are placed and forgotten. Returns (device packed
+        array, chunk layout) — no host sync."""
         import jax.numpy as jnp
 
         n_gather = len(dim_mats)
@@ -1897,7 +1946,6 @@ class Executor:
             filt_node, len(filt_leaves), len(scalars), n_gather, n_planes,
             quantized=quantized,
         )
-        put = self._groupby_operand_put(scalars)
         args = list(filt_leaves) + list(dim_mats)
         if has_agg:
             args.append(planes)
@@ -1905,18 +1953,14 @@ class Executor:
         packs = []
         layout = []  # (padded, actual) per chunk
         for lo in range(0, c_total, chunk):
-            ci = cand[lo: lo + chunk]
-            actual = ci.shape[0]
+            hi = min(lo + chunk, c_total)
+            actual = hi - lo
             # padding is marked negative: the kernel stops at the last
             # real candidate (at least 8 wide, so that the smallest
             # levels share one compiled shape)
             padded = max(8, next_pow2(actual))
-            if padded > actual:
-                ci = np.concatenate(
-                    [ci, np.full((padded - actual, n_gather), -1, np.int32)]
-                )
-            with stage(self._operand_stage):
-                operand = put(ci)
+            operand = self._level_operand(cand, lo, hi, padded, scalars,
+                                          cand_key)
             site = stage("device.dispatch", reduce="groupby")
             with site:
                 packs.append(fn(*args, operand))
@@ -2278,6 +2322,19 @@ def _index_cross(cand: np.ndarray, n: int) -> np.ndarray:
     left = np.repeat(cand, n, axis=0)
     right = np.tile(np.arange(n, dtype=np.int32), p)[:, None]
     return np.concatenate([left, right], axis=1)
+
+
+@functools.lru_cache(maxsize=64)
+def _dense_candidates(sizes: tuple) -> np.ndarray:
+    """Every index tuple of a cross-product of ``sizes`` rows, in
+    lexicographic order: a dense level's candidates, the same for every
+    request of a query template (at most GROUPBY_DENSE_MAX_GROUPS rows).
+    Shared between callers, so read-only."""
+    cand = np.zeros((1, 0), np.int32)
+    for n in sizes:
+        cand = _index_cross(cand, n)
+    cand.flags.writeable = False
+    return cand
 
 
 def _check_row(row) -> None:

@@ -699,10 +699,15 @@ def stage_metrics() -> dict:
 # real candidates summed over levels (padding left out), and the
 # dimensions whose Rows() named a previous, limit or column (a range of
 # a field, which owns a stacked matrix of its own in the row cache).
+# One says how many level programs had their packed operand placed on
+# the device(s) for them: the rest found the array an earlier level of
+# the same content had placed (Executor._level_operand), so
+# 1 - placements / programs is that memo's hit share.
 
 _groupby_lock = threading.Lock()
 _groupby_stats = {"levels": 0, "programs": 0, "candidates": 0,
-                  "range_dims": 0, "results": 0, "materialized": 0}
+                  "placements": 0, "range_dims": 0, "results": 0,
+                  "materialized": 0}
 
 
 def note_groupby_level(programs: int, candidates: int) -> None:
@@ -710,6 +715,11 @@ def note_groupby_level(programs: int, candidates: int) -> None:
         _groupby_stats["levels"] += 1
         _groupby_stats["programs"] += programs
         _groupby_stats["candidates"] += candidates
+
+
+def note_groupby_operand_placement() -> None:
+    with _groupby_lock:
+        _groupby_stats["placements"] += 1
 
 
 def note_groupby_range_dims(dims: int) -> None:
@@ -733,6 +743,7 @@ def groupby_metrics() -> dict:
         return {"levels_total": _groupby_stats["levels"],
                 "level_programs_total": _groupby_stats["programs"],
                 "level_candidates_total": _groupby_stats["candidates"],
+                "operand_placements_total": _groupby_stats["placements"],
                 "range_dims_total": _groupby_stats["range_dims"],
                 "results_total": _groupby_stats["results"],
                 "results_materialized_total": _groupby_stats["materialized"]}
