@@ -49,7 +49,10 @@ heartbeat-timeout = 2.0       # tight per-probe timeout for liveness
 # device-budget-bytes = 0     # HBM residency budget PER CHIP (an entry is
                               # charged what it holds on the fullest
                               # chip: a leaf sharded over a mesh costs its
-                              # shard); 0 = auto (4 GiB of a chip's 16)
+                              # shard); 0 = measure: 3/4 of the
+                              # smallest local chip's memory limit
+                              # (~11.8 GiB of a v5e's 16), 4 GiB where
+                              # the backend reports none (CPU)
 long-query-time = 0.0         # log queries slower than this; 0 = off
 max-writes-per-request = 5000 # reject larger write batches; 0 = unlimited
 ingest-workers = 1            # local shard-group apply pool per import

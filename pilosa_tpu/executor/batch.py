@@ -39,6 +39,8 @@ and recombined on the host as ``hi·2^15 + lo``, exact to 2^15 shards
 from __future__ import annotations
 
 import itertools
+import sys
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -150,10 +152,44 @@ class ShardBlock:
         elif inner is None:
             # all-padding process: still must feed correctly-shaped zeros
             inner = per_shard_fn(self.shards[0]).shape if self.shards else ()
-        out = np.zeros((hi - lo,) + tuple(inner), np.uint32)
+        out = _staging_array((hi - lo,) + tuple(inner))
+        out[len(local):] = 0
         for i, s in enumerate(local):
             out[i] = first if i == 0 else per_shard_fn(s)
         return out
+
+
+# Host staging arrays of ShardBlock.stack, recycled. A residency miss
+# decodes into one, hands it to device_put and drops it; whether glibc
+# then keeps the freed 16 MiB of a row leaf or returns them to the
+# kernel depends on what else its heap holds, so one and the same decode
+# cost a server 14 ms a row in one process and 22 in the next (PR 34, on
+# the chip's host: 4,096 page faults a row). An array goes out again only
+# while nothing but the pool refers to it: JAX keeps a reference for as
+# long as a transfer, or on the CPU a zero-copy alias, needs the memory.
+# Stacks too large to pool (a GroupBy dimension's matrix) are built once
+# and stay resident.
+STAGING_POOL_BYTES = 128 << 20
+_staging: dict[tuple, list] = {}
+_staging_bytes = 0
+_staging_lock = threading.Lock()
+
+
+def _staging_array(shape: tuple) -> np.ndarray:
+    """An uninitialised uint32 host array of ``shape``: a pooled one that
+    nobody refers to any more, else a new one (pooled while the pool has
+    room)."""
+    global _staging_bytes
+    with _staging_lock:
+        pool = _staging.setdefault(shape, [])
+        for buf in pool:
+            if sys.getrefcount(buf) == 3:  # the pool, ``buf``, the call
+                return buf
+        buf = np.empty(shape, np.uint32)
+        if _staging_bytes + buf.nbytes <= STAGING_POOL_BYTES:
+            pool.append(buf)
+            _staging_bytes += buf.nbytes
+        return buf
 
 
 # ------------------------------------------------------- host decode helpers

@@ -713,8 +713,10 @@ class Server:
                 )
             )
         else:
-            residency.global_row_cache().host_budget_bytes = \
-                self.config.residency_host_tier_bytes
+            # unset or 0: the budget follows the chips this server holds
+            cache = residency.global_row_cache()
+            cache.budget_bytes = residency.default_budget_bytes()
+            cache.host_budget_bytes = self.config.residency_host_tier_bytes
         # write-invalidated result cache (serving/rescache.py): the
         # process global — fragment write hooks invalidate through it —
         # sized here; 0 keeps it disabled (and clears leftovers from a

@@ -57,9 +57,10 @@ from pilosa_tpu.utils.tracing import stage, staged
 
 ROW_BYTES = WORDS_PER_SHARD * 4  # 128 KiB per resident row
 
-# Default budget: 4 GiB of ONE chip's HBM for row residency (v5e has
-# 16 GiB a chip; the rest is headroom for query intermediates + XLA
-# workspace). Per chip: an entry is charged what it holds on the fullest
+# Budget of a cache nobody sized: 4 GiB of ONE chip's HBM. A server
+# derives its own from the chips it holds (default_budget_bytes); this
+# is what that falls back to where the backend reports no memory limit
+# (the CPU). Per chip: an entry is charged what it holds on the fullest
 # chip (chip_bytes), so a leaf sharded over a four-chip mesh costs a
 # quarter of its nbytes and the mesh as a whole holds four budgets.
 # Tests override.
@@ -80,6 +81,23 @@ PURGE = object()
 # Demote-as-compressed only when it actually saves memory; denser entries
 # are simply dropped (host re-decode is the fallback, as before).
 COMPRESS_MAX_OCCUPANCY = 0.5
+
+
+def default_budget_bytes(devices=None) -> int:
+    """The budget of a server whose ``device-budget-bytes`` is unset:
+    three quarters of the smallest ``memory_stats()["bytes_limit"]``
+    over ``devices`` (the local ones by default; the budget is per chip,
+    so on a mesh the smallest chip decides), DEFAULT_BUDGET_BYTES where
+    a device gives no stats or no limit. The quarter left over is for
+    program temporaries, the one upload in flight when the cache is full
+    (_insert_dense inserts before it evicts) and XLA's workspace."""
+    limits = []
+    for d in jax.local_devices() if devices is None else devices:
+        limit = (d.memory_stats() or {}).get("bytes_limit")
+        if not limit:
+            return DEFAULT_BUDGET_BYTES
+        limits.append(int(limit))
+    return min(limits) * 3 // 4 if limits else DEFAULT_BUDGET_BYTES
 
 
 def chip_bytes(arr) -> int:
@@ -241,6 +259,7 @@ class DeviceRowCache:
         self.evictions = 0
         self.compressions = 0
         self.decompressions = 0
+        self.miss_bytes = 0  # bytes of the dense arrays misses placed
         self.host_hits = 0  # host-tier lookups served (inline promotes)
         self.tier_promotions = 0  # host -> dense (lookup or pass)
         self.tier_demotions = 0  # dense/compressed -> host
@@ -376,12 +395,14 @@ class DeviceRowCache:
         return None
 
     def _put_locked(self, key, host, device_put):
-        if device_put is not None:
-            arr = device_put(host)
-            block_idx = None  # custom placement (mesh sharding): keep dense
-        else:
-            arr = jax.device_put(host, self.device)
-            block_idx = self._host_block_index(host)
+        with stage("residency.upload"):
+            if device_put is not None:
+                arr = device_put(host)
+                block_idx = None  # custom placement (mesh sharding): dense
+            else:
+                arr = jax.device_put(host, self.device)
+                block_idx = self._host_block_index(host)
+        self.miss_bytes += int(arr.nbytes)
         self._insert_dense(key, arr, block_idx,
                            custom=device_put is not None)
         cost = current_cost()
@@ -408,7 +429,9 @@ class DeviceRowCache:
             # (invalidated by their writers), so staleness isn't possible,
             # and single-row decodes are cheap
             with stage("residency.miss"):
-                return self._put_locked(key, decode(), device_put)
+                with stage("residency.decode"):
+                    host = decode()
+                return self._put_locked(key, host, device_put)
 
     def get_or_build(self, key: tuple, tag: tuple | None,
                      probe: Callable | None,
@@ -456,7 +479,8 @@ class DeviceRowCache:
         """The miss half of get_or_build: decode outside the lock,
         upload and replay the buffered writes under it."""
         try:
-            host = decode()  # slow host work, outside the lock
+            with stage("residency.decode"):
+                host = decode()  # slow host work, outside the lock
         except BaseException:
             with self._lock:
                 self._pending_builds.pop(key, None)
@@ -939,6 +963,7 @@ class DeviceRowCache:
     _MONOTONIC_METRICS = frozenset({
         "residency_hits", "residency_misses", "residency_evictions",
         "residency_compressions", "residency_decompressions",
+        "residency_miss_bytes",
         "residency_updates", "residency_patch_retries",
         "residency_write_events",
         "residency_host_hits", "residency_tier_promotions",
@@ -981,6 +1006,7 @@ class DeviceRowCache:
                 "residency_evictions": self.evictions,
                 "residency_compressions": self.compressions,
                 "residency_decompressions": self.decompressions,
+                "residency_miss_bytes": self.miss_bytes,
                 "residency_updates": self.updates,
                 "residency_patch_retries": self.patch_retries,
                 "residency_write_events": self.write_events,

@@ -527,9 +527,10 @@ TOP_LEVEL_STAGES = (
 # ... under the root, and the stages nested in them or on other threads.
 STAGES = ("http.query",) + TOP_LEVEL_STAGES + (
     "pipeline.gather", "pipeline.submit", "executor.plan",
-    "executor.operands", "residency.miss", "residency.patch",
-    "residency.lock_wait", "device.upload", "device.replicate",
-    "device.dispatch", "device.readback", "fragment.write",
+    "executor.operands", "residency.miss", "residency.decode",
+    "residency.upload", "residency.patch", "residency.lock_wait",
+    "device.upload", "device.replicate", "device.dispatch",
+    "device.readback", "fragment.write",
 )
 
 
