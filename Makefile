@@ -143,8 +143,8 @@ elastic-smoke:
 # per-container python loops out of the rewired host paths
 # (docs/OPERATIONS.md host-path kernels)
 hostpath-smoke:
-	$(PYTEST) tests/test_roaring_kernels.py tests/test_hostpath_lint.py \
-		-m "not slow"
+	$(PYTEST) tests/test_roaring_kernels.py tests/test_row_leaf_decode.py \
+		tests/test_hostpath_lint.py -m "not slow"
 	env JAX_PLATFORMS=cpu python scripts/check_hostpath_loops.py
 
 # ingest-kernel-smoke: the write-path fast lane — byte-identity

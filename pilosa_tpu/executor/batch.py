@@ -48,9 +48,11 @@ import numpy as np
 from jax import lax
 
 from pilosa_tpu.executor import expr
+from pilosa_tpu.roaring import kernels
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD, next_pow2
 from pilosa_tpu.storage import residency
 from pilosa_tpu.utils.compile_cache import named_jit
+from pilosa_tpu.utils.cost import current_cost
 
 INT32_MIN = -(1 << 31)
 INT32_MAX = (1 << 31) - 1
@@ -159,8 +161,8 @@ class ShardBlock:
         return out
 
 
-# Host staging arrays of ShardBlock.stack, recycled. A residency miss
-# decodes into one, hands it to device_put and drops it; whether glibc
+# Host staging arrays of ShardBlock.stack and host_leaf, recycled. A
+# residency miss decodes into one, hands it to device_put and drops it; whether glibc
 # then keeps the freed 16 MiB of a row leaf or returns them to the
 # kernel depends on what else its heap holds, so one and the same decode
 # cost a server 14 ms a row in one process and 22 in the next (PR 34, on
@@ -207,6 +209,35 @@ def host_row(idx, spec, shard: int) -> np.ndarray:
         words = frag.row_words(spec.row)
         acc = words if acc is None else np.bitwise_or(acc, words)
     return acc if acc is not None else np.zeros(WORDS_PER_SHARD, np.uint32)
+
+
+def host_leaf(idx, spec, block: ShardBlock) -> np.ndarray:
+    """Dense uint32[host_rows, words] for a _RowSpec leaf (host side):
+    ``block.stack`` of ``host_row`` byte for byte, decoded in one pass
+    over the row's containers of every local shard and view
+    (kernels.flatten_rows, kernels.dense_rows32) straight into a
+    recycled staging array. A missing field, view or fragment, and a
+    slot past the shards, read zeros."""
+    lo, hi = block.local_slots
+    local = block.shards[lo:min(hi, len(block.shards))]
+    field = idx.field(spec.field)
+    bitmaps = []
+    for vname in spec.views:
+        view = field.view(vname) if field else None
+        if view is None:
+            continue
+        for slot, shard in enumerate(local):
+            frag = view.fragment(shard)
+            if frag is not None:
+                bitmaps.append((slot, frag.bitmap))
+    flat = kernels.flatten_rows(bitmaps, spec.row)
+    cost = current_cost()
+    if cost is not None:
+        # one tally a leaf, the totals Fragment.row_words notes a shard
+        cost.note_containers(*flat.kind_counts())
+    out = _staging_array((hi - lo, WORDS_PER_SHARD))
+    kernels.dense_rows32(flat, out)
+    return out
 
 
 def host_planes(idx, spec, shard: int, depth: int) -> np.ndarray:
@@ -422,8 +453,7 @@ def stacked_leaf(idx, spec, block: ShardBlock, device_put=None):
         key = leaf_key(idx, spec, block)
 
         def decode():
-            return block.stack(lambda shard: host_row(idx, spec, shard),
-                               inner=(WORDS_PER_SHARD,))
+            return host_leaf(idx, spec, block)
 
         def probe():  # factory: only built when the key isn't registered
             views = frozenset(spec.views)

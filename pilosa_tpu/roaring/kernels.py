@@ -37,6 +37,7 @@ import struct
 import numpy as np
 
 from pilosa_tpu.roaring.bitmap import ARRAY, BITMAP, RUN, BITMAP_N_WORDS
+from pilosa_tpu.shardwidth import SHARD_WIDTH
 
 _U16 = np.uint64(16)
 _EMPTY_IDS = np.empty(0, np.uint64)
@@ -93,12 +94,32 @@ class FlatFragment:
     Containers are immutable once published (bitmap.py swaps whole
     containers atomically), so a flat view taken lock-free is a
     consistent snapshot of every container it captured.
+
+    ``bmp_words`` is stacked when first read: a view built from
+    containers keeps their word arrays by reference in ``bmp_parts``,
+    and the consumers that never read the stack (metadata folds, the
+    row-leaf decode, which copies each container's words straight to
+    its place) never pay the copy.
     """
 
     __slots__ = ("keys", "kinds", "cards", "kind_row",
                  "arr_sel", "arr_data", "arr_off",
-                 "bmp_sel", "bmp_words",
+                 "bmp_sel", "bmp_parts", "_bmp_words",
                  "run_sel", "run_data", "run_off")
+
+    @property
+    def bmp_words(self) -> np.ndarray:
+        w = self._bmp_words
+        if w is None:
+            w = self._bmp_words = (
+                np.stack(self.bmp_parts) if self.bmp_parts
+                else np.empty((0, BITMAP_N_WORDS), np.uint64))
+        return w
+
+    @bmp_words.setter
+    def bmp_words(self, words: np.ndarray) -> None:
+        self._bmp_words = words
+        self.bmp_parts = None
 
     @property
     def n_containers(self) -> int:
@@ -114,70 +135,94 @@ class FlatFragment:
         return int(c[ARRAY]), int(c[BITMAP]), int(c[RUN])
 
 
-def _build_flat(pairs) -> FlatFragment:
-    """Assemble a FlatFragment from (key, Container) pairs in ascending
-    key order. THE one sanctioned per-container loop on the host path:
-    it gathers references and metadata only — every bit touch happens
-    in the batched kernels below."""
+def _build_flat(keys: list, conts: list) -> FlatFragment:
+    """Assemble a FlatFragment from parallel lists of keys (ascending)
+    and their Containers. With its callers' gathers, THE one sanctioned
+    per-container walk on the host path: references and metadata only —
+    every bit touch happens in the batched kernels below."""
     f = FlatFragment()
-    n = len(pairs)
-    keys = np.empty(n, np.int64)
-    kinds = np.empty(n, np.uint8)
-    cards = np.empty(n, np.int64)
-    kind_row = np.empty(n, np.int64)
-    arr_sel, arr_parts = [], []
-    bmp_sel, bmp_parts = [], []
-    run_sel, run_parts = [], []
-    for i, (key, c) in enumerate(pairs):
-        keys[i] = key
-        kinds[i] = c.kind
-        cards[i] = c.n
-        if c.kind == ARRAY:
-            kind_row[i] = len(arr_sel)
-            arr_sel.append(i)
-            arr_parts.append(c.data)
-        elif c.kind == BITMAP:
-            kind_row[i] = len(bmp_sel)
-            bmp_sel.append(i)
-            bmp_parts.append(c.data)
-        else:
-            kind_row[i] = len(run_sel)
-            run_sel.append(i)
-            run_parts.append(c.data)
-    f.keys, f.kinds, f.cards, f.kind_row = keys, kinds, cards, kind_row
-    f.arr_sel = np.asarray(arr_sel, np.int64)
+    n = len(keys)
+    f.keys = np.asarray(keys, np.int64)
+    f.kinds = kinds = np.asarray([c.kind for c in conts], np.uint8)
+    f.cards = np.asarray([c.n for c in conts], np.int64)
+    f.kind_row = kind_row = np.empty(n, np.int64)
+    parts = {}
+    for kind in (ARRAY, BITMAP, RUN):
+        sel = np.flatnonzero(kinds == kind)
+        kind_row[sel] = np.arange(sel.size)
+        parts[kind] = sel, ([c.data for c in conts] if sel.size == n
+                            else [conts[i].data for i in sel.tolist()])
+    f.arr_sel, arr_parts = parts[ARRAY]
     f.arr_data = (np.concatenate(arr_parts) if arr_parts
                   else np.empty(0, np.uint16))
-    lens = np.asarray([p.size for p in arr_parts], np.int64)
-    f.arr_off = np.concatenate(([0], np.cumsum(lens))).astype(np.int64)
-    f.bmp_sel = np.asarray(bmp_sel, np.int64)
-    f.bmp_words = (np.stack(bmp_parts) if bmp_parts
-                   else np.empty((0, BITMAP_N_WORDS), np.uint64))
-    f.run_sel = np.asarray(run_sel, np.int64)
+    f.arr_off = np.concatenate(
+        ([0], np.cumsum([p.size for p in arr_parts], dtype=np.int64)))
+    f.bmp_sel, f.bmp_parts = parts[BITMAP]
+    f._bmp_words = None
+    f.run_sel, run_parts = parts[RUN]
     f.run_data = (np.concatenate(run_parts).astype(np.int64).reshape(-1, 2)
                   if run_parts else np.empty((0, 2), np.int64))
-    rlens = np.asarray([p.shape[0] for p in run_parts], np.int64)
-    f.run_off = np.concatenate(([0], np.cumsum(rlens))).astype(np.int64)
+    f.run_off = np.concatenate(
+        ([0], np.cumsum([p.shape[0] for p in run_parts], dtype=np.int64)))
     _STATS.containers_flattened += n
     return f
+
+
+def _collect(window: list, containers: dict, shift: int,
+             kept: list, conts: list) -> None:
+    """Append the live containers of the keys in ``window`` (a slice of
+    a bitmap's key list) and their keys plus ``shift``. Lock-free
+    against concurrent writers under the same discipline as ``to_ids``:
+    ``.get`` + skip, empty containers skipped (they contribute nothing
+    and the per-container tally never counted them)."""
+    for key in window:
+        c = containers.get(key)
+        if c is not None and c.n:
+            kept.append(key + shift)
+            conts.append(c)
 
 
 def flatten(bitmap, lo_key: int | None = None,
             hi_key: int | None = None) -> FlatFragment:
     """Flatten a RoaringBitmap's containers with keys in
-    [lo_key, hi_key] (inclusive; None = unbounded). Lock-free against
-    concurrent writers under the same discipline as ``to_ids``: ``.get``
-    + skip, empty containers skipped (they contribute nothing and the
-    per-container tally never counted them)."""
+    [lo_key, hi_key] (inclusive; None = unbounded), lock-free
+    (:func:`_collect`)."""
     keys = bitmap.keys
     lo_i = 0 if lo_key is None else bisect.bisect_left(keys, lo_key)
     hi_i = len(keys) if hi_key is None else bisect.bisect_right(keys, hi_key)
-    pairs = []
-    for key in keys[lo_i:hi_i]:
-        c = bitmap._containers.get(key)
-        if c is not None and c.n:
-            pairs.append((key, c))
-    return _build_flat(pairs)
+    kept, conts = [], []
+    _collect(keys[lo_i:hi_i], bitmap._containers, 0, kept, conts)
+    return _build_flat(kept, conts)
+
+
+# A row of one shard spans 16 consecutive containers (2^20 columns of
+# 2^16), so a stack of rows keys its containers ``slot * 16 + k``.
+ROW_KEYS = SHARD_WIDTH >> 16
+
+
+def flatten_rows(bitmaps, row: int) -> FlatFragment:
+    """Flatten row ``row`` of many fragments into ONE view: ``bitmaps``
+    is (slot, RoaringBitmap) pairs, and container ``row * 16 + k`` of a
+    slot's bitmap takes the key ``slot * 16 + k``. Keys ascend; a slot
+    named more than once (a leaf that ORs several views) repeats its
+    keys, which :func:`dense_rows32` ORs. Each bitmap's window is found
+    by bisection; lock-free as :func:`flatten` is."""
+    base_key = row * ROW_KEYS
+    kept, conts = [], []
+    last_slot, ascending = -1, True
+    for slot, bitmap in bitmaps:
+        ascending &= slot > last_slot
+        last_slot = slot
+        keys = bitmap.keys
+        lo_i = bisect.bisect_left(keys, base_key)
+        hi_i = bisect.bisect_left(keys, base_key + ROW_KEYS, lo_i)
+        _collect(keys[lo_i:hi_i], bitmap._containers,
+                 slot * ROW_KEYS - base_key, kept, conts)
+    if not ascending:
+        order = sorted(range(len(kept)), key=kept.__getitem__)
+        kept = [kept[i] for i in order]
+        conts = [conts[i] for i in order]
+    return _build_flat(kept, conts)
 
 
 def _take(f: FlatFragment, idx: np.ndarray) -> FlatFragment:
@@ -446,6 +491,85 @@ def dense_words32(f: FlatFragment, base_key: int,
     if run_gs is not None:
         _or_runs_into(out64.reshape(-1), run_gs, run_ge)
     return out64.reshape(-1).view("<u4")
+
+
+def _spans(breaks: np.ndarray, n: int):
+    """(start, stop) of each stretch of ``n`` items, where ``breaks[i]``
+    (truthy) says that item ``i + 1`` starts a new one; none for 0."""
+    cuts = (np.flatnonzero(breaks) + 1).tolist()
+    return zip([0, *cuts], [*cuts, n]) if n else ()
+
+
+def dense_rows32(f: FlatFragment, out: np.ndarray) -> None:
+    """Write the rows of a :func:`flatten_rows` view into ``out``,
+    ``uint32[n_slots, 32768]`` with whatever it held before: the
+    whole-leaf residency-miss decode, byte-identical to stacking
+    :func:`dense_words32` of each slot's 16-container window (several
+    views of a slot ORed). One pass, written in place: one zero fill
+    (none where bitmap containers cover the leaf), each run of
+    neighbouring bitmap containers copied to its place, every sparse
+    array container's bits in one ``np.bitwise_or.at`` over the leaf.
+    Only what a single scatter serves badly is done a row at a time, and
+    only for the rows that have it: array bits past 1/128 of the row
+    (one bool write + ``np.packbits``, as in :func:`dense_words32`) and
+    run containers (:func:`_or_runs_into`, whose temporaries follow the
+    words it is given). No temporary is larger than ``out``."""
+    _STATS.kernel_calls += 1
+    _STATS.dense_decodes += 1
+    if not out.flags.c_contiguous:
+        raise ValueError("dense_rows32 writes in place: contiguous rows")
+    out64 = out.reshape(-1).view("<u8").reshape(-1, BITMAP_N_WORDS)
+    keys = f.keys
+    unique = bool((keys[1:] > keys[:-1]).all())
+    bmp_keys = keys[f.bmp_sel]
+    parts = f.bmp_parts if f.bmp_parts is not None else list(f.bmp_words)
+    if parts and unique and bmp_keys.size == out64.shape[0]:
+        np.concatenate(parts, out=out64.reshape(-1))
+        return
+    out.fill(0)
+    arr_keys, arr_off, data = keys[f.arr_sel], f.arr_off, f.arr_data
+    if data.size >= ROW_KEYS << 9:
+        # a row whose array containers hold 1/128 of its bits or more is
+        # packed from a bool image of that row alone, and its containers
+        # leave the scatter
+        rows_of = arr_keys // ROW_KEYS
+        thick = (np.bincount(rows_of, weights=np.diff(arr_off))
+                 >= ROW_KEYS << 9)[rows_of]
+        if thick.any():
+            for r in np.unique(rows_of[thick]).tolist():
+                c0, c1 = np.searchsorted(rows_of, [r, r + 1]).tolist()
+                bits = np.zeros(ROW_KEYS << 16, bool)
+                bits[np.repeat((arr_keys[c0:c1] % ROW_KEYS) << 16,
+                               np.diff(arr_off[c0:c1 + 1]))
+                     + data[arr_off[c0]:arr_off[c1]]] = True
+                out[r] = np.packbits(bits, bitorder="little").view("<u4")
+            starts, stops = arr_off[:-1][~thick], arr_off[1:][~thick]
+            arr_keys = arr_keys[~thick]
+            data = _gather_ranges(data, starts, stops)
+            arr_off = np.concatenate(([0], np.cumsum(stops - starts)))
+    if unique:
+        # each run of neighbouring keys is one copy into its place
+        for a, b in _spans(np.diff(bmp_keys) != 1, len(parts)):
+            k = int(bmp_keys[a])
+            np.concatenate(parts[a:b], out=out64[k:k + b - a].reshape(-1))
+    else:
+        for k, words in zip(bmp_keys.tolist(), parts):
+            np.bitwise_or(out64[k], words, out=out64[k])
+    if data.size:
+        word = np.repeat((arr_keys << 11).astype(np.uint32),
+                         np.diff(arr_off))
+        word += data >> 5
+        np.bitwise_or.at(out.reshape(-1), word, np.left_shift(
+            np.uint32(1), (data & 31).astype(np.uint32)))
+    if f.run_data.shape[0]:
+        run_keys = keys[f.run_sel]
+        for a, b in _spans(np.diff(run_keys // ROW_KEYS), run_keys.size):
+            r = int(run_keys[a]) // ROW_KEYS
+            base = np.repeat((run_keys[a:b] % ROW_KEYS) << 16,
+                             np.diff(f.run_off[a:b + 1]))
+            runs = f.run_data[f.run_off[a]:f.run_off[b]]
+            _or_runs_into(out64[r * ROW_KEYS:(r + 1) * ROW_KEYS].reshape(-1),
+                          base + runs[:, 0], base + runs[:, 1])
 
 
 # ---------------------------------------------------------------- popcount

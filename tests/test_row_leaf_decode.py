@@ -1,0 +1,367 @@
+"""The whole-leaf row decode (ISSUE 35): ``batch.host_leaf`` against the
+per-shard stack it replaced on a residency miss.
+
+The contract is byte identity with ``block.stack(host_row)``, which calls
+``Fragment.row_words`` a shard: for every container kind and their mixes,
+an empty row, a missing field, view or fragment, a zero slot past the
+shards, several views ORed, a ``local_slots`` sub-span (the mesh's
+``ShardAssignment``), and a leaf of array containers dense enough that
+``dense_words32`` would take its window-sized bool image. Beside the
+bytes: the per-request container tally keeps its totals, the
+``hostpath_*`` counters rise by one a leaf, and no temporary of the
+decode is as large as the leaf.
+
+The index is a stand-in (``field`` / ``view`` / ``fragment`` lookups over
+dicts); the fragments are real ``Fragment`` objects, never opened, whose
+bitmaps are built here, so the reference side runs the real
+``row_words``.
+"""
+
+import tracemalloc
+
+import numpy as np
+import pytest
+
+from pilosa_tpu.executor import batch
+from pilosa_tpu.executor.executor import _RowSpec
+from pilosa_tpu.roaring import kernels
+from pilosa_tpu.roaring.bitmap import ARRAY, BITMAP, RUN, RoaringBitmap
+from pilosa_tpu.shardwidth import WORDS_PER_SHARD
+from pilosa_tpu.storage.fragment import Fragment
+
+ROW = 7          # the row the leaves name; rows 6 and 9 lie beside it
+VIEW = "standard"
+
+
+# ---------------------------------------------------------------- stand-ins
+
+
+class _View:
+    def __init__(self, fragments: dict):
+        self.fragments = fragments
+
+    def fragment(self, shard: int):
+        return self.fragments.get(shard)
+
+
+class _Field:
+    def __init__(self, views: dict):
+        self.views = views
+
+    def view(self, name: str):
+        return self.views.get(name)
+
+
+class _Index:
+    """``views``: view name -> {shard: RoaringBitmap}, all of field "f"."""
+
+    scope, name = "", "i"
+
+    def __init__(self, views: dict):
+        self.fields = {"f": _Field({
+            vname: _View({shard: _fragment(vname, shard, bm)
+                          for shard, bm in by_shard.items()})
+            for vname, by_shard in views.items()})}
+
+    def field(self, name: str):
+        return self.fields.get(name)
+
+
+def _fragment(view: str, shard: int, bitmap: RoaringBitmap) -> Fragment:
+    frag = Fragment("/nonexistent", "i", "f", view, shard)  # never opened
+    frag.bitmap = bitmap
+    return frag
+
+
+# ------------------------------------------------------------ shard makers
+
+
+def _lows(rng, kind: str) -> np.ndarray:
+    """Low 16 bits of one container that ``Container.from_lows`` stores
+    as ``kind``."""
+    if kind == "array":
+        return rng.choice(65536, int(rng.integers(1, 2000)), replace=False)
+    if kind == "thick":  # an array container near its 4,096 limit
+        return rng.choice(65536, int(rng.integers(3000, 4097)), replace=False)
+    if kind == "past":   # sixteen of them are past 1/128 of a row
+        return rng.choice(65536, int(rng.integers(600, 900)), replace=False)
+    if kind == "bitmap":
+        return rng.choice(65536, int(rng.integers(4200, 30000)), replace=False)
+    if kind == "full":
+        return np.arange(65536)
+    if kind == "single":
+        return rng.choice(65536, 1)
+    starts = rng.choice(65000, int(rng.integers(1, 8)), replace=False)
+    return np.concatenate([np.arange(s, min(s + int(rng.integers(70, 900)),
+                                            65536)) for s in starts.tolist()])
+
+
+def _shard(rng, kinds, containers=None) -> RoaringBitmap:
+    """One shard's bitmap: row ROW's containers drawn from ``kinds``
+    (``containers`` of the 16, all when None), and sparse rows on either
+    side so that the window is found, not assumed."""
+    ids = [np.asarray([(6 << 20) + 5, (9 << 20) + 70_000], np.uint64)]
+    slots = (range(16) if containers is None else
+             rng.choice(16, containers, replace=False).tolist())
+    for k in slots:
+        lows = np.unique(_lows(rng, str(rng.choice(kinds))))
+        ids.append(lows.astype(np.uint64) + np.uint64((ROW << 20) + (k << 16)))
+    return RoaringBitmap.from_ids(np.concatenate(ids))
+
+
+def _kinds_of(idx, views=(VIEW,)) -> set:
+    out = set()
+    for v in views:
+        for frag in idx.field("f").view(v).fragments.values():
+            f = kernels.flatten(frag.bitmap, ROW * 16, ROW * 16 + 15)
+            out |= set(f.kinds.tolist())
+    return out
+
+
+# ---------------------------------------------------------------- the check
+
+
+@pytest.fixture
+def poisoned_staging(monkeypatch):
+    """Every staging array goes out holding all-ones, as a recycled one
+    may hold anything: a word the decode forgets to write shows."""
+    real = batch._staging_array
+
+    def poisoned(shape):
+        buf = real(shape)
+        buf.fill(0xFFFFFFFF)
+        return buf
+
+    monkeypatch.setattr(batch, "_staging_array", poisoned)
+
+
+def _stacked_reference(idx, spec, block) -> np.ndarray:
+    return block.stack(lambda shard: batch.host_row(idx, spec, shard),
+                       inner=(WORDS_PER_SHARD,)).copy()
+
+
+def _assert_identical(idx, spec, block) -> np.ndarray:
+    want = _stacked_reference(idx, spec, block)
+    got = batch.host_leaf(idx, spec, block)
+    assert got.dtype == np.uint32 and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+    return got
+
+
+LEAVES = {
+    # name: (kinds of the row's containers, containers a shard, kinds
+    #        the built leaf must hold)
+    "array_only": (["array", "single"], None, {ARRAY}),
+    "bitmap_only": (["bitmap"], None, {BITMAP}),
+    "bitmap_some": (["bitmap"], 5, {BITMAP}),
+    "run": (["run", "full"], 9, {RUN}),
+    "mixed": (["array", "bitmap", "run", "full", "single", "thick"], 11,
+              {ARRAY, BITMAP, RUN}),
+}
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("name", sorted(LEAVES))
+def test_leaf_equals_the_stack_of_row_words(name, seed, poisoned_staging):
+    """Three shards, so one zero slot of four; every container kind."""
+    kinds, containers, holds = LEAVES[name]
+    rng = np.random.default_rng([35, seed])
+    idx = _Index({VIEW: {s: _shard(rng, kinds, containers)
+                         for s in (0, 1, 5)}})
+    assert _kinds_of(idx) == holds
+    block = batch.ShardBlock([0, 1, 5])
+    got = _assert_identical(idx, _RowSpec("f", (VIEW,), ROW), block)
+    assert got[:3].any(axis=1).all() and not got[3].any()
+
+
+def test_bitmaps_that_cover_the_leaf_are_copied_without_a_fill(
+        poisoned_staging):
+    """Every container of every slot a bitmap: the slot-wise copy alone
+    writes the leaf (no zero fill is needed, and none is missed)."""
+    rng = np.random.default_rng(351)
+    idx = _Index({VIEW: {s: _shard(rng, ["bitmap"]) for s in (2, 3)}})
+    assert _kinds_of(idx) == {BITMAP}
+    _assert_identical(idx, _RowSpec("f", (VIEW,), ROW),
+                      batch.ShardBlock([2, 3]))
+
+
+@pytest.mark.parametrize("what", ["empty_row", "missing_fragment",
+                                  "missing_view", "missing_field",
+                                  "no_shards"])
+def test_what_is_not_there_reads_zeros(what, poisoned_staging):
+    rng = np.random.default_rng(352)
+    idx = _Index({VIEW: {s: _shard(rng, ["array", "bitmap"], 4)
+                         for s in (0, 1, 2)}})
+    spec = _RowSpec("f", (VIEW,), ROW)
+    block = batch.ShardBlock([0, 1, 2])
+    if what == "empty_row":
+        spec = _RowSpec("f", (VIEW,), 8)  # between the rows written
+    elif what == "missing_fragment":
+        block = batch.ShardBlock([0, 1, 2, 3, 4])  # 3 and 4 have no file
+    elif what == "missing_view":
+        spec = _RowSpec("f", ("standard_2026",), ROW)
+    elif what == "missing_field":
+        spec = _RowSpec("g", (VIEW,), ROW)
+    else:
+        block = batch.ShardBlock([])
+    got = _assert_identical(idx, spec, block)
+    if what == "missing_fragment":
+        assert got[:3].any(axis=1).all() and not got[3:].any()
+    else:
+        assert not got.any()
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_two_views_are_ored(seed, poisoned_staging):
+    """A time range's views: the same container key in both (bitmap over
+    bitmap, array over run, ...), a shard only one of them has, and a view
+    the field lacks."""
+    rng = np.random.default_rng([353, seed])
+    kinds = ["array", "bitmap", "run", "full", "thick"]
+    idx = _Index({
+        "standard_2025": {s: _shard(rng, kinds, 12) for s in (0, 1, 2)},
+        "standard_2026": {s: _shard(rng, kinds, 12) for s in (1, 2, 4)},
+    })
+    views = ("standard_2025", "standard_2024", "standard_2026")
+    assert _kinds_of(idx, (views[0], views[2])) == {ARRAY, BITMAP, RUN}
+    block = batch.ShardBlock([0, 1, 2, 4])
+    got = _assert_identical(idx, _RowSpec("f", views, ROW), block)
+    one = _stacked_reference(idx, _RowSpec("f", views[:1], ROW), block)
+    assert (got | one).tobytes() == got.tobytes() != one.tobytes()
+
+
+@pytest.mark.parametrize("span", [(0, 4), (4, 8), (2, 5), (6, 8), (3, 3)])
+def test_a_sub_span_of_local_slots(span, poisoned_staging):
+    """The mesh's ShardAssignment narrows ``local_slots``: a process
+    decodes its own slots only, zero slots past the shards included."""
+    rng = np.random.default_rng(354)
+    shards = [0, 1, 2, 3, 4, 5]  # padded to 8 slots
+    idx = _Index({VIEW: {s: _shard(rng, ["array", "bitmap", "run"], 8)
+                         for s in shards}})
+    spec = _RowSpec("f", (VIEW,), ROW)
+    whole = _stacked_reference(idx, spec, batch.ShardBlock(shards))
+    block = batch.ShardBlock(shards)
+    block.local_slots = span
+    got = _assert_identical(idx, spec, block)
+    assert got.shape == (span[1] - span[0], WORDS_PER_SHARD)
+    assert got.tobytes() == whole[span[0]:span[1]].tobytes()
+
+
+def test_dense_array_containers_need_no_temporary_as_large_as_the_leaf(
+        poisoned_staging):
+    """Array containers past 1/128 of the row, where ``dense_words32``
+    writes a bool image of its window: at leaf scale that image would be
+    eight times the leaf. Here 32 slots: rows 0-27 past the threshold (a
+    row's image at a time), rows 28-30 sparse (the one scatter), row 31
+    thick arrays beside bitmaps."""
+    rng = np.random.default_rng(355)
+    by_shard = {s: _shard(rng, ["past"]) for s in range(28)}
+    by_shard |= {s: _shard(rng, ["array"], 4) for s in (28, 29, 30)}
+    by_shard[31] = _shard(rng, ["thick", "bitmap"])
+    idx = _Index({VIEW: by_shard})
+    spec = _RowSpec("f", (VIEW,), ROW)
+    block = batch.ShardBlock(list(range(32)))
+    flat = kernels.flatten_rows(list(by_shard.items()), ROW)
+    per_row = np.bincount(flat.keys[flat.arr_sel] // 16,
+                          weights=np.diff(flat.arr_off), minlength=32)
+    threshold = 16 << 9  # dense_words32's, for a row's 16 containers
+    assert (per_row[:28] >= threshold).all() and per_row[31] >= threshold
+    assert (per_row[28:31] < threshold).all()
+    del flat
+    want = _stacked_reference(idx, spec, block)
+    batch.host_leaf(idx, spec, block)  # the staging array exists from here
+    tracemalloc.start()
+    try:
+        base = tracemalloc.get_traced_memory()[0]
+        tracemalloc.reset_peak()
+        got = batch.host_leaf(idx, spec, block)
+        peak = tracemalloc.get_traced_memory()[1] - base
+    finally:
+        tracemalloc.stop()
+    assert got.tobytes() == want.tobytes()
+    # everything the decode held at once (the gathered array containers'
+    # copy, one row's bool image, its positions) is under the 4 MiB leaf;
+    # a bool image of the leaf alone would be 32 MiB
+    assert peak < want.nbytes, (peak, want.nbytes)
+
+
+def test_the_counters_rise_once_a_leaf_and_the_tally_keeps_its_totals():
+    """``hostpath_dense_decodes_total`` and ``hostpath_kernel_calls_total``
+    rise by one a leaf where the stack raised them by one a shard; the
+    containers flattened, and the per-request tally by kind, are the
+    same sums."""
+    from pilosa_tpu.utils.cost import (
+        activate_cost, deactivate_cost, new_cost_context, set_cost_enabled,
+    )
+
+    rng = np.random.default_rng(356)
+    idx = _Index({VIEW: {s: _shard(rng, ["array", "bitmap", "run"], 10)
+                         for s in (0, 1, 2, 3, 4)}})
+    spec = _RowSpec("f", (VIEW,), ROW)
+    block = batch.ShardBlock([0, 1, 2, 3, 4])
+    stats = kernels.global_kernel_stats()
+    set_cost_enabled(True)
+
+    def run(fn) -> tuple:
+        ctx = new_cost_context("t", "i")
+        tok = activate_cost(ctx)
+        before = dict(stats.metrics())
+        try:
+            fn()
+        finally:
+            deactivate_cost(tok)
+        moved = {k: v - before[k] for k, v in stats.metrics().items()}
+        return (ctx.c_array, ctx.c_bitmap, ctx.c_run), moved
+
+    tally_a, moved_a = run(lambda: _stacked_reference(idx, spec, block))
+    tally_b, moved_b = run(lambda: batch.host_leaf(idx, spec, block))
+    assert tally_a == tally_b and sum(tally_b) == 50 and min(tally_b) > 0
+    assert moved_a["hostpath_dense_decodes_total"] == 5
+    assert moved_b["hostpath_dense_decodes_total"] == 1
+    assert moved_a["hostpath_kernel_calls_total"] == 5
+    assert moved_b["hostpath_kernel_calls_total"] == 1
+    assert (moved_a["hostpath_containers_flattened_total"]
+            == moved_b["hostpath_containers_flattened_total"] == 50)
+
+
+def test_a_container_emptied_or_gone_under_the_walk_is_skipped():
+    """``flatten``'s lock-free discipline: a key whose container a writer
+    has just removed (``.get`` answers None) or emptied contributes
+    nothing and is not counted."""
+    rng = np.random.default_rng(357)
+    bm = _shard(rng, ["array"], 6)
+    present = [k for k in bm.keys if k >> 4 == ROW]
+    gone, emptied = present[0], present[1]
+    del bm._containers[gone]              # the key list still names it
+    bm._containers[emptied].n = 0
+    flat = kernels.flatten_rows([(0, bm)], ROW)
+    assert flat.n_containers == 4
+    assert set(flat.keys.tolist()) == {k - ROW * 16 for k in present[2:]}
+    out = np.full((1, WORDS_PER_SHARD), 0xFFFFFFFF, np.uint32)
+    kernels.dense_rows32(flat, out)
+    want = kernels.dense_words32(
+        kernels.flatten(bm, ROW * 16, ROW * 16 + 15), ROW * 16, 16)
+    assert out.tobytes() == want.tobytes()
+
+
+def test_an_array_container_out_of_order_decodes_as_row_words_does():
+    """A corrupt-but-decodable file can hold an array container whose
+    values are not sorted, or repeat: both paths set whatever bits the
+    payload names."""
+    rng = np.random.default_rng(358)
+    by_shard = {s: _shard(rng, ["array", "bitmap"], 7) for s in range(6)}
+    for bm in by_shard.values():
+        for key in bm.keys:
+            c = bm._containers[key]
+            if key >> 4 == ROW and c.kind == ARRAY and c.data.size > 3:
+                c.data = np.concatenate((c.data[::-1], c.data[:3]))
+    idx = _Index({VIEW: by_shard})
+    _assert_identical(idx, _RowSpec("f", (VIEW,), ROW),
+                      batch.ShardBlock(list(by_shard)))
+
+
+def test_dense_rows32_refuses_an_array_it_could_not_write_in_place():
+    flat = kernels.flatten_rows([], ROW)
+    with pytest.raises(ValueError):
+        kernels.dense_rows32(flat, np.zeros((2, 2 * WORDS_PER_SHARD),
+                                            np.uint32)[:, ::2])

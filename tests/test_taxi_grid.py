@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from pilosa_tpu.executor import batch
+from pilosa_tpu.roaring import kernels
 from pilosa_tpu.server.api import API
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 from pilosa_tpu.storage import Holder, residency
@@ -199,6 +200,28 @@ def test_a_row_leaf_miss_places_its_dense_bytes(api, columns, small_cache):
     assert moved(small_cache, m0)["residency_miss_bytes"] == 0
     s = stages_entered(s0)  # a hit enters neither new stage
     assert s["residency_decode_total"] == s["residency_upload_total"] == 0
+
+
+def test_a_row_leaf_miss_is_one_decode_of_the_whole_leaf(api, columns,
+                                                        small_cache):
+    """The two row leaves of a cold Count are decoded in one kernel call
+    each (``batch.host_leaf``), whatever the shard count: the host-path
+    counters rise by the misses, not by misses x N_SHARDS as the per-shard
+    ``row_words`` stack raised them, and each places its SLOTS x 128 KiB."""
+    stats = kernels.global_kernel_stats()
+    pql, want = dropoff_cell_year(columns, 3, 6)
+    m0, k0 = small_cache.metrics(), dict(stats.metrics())
+    assert api.query(INDEX, pql)["results"] == [want]
+    d = moved(small_cache, m0)
+    k = {name: v - k0[name] for name, v in stats.metrics().items()}
+    assert d["residency_misses"] == 2 < 2 * N_SHARDS
+    assert d["residency_miss_bytes"] == 2 * ROW_LEAF
+    assert k["hostpath_dense_decodes_total"] == 2
+    assert k["hostpath_kernel_calls_total"] == 2
+    assert k["hostpath_containers_flattened_total"] > 2 * N_SHARDS
+    k0 = dict(stats.metrics())  # a hit decodes nothing
+    assert api.query(INDEX, pql)["results"] == [want]
+    assert stats.metrics() == k0
 
 
 def test_eight_threads_at_once_get_numpys_answers(api, columns, small_cache):
