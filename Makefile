@@ -48,9 +48,11 @@ durability-smoke:
 # obs-smoke: start a node, run a traced query, assert /debug/traces
 # renders the span tree, /debug/queries shows-then-clears, and /metrics
 # is stock-Prometheus parseable; the stage site's four sinks, the named
-# programs and trace-report (docs/OBSERVABILITY.md)
+# programs and trace-report, the second clock and the CPU by thread role
+# (docs/OBSERVABILITY.md)
 obs-smoke:
-	$(PYTEST) tests/test_tracing.py tests/test_stage_tracing.py -m "not slow"
+	$(PYTEST) tests/test_tracing.py tests/test_stage_tracing.py \
+		tests/test_stage_cpu.py -m "not slow"
 
 # cost-smoke: the query cost plane — PQL PROFILE single-node + 3-node
 # stitching, /debug/tenants accounting, /debug/heatmap skew ranking,

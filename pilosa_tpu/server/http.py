@@ -32,7 +32,13 @@ from urllib.parse import parse_qs, urlparse
 
 from pilosa_tpu.server.api import API, ApiError
 from pilosa_tpu.utils.cost import cost_enabled
-from pilosa_tpu.utils.tracing import TRACE_HEADER, global_tracer, stage
+from pilosa_tpu.utils.tracing import (
+    TRACE_HEADER,
+    enter_thread_role,
+    global_tracer,
+    retire_thread_role,
+    stage,
+)
 
 _ROUTES: list[tuple[str, re.Pattern, str]] = [
     ("POST", re.compile(r"^/index/([^/]+)/query$"), "post_query"),
@@ -125,8 +131,13 @@ class HTTPHandler(BaseHTTPRequestHandler):
             with lock:
                 self.server.connections_opened += 1
                 self.server.open_connections.add(self.connection)
+        # this thread's CPU counts as the handler role's from here to
+        # finish (thread_handler_cpu_seconds_total): request line and
+        # header parse, the stages, the socket send
+        enter_thread_role("handler")
 
     def finish(self):
+        retire_thread_role()
         lock = getattr(self.server, "metrics_lock", None)
         if lock is not None:
             with lock:
@@ -829,10 +840,15 @@ class HTTPHandler(BaseHTTPRequestHandler):
             device_metrics,
             groupby_metrics,
             stage_metrics,
+            thread_metrics,
         )
 
         text += prometheus_block(stage_metrics(), prefix, "stage",
                                  seen=seen)
+        # CPU seconds of the three thread roles of the served path and of
+        # the whole process: process less the roles is CPU no Python
+        # thread of the served path spent
+        text += prometheus_block(thread_metrics(), prefix, seen=seen)
         text += prometheus_block(groupby_metrics(), prefix, "groupby",
                                  seen=seen)
         text += prometheus_block(device_metrics(), prefix, "device",
@@ -1118,9 +1134,11 @@ class HTTPHandler(BaseHTTPRequestHandler):
             device_metrics,
             groupby_metrics,
             stage_metrics,
+            thread_metrics,
         )
 
         snap["stages"] = stage_metrics()
+        snap["threads"] = thread_metrics()
         snap["groupby"] = groupby_metrics()
         snap["device"] = device_metrics()
         from pilosa_tpu.parallel.reduction import global_reduce_stats

@@ -30,7 +30,7 @@ import time
 from concurrent.futures import Future
 
 from pilosa_tpu.utils.cost import current_cost
-from pilosa_tpu.utils.tracing import stage, staged
+from pilosa_tpu.utils.tracing import enter_thread_role, stage, staged
 
 
 class _SharedDeferred:
@@ -162,6 +162,9 @@ class QueryPipeline:
                 self._thread.start()
 
     def _loop(self):
+        # thread_dispatcher_cpu_seconds_total: this thread's CPU, as of
+        # the last pipeline.submit it left; it never retires
+        enter_thread_role("dispatcher")
         while True:
             item = self._q.get()
             wave = [item]

@@ -59,6 +59,12 @@ import time
 import weakref
 import zlib
 
+from pilosa_tpu.utils.tracing import (
+    enter_thread_role,
+    retire_thread_role,
+    staged,
+)
+
 _LOG = logging.getLogger("pilosa_tpu.storage.wal")
 
 MODE_GROUP = "group"
@@ -603,6 +609,7 @@ class WriteAheadLog:
         # spawn, or a plain bug — must record an error and wake the
         # barrier waiters: a silently dead commit thread would wedge
         # every write ACK in the server forever
+        enter_thread_role("wal_commit")
         try:
             self._run_commits()
         except BaseException as e:
@@ -610,6 +617,8 @@ class WriteAheadLog:
                 if self._error is None:
                     self._error = e
                 self._cond.notify_all()
+        finally:
+            retire_thread_role()
 
     def _run_commits(self) -> None:
         while True:
@@ -650,63 +659,74 @@ class WriteAheadLog:
                 self._last_group_size = len(batch)
                 if self._buffer:
                     self._group_open_t = time.monotonic()
-            end_seq = batch[-1][2]
-            data = b"".join(rec for _, rec, _, _, _ in batch)
-            try:
-                with self._seg_lock:
-                    f, seg = self._file, self._active
-                    seg_path = seg.path
-                    f.write(data)
-                    f.flush()
-                from pilosa_tpu.testing import faults as _faults
-
-                _faults.disk_check("fsync", seg_path)
-                self._fsync(f.fileno())
-            except (OSError, ValueError) as e:
-                # an fsync/write failure means this GROUP is lost (its
-                # bytes are a torn tail): fail its barriers forever,
-                # trip the holder into read-only storage_degraded mode,
-                # and park the loop until the health probe's
-                # clear_fault() says the disk answers again — instead
-                # of dying and wedging the node until restart
-                with self._cond:
-                    self._error = e
-                    self._failed_seq = max(self._failed_seq, end_seq)
-                    self._cond.notify_all()
-                if self.health is not None:
-                    self.health.trip(f"wal commit fsync: {e}")
-                continue
-            with self._seg_lock:
-                seg.groups.append(
-                    (batch[0][2], seg.nbytes, len(data), len(batch)))
-                seg.end_seq = end_seq
-                seg.nbytes += len(data)
-                for key, _, seq, frag, rtype in batch:
-                    if rtype == REC_TOMBSTONE:
-                        # register only NOW, post-fsync: _covered must
-                        # never GC op segments on the strength of a
-                        # tombstone a crash could still erase. And keep
-                        # it out of last_seq — a tombstone is not an op
-                        # and must not cover or pin anything as one.
-                        self._tombstones.append((key, seq))
-                        for k in list(self._dirty):
-                            if tombstone_matches(k, key):
-                                del self._dirty[k]
-                        continue
-                    seg.last_seq[key] = seq
-                    if frag is not None:
-                        self._dirty[key] = weakref.ref(frag)
-            self.groups += 1
-            self.fsyncs += 1
-            self.appended_ops += len(batch)
-            self.wal_bytes += len(data)
-            self.max_group_ops = max(self.max_group_ops, len(batch))
-            with self._cond:
-                self._durable_seq = max(self._durable_seq, end_seq)
-                self._cond.notify_all()
-            if seg.nbytes > SEGMENT_MAX_BYTES and not self._closing:
+            seg = self._commit_group(batch)
+            if (seg is not None and seg.nbytes > SEGMENT_MAX_BYTES
+                    and not self._closing):
                 self._open_segment()
                 self._spawn_checkpoint()
+
+    @staged("wal.commit")
+    def _commit_group(self, batch: list):
+        """One group's write, flush, fsync and registration, up to the
+        ``notify_all`` that releases its barriers: the commit thread's
+        busy time (stage ``wal.commit``; the waits on ``_cond`` are
+        outside it). Returns the segment it landed in, None when the
+        group was lost to a disk fault."""
+        end_seq = batch[-1][2]
+        data = b"".join(rec for _, rec, _, _, _ in batch)
+        try:
+            with self._seg_lock:
+                f, seg = self._file, self._active
+                seg_path = seg.path
+                f.write(data)
+                f.flush()
+            from pilosa_tpu.testing import faults as _faults
+
+            _faults.disk_check("fsync", seg_path)
+            self._fsync(f.fileno())
+        except (OSError, ValueError) as e:
+            # an fsync/write failure means this GROUP is lost (its
+            # bytes are a torn tail): fail its barriers forever,
+            # trip the holder into read-only storage_degraded mode,
+            # and park the loop until the health probe's
+            # clear_fault() says the disk answers again — instead
+            # of dying and wedging the node until restart
+            with self._cond:
+                self._error = e
+                self._failed_seq = max(self._failed_seq, end_seq)
+                self._cond.notify_all()
+            if self.health is not None:
+                self.health.trip(f"wal commit fsync: {e}")
+            return None
+        with self._seg_lock:
+            seg.groups.append(
+                (batch[0][2], seg.nbytes, len(data), len(batch)))
+            seg.end_seq = end_seq
+            seg.nbytes += len(data)
+            for key, _, seq, frag, rtype in batch:
+                if rtype == REC_TOMBSTONE:
+                    # register only NOW, post-fsync: _covered must
+                    # never GC op segments on the strength of a
+                    # tombstone a crash could still erase. And keep
+                    # it out of last_seq — a tombstone is not an op
+                    # and must not cover or pin anything as one.
+                    self._tombstones.append((key, seq))
+                    for k in list(self._dirty):
+                        if tombstone_matches(k, key):
+                            del self._dirty[k]
+                    continue
+                seg.last_seq[key] = seq
+                if frag is not None:
+                    self._dirty[key] = weakref.ref(frag)
+        self.groups += 1
+        self.fsyncs += 1
+        self.appended_ops += len(batch)
+        self.wal_bytes += len(data)
+        self.max_group_ops = max(self.max_group_ops, len(batch))
+        with self._cond:
+            self._durable_seq = max(self._durable_seq, end_seq)
+            self._cond.notify_all()
+        return seg
 
     # ------------------------------------------------- checkpoint / segments
 

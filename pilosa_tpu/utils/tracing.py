@@ -22,12 +22,18 @@ rewrite is the real thing, sized for the serving planes PRs 1-6 built:
 - **In-flight inspector**: ``QueryTracker`` (always on, lock-free stage
   updates) backs ``GET /debug/queries`` — upstream's long-running-query
   view: trace id, PQL, index, age, current stage, shards outstanding.
-- **One stage site, four sinks**: ``stage(name)`` is the context manager
-  every layer boundary of the served path uses (``STAGES`` is the list).
-  One pair of clock reads feeds the always-on cumulative counters
-  (``/metrics`` ``pilosa_tpu_stage_*``, ``/debug/vars`` ``stages``), the
-  sampled span tree, a ``jax.profiler.TraceAnnotation`` while a device
-  capture runs, and the inspector's ``stage``.
+- **One stage site, two clocks, four sinks**: ``stage(name)`` is the
+  context manager every layer boundary of the served path uses
+  (``STAGES`` is the list). One pair of wall-clock reads feeds the
+  always-on cumulative counters (``/metrics`` ``pilosa_tpu_stage_*``,
+  ``/debug/vars`` ``stages``), the sampled span tree, a
+  ``jax.profiler.TraceAnnotation`` while a device capture runs, and the
+  inspector's ``stage``. Where somebody will look (inside a sampled
+  trace or a capture) the site also reads the thread's own CPU clock
+  inside the wall pair: a stage's wall seconds less its CPU seconds are
+  the time its thread was not running. The three root stages keep the
+  CPU by thread role (``thread_metrics``: handler, dispatcher, WAL
+  commit), at one CPU-clock read every 0.1 s a thread.
 
 On TPU the device-side story stays the JAX profiler; ``start_jax_trace``
 wraps ``jax.profiler`` (Python tracer off, so the capture leaves the host
@@ -41,6 +47,9 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
+import glob
+import json
+import os
 import random
 import re
 import threading
@@ -530,26 +539,41 @@ STAGES = ("http.query",) + TOP_LEVEL_STAGES + (
     "executor.operands", "residency.miss", "residency.decode",
     "residency.upload", "residency.patch", "residency.lock_wait",
     "device.upload", "device.replicate", "device.dispatch",
-    "device.readback", "fragment.write",
+    "device.readback", "fragment.write", "wal.commit",
 )
+# The outermost stage of each thread role (``enter_thread_role``): its
+# exit refreshes the thread's cumulative CPU.
+ROLE_ROOT_STAGES = ("http.query", "pipeline.submit", "wal.commit")
 
 
 class _StageCounter:
-    """Entries and nanoseconds of one stage, exact under threads (both
-    are only ever changed under ``_lock``)."""
+    """Entries and wall nanoseconds of one stage, and of the entries
+    whose CPU was read their number, wall and CPU nanoseconds; exact
+    under threads (all five are only ever changed under ``_lock``)."""
 
-    __slots__ = ("count", "ns", "_lock")
+    __slots__ = ("count", "ns", "cpu_count", "cpu_wall_ns", "cpu_ns",
+                 "role_root", "_lock")
 
-    def __init__(self):
+    def __init__(self, role_root: bool = False):
         self.count = 0
         self.ns = 0
+        self.cpu_count = 0
+        self.cpu_wall_ns = 0
+        self.cpu_ns = 0
+        self.role_root = role_root
         self._lock = threading.Lock()
 
 
 _clock_ns = time.perf_counter_ns
+# The calling thread's own CPU. A system call where the wall clock is
+# not: 0.3 us on a plain kernel and 5.6 us under the sandboxed kernel of
+# the machines that hold the chips (PERF.md 6, PR 36), where two reads a
+# site at every site cost a sixth of the rate. So a site reads it only
+# where somebody will look at the answer.
+_cpu_clock_ns = time.thread_time_ns
 
-_stage_counters: dict[str, _StageCounter] = {n: _StageCounter()
-                                             for n in STAGES}
+_stage_counters: dict[str, _StageCounter] = {
+    n: _StageCounter(n in ROLE_ROOT_STAGES) for n in STAGES}
 _stage_registry_lock = threading.Lock()
 
 # jax.profiler.TraceAnnotation while a device capture runs, else None:
@@ -568,12 +592,14 @@ _request_id: contextvars.ContextVar = contextvars.ContextVar(
 class _Stage:
     """One entry of a stage; see ``stage``."""
 
-    __slots__ = ("name", "elapsed", "_counter", "_tags", "_root", "_cm",
-                 "_t0", "_ann", "_query", "_prev", "_rid_token")
+    __slots__ = ("name", "elapsed", "cpu", "_counter", "_tags", "_root",
+                 "_cm", "_t0", "_c0", "_ann", "_query", "_prev",
+                 "_rid_token")
 
     def __init__(self, name: str, counter: _StageCounter, root, tags: dict):
         self.name = name
-        self.elapsed = 0.0  # seconds, set on exit
+        self.elapsed = 0.0  # wall seconds, set on exit
+        self.cpu = None  # the thread's CPU seconds inside them, if read
         self._counter = counter
         self._tags = tags
         self._root = root
@@ -603,26 +629,49 @@ class _Stage:
             cm = (_NOP if cur is None or cur is _NOT_SAMPLED
                   else global_tracer()._join(name, self._tags, t0 * 1e-9))
         self._cm = cm
-        if cm is _NOP:
-            return None
-        span = cm.__enter__()
+        span = None if cm is _NOP else cm.__enter__()
         if span is not None:
             span.start = t0 * 1e-9
+        # the second clock, inside a capture or a sampled trace only:
+        # wall then CPU here, CPU then wall on exit, so the CPU interval
+        # lies inside the wall interval
+        self._c0 = (_cpu_clock_ns()
+                    if annotate is not None or span is not None else -1)
         return span
 
     def __exit__(self, exc_type, exc, tb):
+        counter = self._counter
+        c0 = self._c0
+        if c0 >= 0:
+            c1 = _cpu_clock_ns()
         t1 = _clock_ns()
         ns = t1 - self._t0
         self.elapsed = ns * 1e-9
-        counter = self._counter
+        if c0 >= 0:
+            cpu_ns = c1 - c0
+            self.cpu = cpu_ns * 1e-9
         with counter._lock:
             counter.count += 1
             counter.ns += ns
+            if c0 >= 0:
+                counter.cpu_count += 1
+                counter.cpu_wall_ns += ns
+                counter.cpu_ns += cpu_ns
+        if counter.role_root:
+            cell = getattr(_role_local, "cell", None)
+            if cell is not None and (
+                    c0 >= 0 or t1 - cell.read_ns >= ROLE_REFRESH_NS):
+                cell.cpu_ns = (c1 if c0 >= 0
+                               else _cpu_clock_ns()) - cell.base_ns
+                cell.read_ns = t1
         cm = self._cm
         if cm is not _NOP:
             span = getattr(cm, "_span", None)
-            if span is not None and span.end is None:
-                span.end = t1 * 1e-9
+            if span is not None:
+                if c0 >= 0:
+                    span.tags["cpu_ms"] = round(cpu_ns * 1e-6, 3)
+                if span.end is None:
+                    span.end = t1 * 1e-9
             cm.__exit__(exc_type, exc, tb)
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
@@ -638,10 +687,10 @@ def stage(name: str, _root=None, /, **tags) -> _Stage:
     """The one site every layer boundary of the served path uses.
 
     ``with stage("device.dispatch", reduce=kind) as span:`` reads the
-    clock twice and feeds four sinks: (1) the always-on cumulative
-    counters of ``name`` (entries and seconds, exact under threads);
-    (2) the sampled span tree — join-only, ``span`` is the child Span
-    or None outside a sampled trace; (3) a
+    wall clock on entry and on exit and feeds four sinks: (1) the
+    always-on cumulative counters of ``name`` (entries and seconds,
+    exact under threads); (2) the sampled span tree — join-only,
+    ``span`` is the child Span or None outside a sampled trace; (3) a
     ``jax.profiler.TraceAnnotation(name, rid=<request id>)`` while a
     device capture runs, which puts the stage on the profiler's own
     clock, on the line of the thread that did the work; (4) the
@@ -649,6 +698,15 @@ def stage(name: str, _root=None, /, **tags) -> _Stage:
     exit). With no sampled trace and no capture it allocates neither a
     Span nor an annotation. ``handle.elapsed`` holds the seconds after
     exit (the cost plane's dispatch timer reads it).
+
+    The second clock: inside a sampled trace or a capture the site also
+    reads the calling thread's CPU clock, within the wall pair, and
+    counts the entry a second time among the measured ones (entries,
+    their wall seconds, their CPU seconds: ``stage_metrics``); the span
+    gets the tag ``cpu_ms`` and ``handle.cpu`` the seconds (None when
+    not read). Everywhere else the CPU clock is left alone, but for one
+    read on exit of the three ROLE_ROOT_STAGES every ROLE_REFRESH_NS a
+    thread.
 
     ``_root`` is a root handle (``Tracer.request_root`` /
     ``remote_root``) for the one stage that is also the trace's root:
@@ -677,13 +735,100 @@ def staged(name: str):
 def stage_metrics() -> dict:
     """``<stage>_total`` and ``<stage>_seconds_total`` (dots to
     underscores) for every stage, zeros included: the ``stage`` block of
-    /metrics and group ``stages`` of /debug/vars."""
+    /metrics and group ``stages`` of /debug/vars. Beside them, of the
+    entries whose CPU was read (inside a sampled trace or a capture):
+    ``<stage>_cpu_entries_total``, ``<stage>_cpu_wall_seconds_total``
+    and ``<stage>_cpu_seconds_total``. Over the same entries, wall less
+    CPU seconds is the time the stage's threads were not running:
+    waiting for the interpreter, the device, a lock, a condition or the
+    disk."""
     out: dict = {}
     for name, c in list(_stage_counters.items()):
         key = name.replace(".", "_")
         with c._lock:
             out[f"{key}_total"] = c.count
             out[f"{key}_seconds_total"] = c.ns * 1e-9
+            out[f"{key}_cpu_entries_total"] = c.cpu_count
+            out[f"{key}_cpu_wall_seconds_total"] = c.cpu_wall_ns * 1e-9
+            out[f"{key}_cpu_seconds_total"] = c.cpu_ns * 1e-9
+    return out
+
+
+# ------------------------------------------------------ CPU by thread role
+#
+# The three kinds of thread the served path runs on. A thread in a role
+# keeps one cell: its cumulative CPU (``time.thread_time_ns``, which only
+# the thread itself can read) less the reading when it entered the role.
+# The cell is refreshed on exit of the thread's root stage
+# (ROLE_ROOT_STAGES): from the reading the site took anyway inside a
+# capture or a sampled trace, else by a read of its own if the last one
+# is ROLE_REFRESH_NS old; and once more when the thread retires. So it
+# holds what the thread did between stages too (header parse, ``send``,
+# the dispatcher's queue handling), a live thread's share is at most
+# that long behind, and a retired thread's CPU is folded into its role's
+# total, so a closed connection takes nothing away.
+
+THREAD_ROLES = ("handler", "dispatcher", "wal_commit")
+# 0.1 s: under 1 % of a scrape interval of 15 s or a benchmark window of
+# 30 s, and ten ticks of the CPU clock of the machines that hold the
+# chips. One read at every root exit (two a request) cost `dashboard`
+# 1-5 % of its rate there (PERF.md 6, PR 36).
+ROLE_REFRESH_NS = 100_000_000
+
+
+class _RoleCell:
+    __slots__ = ("role", "base_ns", "cpu_ns", "read_ns")
+
+    def __init__(self, role: str, base_ns: int):
+        self.role = role
+        self.base_ns = base_ns
+        self.cpu_ns = 0
+        self.read_ns = 0  # wall clock of the last refresh
+
+
+_role_local = threading.local()
+_role_lock = threading.Lock()
+_role_live: set = set()
+_role_retired_ns = dict.fromkeys(THREAD_ROLES, 0)
+
+
+def enter_thread_role(role: str) -> None:
+    """The calling thread serves as ``role`` from here on (a connection's
+    handler, the wave dispatcher, a WAL's commit thread)."""
+    cell = _RoleCell(role, _cpu_clock_ns())
+    _role_local.cell = cell
+    with _role_lock:
+        _role_live.add(cell)
+
+
+def retire_thread_role() -> None:
+    """The calling thread leaves its role: one last reading of its own
+    clock, folded into the role's retired total."""
+    cell = getattr(_role_local, "cell", None)
+    if cell is None:
+        return
+    _role_local.cell = None
+    cell.cpu_ns = _cpu_clock_ns() - cell.base_ns
+    with _role_lock:
+        _role_live.discard(cell)
+        _role_retired_ns[cell.role] += cell.cpu_ns
+
+
+def thread_metrics() -> dict:
+    """``thread_<role>_cpu_seconds_total`` for the three roles (zero
+    where a role has had no thread yet) and ``process_cpu_seconds_total``
+    (``time.process_time()``: every thread of the process, JAX's and
+    XLA's too): a block of its own on /metrics and group ``threads`` of
+    /debug/vars. A role's total is as of each live thread's last
+    refresh (at most ROLE_REFRESH_NS before its last root stage); it
+    never falls."""
+    with _role_lock:
+        ns = dict(_role_retired_ns)
+        for cell in _role_live:
+            ns[cell.role] += cell.cpu_ns
+    out = {f"thread_{role}_cpu_seconds_total": ns[role] * 1e-9
+           for role in THREAD_ROLES}
+    out["process_cpu_seconds_total"] = time.process_time()
     return out
 
 
@@ -838,12 +983,43 @@ def start_jax_trace(log_dir: str):
     _annotation = jax.profiler.TraceAnnotation
     try:
         jax.profiler.start_trace(log_dir, profiler_options=options)
+        before = _counter_snapshot()
         try:
             yield
         finally:
+            after = _counter_snapshot()
             jax.profiler.stop_trace()
+            _write_capture_counters(log_dir, before, after)
     finally:
         _annotation = None
+
+
+CAPTURE_COUNTERS_FILE = "stages.json"
+
+
+def _counter_snapshot() -> tuple[int, dict, dict]:
+    return _clock_ns(), stage_metrics(), thread_metrics()
+
+
+def _write_capture_counters(log_dir: str, before, after) -> None:
+    """The stage and thread counters' deltas over a capture, beside its
+    ``.xplane.pb``: the one place where the device's busy seconds and the
+    interpreter's are read over one span (``trace_report`` prints both)."""
+    path = _newest_xplane(log_dir)
+    (t0, stages0, threads0), (t1, stages1, threads1) = before, after
+    body = {"span_s": (t1 - t0) * 1e-9,
+            "stages": {k: v - stages0.get(k, 0)
+                       for k, v in stages1.items()},
+            "threads": {k: v - threads0[k] for k, v in threads1.items()}}
+    target = os.path.dirname(path) if path else log_dir
+    with open(os.path.join(target, CAPTURE_COUNTERS_FILE), "w") as f:
+        json.dump(body, f, indent=1)
+
+
+def _newest_xplane(log_dir: str) -> str | None:
+    paths = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    return max(paths, key=os.path.getmtime) if paths else None
 
 
 # ------------------------------------------------------------- trace report
@@ -945,18 +1121,13 @@ def trace_report(log_dir: str, gaps_n: int = 5, top_n: int = 10) -> dict:
     XLA module and per operation, and its ``gaps_n`` longest idle gaps,
     each labelled by what the host was doing (``label_gap``); and the
     host threads' seconds by innermost stage, summed over threads."""
-    import glob
-    import os
-
     from jax.profiler import ProfileData
 
     path = log_dir
     if os.path.isdir(log_dir):
-        paths = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
-                          recursive=True)
-        if not paths:
+        path = _newest_xplane(log_dir)
+        if path is None:
             raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
-        path = max(paths, key=os.path.getmtime)
     try:
         data = ProfileData.from_file(path)
     except RuntimeError as e:  # a capture cut short, or not a capture
@@ -1042,7 +1213,34 @@ def trace_report(log_dir: str, gaps_n: int = 5, top_n: int = 10) -> dict:
             "python_tracer_events": python_events,
             "host_stage_thread_s": sorted(host.items(),
                                           key=lambda kv: -kv[1]),
+            "counters": _capture_counters(os.path.dirname(path)),
             "devices": out_devices}
+
+
+def _capture_counters(capture_dir: str) -> dict | None:
+    """What ``start_jax_trace`` wrote beside the capture, reduced to
+    per-stage [name, CPU seconds, wall seconds, entries] of the entries
+    whose CPU was read (largest CPU first, stages with none left out)
+    and CPU seconds by thread role;
+    None for a capture that has no such file (an older one, a written
+    one)."""
+    try:
+        with open(os.path.join(capture_dir, CAPTURE_COUNTERS_FILE)) as f:
+            body = json.load(f)
+    except (OSError, ValueError):
+        return None
+    stages = body["stages"]
+    rows = []
+    for name in _stage_counters:
+        key = name.replace(".", "_")
+        if stages.get(f"{key}_cpu_entries_total"):
+            rows.append([name, stages[f"{key}_cpu_seconds_total"],
+                         stages[f"{key}_cpu_wall_seconds_total"],
+                         stages[f"{key}_cpu_entries_total"]])
+    return {"span_s": body["span_s"],
+            "stage_cpu_s": sorted(rows, key=lambda r: -r[1]),
+            "thread_cpu_s": {k.removesuffix("_cpu_seconds_total"): v
+                             for k, v in body["threads"].items()}}
 
 
 def format_trace_report(report: dict) -> str:
@@ -1053,6 +1251,17 @@ def format_trace_report(report: dict) -> str:
     if report["host_stage_thread_s"]:
         lines.append("host thread-seconds by innermost stage: " + ", ".join(
             f"{n} {s:.3f}" for n, s in report["host_stage_thread_s"]))
+    counters = report.get("counters")
+    if counters:
+        span = counters["span_s"]
+        lines.append(
+            f"stage CPU seconds over {span:.3f} s (wall seconds, entries): "
+            + ", ".join(f"{n} {cpu:.3f} ({wall:.3f}, {count})"
+                        for n, cpu, wall, count in counters["stage_cpu_s"]))
+        lines.append(
+            f"CPU seconds by thread role over {span:.3f} s: " + ", ".join(
+                f"{n} {cpu:.3f} ({100 * cpu / span:.1f} % of a core)"
+                for n, cpu in counters["thread_cpu_s"].items()))
     if not report["devices"]:
         lines.append("no operation ran on any device in this capture")
     for d in report["devices"]:

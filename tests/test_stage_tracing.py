@@ -68,6 +68,14 @@ def server(tmp_path):
     s.close()
 
 
+SUFFIXES = ("_total", "_seconds_total", "_cpu_entries_total",
+            "_cpu_wall_seconds_total", "_cpu_seconds_total")
+THREAD_SERIES = ("thread_handler_cpu_seconds_total",
+                 "thread_dispatcher_cpu_seconds_total",
+                 "thread_wal_commit_cpu_seconds_total",
+                 "process_cpu_seconds_total")
+
+
 def _key(name: str) -> str:
     return name.replace(".", "_")
 
@@ -141,11 +149,23 @@ def test_every_stage_series_is_present_and_zero_on_the_first_scrape(tmp_path):
         samples = dict(line.split(" ", 1) for line in text.splitlines()
                        if line and not line.startswith("#")
                        and "{" not in line)
+        assert len(STAGES) == 25  # wal.commit joined in PR 36
         for name in STAGES:
-            for suffix in ("_total", "_seconds_total"):
+            for suffix in SUFFIXES:
                 series = f"pilosa_tpu_stage_{_key(name)}{suffix}"
                 assert samples.get(series) == "0", series
                 assert f"# TYPE {series} counter" in text
+                assert f"# HELP {series} " in text
+        # the CPU of the three thread roles and of the process: unlabelled
+        # counters in a block of their own, there before any query ran
+        for series in THREAD_SERIES:
+            assert float(samples[f"pilosa_tpu_{series}"]) >= 0, series
+            assert f"# TYPE pilosa_tpu_{series} counter" in text
+            assert f"# HELP pilosa_tpu_{series} " in text
+        for role in ("dispatcher", "wal_commit"):  # no such thread yet
+            assert samples[
+                f"pilosa_tpu_thread_{role}_cpu_seconds_total"] == "0"
+        assert float(samples["pilosa_tpu_process_cpu_seconds_total"]) > 0
         for series in ("pilosa_tpu_device_compiles_total",
                        "pilosa_tpu_device_compile_seconds_total",
                        "pilosa_tpu_device_compile_cache_loads_total",
@@ -154,10 +174,12 @@ def test_every_stage_series_is_present_and_zero_on_the_first_scrape(tmp_path):
             assert series in samples, series
         # the per-device gauges beside the unlabelled sums
         assert 'pilosa_tpu_device_memory_bytes_in_use{device="0"}' in text
-        stages = req("GET", f"{base}/debug/vars")["stages"]
+        debug_vars = req("GET", f"{base}/debug/vars")
+        stages = debug_vars["stages"]
         assert set(stages) == {f"{_key(n)}{s}" for n in STAGES
-                               for s in ("_total", "_seconds_total")}
+                               for s in SUFFIXES}
         assert all(v == 0 for v in stages.values())
+        assert set(debug_vars["threads"]) == set(THREAD_SERIES)
     finally:
         proc.terminate()
         proc.wait(60)
@@ -299,6 +321,10 @@ def test_a_sampled_tree_holds_the_old_names_and_the_new_children(server):
     assert tree["tags"]["tenant"] == "default"
     # one clock: a child never outlasts its parent
     assert top["pipeline.wave"]["durationMs"] <= tree["durationMs"]
+    # the second clock reaches the sampled span: a stage's CPU inside its
+    # wall time (both rounded to a microsecond)
+    for child in [tree, *top.values()]:
+        assert 0 <= child["tags"]["cpu_ms"] <= child["durationMs"] + 0.001
 
 
 # ------------------------------------------------------------ the inspector
@@ -415,6 +441,28 @@ def test_a_capture_holds_the_stages_with_a_request_id_and_no_python_frames(
     report = tracing.trace_report(out["logDir"])
     assert report["python_tracer_events"] == 0
     assert report["host_threads_with_stages"] >= 2
+    # the capture carries the counters: inside it every site read both
+    # clocks, and stages.json beside the .xplane.pb holds the deltas
+    import json
+
+    with open(os.path.join(os.path.dirname(path), "stages.json")) as f:
+        counters = json.load(f)
+    assert 0 < counters["span_s"] <= out["seconds"]
+    assert set(counters["threads"]) == set(THREAD_SERIES)
+    stages = counters["stages"]
+    for name in ("http.query", "pipeline.submit", "wal.commit",
+                 "device.dispatch"):
+        key = _key(name)
+        assert 1 <= stages[f"{key}_cpu_entries_total"] <= stages[
+            f"{key}_total"], name
+        assert 0 <= stages[f"{key}_cpu_seconds_total"] <= stages[
+            f"{key}_cpu_wall_seconds_total"], name
+    by_stage = {row[0]: row for row in report["counters"]["stage_cpu_s"]}
+    assert by_stage["http.query"][3] == stages["http_query_cpu_entries_total"]
+    assert report["counters"]["thread_cpu_s"]["thread_handler"] > 0
+    text = tracing.format_trace_report(report)
+    assert "stage CPU seconds over " in text
+    assert "CPU seconds by thread role over " in text
 
 
 def test_trace_report_labels_gaps_by_the_rule():
