@@ -51,7 +51,7 @@ from pilosa_tpu.executor import expr
 from pilosa_tpu.roaring import kernels
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD, next_pow2
 from pilosa_tpu.storage import residency
-from pilosa_tpu.utils.compile_cache import named_jit
+from pilosa_tpu.utils.compile_cache import named_jit, pallas_interpret
 from pilosa_tpu.utils.cost import current_cost
 
 INT32_MIN = -(1 << 31)
@@ -211,13 +211,17 @@ def host_row(idx, spec, shard: int) -> np.ndarray:
     return acc if acc is not None else np.zeros(WORDS_PER_SHARD, np.uint32)
 
 
-def host_leaf(idx, spec, block: ShardBlock) -> np.ndarray:
+def host_leaf(idx, spec, block: ShardBlock, sparse: bool = False):
     """Dense uint32[host_rows, words] for a _RowSpec leaf (host side):
     ``block.stack`` of ``host_row`` byte for byte, decoded in one pass
     over the row's containers of every local shard and view
     (kernels.flatten_rows, kernels.dense_rows32) straight into a
     recycled staging array. A missing field, view or fragment, and a
-    slot past the shards, read zeros."""
+    slot past the shards, read zeros. With ``sparse`` (the row cache
+    places the leaf itself) a leaf whose containers are all sparse
+    arrays comes back as its set bits instead, a kernels.SparseRows
+    in a staging array of its bucket's shape, for the chip to expand:
+    the same leaf, never laid out on the host."""
     lo, hi = block.local_slots
     local = block.shards[lo:min(hi, len(block.shards))]
     field = idx.field(spec.field)
@@ -235,6 +239,10 @@ def host_leaf(idx, spec, block: ShardBlock) -> np.ndarray:
     if cost is not None:
         # one tally a leaf, the totals Fragment.row_words notes a shard
         cost.note_containers(*flat.kind_counts())
+    if sparse:
+        rows = kernels.sparse_rows32(flat, hi - lo, _staging_array)
+        if rows is not None:
+            return rows
     out = _staging_array((hi - lo, WORDS_PER_SHARD))
     kernels.dense_rows32(flat, out)
     return out
@@ -453,7 +461,7 @@ def stacked_leaf(idx, spec, block: ShardBlock, device_put=None):
         key = leaf_key(idx, spec, block)
 
         def decode():
-            return host_leaf(idx, spec, block)
+            return host_leaf(idx, spec, block, sparse=device_put is None)
 
         def probe():  # factory: only built when the key isn't registered
             views = frozenset(spec.views)
@@ -834,9 +842,8 @@ def groupby_tile_plan(dim_rows: tuple, other_rows: int, slots: int,
         paged[resident.index(max(resident))] = True
 
 
-def _pallas_interpret() -> bool:
-    # off the TPU the same kernel body runs through Pallas' interpreter
-    return jax.default_backend() != "tpu"
+# off the TPU the same kernel body runs through Pallas' interpreter
+_pallas_interpret = pallas_interpret
 
 
 def groupby_level_body(leaves, idxs, scalars, filt_structure, n_filt: int,

@@ -572,6 +572,98 @@ def dense_rows32(f: FlatFragment, out: np.ndarray) -> None:
                           base + runs[:, 0], base + runs[:, 1])
 
 
+# A tile of the sparse form: 1,024 words, which the expansion holds in one
+# (8, 128) vreg and the residency's compressed tier calls a 4 KiB block.
+SPARSE_TILE_WORDS = 1024
+# The list of set bits is padded to a power of two from SPARSE_MIN_BITS up
+# to one listed bit (4 bytes) for every eight words of the dense leaf, an
+# eighth of its bytes: a closed list of shapes for the expansion to be
+# compiled for.
+SPARSE_MIN_BITS = 8192
+_TILE_BIT_SHIFT = (SPARSE_TILE_WORDS * 32).bit_length() - 1
+_LANES = 128
+# padding of the list: a bit no leaf has, and an int32 on the chip
+_NO_BIT = np.uint32(0x7FFFFFFF)
+
+
+def sparse_buckets(n_rows: int) -> tuple[int, ...]:
+    """The padded bit counts a sparse leaf of ``n_rows`` may have (none
+    where a bit's number within the leaf would not fit beside _NO_BIT)."""
+    if n_rows * SHARD_WIDTH >= _NO_BIT:
+        return ()
+    out, n = [], SPARSE_MIN_BITS
+    while n * 8 <= n_rows * (SHARD_WIDTH >> 5):
+        out.append(n)
+        n *= 2
+    return tuple(out)
+
+
+def sparse_starts_len(n_rows: int) -> int:
+    """Length of a sparse leaf's tile table: one start a tile and the
+    end, rounded up to whole lanes."""
+    n = n_rows * (SHARD_WIDTH >> 5) // SPARSE_TILE_WORDS + 1
+    return -(-n // _LANES) * _LANES
+
+
+def sparse_packed_len(n_rows: int, n_pad: int) -> int:
+    """Length of ``SparseRows.packed`` for a list padded to ``n_pad``."""
+    return sparse_starts_len(n_rows) + n_pad
+
+
+class SparseRows:
+    """A row leaf ``uint32[n_rows, 32768]`` as its set bits, for the
+    device to expand (residency.expand_rows). ``packed`` is ONE host
+    array, uint32[sparse_packed_len(n_rows, n_pad)]: the tile table
+    (``starts[t]`` .. ``starts[t + 1]`` are the listed bits of tile
+    ``t``), then ``n_pad`` bits in tile order, each as its number within
+    the leaf (``word * 32 + bit``: the flat word index and the mask in
+    one integer); a padding entry is a bit no leaf has. ``tiles`` are
+    the tiles that hold a set bit, ascending."""
+
+    __slots__ = ("packed", "n_rows", "n_pad", "tiles")
+
+    def __init__(self, packed, n_rows: int, n_pad: int, tiles):
+        self.packed = packed
+        self.n_rows = n_rows
+        self.n_pad = n_pad
+        self.tiles = tiles
+
+
+def sparse_rows32(f: FlatFragment, n_rows: int, staging) -> SparseRows | None:
+    """The rows of a :func:`flatten_rows` view as a :class:`SparseRows`,
+    or None where :func:`dense_rows32` has to write them: a bitmap or a
+    run container in the view, a key named twice (several views of a
+    slot, whose bits may repeat), array containers whose values leave
+    tile order, or more set bits than the largest bucket holds.
+    ``staging(shape)`` gives the uint32 array written; nothing of the
+    dense leaf's size is made or read."""
+    n = int(f.arr_data.size)
+    buckets = sparse_buckets(n_rows)
+    keys = f.keys
+    if (f.bmp_sel.size or f.run_sel.size or not buckets or n > buckets[-1]
+            or not bool((keys[1:] > keys[:-1]).all())):
+        return None
+    _STATS.kernel_calls += 1
+    n_pad = next(b for b in buckets if n <= b)
+    n_tiles = n_rows * (SHARD_WIDTH >> 5) // SPARSE_TILE_WORDS
+    t1 = sparse_starts_len(n_rows)
+    packed = staging((t1 + n_pad,))
+    bits = packed[t1:t1 + n]
+    # key = slot * 16 + k is the bit's number within the leaf >> 16
+    np.add(np.repeat((keys << 16).astype(np.uint32), np.diff(f.arr_off)),
+           f.arr_data, out=bits)
+    tile_of = bits >> np.uint32(_TILE_BIT_SHIFT)
+    if not bool((tile_of[1:] >= tile_of[:-1]).all()):
+        return None  # a container out of order: dense_rows32 ORs any order
+    packed[t1 + n:] = _NO_BIT
+    counts = np.bincount(tile_of, minlength=n_tiles)
+    packed[0] = 0
+    packed[1:n_tiles + 1] = np.cumsum(counts)
+    packed[n_tiles + 1:t1] = n
+    return SparseRows(packed, n_rows, n_pad,
+                      np.flatnonzero(counts).astype(np.int32))
+
+
 # ---------------------------------------------------------------- popcount
 
 

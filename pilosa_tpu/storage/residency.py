@@ -49,9 +49,11 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
+from pilosa_tpu.roaring import kernels
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD, next_pow2
-from pilosa_tpu.utils.compile_cache import named_jit
+from pilosa_tpu.utils.compile_cache import named_jit, pallas_interpret
 from pilosa_tpu.utils.cost import current_cost
 from pilosa_tpu.utils.tracing import stage, staged
 
@@ -70,8 +72,10 @@ DEFAULT_BUDGET_BYTES = 4 << 30
 # host RAM parking for cold demoted entries.
 DEFAULT_HOST_BUDGET_BYTES = 1 << 30
 
-# Compression granularity: 4 KiB device blocks. Row = 32 blocks.
-COMPRESS_BLOCK_WORDS = 1024
+# Compression granularity: 4 KiB device blocks. Row = 32 blocks. A tile
+# of a sparse miss (kernels.SparseRows) is the same 1,024 words, so the
+# tiles it lists are the leaf's block index.
+COMPRESS_BLOCK_WORDS = kernels.SPARSE_TILE_WORDS
 
 # Probe return sentinel: "this write affects the entry but it cannot be
 # patched in place — drop it" (multi-host sharded leaves, where a device
@@ -130,6 +134,169 @@ _gather_blocks = named_jit("gather_blocks", _gather_blocks,
                            static_argnames=("block_words",))
 _scatter_blocks = named_jit("scatter_blocks", _scatter_blocks,
                             static_argnames=("n_blocks", "block_words"))
+
+
+# ------------------------------------------------------------ sparse misses
+#
+# A row leaf whose containers are all sparse arrays reaches the cache as
+# its set bits (kernels.sparse_rows32: a tile table and the bits' numbers
+# within the leaf, in tile order) and is made dense here, on the chip: the
+# host neither fills nor scans nor ships the 16 MiB of a row that is
+# 0.05 % set. What becomes resident is the dense leaf every program takes.
+
+_LANES = 128
+_SUBLANES = 8
+_TILE_SUBLANES = COMPRESS_BLOCK_WORDS // _LANES  # a tile is one vreg
+_TILE_BITS = COMPRESS_BLOCK_WORDS * 32
+# Listed bits a copy into scalar memory (there are two buffers), and how
+# many of them one step of the walk ORs into its tile: a power of two,
+# because the step ORs its hits pairwise (on the chip 8 read 0.36 ms a
+# 64 k-bit row, 16 0.29, 32 0.29 and 0.29 against 0.23 a 4.5 k-bit one).
+EXPAND_CHUNK = 4096
+EXPAND_UNROLL = 16
+assert EXPAND_UNROLL & (EXPAND_UNROLL - 1) == 0
+
+
+def _expand_rows(packed, n_rows: int, n_pad: int):
+    """``kernels.SparseRows.packed`` to the leaf ``uint32[n_rows, 32768]``.
+
+    One Pallas kernel, a grid step for eight slot rows. A step reads the
+    bounds of its tiles from the tile table (scalar prefetch) and copies
+    the listed bits between them from HBM into scalar memory a chunk at
+    a time (the next chunk travels while this one is walked). The walk is
+    ONE loop over the chunk, EXPAND_UNROLL listed bits a pass: a listed
+    bit XOR the number of a lane's first bit is under 32, and is then the
+    bit's place in the word, in the one lane whose word holds it; the
+    pass ORs ``1 << place`` there into the vreg of the tile it stands in,
+    stores that vreg, and moves to the next tile when it reached the
+    tile's end, by selects and not by branches (a loop a tile inside a
+    loop over tiles cost ~70 cycles a tile of loop overhead). A pass may
+    read past its tile's end or, moved back from the chunk's end, before
+    its start: entries read again change nothing under an OR, and entries
+    of other tiles and padding match no lane of this one. The tiles of a
+    row are 1,024 consecutive words each, the leaf wants its rows on the
+    sublanes: strided reads of the scratch turn one into the other when
+    the step ends."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    words = WORDS_PER_SHARD
+    row_tiles = words // COMPRESS_BLOCK_WORDS
+    sb = min(_SUBLANES, n_rows)
+    step_tiles = sb * row_tiles
+    t1 = kernels.sparse_starts_len(n_rows)
+    ch, unroll = EXPAND_CHUNK, EXPAND_UNROLL
+    packed = lax.bitcast_convert_type(packed, jnp.int32)
+
+    def kernel(starts_ref, listed_ref, out_ref, acc_ref, buf, sem):
+        t0 = pl.program_id(0) * step_tiles
+        lo, hi = starts_ref[t0], starts_ref[t0 + step_tiles]
+        c0 = lo // ch
+        c1 = jnp.where(hi > lo, (hi + ch - 1) // ch, c0)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # the first bit of each lane's word, within a tile
+        lane_bit = 32 * (
+            lax.broadcasted_iota(jnp.int32, (_TILE_SUBLANES, _LANES), 0)
+            * _LANES
+            + lax.broadcasted_iota(jnp.int32, (_TILE_SUBLANES, _LANES), 1))
+
+        def copy(c, half):
+            return pltpu.make_async_copy(
+                listed_ref.at[pl.ds(pl.multiple_of(c * ch, ch), ch)],
+                buf.at[pl.ds(pl.multiple_of(half * ch, ch), ch)],
+                sem.at[half])
+
+        def vreg_of(t):
+            return pl.ds(pl.multiple_of(t * _TILE_SUBLANES, _TILE_SUBLANES),
+                         _TILE_SUBLANES)
+
+        @pl.when(c1 > c0)
+        def _():
+            copy(c0, lax.rem(c0, 2)).start()
+
+        def chunk(c, t_first):
+            half = lax.rem(c, 2)
+            copy(c, half).wait()
+
+            @pl.when(c + 1 < c1)
+            def _():
+                copy(c + 1, 1 - half).start()
+
+            base = c * ch
+            stop = jnp.minimum(hi, base + ch)
+
+            def more(at):
+                t, j, _ = at
+                return (t < step_tiles) & (j < stop)
+
+            def walk(at):
+                t, j, acc = at
+                tile_end = starts_ref[t0 + t + 1]
+                k = half * ch + jnp.minimum(j - base, ch - unroll)
+                first_bit = lane_bit + (t0 + t) * _TILE_BITS
+                hits = []
+                for u in range(unroll):
+                    place = first_bit ^ buf[k + u]
+                    hits.append(jnp.where(
+                        place < 32, jnp.left_shift(1, place & 31), 0))
+                while len(hits) > 1:  # pairwise, not one chain of ORs
+                    hits = [a | b for a, b in zip(hits[::2], hits[1::2])]
+                acc = acc | hits[0]
+                acc_ref[vreg_of(t), :] = acc
+                done = j + unroll >= jnp.minimum(tile_end, base + ch)
+                t_next = jnp.where(done, t + 1, t)
+                return (t_next, jnp.where(done, tile_end, j + unroll),
+                        jnp.where(done, acc_ref[vreg_of(jnp.minimum(
+                            t_next, step_tiles - 1)), :], acc))
+
+            t_last, _, _ = lax.while_loop(more, walk, (
+                t_first, jnp.maximum(starts_ref[t0 + t_first], base),
+                acc_ref[vreg_of(t_first), :]))
+            # the tile the chunk ended in may go on in the next one
+            return jnp.maximum(t_last - 1, 0)
+
+        lax.fori_loop(c0, c1, chunk, 0)
+
+        # scratch row (r * row_tiles + k) * 8 + s holds the words from
+        # k * 1024 + s * 128 of slot row r
+        def relay(k, _):
+            for s in range(_TILE_SUBLANES):
+                ks = k * _TILE_SUBLANES + s
+                rows = acc_ref[pl.ds(ks, sb,
+                                     stride=row_tiles * _TILE_SUBLANES), :]
+                out_ref[:, pl.ds(pl.multiple_of(ks * _LANES, _LANES),
+                                 _LANES)] = pltpu.bitcast(rows, jnp.uint32)
+            return 0
+
+        lax.fori_loop(0, row_tiles, relay, 0)
+
+    with jax.named_scope("expand_rows"):
+        return pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct((n_rows, words), jnp.uint32),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(n_rows // sb,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((sb, words), lambda g, *_: (g, 0)),
+                scratch_shapes=[
+                    pltpu.VMEM((step_tiles * _TILE_SUBLANES, _LANES),
+                               jnp.int32),
+                    pltpu.SMEM((2 * ch,), jnp.int32),
+                    pltpu.SemaphoreType.DMA((2,)),
+                ],
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=pallas_interpret(),
+            name="expand_rows",
+        )(packed[:t1], packed[t1:])
+
+
+_expand_rows = named_jit("expand_rows", _expand_rows,
+                         static_argnames=("n_rows", "n_pad"))
+# (row count, device) whose every bucket's expansion has been compiled
+_expansions_ready: set = set()
 
 
 class WriteEvent:
@@ -260,6 +427,8 @@ class DeviceRowCache:
         self.compressions = 0
         self.decompressions = 0
         self.miss_bytes = 0  # bytes of the dense arrays misses placed
+        self.miss_transfer_bytes = 0  # bytes misses handed to device_put
+        self.sparse_misses = 0  # misses placed from their set bits
         self.host_hits = 0  # host-tier lookups served (inline promotes)
         self.tier_promotions = 0  # host -> dense (lookup or pass)
         self.tier_demotions = 0  # dense/compressed -> host
@@ -394,20 +563,60 @@ class DeviceRowCache:
             return arr
         return None
 
+    def _place(self, host, device_put):
+        """A miss's decode to ``(device array, block index)``: a dense
+        host array is transferred (by ``device_put``, a custom placement
+        that is never compressed, or to this cache's device), a
+        kernels.SparseRows is transferred as it is and expanded there."""
+        block_idx = None
+        if device_put is not None:
+            arr = device_put(host)
+            sent = int(arr.nbytes)
+        elif isinstance(host, kernels.SparseRows):
+            self._compile_expansions(host.n_rows)
+            arr = self._expand(host.packed, host.n_rows, host.n_pad)
+            if (host.tiles.size * COMPRESS_BLOCK_WORDS
+                    <= COMPRESS_MAX_OCCUPANCY * arr.size):
+                block_idx = host.tiles
+            self.sparse_misses += 1
+            sent = int(host.packed.nbytes)
+        else:
+            arr = jax.device_put(host, self.device)
+            block_idx = self._host_block_index(host)
+            sent = int(arr.nbytes)
+        self.miss_transfer_bytes += sent
+        cost = current_cost()
+        if cost is not None:  # host→device bytes for the active request
+            cost.note_upload(sent)
+        return arr, block_idx
+
+    def _expand(self, packed: np.ndarray, n_rows: int, n_pad: int):
+        # the program call transfers its one host argument itself: a
+        # device_put of its own first costs the thread 0.16 ms more
+        # (PERF.md, PR 38); only a cache bound to a device needs one
+        if self.device is not None:
+            packed = jax.device_put(packed, self.device)
+        return _expand_rows(packed, n_rows=n_rows, n_pad=n_pad)
+
+    def _compile_expansions(self, n_rows: int) -> None:
+        """Before the first sparse leaf of ``n_rows`` is expanded, run
+        the expansion of every bucket such a leaf may come in once, on
+        an empty list: the closed list of programs is compiled (or read
+        from the persistent cache) at one known moment, and no later
+        miss, however rare its bucket, compiles."""
+        if (n_rows, self.device) in _expansions_ready:
+            return
+        for n_pad in kernels.sparse_buckets(n_rows):
+            self._expand(np.zeros(kernels.sparse_packed_len(n_rows, n_pad),
+                                  np.uint32), n_rows, n_pad)
+        _expansions_ready.add((n_rows, self.device))
+
     def _put_locked(self, key, host, device_put):
         with stage("residency.upload"):
-            if device_put is not None:
-                arr = device_put(host)
-                block_idx = None  # custom placement (mesh sharding): dense
-            else:
-                arr = jax.device_put(host, self.device)
-                block_idx = self._host_block_index(host)
+            arr, block_idx = self._place(host, device_put)
         self.miss_bytes += int(arr.nbytes)
         self._insert_dense(key, arr, block_idx,
                            custom=device_put is not None)
-        cost = current_cost()
-        if cost is not None:  # host→device bytes for the active request
-            cost.note_upload(int(arr.nbytes))
         return arr
 
     def get_row(self, key: tuple, decode: Callable[[], np.ndarray],
@@ -495,8 +704,7 @@ class DeviceRowCache:
                     # invalidate_tag raced the build (field delete): the
                     # decode belongs to a dead field — serve it to this
                     # query but don't cache it
-                    return (jax.device_put(host, self.device)
-                            if device_put is None else device_put(host))
+                    return self._place(host, device_put)[0]
                 arr = self._put_locked(key, host, device_put)
                 for ev in buf:  # replay writes that landed mid-decode
                     apply = reg[1](ev) if reg is not None else None
@@ -963,7 +1171,8 @@ class DeviceRowCache:
     _MONOTONIC_METRICS = frozenset({
         "residency_hits", "residency_misses", "residency_evictions",
         "residency_compressions", "residency_decompressions",
-        "residency_miss_bytes",
+        "residency_miss_bytes", "residency_miss_transfer_bytes",
+        "residency_sparse_misses",
         "residency_updates", "residency_patch_retries",
         "residency_write_events",
         "residency_host_hits", "residency_tier_promotions",
@@ -1007,6 +1216,8 @@ class DeviceRowCache:
                 "residency_compressions": self.compressions,
                 "residency_decompressions": self.decompressions,
                 "residency_miss_bytes": self.miss_bytes,
+                "residency_miss_transfer_bytes": self.miss_transfer_bytes,
+                "residency_sparse_misses": self.sparse_misses,
                 "residency_updates": self.updates,
                 "residency_patch_retries": self.patch_retries,
                 "residency_write_events": self.write_events,

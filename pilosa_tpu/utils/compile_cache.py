@@ -38,6 +38,14 @@ def named_jit(name: str, fn, **jit_kwargs):
     return jax.jit(fn, **jit_kwargs)
 
 
+def pallas_interpret() -> bool:
+    """Off the TPU a Pallas kernel's body runs through Pallas'
+    interpreter (the ``interpret`` argument of its ``pallas_call``)."""
+    import jax
+
+    return jax.default_backend() != "tpu"
+
+
 def configure() -> str:
     """Point JAX's persistent compile cache at ``cache_dir()`` and cache
     every program, however quickly it compiled: the default 1 s

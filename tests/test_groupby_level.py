@@ -522,3 +522,42 @@ def test_cell_level_compiles_for_the_four_chip_mesh(topo, level,
         *_level_args(512, CELL_LEVELS[level], sharding_of)).compile()
     _assert_reads_rows_in_place(compiled)
     assert "all-reduce" in compiled.as_text()
+
+
+# The expansion of a sparse residency miss (ISSUE 38) is the tree's other
+# Pallas kernel; it is compiled here because one file describes the chip
+# (a second file could go to another worker, whose fixture would skip).
+
+@pytest.mark.parametrize("n_rows", [128, 8, 2])
+def test_sparse_miss_expansion_compiles_for_one_v5e_chip(topo, n_rows,
+                                                         monkeypatch, request):
+    """Every bucket of the cells' 128-slot leaves, and of two leaves
+    smaller than a block of eight slot rows: copies from HBM into scalar
+    memory, strided reads of the scratch, no temporary beside the leaf
+    (what Pallas' interpreter cannot show)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from pilosa_tpu.roaring import kernels
+    from pilosa_tpu.storage import residency
+
+    monkeypatch.setattr(residency, "pallas_interpret", lambda: False)
+    # a trace of these shapes made for the CPU by an earlier test of this
+    # process holds the interpreter's call, and a later test must not
+    # find the chip's: both ways the jit's cache is emptied
+    residency._expand_rows.clear_cache()
+    request.addfinalizer(residency._expand_rows.clear_cache)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    buckets = kernels.sparse_buckets(n_rows)
+    assert buckets[0] == 8192 and buckets[-1] == n_rows * 4096
+    for n_pad in buckets:
+        packed = jax.ShapeDtypeStruct(
+            (kernels.sparse_packed_len(n_rows, n_pad),), jnp.uint32,
+            sharding=one_chip)
+        compiled = residency._expand_rows.lower(
+            packed, n_rows=n_rows, n_pad=n_pad).compile()
+        assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes == 0
+        assert compiled.memory_analysis().output_size_in_bytes == (
+            n_rows * WORDS_PER_SHARD * 4)

@@ -784,3 +784,242 @@ def test_metrics_report_the_per_chip_figure_and_each_chips_own():
     per_frag, per_field = cache.residency_overlay()
     assert per_field == {("", "i", "f"): shard}
     assert per_frag == {("", "i", "f", 0): single.nbytes}
+
+
+# ----------------------------------------------------------- sparse misses
+#
+# A miss whose decode answers a kernels.SparseRows (ISSUE 38): the set
+# bits are transferred and the chip expands them; what becomes resident,
+# is charged, patched and evicted is the dense leaf, as for any miss.
+
+SPARSE_ROWS = 4  # buckets of 8,192 and 16,384 listed bits
+
+
+def sparse_leaf(seed, n_bits, n_rows=SPARSE_ROWS):
+    """(decode answering a fresh SparseRows, the dense leaf it stands
+    for) of ``n_bits`` random bits."""
+    from pilosa_tpu.roaring import kernels
+    from pilosa_tpu.roaring.bitmap import RoaringBitmap
+
+    rng = np.random.default_rng(seed)
+    pos = np.sort(rng.choice(n_rows << 20, n_bits, replace=False))
+    bitmaps = [(slot, RoaringBitmap.from_ids(
+        (pos[pos >> 20 == slot] & ((1 << 20) - 1)).astype(np.uint64)))
+        for slot in range(n_rows)]
+    want = np.empty((n_rows, WORDS_PER_SHARD), np.uint32)
+    kernels.dense_rows32(kernels.flatten_rows(bitmaps, 0), want)
+
+    def decode():
+        rows = kernels.sparse_rows32(
+            kernels.flatten_rows(bitmaps, 0), n_rows,
+            lambda shape: np.empty(shape, np.uint32))
+        assert rows is not None
+        return rows
+
+    return decode, want
+
+
+def counting(decode):
+    calls = []
+
+    def counted():
+        calls.append(1)
+        return decode()
+
+    return counted, calls
+
+
+def test_sparse_miss_counters_say_what_was_sent_and_what_was_placed():
+    from pilosa_tpu.roaring import kernels
+
+    cache = DeviceRowCache(budget_bytes=8 << 20)
+    decode, want = sparse_leaf(40, 3000)
+    got = np.asarray(cache.get_or_build(LEAF, TAG, lambda: lambda ev: None,
+                                        decode))
+    np.testing.assert_array_equal(got, want)
+    m = cache.metrics()
+    packed_bytes = kernels.sparse_packed_len(SPARSE_ROWS, 8192) * 4
+    assert m["residency_misses"] == m["residency_sparse_misses"] == 1
+    assert m["residency_miss_transfer_bytes"] == packed_bytes
+    assert m["residency_miss_bytes"] == want.nbytes == m["residency_bytes_used"]
+    # a dense miss beside it: transfer and placed bytes are the same
+    dense = sparse_row(np.random.default_rng(41), 2)
+    cache.get_row(("r", 0), lambda: dense)
+    m = cache.metrics()
+    assert m["residency_misses"] == 2 and m["residency_sparse_misses"] == 1
+    assert m["residency_miss_transfer_bytes"] == packed_bytes + dense.nbytes
+    assert m["residency_miss_bytes"] == want.nbytes + dense.nbytes
+    text = cache.prometheus_lines()
+    assert "pilosa_tpu_residency_sparse_misses_total 1\n" in text
+    assert ("pilosa_tpu_residency_miss_transfer_bytes_total "
+            f"{packed_bytes + dense.nbytes}\n") in text
+    assert DeviceRowCache().metrics()["residency_sparse_misses"] == 0
+
+
+def test_sparse_leafs_block_index_is_its_tiles_under_the_occupancy_rule():
+    """The compressed tier's block index comes from the listed bits, by
+    the rule a dense miss's scan applies: 40 bits touch at most 40 of the
+    128 blocks (demoted, and promoted back bit for bit), 3,000 bits touch
+    nearly all of them (dropped)."""
+    cache = DeviceRowCache(budget_bytes=8 << 20)
+    thin, want = sparse_leaf(42, 40)
+    thick, _ = sparse_leaf(43, 3000)
+    cache.get_or_build(("thin",), None, None, thin)
+    cache.get_or_build(("thick",), None, None, thick)
+    np.testing.assert_array_equal(
+        cache._rows[("thin",)].block_idx,
+        np.flatnonzero(want.reshape(-1, COMPRESS_BLOCK_WORDS).any(axis=1)))
+    assert cache._rows[("thick",)].block_idx is None
+    # room for the thick leaf and the thin one's 64 padded blocks
+    cache.budget_bytes = want.nbytes * 3 // 2 + (64 << 10)
+    cache._evict()
+    assert ("thin",) in cache._compressed and cache.compressions == 1
+    cache.budget_bytes = 8 << 20
+    np.testing.assert_array_equal(
+        np.asarray(cache.get_row(("thin",), None)), want)
+
+
+def test_write_buffered_during_a_sparse_decode_lands_on_the_expanded_leaf():
+    cache = DeviceRowCache(budget_bytes=8 << 20)
+    decode, want = sparse_leaf(44, 2000)
+    decoding, decoded = threading.Event(), threading.Event()
+
+    def slow_decode():
+        decoding.set()
+        assert decoded.wait(WAIT)
+        return decode()
+
+    asked = []
+
+    def probe(e):
+        asked.append(e)
+        return lambda arr: arr | np.uint32(4)
+
+    b = started(cache.get_or_build, LEAF, TAG, lambda: probe, slow_decode)
+    assert decoding.wait(WAIT)
+    ev = WriteEvent("i", "f", "standard", 0, 1)
+    cache.apply_write(ev)
+    assert cache._pending_builds[LEAF] == [ev]
+    decoded.set()
+    got = np.asarray(finished(b))
+    assert asked == [ev] and cache.sparse_misses == 1
+    np.testing.assert_array_equal(got, want | np.uint32(4))
+    np.testing.assert_array_equal(np.asarray(cache._rows[LEAF].arr), got)
+    assert cache._rows[LEAF].block_idx is None  # patched: never demoted
+
+
+def test_purge_redecode_and_dead_field_take_the_sparse_form():
+    cache = DeviceRowCache(budget_bytes=8 << 20)
+    decode, want = sparse_leaf(45, 2000)
+    decode, calls = counting(decode)
+    decoding, decoded = threading.Event(), threading.Event()
+
+    def slow_decode():
+        if not decoding.is_set():
+            decoding.set()
+            assert decoded.wait(WAIT)
+        return decode()
+
+    # a buffered event the probe cannot patch: decoded again under the
+    # lock, expanded again, charged once
+    b = started(cache.get_or_build, LEAF, TAG, lambda: lambda ev: PURGE,
+                slow_decode)
+    assert decoding.wait(WAIT)
+    cache.apply_write(WriteEvent("i", "f", "standard", 0, 1))
+    decoded.set()
+    np.testing.assert_array_equal(np.asarray(finished(b)), want)
+    assert len(calls) == 2 and cache.sparse_misses == 2
+    assert cache.bytes_used == want.nbytes
+    np.testing.assert_array_equal(np.asarray(cache._rows[LEAF].arr), want)
+    # the field is deleted while its leaf decodes: served, not cached
+    decoding.clear(), decoded.clear()
+    other = ("stack", "", "i", "f", ("standard",), 2, 0)
+    b = started(cache.get_or_build, other, TAG, lambda: lambda ev: None,
+                slow_decode)
+    assert decoding.wait(WAIT)
+    cache.invalidate_tag(TAG)
+    decoded.set()
+    np.testing.assert_array_equal(np.asarray(finished(b)), want)
+    assert other not in cache._rows and cache.bytes_used == 0
+    assert cache.sparse_misses == 3
+
+
+def test_every_buckets_expansion_is_compiled_with_the_first():
+    """The first sparse leaf of a row count compiles the expansion of
+    every bucket; leaves of two other buckets after it compile nothing."""
+    from pilosa_tpu.roaring import kernels
+    from pilosa_tpu.utils import tracing
+
+    from pilosa_tpu.storage import residency
+
+    n_rows = 16
+    assert kernels.sparse_buckets(n_rows) == (8192, 16384, 32768, 65536)
+    # as in a process that has expanded nothing yet
+    residency._expansions_ready.clear()
+    residency._expand_rows.clear_cache()
+    tracing.install_compile_listener()
+    cache = DeviceRowCache(budget_bytes=64 << 20)
+
+    def compiles():  # programs made executable: compiled, or loaded
+        m = tracing.device_metrics()
+        return m["compiles_total"] + m["compile_cache_loads_total"]
+
+    c0 = compiles()
+    first, want = sparse_leaf(46, 5000, n_rows)
+    np.testing.assert_array_equal(
+        np.asarray(cache.get_or_build(("a",), None, None, first)), want)
+    assert compiles() - c0 >= 4
+    c1 = compiles()
+    for key, n_bits, n_pad in ((("b",), 20_000, 32768),
+                               (("c",), 65_536, 65536)):
+        decode, want = sparse_leaf(47, n_bits, n_rows)
+        rows = decode()
+        assert rows.n_pad == n_pad
+        np.testing.assert_array_equal(
+            np.asarray(cache.get_or_build(key, None, None, lambda: rows)),
+            want)
+    assert compiles() == c1 and cache.sparse_misses == 3
+
+
+def test_custom_device_put_never_receives_the_sparse_form(monkeypatch):
+    """A leaf placed by the caller (the mesh's sharded put) is decoded
+    dense: ``stacked_leaf`` asks for the sparse form only when the cache
+    places the leaf itself."""
+    import jax
+
+    from pilosa_tpu.executor import batch
+    from pilosa_tpu.executor.executor import _RowSpec
+    from pilosa_tpu.storage import residency
+
+    asked = []
+    real = batch.host_leaf
+
+    def host_leaf(idx, spec, block, sparse=False):
+        asked.append(sparse)
+        return real(idx, spec, block, sparse=sparse)
+
+    monkeypatch.setattr(batch, "host_leaf", host_leaf)
+
+    class NoField:
+        scope, name = "", "i"
+
+        def field(self, name):
+            return None
+
+    received = []
+
+    def put(host):
+        received.append(host)
+        return jax.device_put(host)
+
+    cache = residency.global_row_cache()
+    block = batch.ShardBlock([0, 1, 2])
+    spec = _RowSpec("f", ("standard",), 1)
+    batch.stacked_leaf(NoField(), spec, block, device_put=put)
+    assert asked == [False] and isinstance(received[0], np.ndarray)
+    assert received[0].shape == (4, WORDS_PER_SHARD)
+    assert cache.sparse_misses == 0
+    cache.clear()
+    got = batch.stacked_leaf(NoField(), spec, block)  # the cache's own put
+    assert asked == [False, True] and cache.sparse_misses == 1
+    assert not np.asarray(got).any()

@@ -365,3 +365,170 @@ def test_dense_rows32_refuses_an_array_it_could_not_write_in_place():
     with pytest.raises(ValueError):
         kernels.dense_rows32(flat, np.zeros((2, 2 * WORDS_PER_SHARD),
                                             np.uint32)[:, ::2])
+
+
+# ------------------------------------------------- the sparse form (ISSUE 38)
+#
+# With ``sparse=True`` (the row cache places the leaf itself) a leaf whose
+# containers are all arrays comes back as its set bits, and the cache's
+# expansion program makes the dense leaf. The contract is the same byte
+# identity, held on what is PLACED: ``block.stack(host_row)`` against the
+# device array. Three shards, so four slot rows: buckets of 8,192 and
+# 16,384 listed bits, the second an eighth of the leaf's 512 KiB.
+
+
+def _exact_bits(rng, n_bits: int, shards=(0, 1, 5)) -> dict:
+    """{shard: bitmap} with ``n_bits`` bits in row ROW over ``shards``, a
+    few hundred a container at most (array containers), and the sparse
+    rows beside it that ``_shard`` writes."""
+    pos = np.sort(rng.choice(len(shards) << 20, n_bits, replace=False))
+    out = {}
+    for i, shard in enumerate(shards):
+        mine = pos[pos >> 20 == i] & ((1 << 20) - 1)
+        ids = np.concatenate((
+            np.asarray([(6 << 20) + 5, (9 << 20) + 70_000], np.uint64),
+            mine.astype(np.uint64) + np.uint64(ROW << 20)))
+        out[shard] = RoaringBitmap.from_ids(ids)
+    return out
+
+
+def _with_one(rng, kind: str) -> dict:
+    """Array containers everywhere but one of shard 1's, which is a
+    ``kind`` container."""
+    by_shard = _exact_bits(rng, 3000)
+    lows = np.unique(_lows(rng, kind)).astype(np.uint64)
+    ids = np.concatenate((by_shard[1].to_ids(),
+                          lows + np.uint64((ROW << 20) + (3 << 16))))
+    by_shard[1] = RoaringBitmap.from_ids(np.unique(ids))
+    return by_shard
+
+
+def _sparse_case(name: str, rng):
+    """(index, spec, sparse path taken?) of one case."""
+    spec = _RowSpec("f", (VIEW,), ROW)
+    if name == "empty_row":
+        return _Index({VIEW: _exact_bits(rng, 500)}), _RowSpec(
+            "f", (VIEW,), 8), True
+    if name == "absent_from_shards_and_a_view":
+        # shard 1 has no bit of the row, shard 5 no fragment, and the
+        # second view the spec names does not exist: one view is read,
+        # its keys ascend, the leaf is sparse
+        by_shard = _exact_bits(rng, 700, shards=(0,))
+        by_shard[1] = _exact_bits(rng, 0, shards=(1,))[1]
+        return _Index({VIEW: by_shard}), _RowSpec(
+            "f", (VIEW, "standard_2026"), ROW), True
+    if name.startswith("bits_"):
+        n = int(name[5:])
+        # one past the largest bucket falls back to the dense decode
+        return _Index({VIEW: _exact_bits(rng, n)}), spec, n <= 16384
+    if name == "zero_slot_past_the_shards":
+        return _Index({VIEW: _exact_bits(rng, 2500)}), spec, True
+    if name in ("one_bitmap_among_arrays", "one_run_among_arrays"):
+        kind = "bitmap" if "bitmap" in name else "run"
+        return _Index({VIEW: _with_one(rng, kind)}), spec, False
+    if name == "two_views_share_a_bit":
+        # the leaf ORs two views of each slot, so a container key comes
+        # twice and a tile's bits are no longer one stretch of the list
+        # (and a bit set in both views would be listed twice): dense
+        a = _exact_bits(rng, 900)
+        b = {s: RoaringBitmap.from_ids(np.concatenate((
+            bm.to_ids()[:40], [np.uint64((ROW << 20) + 123_456)])))
+            for s, bm in _exact_bits(rng, 900).items()}
+        for s in a:
+            a[s] = RoaringBitmap.from_ids(np.unique(np.concatenate((
+                a[s].to_ids(), b[s].to_ids()[:20]))))
+        return (_Index({"standard_2025": a, "standard_2026": b}),
+                _RowSpec("f", ("standard_2025", "standard_2026"), ROW),
+                False)
+    by_shard = _exact_bits(rng, 1200)
+    for bm in by_shard.values():
+        for key in bm.keys:
+            c = bm._containers[key]
+            if key >> 4 == ROW and c.kind == ARRAY and c.data.size > 3:
+                if name == "array_repeats_a_value":
+                    # still in tile order: listed twice, ORed once
+                    c.data = np.concatenate((c.data, c.data[-1:]))
+                else:
+                    c.data = c.data[::-1]
+    return (_Index({VIEW: by_shard}), spec,
+            name == "array_repeats_a_value")
+
+
+SPARSE_CASES = [
+    "empty_row", "absent_from_shards_and_a_view",
+    "bits_1", "bits_8192", "bits_8193", "bits_16384", "bits_16385",
+    "zero_slot_past_the_shards", "one_bitmap_among_arrays",
+    "one_run_among_arrays", "two_views_share_a_bit",
+    "array_repeats_a_value", "array_out_of_tile_order",
+]
+
+
+@pytest.fixture
+def staging_of_threes(monkeypatch):
+    """Every staging array goes out holding 3s: a list entry the decode
+    leaves as it found it names bit 3 of the leaf."""
+    real = batch._staging_array
+
+    def poisoned(shape):
+        buf = real(shape)
+        buf.fill(3)
+        return buf
+
+    monkeypatch.setattr(batch, "_staging_array", poisoned)
+
+
+@pytest.mark.parametrize("name", SPARSE_CASES)
+def test_sparse_leaf_is_placed_word_for_word(name, staging_of_threes):
+    from pilosa_tpu.storage.residency import DeviceRowCache
+
+    rng = np.random.default_rng([38, SPARSE_CASES.index(name)])
+    idx, spec, sparse = _sparse_case(name, rng)
+    block = batch.ShardBlock([0, 1, 5])
+    want = _stacked_reference(idx, spec, block)
+    stats = kernels.global_kernel_stats()
+    dense_before = stats.dense_decodes
+    host = batch.host_leaf(idx, spec, block, sparse=True)
+    assert isinstance(host, kernels.SparseRows) == sparse
+    views = [v for v in spec.views if idx.field("f").view(v)]
+    kinds = _kinds_of(idx, views)
+    if "one_" in name:
+        assert kinds == {ARRAY, BITMAP if "bitmap" in name else RUN}
+    else:
+        assert kinds <= {ARRAY}
+    assert stats.dense_decodes - dense_before == (0 if sparse else 1)
+    if sparse:
+        assert host.n_rows == 4
+        assert host.packed.nbytes * 4 <= want.nbytes + 4 * (
+            kernels.sparse_starts_len(4) * 4)
+        assert host.n_pad == (16384 if name in ("bits_8193", "bits_16384")
+                              else 8192)
+        np.testing.assert_array_equal(
+            host.tiles, np.flatnonzero(want.reshape(-1, 1024).any(axis=1)))
+        # the padding after the listed bits names a bit no leaf has
+        t1 = kernels.sparse_starts_len(4)
+        assert host.packed.size == t1 + host.n_pad
+        listed = int(host.packed[4 * 32])
+        assert listed >= int(np.bitwise_count(want).sum())
+        assert (host.packed[t1 + listed:] == 0x7FFFFFFF).all()
+        assert (host.packed[t1:t1 + listed] < want.size * 32).all()
+    cache = DeviceRowCache(budget_bytes=8 << 20)
+    got = np.asarray(cache.get_or_build(("leaf", name), None, None,
+                                        lambda: host))
+    assert got.dtype == np.uint32 and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+    assert cache.sparse_misses == int(sparse)
+    assert cache.miss_bytes == want.nbytes
+    if name == "zero_slot_past_the_shards":
+        assert got[:3].any(axis=1).all() and not got[3].any()
+    if name == "empty_row":
+        assert not got.any()
+
+
+def test_sparse_is_asked_for_only_where_the_cache_places_the_leaf():
+    """``host_leaf`` alone, as the write probe's callers and the mesh's
+    placement use it, is the dense decode it was."""
+    rng = np.random.default_rng(381)
+    idx = _Index({VIEW: _exact_bits(rng, 900)})
+    got = batch.host_leaf(idx, _RowSpec("f", (VIEW,), ROW),
+                          batch.ShardBlock([0, 1, 5]))
+    assert isinstance(got, np.ndarray)

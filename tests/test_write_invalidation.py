@@ -199,8 +199,8 @@ class TestBufferedBuild:
         fired = {"done": False}
         real_host_leaf = batch.host_leaf
 
-        def host_leaf_with_midwrite(idx_, spec, block):
-            out = real_host_leaf(idx_, spec, block)
+        def host_leaf_with_midwrite(idx_, spec, block, **kw):
+            out = real_host_leaf(idx_, spec, block, **kw)
             if not fired["done"] and spec.field == "f":
                 fired["done"] = True
                 # the builder has already claimed the key and registered
@@ -238,13 +238,13 @@ class TestBufferedBuild:
         release = threading.Event()
         real_host_leaf = batch.host_leaf
 
-        def slow_host_leaf(idx_, spec, block):
+        def slow_host_leaf(idx_, spec, block, **kw):
             if spec.field == "f":
                 decodes.append(1)
                 if len(decodes) == 1:
                     entered.set()
                     assert release.wait(20)
-            return real_host_leaf(idx_, spec, block)
+            return real_host_leaf(idx_, spec, block, **kw)
 
         batch.host_leaf = slow_host_leaf
         results = []
