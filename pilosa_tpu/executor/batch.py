@@ -211,17 +211,18 @@ def host_row(idx, spec, shard: int) -> np.ndarray:
     return acc if acc is not None else np.zeros(WORDS_PER_SHARD, np.uint32)
 
 
-def host_leaf(idx, spec, block: ShardBlock, sparse: bool = False):
+def host_leaf(idx, spec, block: ShardBlock, sparse: int = 0):
     """Dense uint32[host_rows, words] for a _RowSpec leaf (host side):
     ``block.stack`` of ``host_row`` byte for byte, decoded in one pass
     over the row's containers of every local shard and view
     (kernels.flatten_rows, kernels.dense_rows32) straight into a
     recycled staging array. A missing field, view or fragment, and a
-    slot past the shards, read zeros. With ``sparse`` (the row cache
-    places the leaf itself) a leaf whose containers are all sparse
-    arrays comes back as its set bits instead, a kernels.SparseRows
-    in a staging array of its bucket's shape, for the chip to expand:
-    the same leaf, never laid out on the host."""
+    slot past the shards, read zeros. With ``sparse`` (the shares whoever
+    places the leaf expands it in: 1 where the row cache places it
+    itself, a share a chip where a one-process mesh does) a leaf whose
+    containers are all sparse arrays comes back as its set bits instead,
+    a kernels.SparseRows in a staging array of its bucket's shape, for
+    the chips to expand: the same leaf, never laid out on the host."""
     lo, hi = block.local_slots
     local = block.shards[lo:min(hi, len(block.shards))]
     field = idx.field(spec.field)
@@ -240,7 +241,7 @@ def host_leaf(idx, spec, block: ShardBlock, sparse: bool = False):
         # one tally a leaf, the totals Fragment.row_words notes a shard
         cost.note_containers(*flat.kind_counts())
     if sparse:
-        rows = kernels.sparse_rows32(flat, hi - lo, _staging_array)
+        rows = kernels.sparse_rows32(flat, hi - lo, _staging_array, sparse)
         if rows is not None:
             return rows
     out = _staging_array((hi - lo, WORDS_PER_SHARD))
@@ -448,7 +449,8 @@ def leaf_keys(idx, specs, block: ShardBlock) -> tuple:
 
 def stacked_leaf(idx, spec, block: ShardBlock, device_put=None):
     """Device-resident stacked leaf for a compiled spec, via the residency
-    LRU. ``device_put`` overrides placement (mesh sharding)."""
+    LRU. ``device_put`` overrides placement (mesh sharding); one that
+    can expand a sparse row leaf says in how many shares (``sparse``)."""
     from pilosa_tpu.executor.executor import (
         PQLError,
         _PlanesSpec,
@@ -461,7 +463,8 @@ def stacked_leaf(idx, spec, block: ShardBlock, device_put=None):
         key = leaf_key(idx, spec, block)
 
         def decode():
-            return host_leaf(idx, spec, block, sparse=device_put is None)
+            return host_leaf(idx, spec, block, sparse=1 if device_put is None
+                             else getattr(device_put, "sparse", 0))
 
         def probe():  # factory: only built when the key isn't registered
             views = frozenset(spec.views)

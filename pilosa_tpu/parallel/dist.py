@@ -32,7 +32,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from pilosa_tpu.executor import expr
 from pilosa_tpu.executor.executor import Executor
@@ -40,8 +40,9 @@ from pilosa_tpu.executor import batch
 from pilosa_tpu.parallel import reduction
 from pilosa_tpu.parallel.mesh import (
     GROUPS_AXIS, SHARDS_AXIS, ShardAssignment, make_mesh, mesh_groups,
-    replicated, shards_spec,
+    replicated, shards_sharding, shards_spec,
 )
+from pilosa_tpu.storage import residency
 from pilosa_tpu.utils.compile_cache import named_jit
 from pilosa_tpu.utils.cost import current_cost
 
@@ -275,6 +276,49 @@ def _dist_groupby_level_fn(mesh, filt_structure, n_filt: int, n_scalars: int,
     return fn
 
 
+def _dist_expand_fn(mesh, n_rows: int, n_pad: int):
+    """The sparse miss's expansion on a mesh (residency.expand_rows_body
+    under shard_map): the shares' packed lists arrive as one array split
+    over the shard axis, every chip expands its own slot rows, and the
+    leaf ``uint32[n_rows, 32768]`` comes back sharded as _leaf_put shards
+    a dense one. No collective."""
+    key = ("expand_rows", mesh, n_rows, n_pad)
+    fn = _DIST_JIT_CACHE.get(key)
+    if fn is None:
+        spec, sharding = shards_spec(mesh), shards_sharding(mesh)
+        rows = n_rows // mesh.size
+
+        def body(packed):
+            return residency.expand_rows_body(packed, rows, n_pad)
+
+        fn = _DIST_JIT_CACHE[key] = named_jit(
+            "dist_expand_rows",
+            shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
+                      check_vma=False),
+            in_shardings=sharding, out_shardings=sharding)
+    return fn
+
+
+class _MeshLeafPut:
+    """DistExecutor._leaf_put in one process: a dense host leaf is split
+    over the mesh's chips by its slot rows; a sparse row leaf
+    (kernels.SparseRows in ``sparse`` = mesh.size shares) is placed as
+    its packed lists, a share a chip, and expanded there."""
+
+    def __init__(self, mesh):
+        self.mesh = mesh
+        self.sparse = mesh.size
+        self.sharding = shards_sharding(mesh)
+
+    def __call__(self, host):
+        return jax.device_put(host, self.sharding)
+
+    def expand(self, packed, n_rows: int, n_pad: int):
+        # the program call places its one host argument itself, a share
+        # on each chip
+        return _dist_expand_fn(self.mesh, n_rows, n_pad)(packed)
+
+
 class DistExecutor(Executor):
     """Executor whose shard map phase runs as one SPMD program on a mesh.
 
@@ -333,9 +377,9 @@ class DistExecutor(Executor):
         return ShardAssignment(shard_list, self.mesh)
 
     def _leaf_put(self, block):
-        sharding = NamedSharding(self.mesh, shards_spec(self.mesh))
         if jax.process_count() == 1:
-            return lambda host: jax.device_put(host, sharding)
+            return _MeshLeafPut(self.mesh)
+        sharding = shards_sharding(self.mesh)
         # Multi-host: ``host`` holds only this process's slot rows
         # (ShardAssignment narrows block.local_slots, so block.stack
         # decoded just the addressable slice); assemble the global array

@@ -612,56 +612,79 @@ def sparse_packed_len(n_rows: int, n_pad: int) -> int:
 
 class SparseRows:
     """A row leaf ``uint32[n_rows, 32768]`` as its set bits, for the
-    device to expand (residency.expand_rows). ``packed`` is ONE host
-    array, uint32[sparse_packed_len(n_rows, n_pad)]: the tile table
-    (``starts[t]`` .. ``starts[t + 1]`` are the listed bits of tile
-    ``t``), then ``n_pad`` bits in tile order, each as its number within
-    the leaf (``word * 32 + bit``: the flat word index and the mask in
-    one integer); a padding entry is a bit no leaf has. ``tiles`` are
-    the tiles that hold a set bit, ascending."""
+    device to expand (residency.expand_rows_body). The leaf is listed in
+    ``parts`` equal shares of its slot rows, one a chip that holds a
+    share (1 off a mesh), each a list of its own: ``packed`` is ONE host
+    array, the shares' lists end to end, each
+    uint32[sparse_packed_len(n_rows // parts, n_pad)]: the share's tile
+    table (``starts[t]`` .. ``starts[t + 1]`` are the listed bits of its
+    tile ``t``), then ``n_pad`` bits in tile order, each as its number
+    within the share (``word * 32 + bit``: the flat word index and the
+    mask in one integer); a padding entry is a bit no leaf has. Every
+    share is padded to the same ``n_pad``, the bucket of the fullest.
+    ``tiles`` are the leaf's tiles that hold a set bit, ascending."""
 
-    __slots__ = ("packed", "n_rows", "n_pad", "tiles")
+    __slots__ = ("packed", "n_rows", "n_pad", "tiles", "parts")
 
-    def __init__(self, packed, n_rows: int, n_pad: int, tiles):
+    def __init__(self, packed, n_rows: int, n_pad: int, tiles,
+                 parts: int = 1):
         self.packed = packed
         self.n_rows = n_rows
         self.n_pad = n_pad
         self.tiles = tiles
+        self.parts = parts
 
 
-def sparse_rows32(f: FlatFragment, n_rows: int, staging) -> SparseRows | None:
-    """The rows of a :func:`flatten_rows` view as a :class:`SparseRows`,
-    or None where :func:`dense_rows32` has to write them: a bitmap or a
-    run container in the view, a key named twice (several views of a
-    slot, whose bits may repeat), array containers whose values leave
-    tile order, or more set bits than the largest bucket holds.
-    ``staging(shape)`` gives the uint32 array written; nothing of the
-    dense leaf's size is made or read."""
-    n = int(f.arr_data.size)
-    buckets = sparse_buckets(n_rows)
+def sparse_rows32(f: FlatFragment, n_rows: int, staging,
+                  parts: int = 1) -> SparseRows | None:
+    """The rows of a :func:`flatten_rows` view as a :class:`SparseRows`
+    of ``parts`` shares, or None where :func:`dense_rows32` has to write
+    them: a bitmap or a run container in the view, a key named twice
+    (several views of a slot, whose bits may repeat), array containers
+    whose values leave tile order, or a share with more set bits than
+    the largest bucket of its row count holds. ``staging(shape)`` gives
+    the uint32 array written; nothing of the dense leaf's size is made
+    or read."""
+    rows = n_rows // parts
+    buckets = sparse_buckets(rows)
     keys = f.keys
-    if (f.bmp_sel.size or f.run_sel.size or not buckets or n > buckets[-1]
+    if (f.bmp_sel.size or f.run_sel.size or not buckets
             or not bool((keys[1:] > keys[:-1]).all())):
+        return None
+    # key = slot * 16 + k: a share's containers lie together
+    key_cuts = np.searchsorted(
+        keys, np.arange(parts + 1) * (rows * ROW_KEYS)).tolist()
+    cuts = f.arr_off[key_cuts].tolist()
+    n = max(b - a for a, b in zip(cuts, cuts[1:]))
+    if n > buckets[-1]:
         return None
     _STATS.kernel_calls += 1
     n_pad = next(b for b in buckets if n <= b)
-    n_tiles = n_rows * (SHARD_WIDTH >> 5) // SPARSE_TILE_WORDS
-    t1 = sparse_starts_len(n_rows)
-    packed = staging((t1 + n_pad,))
-    bits = packed[t1:t1 + n]
-    # key = slot * 16 + k is the bit's number within the leaf >> 16
-    np.add(np.repeat((keys << 16).astype(np.uint32), np.diff(f.arr_off)),
-           f.arr_data, out=bits)
-    tile_of = bits >> np.uint32(_TILE_BIT_SHIFT)
-    if not bool((tile_of[1:] >= tile_of[:-1]).all()):
-        return None  # a container out of order: dense_rows32 ORs any order
-    packed[t1 + n:] = _NO_BIT
-    counts = np.bincount(tile_of, minlength=n_tiles)
-    packed[0] = 0
-    packed[1:n_tiles + 1] = np.cumsum(counts)
-    packed[n_tiles + 1:t1] = n
+    n_tiles = rows * (SHARD_WIDTH >> 5) // SPARSE_TILE_WORDS
+    t1 = sparse_starts_len(rows)
+    packed = staging((parts * (t1 + n_pad),))
+    tiles = []
+    for p in range(parts):
+        part = packed[p * (t1 + n_pad):(p + 1) * (t1 + n_pad)]
+        ka, kb = key_cuts[p], key_cuts[p + 1]
+        m = cuts[p + 1] - cuts[p]
+        bits = part[t1:t1 + m]
+        # the key within the share is the bit's number within it >> 16
+        np.add(np.repeat(((keys[ka:kb] - p * rows * ROW_KEYS) << 16
+                          ).astype(np.uint32), np.diff(f.arr_off[ka:kb + 1])),
+               f.arr_data[cuts[p]:cuts[p + 1]], out=bits)
+        tile_of = bits >> np.uint32(_TILE_BIT_SHIFT)
+        if not bool((tile_of[1:] >= tile_of[:-1]).all()):
+            return None  # a container out of order: dense_rows32 ORs any
+        part[t1 + m:] = _NO_BIT
+        counts = np.bincount(tile_of, minlength=n_tiles)
+        part[0] = 0
+        part[1:n_tiles + 1] = np.cumsum(counts)
+        part[n_tiles + 1:t1] = m
+        tiles.append(np.flatnonzero(counts).astype(np.int32) + p * n_tiles)
     return SparseRows(packed, n_rows, n_pad,
-                      np.flatnonzero(counts).astype(np.int32))
+                      tiles[0] if parts == 1 else np.concatenate(tiles),
+                      parts)
 
 
 # ---------------------------------------------------------------- popcount

@@ -157,8 +157,11 @@ EXPAND_UNROLL = 16
 assert EXPAND_UNROLL & (EXPAND_UNROLL - 1) == 0
 
 
-def _expand_rows(packed, n_rows: int, n_pad: int):
-    """``kernels.SparseRows.packed`` to the leaf ``uint32[n_rows, 32768]``.
+def expand_rows_body(packed, n_rows: int, n_pad: int):
+    """One share of ``kernels.SparseRows.packed`` to its rows of the
+    leaf, ``uint32[n_rows, 32768]``: the whole leaf on one chip
+    (``jit_expand_rows``), a chip's slot rows under a mesh's
+    ``shard_map`` (``jit_dist_expand_rows``, parallel/dist.py).
 
     One Pallas kernel, a grid step for eight slot rows. A step reads the
     bounds of its tiles from the tile table (scalar prefetch) and copies
@@ -293,9 +296,10 @@ def _expand_rows(packed, n_rows: int, n_pad: int):
         )(packed[:t1], packed[t1:])
 
 
-_expand_rows = named_jit("expand_rows", _expand_rows,
+_expand_rows = named_jit("expand_rows", expand_rows_body,
                          static_argnames=("n_rows", "n_pad"))
-# (row count, device) whose every bucket's expansion has been compiled
+# (row count, shares, the device or mesh) whose every bucket's expansion
+# has been compiled
 _expansions_ready: set = set()
 
 
@@ -567,19 +571,25 @@ class DeviceRowCache:
         """A miss's decode to ``(device array, block index)``: a dense
         host array is transferred (by ``device_put``, a custom placement
         that is never compressed, or to this cache's device), a
-        kernels.SparseRows is transferred as it is and expanded there."""
+        kernels.SparseRows is transferred as it is and expanded there
+        (by ``device_put.expand`` where the placement is a mesh's: only a
+        placement that has one is ever handed the sparse form)."""
         block_idx = None
-        if device_put is not None:
-            arr = device_put(host)
-            sent = int(arr.nbytes)
-        elif isinstance(host, kernels.SparseRows):
-            self._compile_expansions(host.n_rows)
-            arr = self._expand(host.packed, host.n_rows, host.n_pad)
-            if (host.tiles.size * COMPRESS_BLOCK_WORDS
+        if isinstance(host, kernels.SparseRows):
+            if device_put is None:
+                expand, where = self._expand, self.device
+            else:
+                expand, where = device_put.expand, device_put.mesh
+            self._compile_expansions(host.n_rows, host.parts, expand, where)
+            arr = expand(host.packed, host.n_rows, host.n_pad)
+            if (device_put is None and host.tiles.size * COMPRESS_BLOCK_WORDS
                     <= COMPRESS_MAX_OCCUPANCY * arr.size):
                 block_idx = host.tiles
             self.sparse_misses += 1
             sent = int(host.packed.nbytes)
+        elif device_put is not None:
+            arr = device_put(host)
+            sent = int(arr.nbytes)
         else:
             arr = jax.device_put(host, self.device)
             block_idx = self._host_block_index(host)
@@ -598,18 +608,21 @@ class DeviceRowCache:
             packed = jax.device_put(packed, self.device)
         return _expand_rows(packed, n_rows=n_rows, n_pad=n_pad)
 
-    def _compile_expansions(self, n_rows: int) -> None:
-        """Before the first sparse leaf of ``n_rows`` is expanded, run
-        the expansion of every bucket such a leaf may come in once, on
-        an empty list: the closed list of programs is compiled (or read
-        from the persistent cache) at one known moment, and no later
-        miss, however rare its bucket, compiles."""
-        if (n_rows, self.device) in _expansions_ready:
+    @staticmethod
+    def _compile_expansions(n_rows: int, parts: int, expand, where) -> None:
+        """Before the first sparse leaf of ``n_rows`` in ``parts`` shares
+        is expanded on ``where`` (a device, a mesh), run the expansion of
+        every bucket such a leaf may come in once, on an empty list: the
+        closed list of programs is compiled (or read from the persistent
+        cache) at one known moment, and no later miss, however rare its
+        bucket, compiles."""
+        if (n_rows, parts, where) in _expansions_ready:
             return
-        for n_pad in kernels.sparse_buckets(n_rows):
-            self._expand(np.zeros(kernels.sparse_packed_len(n_rows, n_pad),
-                                  np.uint32), n_rows, n_pad)
-        _expansions_ready.add((n_rows, self.device))
+        rows = n_rows // parts
+        for n_pad in kernels.sparse_buckets(rows):
+            expand(np.zeros(parts * kernels.sparse_packed_len(rows, n_pad),
+                            np.uint32), n_rows, n_pad)
+        _expansions_ready.add((n_rows, parts, where))
 
     def _put_locked(self, key, host, device_put):
         with stage("residency.upload"):

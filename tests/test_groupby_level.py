@@ -561,3 +561,47 @@ def test_sparse_miss_expansion_compiles_for_one_v5e_chip(topo, n_rows,
         assert compiled.memory_analysis().temp_size_in_bytes == 0
         assert compiled.memory_analysis().output_size_in_bytes == (
             n_rows * WORDS_PER_SHARD * 4)
+
+
+@pytest.mark.parametrize("n_rows", [512, 8])
+def test_sparse_miss_expansion_compiles_for_the_four_chip_mesh(
+        topo, n_rows, monkeypatch):
+    """ISSUE 39: the mesh program of every bucket of a 512-slot leaf (128
+    slot rows a chip: the one-chip cell's buckets) and of the rehearsal's
+    8-slot leaf. Every chip runs the kernel on its own share: the packed
+    lists come in split over the shard axis, the leaf goes out split by
+    slot rows, and nothing crosses the mesh."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from pilosa_tpu.parallel import dist
+    from pilosa_tpu.parallel.mesh import SHARDS_AXIS
+    from pilosa_tpu.roaring import kernels
+    from pilosa_tpu.storage import residency
+
+    monkeypatch.setattr(residency, "pallas_interpret", lambda: False)
+    mesh = Mesh(np.asarray(topo.devices), (SHARDS_AXIS,))
+    monkeypatch.setattr(dist, "_DIST_JIT_CACHE", {})
+    sharded = NamedSharding(mesh, P(SHARDS_AXIS))
+    rows = n_rows // mesh.size
+    buckets = kernels.sparse_buckets(rows)
+    assert buckets == kernels.sparse_buckets(128) if n_rows == 512 else (
+        buckets == (8192,))
+    for n_pad in buckets:
+        packed = jax.ShapeDtypeStruct(
+            (mesh.size * kernels.sparse_packed_len(rows, n_pad),),
+            jnp.uint32, sharding=sharded)
+        compiled = dist._dist_expand_fn(mesh, n_rows, n_pad).lower(
+            packed).compile()
+        text = compiled.as_text()
+        assert 'custom_call_target="tpu_custom_call"' in text
+        assert "jit_dist_expand_rows" in text
+        for collective in ("all-reduce", "all-gather", "all-to-all",
+                           "collective-permute", "reduce-scatter"):
+            assert collective not in text
+        assert compiled.memory_analysis().temp_size_in_bytes == 0
+        # a chip's share of the leaf
+        assert compiled.memory_analysis().output_size_in_bytes == (
+            rows * WORDS_PER_SHARD * 4)
+        assert compiled.output_shardings.is_equivalent_to(sharded, 2)
