@@ -124,12 +124,40 @@ class Container:
         return words.view("<u4").copy()
 
 
+class ContainerDirectory:
+    """A snapshot's containers as parallel arrays over the snapshot's own
+    bytes: what a reader needs of every container without touching a
+    ``Container``. ``keys`` ascend (int64); ``kinds`` and ``cards`` are
+    each container's kind and cardinality; container ``i``'s payload is
+    ``payload[starts[i]:starts[i + 1]]``, where ``payload`` is a
+    read-only uint16 view of the snapshot bytes (every regular payload
+    is a whole number of uint16) and ``starts`` has one entry more than
+    ``keys``. ``all_arrays`` says that no container is a bitmap or a run.
+    Immutable: made from the bytes of a canonical snapshot
+    (kernels.directory_from_snapshot) while the bitmap equals them, and
+    dropped, never edited, by the first mutation (RoaringBitmap._merge)."""
+
+    __slots__ = ("keys", "kinds", "cards", "starts", "payload", "all_arrays")
+
+    def __init__(self, keys, kinds, cards, starts, payload):
+        self.keys = keys
+        self.kinds = kinds
+        self.cards = cards
+        self.starts = starts
+        self.payload = payload
+        self.all_arrays = bool((kinds == ARRAY).all())
+
+
 class RoaringBitmap:
     """Sorted map: container key (high 48 bits) → Container."""
 
     def __init__(self):
         self.keys: list[int] = []
         self._containers: dict[int, Container] = {}
+        # ContainerDirectory of the snapshot this bitmap was read from or
+        # written to, while it still equals it (storage/fragment.py sets
+        # it, _merge drops it); None otherwise
+        self.directory: ContainerDirectory | None = None
 
     # --- constructors ---
 
@@ -288,10 +316,15 @@ class RoaringBitmap:
         threshold, the per-container loop below it (a point write must
         not pay batch bookkeeping). Both produce byte-identical
         containers — tests/test_merge_kernels.py pins the property, so
-        the threshold is pure performance tuning."""
+        the threshold is pure performance tuning. The one door every
+        mutation passes (add_ids, remove_ids, op replay, WAL recovery),
+        so the snapshot's ContainerDirectory dies here."""
         ids = np.atleast_1d(np.asarray(ids, dtype=np.uint64))
         if ids.size == 0:
             return 0
+        # before any container is swapped: a reader that already holds the
+        # directory reads the older snapshot whole, a later one walks
+        self.directory = None
         from pilosa_tpu.roaring import merge_kernels
 
         if ids.size >= merge_kernels.KERNEL_MIN_IDS:

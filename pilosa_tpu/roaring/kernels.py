@@ -33,10 +33,13 @@ from __future__ import annotations
 
 import bisect
 import struct
+import threading
 
 import numpy as np
 
-from pilosa_tpu.roaring.bitmap import ARRAY, BITMAP, RUN, BITMAP_N_WORDS
+from pilosa_tpu.roaring.bitmap import (
+    ARRAY, BITMAP, RUN, BITMAP_N_WORDS, ContainerDirectory,
+)
 from pilosa_tpu.shardwidth import SHARD_WIDTH
 
 _U16 = np.uint64(16)
@@ -53,7 +56,8 @@ class KernelStats:
     correctness invariants, and the hot paths must not pay a lock."""
 
     __slots__ = ("kernel_calls", "containers_flattened", "ids_materialized",
-                 "dense_decodes", "set_ops")
+                 "dense_decodes", "set_ops", "directory_windows",
+                 "walked_windows")
 
     def __init__(self):
         self.kernel_calls = 0
@@ -61,6 +65,10 @@ class KernelStats:
         self.ids_materialized = 0
         self.dense_decodes = 0
         self.set_ops = 0
+        # fragment windows of row-leaf gathers (flatten_rows): read from
+        # a ContainerDirectory, or walked container by container
+        self.directory_windows = 0
+        self.walked_windows = 0
 
     def metrics(self) -> dict:
         return {
@@ -69,6 +77,8 @@ class KernelStats:
             "hostpath_ids_materialized_total": self.ids_materialized,
             "hostpath_dense_decodes_total": self.dense_decodes,
             "hostpath_set_ops_total": self.set_ops,
+            "hostpath_directory_windows_total": self.directory_windows,
+            "hostpath_walked_windows_total": self.walked_windows,
         }
 
 
@@ -200,29 +210,236 @@ def flatten(bitmap, lo_key: int | None = None,
 ROW_KEYS = SHARD_WIDTH >> 16
 
 
+def _collect_row(bitmap, base_key: int, shift: int, kept: list,
+                 conts: list) -> None:
+    """:func:`_collect` over the row window that starts at ``base_key``,
+    found by bisection."""
+    keys = bitmap.keys
+    lo_i = bisect.bisect_left(keys, base_key)
+    hi_i = bisect.bisect_left(keys, base_key + ROW_KEYS, lo_i)
+    _collect(keys[lo_i:hi_i], bitmap._containers, shift, kept, conts)
+
+
 def flatten_rows(bitmaps, row: int) -> FlatFragment:
     """Flatten row ``row`` of many fragments into ONE view: ``bitmaps``
     is (slot, RoaringBitmap) pairs, and container ``row * 16 + k`` of a
     slot's bitmap takes the key ``slot * 16 + k``. Keys ascend; a slot
     named more than once (a leaf that ORs several views) repeats its
-    keys, which :func:`dense_rows32` ORs. Each bitmap's window is found
-    by bisection; lock-free as :func:`flatten` is."""
+    keys, which :func:`dense_rows32` ORs. A leaf of array containers
+    whose slots ascend is sliced from the bitmaps' directories
+    (:func:`_row_windows`); any other is walked: each bitmap's window
+    found by bisection, lock-free as :func:`flatten` is."""
+    bitmaps = list(bitmaps)
+    flat = _row_windows(bitmaps, row)
+    if flat is not None:
+        return flat
+    _STATS.walked_windows += len(bitmaps)
     base_key = row * ROW_KEYS
     kept, conts = [], []
     last_slot, ascending = -1, True
     for slot, bitmap in bitmaps:
         ascending &= slot > last_slot
         last_slot = slot
-        keys = bitmap.keys
-        lo_i = bisect.bisect_left(keys, base_key)
-        hi_i = bisect.bisect_left(keys, base_key + ROW_KEYS, lo_i)
-        _collect(keys[lo_i:hi_i], bitmap._containers,
-                 slot * ROW_KEYS - base_key, kept, conts)
+        _collect_row(bitmap, base_key, slot * ROW_KEYS - base_key, kept,
+                     conts)
     if not ascending:
         order = sorted(range(len(kept)), key=kept.__getitem__)
         kept = [kept[i] for i in order]
         conts = [conts[i] for i in order]
     return _build_flat(kept, conts)
+
+
+def _flat_of_arrays(keys, cards, arr_off, arr_data) -> FlatFragment:
+    """The FlatFragment of array containers alone."""
+    f = FlatFragment()
+    n = keys.size
+    f.keys, f.cards, f.arr_off, f.arr_data = keys, cards, arr_off, arr_data
+    f.kinds = np.full(n, ARRAY, np.uint8)
+    f.kind_row = np.arange(n)
+    f.arr_sel = np.arange(n)
+    f.bmp_sel = f.run_sel = np.empty(0, np.int64)
+    f.bmp_parts, f._bmp_words = [], None
+    f.run_data = np.empty((0, 2), np.int64)
+    f.run_off = np.zeros(1, np.int64)
+    _STATS.containers_flattened += n
+    return f
+
+
+def _row_windows(bitmaps: list, row: int) -> FlatFragment | None:
+    """:func:`flatten_rows` of a leaf whose containers are all arrays
+    and whose slots ascend, element for element, or None for any other
+    leaf (and for one no bitmap of which has a ContainerDirectory). A
+    bitmap with a directory gives its row window as slices of the
+    directory's arrays: two positions by ``searchsorted``, then the
+    keys, the cardinalities and ONE contiguous stretch of the
+    snapshot's payload (a row is 16 consecutive keys and a snapshot's
+    payloads lie in key order), with no Container touched. A bitmap
+    without one (written since its snapshot) is walked as ever. A leaf
+    that comes again with the same directories, all of its bitmaps',
+    is read from their stack (:class:`_DirectoryStack`): one
+    ``searchsorted`` for every fragment's window. A reader that took a
+    directory before a write dropped it holds the older snapshot whole,
+    which the lock-free walk may return too."""
+    dirs = [bitmap.directory for _, bitmap in bitmaps]
+    n_walked = dirs.count(None)
+    if n_walked == len(dirs):
+        return None
+    slots = [slot for slot, _ in bitmaps]
+    if any(a >= b for a, b in zip(slots, slots[1:])):
+        return None  # several views of a slot: keys repeat
+    base_key = row * ROW_KEYS
+    stack = _leaf_stack(dirs) if not n_walked else None
+    if stack is not None:
+        flat = stack.row_windows(slots, base_key)
+    else:
+        flat = _sliced_windows(bitmaps, dirs, base_key)
+    if flat is not None:
+        _STATS.walked_windows += n_walked
+        _STATS.directory_windows += len(dirs) - n_walked
+    return flat
+
+
+def _sliced_windows(bitmaps: list, dirs: list,
+                    base_key: int) -> FlatFragment | None:
+    """:func:`_row_windows` a fragment at a time: ``dirs[i]`` is the
+    directory of ``bitmaps[i]``'s bitmap or None."""
+    probe = np.asarray((base_key, base_key + ROW_KEYS))
+    # a piece a non-empty window: its keys, cardinalities and payload
+    # starts (a slice a container), its payload, and what takes the keys
+    # to the leaf's and the starts to the leaf's payload
+    keys, cards, starts, data, shifts, firsts, lasts = ([], [], [], [], [],
+                                                        [], [])
+    for (slot, bitmap), d in zip(bitmaps, dirs):
+        if d is None:
+            kept, conts = [], []
+            _collect_row(bitmap, base_key, 0, kept, conts)
+            if not kept:
+                continue
+            if any(c.kind != ARRAY for c in conts):
+                return None
+            sizes = [c.data.size for c in conts]
+            keys.append(np.asarray(kept, np.int64))
+            cards.append(np.asarray([c.n for c in conts], np.int32))
+            starts.append(np.cumsum([0, *sizes[:-1]]))
+            data.append(np.concatenate([c.data for c in conts]))
+            firsts.append(0)
+            lasts.append(sum(sizes))
+        else:
+            lo, hi = d.keys.searchsorted(probe).tolist()
+            if lo == hi:
+                continue
+            if not d.all_arrays and bool((d.kinds[lo:hi] != ARRAY).any()):
+                return None
+            first, last = int(d.starts[lo]), int(d.starts[hi])
+            keys.append(d.keys[lo:hi])
+            cards.append(d.cards[lo:hi])
+            starts.append(d.starts[lo:hi])
+            data.append(d.payload[first:last])
+            firsts.append(first)
+            lasts.append(last)
+        shifts.append(slot * ROW_KEYS - base_key)
+    if not keys:
+        return _flat_of_arrays(np.empty(0, np.int64), np.empty(0, np.int64),
+                               np.zeros(1, np.int64), np.empty(0, np.uint16))
+    counts = [k.size for k in keys]
+    firsts = np.asarray(firsts)
+    lens = np.asarray(lasts) - firsts
+    before = np.cumsum(lens) - lens  # the leaf's payload before a piece
+    return _flat_of_arrays(
+        np.concatenate(keys) + np.repeat(shifts, counts),
+        np.concatenate(cards).astype(np.int64),
+        np.append(np.concatenate(starts) - np.repeat(firsts - before, counts),
+                  before[-1] + lens[-1]),
+        np.concatenate(data))
+
+
+# A stack shifts fragment i's container keys (under 2^48: a 64-bit id's
+# high 48 bits) by i << 48, so a leaf may stack this many fragments.
+_STACK_SHIFT = 48
+_STACK_MAX_FRAGMENTS = 1 << 14
+# Stacks and candidates kept, by the id of their first directory (which
+# they keep alive). A stack repeats its directories' arrays, 25 bytes a
+# container: the leaves that miss steadily are few.
+_STACKS_KEPT = 4
+_stacks: dict[int, "_DirectoryStack | list"] = {}
+_stacks_lock = threading.Lock()
+
+
+class _DirectoryStack:
+    """The ContainerDirectories of a leaf's fragments end to end, for
+    the leaf's row windows in a fixed number of numpy calls: fragment
+    ``i``'s keys are shifted by ``i << 48``, so the stacked keys ascend
+    and ONE ``searchsorted`` finds every fragment's window; keys,
+    cardinalities and payload sizes are then gathered by one index, and
+    only the payload is sliced a fragment (each from its own snapshot's
+    bytes). Valid for exactly the directories it was made from
+    (``dirs``, compared by identity): a write drops a fragment's
+    directory and the leaf is then sliced a fragment at a time."""
+
+    __slots__ = ("dirs", "bases", "keys", "kinds", "cards", "starts",
+                 "sizes", "payloads", "all_arrays")
+
+    def __init__(self, dirs: list):
+        self.dirs = dirs
+        self.bases = np.arange(len(dirs), dtype=np.int64) << _STACK_SHIFT
+        self.keys = np.concatenate(
+            [d.keys + b for d, b in zip(dirs, self.bases.tolist())])
+        self.kinds = np.concatenate([d.kinds for d in dirs])
+        self.cards = np.concatenate([d.cards for d in dirs])
+        self.starts = np.concatenate([d.starts[:-1] for d in dirs])
+        self.sizes = np.concatenate(
+            [np.diff(d.starts) for d in dirs]).astype(np.int32)
+        self.payloads = [d.payload for d in dirs]
+        self.all_arrays = all(d.all_arrays for d in dirs)
+
+    def row_windows(self, slots: list, base_key: int) -> FlatFragment | None:
+        """:func:`_row_windows` of the row that starts at ``base_key``,
+        ``slots[i]`` being the slot of fragment ``i``."""
+        edges = self.keys.searchsorted(np.concatenate(
+            (self.bases + base_key, self.bases + (base_key + ROW_KEYS))))
+        lo, hi = edges[:len(slots)], edges[len(slots):]
+        counts = hi - lo
+        n = int(counts.sum())
+        # the stacked position of every container of the windows
+        at = np.arange(n) + np.repeat(lo - (np.cumsum(counts) - counts),
+                                      counts)
+        if not self.all_arrays and bool((self.kinds[at] != ARRAY).any()):
+            return None
+        sizes = self.sizes[at]
+        held = np.flatnonzero(counts)
+        firsts = self.starts[lo[held]]
+        lasts = self.starts[hi[held] - 1] + self.sizes[hi[held] - 1]
+        payloads = self.payloads
+        data = [payloads[i][a:b] for i, a, b in zip(
+            held.tolist(), firsts.tolist(), lasts.tolist())]
+        return _flat_of_arrays(
+            self.keys[at] - np.repeat(
+                self.bases + base_key - np.asarray(slots) * ROW_KEYS, counts),
+            self.cards[at].astype(np.int64),
+            np.concatenate(([0], np.cumsum(sizes, dtype=np.int64))),
+            np.concatenate(data) if data else np.empty(0, np.uint16))
+
+
+def _leaf_stack(dirs: list) -> _DirectoryStack | None:
+    """The stack of exactly ``dirs`` (none None), made when they come the
+    second time: a leaf seen once is remembered and sliced a fragment at
+    a time, so leaves that do not come again (or more of them than are
+    kept) never pay for a stack."""
+    key = id(dirs[0])
+    seen = _stacks.get(key)
+    if type(seen) is _DirectoryStack:
+        if seen.dirs == dirs:
+            return seen
+        seen = None
+    if len(dirs) > _STACK_MAX_FRAGMENTS:
+        return None
+    stack = _DirectoryStack(dirs) if seen == dirs else None
+    with _stacks_lock:
+        _stacks.pop(key, None)
+        while len(_stacks) >= _STACKS_KEPT:
+            del _stacks[next(iter(_stacks))]
+        _stacks[key] = stack or dirs
+    return stack
 
 
 def _take(f: FlatFragment, idx: np.ndarray) -> FlatFragment:
@@ -856,20 +1073,13 @@ _DESCR_DTYPE = np.dtype([("key", "<u8"), ("kind", "<u2"),
                          ("nm1", "<u2"), ("plen", "<u4")])
 
 
-def flat_from_snapshot(buf) -> tuple[FlatFragment, int]:
-    """Parse a roaring/format.py snapshot straight into a FlatFragment —
-    no Container objects, no per-container ``np.frombuffer`` — with the
-    same structural validation (and error text) as ``deserialize``.
-    Returns (flat, offset-where-ops-begin). The scrub/verify fast path:
-    digesting a fragment file becomes parse → :func:`fragment_ids` →
-    ``block_digests`` with zero per-container dispatches.
-
-    Falls back (ValueError) only on inputs ``deserialize`` also
-    rejects; irregular-but-accepted payloads (bitmap payload not
-    exactly 1024 words) raise :class:`_IrregularSnapshot` so the caller
-    can retry through the reference decoder.
-    """
-    buf = memoryview(buf)
+def _snapshot_descriptors(buf: memoryview):
+    """The descriptor table of a roaring/format.py snapshot as ONE
+    structured array, with ``deserialize``'s structural validation (and
+    error text): returns (descrs, kinds uint8, offs int64), container
+    ``i``'s payload being ``buf[offs[i]:offs[i + 1]]`` and the ops
+    beginning at ``offs[-1]``. Payload lengths a kind forbids (a bitmap
+    payload not exactly 1024 words) raise :class:`_IrregularSnapshot`."""
     if len(buf) < _HEADER.size:
         raise ValueError("roaring: truncated header")
     magic, version, _flags, n_containers, payload_bytes = _HEADER.unpack_from(
@@ -899,6 +1109,49 @@ def flat_from_snapshot(buf) -> tuple[FlatFragment, int]:
             or (plens[is_b] != BITMAP_N_WORDS * 8).any()
             or (plens[kinds == RUN] & 3).any()):
         raise _IrregularSnapshot()
+    return descrs, kinds, offs
+
+
+def directory_from_snapshot(buf) -> ContainerDirectory | None:
+    """The :class:`ContainerDirectory` of snapshot bytes that
+    ``deserialize`` accepted, or None for an irregular snapshot
+    (descriptors out of key order, a key twice, a payload length its
+    kind forbids): :func:`flat_from_snapshot`'s descriptor arithmetic
+    and nothing a container: a canonical snapshot's payloads already lie
+    in key order, so ``payload`` is a view of ``buf`` and no byte of a
+    container is copied."""
+    buf = memoryview(buf)
+    try:
+        descrs, kinds, offs = _snapshot_descriptors(buf)
+    except _IrregularSnapshot:
+        return None
+    keys = descrs["key"].astype(np.int64)
+    if not bool((keys[1:] > keys[:-1]).all()):
+        return None
+    # every regular payload is a whole number of uint16: starts count them
+    first, end = int(offs[0]), int(offs[-1])
+    payload = np.frombuffer(buf, "<u2", count=(end - first) >> 1, offset=first)
+    return ContainerDirectory(keys, kinds,
+                              descrs["nm1"].astype(np.int32) + 1,
+                              (offs - first) >> 1, payload)
+
+
+def flat_from_snapshot(buf) -> tuple[FlatFragment, int]:
+    """Parse a roaring/format.py snapshot straight into a FlatFragment —
+    no Container objects, no per-container ``np.frombuffer`` — with the
+    same structural validation (and error text) as ``deserialize``.
+    Returns (flat, offset-where-ops-begin). The scrub/verify fast path:
+    digesting a fragment file becomes parse → :func:`fragment_ids` →
+    ``block_digests`` with zero per-container dispatches.
+
+    Falls back (ValueError) only on inputs ``deserialize`` also
+    rejects; irregular-but-accepted payloads (bitmap payload not
+    exactly 1024 words) raise :class:`_IrregularSnapshot` so the caller
+    can retry through the reference decoder.
+    """
+    buf = memoryview(buf)
+    descrs, kinds, offs = _snapshot_descriptors(buf)
+    n_containers = int(kinds.size)
     keys = descrs["key"].astype(np.int64)
     order = np.argsort(keys, kind="stable")
     if np.unique(keys).size != keys.size:

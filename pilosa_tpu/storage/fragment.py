@@ -162,6 +162,12 @@ class Fragment:
                     raise CorruptFragmentError(
                         self.path, f"op replay failed: {e}", offset=ops_at,
                     ) from e
+                if self.op_n == 0:
+                    # the bitmap equals the bytes in hand: row-leaf
+                    # misses read them through the container directory
+                    # (kernels.flatten_rows) until the first write
+                    self.bitmap.directory = kernels.directory_from_snapshot(
+                        buf)
         else:
             with open(self.path, "wb") as f:
                 f.write(serialize(self.bitmap))
@@ -698,15 +704,20 @@ class Fragment:
         """Compact: rewrite the file as a clean snapshot, dropping the log
         (reference fragment.snapshot — SURVEY.md §3.3)."""
         with self.lock:
-            self._snapshot_locked()
+            # the fragment stays open and, under the lock, still equals
+            # the bytes serialized (not what a write fault made of them)
+            self.bitmap.directory = kernels.directory_from_snapshot(
+                self._snapshot_locked())
 
-    def _snapshot_locked(self) -> None:
+    def _snapshot_locked(self) -> bytes:
+        """Returns the snapshot's bytes as serialized."""
         if self._file:
             self._file.close()
         tmp = self.path + ".snapshotting"
+        snapshot = serialize(self.bitmap)
         try:
             payload = _faults.disk_filter_write(  # torn-write seam
-                self.path, serialize(self.bitmap)
+                self.path, snapshot
             )
             with open(tmp, "wb") as f:
                 f.write(payload)
@@ -756,6 +767,7 @@ class Fragment:
         self.op_n = 0
         if self._open:
             self._file = open(self.path, "ab")
+        return snapshot
 
     def _row_count_feed(self, n_rows: int):
         """Row-count source for batch bookkeeping: above a few touched

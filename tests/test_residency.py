@@ -1023,3 +1023,55 @@ def test_custom_device_put_never_receives_the_sparse_form(monkeypatch):
     got = batch.stacked_leaf(NoField(), spec, block)  # the cache's own put
     assert asked == [False, True] and cache.sparse_misses == 1
     assert not np.asarray(got).any()
+
+
+def test_set_between_a_directory_decode_and_its_placement_is_replayed(
+        tmp_path, monkeypatch):
+    """ISSUE 40: the miss of a row leaf reads the fragments' container
+    directories. A ``Set`` that lands after that decode and before the
+    leaf is placed drops its fragment's directory, is buffered by the
+    build and replayed on the placed leaf exactly as before: the leaf,
+    and every later answer from it, holds the bit."""
+    from pilosa_tpu.executor import Executor, batch
+    from pilosa_tpu.roaring import kernels
+    from pilosa_tpu.shardwidth import SHARD_WIDTH
+    from pilosa_tpu.storage import Holder
+    from pilosa_tpu.storage import residency
+
+    holder = Holder(str(tmp_path / "data")).open()
+    try:
+        f = holder.create_index("i", track_existence=False).create_field("f")
+        view = f.view("standard", create=True)
+        for shard in range(4):
+            frag = view.fragment(shard, create=True)
+            frag.bulk_import([1] * 50, [i * 17 for i in range(50)])
+            frag.snapshot()  # as an open of the file would leave it
+            assert frag.bitmap.directory is not None
+        new_col = 2 * SHARD_WIDTH + 3  # not in the stride pattern
+        stats = kernels.global_kernel_stats()
+        real, seen = batch.host_leaf, []
+
+        def host_leaf_then_a_set(idx, spec, block, **kw):
+            before = stats.directory_windows, stats.walked_windows
+            out = real(idx, spec, block, **kw)
+            if not seen and spec.field == "f":
+                seen.append((stats.directory_windows - before[0],
+                             stats.walked_windows - before[1], type(out)))
+                f.set_bit(1, new_col)  # the decode above did not see it
+            return out
+
+        monkeypatch.setattr(batch, "host_leaf", host_leaf_then_a_set)
+        ex = Executor(holder)
+        cache = residency.global_row_cache()
+        misses = cache.misses
+        (row,) = ex.execute("i", "Row(f=1)")
+        assert seen == [(4, 0, kernels.SparseRows)]
+        assert new_col in row.columns().tolist()
+        assert [view.fragment(s).bitmap.directory is None
+                for s in range(4)] == [False, False, True, False]
+        (again,) = ex.execute("i", "Row(f=1)")  # the resident leaf
+        assert again.columns().tolist() == row.columns().tolist()
+        assert len(row.columns()) == 4 * 50 + 1
+        assert cache.misses == misses + 1
+    finally:
+        holder.close()

@@ -325,6 +325,88 @@ def test_snapshot_ids_irregular_falls_back():
     assert_ids_identical(ids, ref_to_ids(want))
 
 
+# -------------------------------------------------- container directory
+
+
+def _irregular(bm: RoaringBitmap, how: str) -> bytes:
+    """Snapshot bytes ``deserialize`` accepts and no directory describes."""
+    buf = bytearray(serialize(bm))
+    n = len(bm.keys)
+
+    def descr(i: int) -> slice:
+        return slice(20 + 16 * i, 36 + 16 * i)
+
+    if how == "duplicate_keys":
+        buf[36:44] = buf[20:28]  # the second descriptor's key, the first's
+    elif how == "unsorted_descriptors":
+        # descriptors and payloads written last key first: each payload
+        # still follows its descriptor's turn
+        ends = 20 + 16 * n + np.cumsum(
+            [int.from_bytes(buf[descr(i)][12:], "little") for i in range(n)])
+        starts = [20 + 16 * n, *ends[:-1].tolist()]
+        buf = (buf[:20]
+               + b"".join(buf[descr(i)] for i in reversed(range(n)))
+               + b"".join(buf[starts[i]:ends[i]] for i in reversed(range(n))))
+    else:  # a bitmap payload one word short, the header and the file with it
+        i = next(j for j, k in enumerate(bm.keys)
+                 if bm._containers[k].kind == BITMAP)
+        end = 20 + 16 * n + sum(
+            int.from_bytes(buf[descr(j)][12:], "little") for j in range(i + 1))
+        del buf[end - 8:end]
+        buf[32 + 16 * i:36 + 16 * i] = (8184).to_bytes(4, "little")
+        total = int.from_bytes(buf[12:20], "little") - 8
+        buf[12:20] = total.to_bytes(8, "little")
+    return bytes(buf)
+
+
+@pytest.mark.parametrize("kinds", ["mixed", "array", "bitmap", "run"])
+@pytest.mark.parametrize("seed", range(3))
+def test_directory_of_a_snapshot_is_the_flat_view(kinds, seed):
+    """Keys, kinds, cardinalities and every payload, container for
+    container, without a Container made; nothing of the buffer copied."""
+    rng = np.random.default_rng([40, seed])
+    bm = make_bitmap(rng, n_containers=int(rng.integers(1, 40)), kinds=kinds)
+    buf = serialize(bm)
+    d = kernels.directory_from_snapshot(buf)
+    f = kernels.flatten(bm)
+    np.testing.assert_array_equal(d.keys, f.keys)
+    assert d.keys.dtype == np.int64 and d.starts.size == d.keys.size + 1
+    np.testing.assert_array_equal(d.kinds, f.kinds)
+    np.testing.assert_array_equal(d.cards, f.cards)
+    assert d.all_arrays == (f.arr_sel.size == f.n_containers)
+    assert not d.payload.flags.writeable and not d.payload.flags.owndata
+    for i, (kind, j) in enumerate(zip(f.kinds.tolist(), f.kind_row.tolist())):
+        raw = d.payload[d.starts[i]:d.starts[i + 1]]
+        if kind == ARRAY:
+            want = f.arr_data[f.arr_off[j]:f.arr_off[j + 1]]
+        elif kind == BITMAP:
+            raw, want = raw.view("<u8"), f.bmp_words[j]
+        else:
+            raw = raw.reshape(-1, 2)
+            want = f.run_data[f.run_off[j]:f.run_off[j + 1]]
+        np.testing.assert_array_equal(raw, want)
+    assert kernels.directory_from_snapshot(
+        buf + encode_op(OP_ADD, np.asarray([1, 2], np.uint64))
+    ).starts[-1] == d.starts[-1]
+
+
+def test_directory_of_an_empty_snapshot():
+    d = kernels.directory_from_snapshot(serialize(RoaringBitmap()))
+    assert d.keys.size == 0 and d.starts.tolist() == [0] and d.all_arrays
+
+
+@pytest.mark.parametrize("how", ["duplicate_keys", "unsorted_descriptors",
+                                 "short_bitmap_payload"])
+def test_irregular_snapshot_has_no_directory(how):
+    bm = make_bitmap(np.random.default_rng(41), 12, kinds="bitmap")
+    bad = _irregular(bm, how)
+    got, _ = deserialize(bad)  # still a snapshot the reference reads
+    if how == "unsorted_descriptors":
+        assert got == bm
+    assert kernels.directory_from_snapshot(serialize(bm)) is not None
+    assert kernels.directory_from_snapshot(bad) is None
+
+
 # ------------------------------------------------------- live-path parity
 
 

@@ -17,6 +17,7 @@ bitmaps are built here, so the reference side runs the real
 ``row_words``.
 """
 
+import os
 import tracemalloc
 
 import numpy as np
@@ -26,6 +27,7 @@ from pilosa_tpu.executor import batch
 from pilosa_tpu.executor.executor import _RowSpec
 from pilosa_tpu.roaring import kernels
 from pilosa_tpu.roaring.bitmap import ARRAY, BITMAP, RUN, RoaringBitmap
+from pilosa_tpu.roaring.format import serialize
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD
 from pilosa_tpu.storage.fragment import Fragment
 
@@ -53,13 +55,15 @@ class _Field:
 
 
 class _Index:
-    """``views``: view name -> {shard: RoaringBitmap}, all of field "f"."""
+    """``views``: view name -> {shard: RoaringBitmap, or a Fragment as it
+    is}, all of field "f"."""
 
     scope, name = "", "i"
 
     def __init__(self, views: dict):
         self.fields = {"f": _Field({
-            vname: _View({shard: _fragment(vname, shard, bm)
+            vname: _View({shard: (bm if isinstance(bm, Fragment)
+                                  else _fragment(vname, shard, bm))
                           for shard, bm in by_shard.items()})
             for vname, by_shard in views.items()})}
 
@@ -532,3 +536,276 @@ def test_sparse_is_asked_for_only_where_the_cache_places_the_leaf():
     got = batch.host_leaf(idx, _RowSpec("f", (VIEW,), ROW),
                           batch.ShardBlock([0, 1, 5]))
     assert isinstance(got, np.ndarray)
+
+
+# ------------------------------------- the container directory (ISSUE 40)
+#
+# A fragment opened from a snapshot holds the snapshot's container
+# directory, and ``flatten_rows`` slices a leaf of array containers from
+# the directories instead of walking the containers. The contract is the
+# view the walk gives, element for element, whatever a fragment holds or
+# lacks; the fragments here are real ones, opened from files, and the
+# reference is the walk of the same containers with the directories
+# taken away (and, for the leaf, ``block.stack(host_row)`` as above).
+
+
+@pytest.fixture
+def opened(tmp_path):
+    """``opened(view, shard, bitmap)``: a Fragment opened from a snapshot
+    file of ``bitmap`` (from the file that is there for None), closed
+    when the test ends."""
+    made = []
+
+    def make(view: str, shard: int, bitmap: RoaringBitmap | None) -> Fragment:
+        path = str(tmp_path / view / str(shard))
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        if bitmap is not None:
+            with open(path, "wb") as f:
+                f.write(serialize(bitmap))
+        made.append(Fragment(path, "i", "f", view, shard).open())
+        return made[-1]
+
+    yield make
+    for frag in made:
+        frag.close()
+
+
+def _leaf_bitmaps(idx, spec, shards) -> list:
+    """The (slot, bitmap) pairs ``host_leaf`` hands to ``flatten_rows``."""
+    out = []
+    for vname in spec.views:
+        view = idx.field(spec.field).view(vname)
+        for slot, shard in enumerate(shards if view else ()):
+            frag = view.fragment(shard)
+            if frag is not None:
+                out.append((slot, frag.bitmap))
+    return out
+
+
+def _without_directories(bitmaps: list) -> list:
+    """The same containers behind bitmaps that have no directory."""
+    out = []
+    for slot, bm in bitmaps:
+        bare = RoaringBitmap()
+        bare.keys, bare._containers = bm.keys, bm._containers
+        out.append((slot, bare))
+    return out
+
+
+def _with_container(bitmap: RoaringBitmap, rng, kind: str, row: int):
+    """``bitmap`` and one ``kind`` container in ``row``."""
+    lows = np.unique(_lows(rng, kind)).astype(np.uint64)
+    return RoaringBitmap.from_ids(np.unique(np.concatenate((
+        bitmap.to_ids(), lows + np.uint64((row << 20) + (3 << 16))))))
+
+
+N_DIR = 8  # fragments of a directory case (128 where the case says so)
+
+
+def _directory_case(name: str, rng, opened):
+    """(index, spec, shards, (windows read from a directory, windows
+    walked)) of one case."""
+    spec = _RowSpec("f", (VIEW,), ROW)
+    shards = list(range(N_DIR))
+    by_shard = _exact_bits(rng, 600 * N_DIR, shards=shards)
+    if name == "all_arrays":
+        expect = (N_DIR, 0)
+    elif name in ("bitmap_in_another_row", "run_in_another_row"):
+        kind = "bitmap" if "bitmap" in name else "run"
+        by_shard[2] = _with_container(by_shard[2], rng, kind, ROW + 2)
+        expect = (N_DIR, 0)
+    elif name in ("bitmap_in_the_row", "run_in_the_row"):
+        kind = "bitmap" if "bitmap" in name else "run"
+        by_shard[2] = _with_container(by_shard[2], rng, kind, ROW)
+        expect = (0, N_DIR)  # the whole leaf is walked
+    elif name == "missing_fragment":
+        del by_shard[3]
+        expect = (N_DIR - 1, 0)
+    elif name == "empty_window":
+        by_shard[3] = _exact_bits(rng, 0, shards=(3,))[3]
+        expect = (N_DIR, 0)
+    elif name == "row_past_the_last_key":
+        spec = _RowSpec("f", (VIEW,), 11)  # the files end in row 9
+        expect = (N_DIR, 0)
+    elif name == "row_that_ends_the_file":
+        spec = _RowSpec("f", (VIEW,), 9)
+        expect = (N_DIR, 0)
+    elif name == "several_views":
+        frags = {s: opened(VIEW, s, bm) for s, bm in by_shard.items()}
+        other = {s: opened("standard_2026", s, bm) for s, bm in _exact_bits(
+            rng, 300 * N_DIR, shards=shards).items()}
+        return (_Index({VIEW: frags, "standard_2026": other}),
+                _RowSpec("f", (VIEW, "standard_2026"), ROW), shards,
+                (0, 2 * N_DIR))
+    elif name in ("written_after_open", "reopened_after_snapshot"):
+        shards = list(range(128))
+        by_shard = _exact_bits(rng, 200 * 128, shards=shards)
+        expect = (127, 1) if name == "written_after_open" else (128, 0)
+    frags = {s: opened(VIEW, s, bm) for s, bm in by_shard.items()}
+    assert all(f.bitmap.directory is not None for f in frags.values())
+    if name in ("written_after_open", "reopened_after_snapshot"):
+        frag = frags[77]
+        assert not frag.contains(ROW, 70_001)
+        assert frag.set_bit(ROW, 70_001)
+        assert frag.bitmap.directory is None
+        if name == "reopened_after_snapshot":
+            frag.snapshot()
+            assert frag.bitmap.directory is not None
+            frag.close()
+            frags[77] = opened(VIEW, 77, None)
+            assert frags[77].bitmap.directory is not None
+    return _Index({VIEW: frags}), spec, shards, expect
+
+
+DIRECTORY_CASES = [
+    "all_arrays", "bitmap_in_another_row", "run_in_another_row",
+    "bitmap_in_the_row", "run_in_the_row", "written_after_open",
+    "missing_fragment", "empty_window", "row_past_the_last_key",
+    "row_that_ends_the_file", "several_views", "reopened_after_snapshot",
+]
+
+
+def _staging_of(value: int):
+    def staging(shape):
+        return np.full(shape, value, np.uint32)
+    return staging
+
+
+@pytest.mark.parametrize("name", DIRECTORY_CASES)
+def test_directory_gather_is_the_walked_view(name, opened, poisoned_staging):
+    rng = np.random.default_rng([40, DIRECTORY_CASES.index(name)])
+    idx, spec, shards, expect = _directory_case(name, rng, opened)
+    bitmaps = _leaf_bitmaps(idx, spec, shards)
+    stats = kernels.global_kernel_stats()
+    before = stats.metrics()
+    got = kernels.flatten_rows(bitmaps, spec.row)
+    moved = {k: v - before[k] for k, v in stats.metrics().items()}
+    assert (moved["hostpath_directory_windows_total"],
+            moved["hostpath_walked_windows_total"]) == expect
+    want = kernels.flatten_rows(_without_directories(bitmaps), spec.row)
+    assert moved["hostpath_containers_flattened_total"] == want.n_containers
+    # the leaf comes again: where every fragment has its directory the
+    # view is now read from their stack, and is the same view
+    again = kernels.flatten_rows(bitmaps, spec.row)
+    stacked = type(kernels._stacks.get(id(bitmaps[0][1].directory))
+                   ) is kernels._DirectoryStack
+    assert stacked == (name not in ("several_views", "written_after_open"))
+    after = stats.metrics()
+    assert (after["hostpath_directory_windows_total"]
+            - before["hostpath_directory_windows_total"],
+            after["hostpath_walked_windows_total"]
+            - before["hostpath_walked_windows_total"]) == (
+        2 * expect[0], 2 * expect[1] + len(bitmaps))  # and want's walk
+    for flat in (got, again):
+        for field in ("keys", "kinds", "cards", "kind_row", "arr_sel",
+                      "arr_off", "arr_data", "bmp_sel", "run_sel",
+                      "run_data", "run_off"):
+            a, b = getattr(flat, field), getattr(want, field)
+            assert a.dtype == b.dtype and a.shape == b.shape, field
+            np.testing.assert_array_equal(a, b, err_msg=field)
+        assert flat.kind_counts() == want.kind_counts()
+    if name in ("written_after_open", "reopened_after_snapshot"):
+        # the bit written is in the view: container 1 of slot 77
+        at = int(np.searchsorted(got.keys, 77 * 16 + 1))
+        assert got.keys[at] == 77 * 16 + 1
+        assert 70_001 - 65_536 in got.arr_data[
+            got.arr_off[at]:got.arr_off[at + 1]].tolist()
+    # the same listing of set bits, in one share and in four
+    n_rows = len(shards)
+    for parts in (1, 4):
+        a = kernels.sparse_rows32(got, n_rows, _staging_of(3), parts)
+        b = kernels.sparse_rows32(want, n_rows, _staging_of(3), parts)
+        assert (a is None) == (b is None) == (
+            "in_the_row" in name or name == "several_views")
+        if a is not None:
+            assert (a.n_rows, a.n_pad, a.parts) == (b.n_rows, b.n_pad, parts)
+            assert a.packed.tobytes() == b.packed.tobytes()
+            np.testing.assert_array_equal(a.tiles, b.tiles)
+    # and the same dense leaf, which is the stack of row_words
+    block = batch.ShardBlock(shards)
+    leaf = _assert_identical(idx, spec, block)
+    out = np.full_like(leaf, 0xFFFFFFFF)
+    kernels.dense_rows32(got, out)
+    assert out.tobytes() == leaf.tobytes()
+
+
+def test_a_directory_taken_before_a_write_reads_the_older_snapshot(opened):
+    """The gather holds what it took: a write that lands after a reader
+    took the directory swaps containers the reader never looks at."""
+    rng = np.random.default_rng(401)
+    frag = opened(VIEW, 0, _exact_bits(rng, 900, shards=(0,))[0])
+    bm = frag.bitmap
+    held = RoaringBitmap()
+    held.keys, held._containers = bm.keys, bm._containers
+    held.directory = bm.directory
+    before = kernels.flatten_rows([(0, bm)], ROW)
+    assert frag.set_bit(ROW, 70_001) and bm.directory is None
+    after = kernels.flatten_rows([(0, held)], ROW)  # containers swapped
+    assert after.arr_data.tobytes() == before.arr_data.tobytes()
+    assert kernels.flatten_rows([(0, bm)], ROW).total() == before.total() + 1
+
+
+def test_a_stack_lives_as_long_as_its_directories(opened):
+    """The stack of a leaf's directories is made when the leaf comes the
+    second time, serves it until a write drops one of them (the leaf is
+    then sliced a fragment at a time, the written one walked), and is
+    made anew from the directory a snapshot brings."""
+    rng = np.random.default_rng(402)
+    shards = list(range(6))
+    frags = [opened(VIEW, s, bm) for s, bm in _exact_bits(
+        rng, 3000, shards=shards).items()]
+    bitmaps = [(f.shard, f.bitmap) for f in frags]
+
+    def stack():
+        held = kernels._stacks.get(id(bitmaps[0][1].directory))
+        return held if type(held) is kernels._DirectoryStack else None
+
+    def view():
+        flat = kernels.flatten_rows(bitmaps, ROW)
+        want = kernels.flatten_rows(_without_directories(bitmaps), ROW)
+        assert flat.arr_data.tobytes() == want.arr_data.tobytes()
+        assert flat.keys.tolist() == want.keys.tolist()
+        assert flat.arr_off.tolist() == want.arr_off.tolist()
+        return flat
+
+    view()
+    assert stack() is None  # seen once: remembered, not stacked
+    view()
+    first = stack()
+    assert first is not None
+    assert first.dirs == [b.directory for _, b in bitmaps]
+    assert view().total() == 3000 and stack() is first
+    assert frags[3].set_bit(ROW, 70_001)
+    assert view().total() == 3001  # sliced, the written fragment walked
+    frags[3].snapshot()
+    # the new list is seen once: the stale stack goes, none is made yet
+    assert view().total() == 3001 and stack() is None
+    assert view().total() == 3001
+    second = stack()
+    assert second is not first and second.dirs[3] is frags[3].bitmap.directory
+    # a stale stack is never read: the first fragment's own rewrite
+    assert frags[0].clear_bit(ROW, int(view().arr_data[0]))
+    frags[0].snapshot()
+    assert view().total() == 3000 and view().total() == 3000
+
+
+def test_more_leaves_than_stacks_are_kept_never_pay_for_one(opened):
+    """Leaves that do not come again before they are forgotten are
+    sliced a fragment at a time every time."""
+    rng = np.random.default_rng(403)
+    kernels._stacks.clear()
+    leaves = []
+    for n in range(kernels._STACKS_KEPT + 1):
+        frags = [opened(f"v{n}", s, bm) for s, bm in _exact_bits(
+            rng, 500, shards=(0, 1)).items()]
+        leaves.append([(f.shard, f.bitmap) for f in frags])
+    for _ in range(3):
+        for bitmaps in leaves:
+            assert kernels.flatten_rows(bitmaps, ROW).total() == 500
+    assert len(kernels._stacks) == kernels._STACKS_KEPT
+    assert not any(type(v) is kernels._DirectoryStack
+                   for v in kernels._stacks.values())
+    for _ in range(2):  # one of them comes twice in a row: stacked
+        assert kernels.flatten_rows(leaves[0], ROW).total() == 500
+    assert type(kernels._stacks[id(leaves[0][0][1].directory)]
+                ) is kernels._DirectoryStack
