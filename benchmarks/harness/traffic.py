@@ -15,6 +15,14 @@ Template kinds: ``count`` (Count of a Row or an Intersect of Rows),
 field under a filter), ``groupby`` (GroupBy over Rows dimensions,
 optional filter and Sum aggregate), ``set`` (one Set).
 
+Filter terms (``"filter": [term, ...]``, one term a plain ``Row`` or a
+``Union`` of them, several an ``Intersect``); every constant may be a
+drawn variable:
+  [F, r]                     Row(F=r)
+  [F, {"in": [a, b]}]        Union(Row(F=a), Row(F=b)); one value, Row(F=a)
+  [I, {"lt": v}]             Row(I < v) of int field I
+  [I, {"between": [lo, hi]}] Row(I >< [lo, hi]), both ends inside
+
 Draw kinds (``"draw": {name: spec}``, evaluated in file order):
   {"row_of": F}              a row id of field F, uniform
   {"row_of": F, "top": K}    among F's K commonest rows (config weights)
@@ -23,6 +31,7 @@ Draw kinds (``"draw": {name: spec}``, evaluated in file order):
   {"fields": [F, ...]}       distinct fields; the name "f,g" binds two
   {"column": true}           a column of the loaded index, uniform
   {"affine": [V, a, b]}      a * V + b
+  {"int": [lo, hi]}          an integer, uniform, both ends inside
 """
 
 from __future__ import annotations
@@ -75,12 +84,17 @@ def fields_read(mix: dict, config: dict) -> list[str]:
 
 
 def preload_rows(mix: dict, config: dict) -> list[tuple[str, int]]:
-    """Every (set field, row) a drawn filter constant can name: the rows
-    set-up makes resident before the window when the mix asks for it."""
+    """Every (set field, row) a filter constant can name, drawn or
+    written out in an ``in`` list: the rows set-up makes resident before
+    the window when the mix asks for it."""
     rows = set()
     for t in mix["templates"].values():
         if t["kind"] in WRITE_KINDS:
             continue
+        for f, spec in t.get("filter", ()):
+            if isinstance(spec, dict) and f in config["fields"]:
+                rows.update((f, r) for r in spec.get("in", ())
+                            if isinstance(r, int))
         for draw in t.get("draw", {}).values():
             if "row_of" in draw:
                 rows.update((draw["row_of"], r)
@@ -138,6 +152,8 @@ class Client:
             elif "affine" in draw:
                 v, a, b = draw["affine"]
                 env[name] = a * env[v] + b
+            elif "int" in draw:
+                env[name] = self.rng.randint(*draw["int"])
             else:
                 raise ValueError(f"unknown draw {draw!r}")
         return env
@@ -149,7 +165,7 @@ class Client:
         self.i += 1
         t = self.mix["templates"][name]
         env = self._draw(name)
-        val = lambda x: env.get(x, x) if isinstance(x, str) else x
+        val = lambda x: _bound(x, env)
         sem = {"kind": t["kind"]}
         if "filter" in t:
             sem["filter"] = [(val(f), val(r)) for f, r in t["filter"]]
@@ -167,12 +183,41 @@ class Client:
         return name, render(sem), sem
 
 
+def _bound(x, env: dict):
+    """A template's value as written, the variables it names replaced by
+    what was drawn for them, inside a filter term too."""
+    if isinstance(x, str):
+        return env.get(x, x)
+    if isinstance(x, dict):
+        return {op: _bound(v, env) for op, v in x.items()}
+    if isinstance(x, list):
+        return [_bound(v, env) for v in x]
+    return x
+
+
+def render_term(field: str, spec) -> str:
+    """PQL text of one filter term (the forms the module's text lists)."""
+    if not isinstance(spec, dict):
+        return f"Row({field}={spec})"
+    (op, v), = spec.items()
+    if op == "in":
+        rows = [f"Row({field}={r})" for r in v]
+        if not rows:
+            raise ValueError(f"filter term on {field} names no row")
+        return rows[0] if len(rows) == 1 else f"Union({', '.join(rows)})"
+    if op == "between":
+        return f"Row({field} >< [{v[0]}, {v[1]}])"
+    if op == "lt":
+        return f"Row({field} < {v})"
+    raise ValueError(f"unknown filter term {spec!r} on {field}")
+
+
 def render(sem: dict) -> str:
     """PQL text of a semantic form."""
     kind = sem["kind"]
     if kind == "set":
         return f"Set({sem['column']}, {sem['field']}={sem['row']})"
-    rows = [f"Row({f}={r})" for f, r in sem.get("filter", ())]
+    rows = [render_term(f, r) for f, r in sem.get("filter", ())]
     filt = (rows[0] if len(rows) == 1
             else f"Intersect({', '.join(rows)})" if rows else None)
     if kind == "count":

@@ -21,7 +21,9 @@ result line. ``setup_s`` is (1) to (4).
 
 ``--rehearse`` runs the same code on the CPU at the configuration's
 ``rehearse_shards`` and stamps its line ``cpu``; it is for tests and
-never yields a device number. Without it, no TPU is an error.
+never yields a device number. Without it, no TPU is an error. A
+rehearsal keeps its work files in a directory of its own
+(``.work/<cell>.<pid>``: several may rehearse one cell at once).
 """
 
 from __future__ import annotations
@@ -78,6 +80,30 @@ def load_cell(workload: str):
         die(f"cell {workload} asks for {cell['chips']} chips, its "
             f"configuration for {config['chips']}")
     return manifest, cell, config, mix
+
+
+def work_dir(workload: str, rehearse: bool) -> str:
+    """Where a run keeps its data dir and logs, removed at its start and
+    its end. A rehearsal's is its own (the pid): tests rehearse one cell
+    from several processes at once."""
+    return os.path.join(HERE, ".work", workload + (
+        f".{os.getpid()}" if rehearse else ""))
+
+
+def remove_orphans(workload: str) -> None:
+    """The work directories that killed rehearsals of this cell left
+    behind: ``.work/<cell>.<pid>`` whose process is gone."""
+    top = os.path.join(HERE, ".work")
+    for name in os.listdir(top) if os.path.isdir(top) else ():
+        cell, _, pid = name.rpartition(".")
+        if cell != workload or not pid.isdigit():
+            continue
+        try:
+            os.kill(int(pid), 0)
+        except ProcessLookupError:
+            shutil.rmtree(os.path.join(top, name), ignore_errors=True)
+        except OSError:
+            pass  # another user's process: alive
 
 
 def compile_cache_dir() -> str:
@@ -371,9 +397,10 @@ def main(argv=None) -> int:
         flags.append("--xla_force_host_platform_device_count="
                      f"{cell['chips']}")
         env_extra["XLA_FLAGS"] = " ".join(flags)
+        remove_orphans(args.workload)
     n_shards = config["rehearse_shards"] if args.rehearse else config["shards"]
     index = config["index"]
-    work = os.path.join(HERE, ".work", args.workload)
+    work = work_dir(args.workload, args.rehearse)
     shutil.rmtree(work, ignore_errors=True)
     os.makedirs(work)
     data_dir = os.path.join(work, "data")

@@ -17,7 +17,11 @@ DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
-with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+# BENCH_MANIFEST: a manifest other than the shipped one, for the one test
+# that runs the pins of these files against a copy with entries appended
+# (test_bench_pins.py); nothing else sets it
+with open(os.environ.get("BENCH_MANIFEST")
+          or os.path.join(ROOT, "BENCHMARK.json")) as _f:
     MANIFEST = json.load(_f)
 CELLS = {w["name"]: w for w in MANIFEST["workloads"]}
 CONTROL = {name: ("lost-write" if cell["traffic"] == "point-rw" else "sampled")
@@ -36,9 +40,13 @@ def load_mix(name: str) -> dict:
     return traffic.load_mix(traffic.mix_path(BENCH, name))
 
 
-# Kinds of field, template and draw that no shipped mix uses yet (they are
-# what the SSB flights in PERF.md's Open questions need, and a later PR can
-# only add data files), exercised by the tests on a toy of their own.
+# Kinds of field, template, filter term and draw that no shipped mix uses
+# yet (they are what the SSB flights in PERF.md's Open questions need, and
+# a later PR can only add data files), exercised by the tests on a toy of
+# their own. The last three templates are in the forms of SSB's Q1.1 (a Sum
+# under two range terms and a row), Q3.3 (a GroupBy under two ``in`` terms,
+# on its own dimension and on another field) and Q4.1 (a GroupBy under two
+# rows and an ``in``).
 TOY_CONFIG = {
     "name": "toy", "index": "toy", "shards": 2, "fields": {
         "brand": {"type": "set", "uniform": 80},
@@ -46,14 +54,47 @@ TOY_CONFIG = {
                      "derived": {"field": "brand", "div": 8}},
         "region": {"type": "set", "uniform": 5},
         "year": {"type": "set", "uniform": 7},
+        "city": {"type": "set", "uniform": 20},
+        "nation": {"type": "set", "rows": 5,
+                   "derived": {"field": "city", "div": 4}},
         "revenue": {"type": "int", "min": 0, "max": 5000,
-                    "uniform_int": [100, 5000]}}}
+                    "uniform_int": [100, 5000]},
+        "quantity": {"type": "int", "min": 0, "max": 50,
+                     "uniform_int": [1, 50]},
+        "discount": {"type": "int", "min": 0, "max": 10,
+                     "uniform_int": [0, 10]}}}
 _BY_YEAR_AND_BRAND = {"kind": "groupby", "sum": "revenue"}
 TOY_MIX = {
     "name": "toy-flight", "preload": False,
     "groups": [{"name": "analysts", "clients": 4, "loop": "closed",
-                "rotation": ["category_page", "brand_span", "one_brand"]}],
+                "rotation": ["category_page", "brand_span", "one_brand",
+                             "discounted_revenue", "city_pair",
+                             "nation_by_year"]}],
     "templates": {
+        "discounted_revenue": {
+            "kind": "sum", "sum": "revenue",
+            "filter": [["year", "Y"],
+                       ["discount", {"between": ["LO", "HI"]}],
+                       ["quantity", {"lt": 25}]],
+            "draw": {"Y": {"row_of": "year"}, "LO": {"int": [1, 5]},
+                     "HI": {"affine": ["LO", 1, 2]}}},
+        "city_pair": {
+            "kind": "groupby", "sum": "revenue",
+            "dims": [{"field": "city"}, {"field": "year", "limit": 6}],
+            "filter": [["city", {"in": ["A", "B"]}],
+                       ["region", {"in": ["R", "S"]}]],
+            "draw": {"A": {"row_of": "city", "span": 5},
+                     "B": {"affine": ["A", 1, 4]},
+                     "R": {"row_of": "region", "span": 2},
+                     "S": {"affine": ["R", 1, 1]}}},
+        "nation_by_year": {
+            "kind": "groupby", "sum": "revenue",
+            "dims": [{"field": "year"}, {"field": "nation"}],
+            "filter": [["region", "R"], ["category", "C"],
+                       ["brand", {"in": ["B", "B2"]}]],
+            "draw": {"R": {"row_of": "region"}, "C": {"row_of": "category"},
+                     "B": {"affine": ["C", 8, 0]},
+                     "B2": {"affine": ["C", 8, 5]}}},
         "category_page": dict(
             _BY_YEAR_AND_BRAND,
             dims=[{"field": "year"},

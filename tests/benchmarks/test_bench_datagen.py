@@ -2,6 +2,7 @@
 bytes decode (by a decoder of this file's own) to the bits they were
 made from, and the reference's histogram answers equal brute force."""
 
+import itertools
 import struct
 
 import numpy as np
@@ -169,37 +170,115 @@ def test_reference_holds_reads_between_acknowledged_and_sent_writes(small_ref):
         ref.row_count("pickup_year", 4, acked_only=True)
 
 
+def admitted(c: dict, term) -> np.ndarray:
+    """The columns a filter term admits, by a mask of this file's own."""
+    f, spec = term
+    (op, v), = spec.items() if isinstance(spec, dict) else [("in", [spec])]
+    if op == "in":
+        return np.isin(c[f], v)
+    if op == "between":
+        return (c[f] >= v[0]) & (c[f] <= v[1])
+    assert op == "lt", op
+    return c[f] < v
+
+
+def brute_force(config: dict, c: dict, sem: dict):
+    """One answer from masks over the columns, cell by cell."""
+    sel = np.ones(len(next(iter(c.values()))), bool)
+    for term in sem.get("filter", ()):
+        sel &= admitted(c, term)
+    if sem["kind"] == "count":
+        return int(sel.sum())
+    if sem["kind"] == "sum":
+        return {"value": int(c[sem["sum"]][sel].sum()), "count": int(sel.sum())}
+    pages = []
+    for d in sem["dims"]:
+        rows = [r for r in range(datagen.field_rows(config["fields"][d["field"]]))
+                if (c[d["field"]] == r).any() and r > d.get("previous", -1)]
+        pages.append(rows[:d["limit"]] if d.get("limit") else rows)
+    want = []
+    for rows in itertools.product(*pages):
+        cell = sel.copy()
+        for d, r in zip(sem["dims"], rows):
+            cell &= c[d["field"]] == r
+        if cell.any():
+            item = {"group": [{"field": d["field"], "rowID": r}
+                              for d, r in zip(sem["dims"], rows)],
+                    "count": int(cell.sum())}
+            if sem.get("sum"):
+                item["sum"] = int(c[sem["sum"]][cell].sum())
+            want.append(item)
+    return want
+
+
 @pytest.mark.parametrize("template", sorted(TOY_MIX["templates"]))
 def test_reference_answers_the_toy_templates_as_brute_force(template):
-    """Derived and uniform fields, paged dimensions, a Sum under a filter:
-    what no shipped mix asks yet, against masks over the columns."""
+    """Derived and uniform fields, paged dimensions, a Sum under a filter,
+    range terms on int fields and ``in`` terms on a dimension and beside
+    one: what no shipped mix asks yet, against masks over the columns."""
     c = datagen.make_columns(TOY_CONFIG, 17, 2, list(TOY_CONFIG["fields"]))
     ref = Reference(TOY_CONFIG, c)
     only = dict(TOY_MIX["groups"][0], rotation=[template])
     client = traffic.Client(TOY_MIX, TOY_CONFIG, 2, only, 0, 17, "t")
     for _ in range(6):
         _, _, sem = client.next()
-        sel = np.ones(2 * SW, bool)
-        for f, r in sem["filter"]:
-            sel &= c[f] == r
-        got = ref.answer(sem)
-        if sem["kind"] == "sum":
-            assert got == {"value": int(c["revenue"][sel].sum()),
-                           "count": int(sel.sum())}
-            continue
-        page = sem["dims"][1]
-        brands = range(page["previous"] + 1,
-                       page["previous"] + 1 + page["limit"])
-        want = []
-        for y in range(7):
-            for b in brands:
-                cell = sel & (c["year"] == y) & (c["brand"] == b)
-                if cell.any():
-                    want.append({"group": [{"field": "year", "rowID": y},
-                                           {"field": "brand", "rowID": b}],
-                                 "count": int(cell.sum()),
-                                 "sum": int(c["revenue"][cell].sum())})
-        assert got == want and want
+        want = brute_force(TOY_CONFIG, c, sem)
+        assert ref.answer(sem) == want and want
+
+
+@pytest.mark.parametrize("fold, list_values, how", [
+    (0, 1 << 16, "listed"), (0, 0, "masked"), (40, 6, "mixed")])
+def test_reference_lists_or_masks_what_it_cannot_fold_and_answers_the_same(
+        monkeypatch, fold, list_values, how):
+    """With all the room, every field a filter names is an axis of the
+    table. With none, the columns are listed by those fields' joint value
+    and a request tabulates the ones it admits; with no room for that
+    either, every term is a mask over the columns; with a little of both,
+    some of each. The answers are the same, and a term twice on one field
+    intersects."""
+    from harness import reference
+
+    c = datagen.make_columns(TOY_CONFIG, 19, 2, list(TOY_CONFIG["fields"]))
+    sems = []
+    for template in sorted(TOY_MIX["templates"]):
+        only = dict(TOY_MIX["groups"][0], rotation=[template])
+        client = traffic.Client(TOY_MIX, TOY_CONFIG, 2, only, 0, 19, "m")
+        sems += [client.next()[2] for _ in range(3)]
+    sems.append({"kind": "count", "filter": [("region", {"in": [1, 2]}),
+                                             ("region", 2), ("year", 3)]})
+    sems.append({"kind": "topn", "field": "nation",
+                 "filter": [("quantity", {"between": [26, 50]}),
+                            ("nation", {"in": [0, 3]})]})
+    sems.append({"kind": "count", "filter": [("region", {"in": [7, 1]}),
+                                             ("quantity", {"lt": 1})]})
+    whole = Reference(TOY_CONFIG, c)
+    folded = [whole.answer(s) for s in sems]
+    assert not whole._lists and all(
+        listed == () and set(named) <= set(fields)
+        for (_, named), (fields, listed) in whole._plans.items())
+    monkeypatch.setattr(reference, "FOLD_CELLS", fold)
+    monkeypatch.setattr(reference, "LIST_VALUES", list_values)
+    ref = Reference(TOY_CONFIG, c)
+    assert [ref.answer(s) for s in sems] == folded
+    assert folded[-3] == int(((c["region"] == 2) & (c["year"] == 3)).sum())
+    assert [p["id"] for p in folded[-2]] == sorted(
+        (0, 3), key=lambda n: -int(((c["nation"] == n)
+                                    & (c["quantity"] >= 26)).sum()))
+    assert folded[-1] == 0
+    # of the fields a filter names beside its dimensions: (all, the
+    # table's further axes, what the columns are listed by)
+    plans = [(set(named) - set(dims), set(fields) - set(dims), set(listed))
+             for (dims, named), (fields, listed) in ref._plans.items()]
+    if how == "listed":
+        assert all(not axes and lists == named for named, axes, lists in plans)
+    elif how == "masked":
+        assert all(not axes and not lists for _, axes, lists in plans)
+    else:
+        assert any(axes for _, axes, _ in plans)
+        assert any(named - axes - lists for named, axes, lists in plans)
+    assert bool(ref._lists) == (how != "masked")
+    # only a table over every column is kept
+    assert all(len(k) == 2 for k in ref._hist)
 
 
 def test_reference_tabulates_a_pair_of_fields_once(small_ref):

@@ -103,9 +103,14 @@ def test_both_cells_are_one_chip_and_say_what_they_do():
         assert words in grid["why"], words
     for words in ("2 closed-loop clients", "640", "low concurrency"):
         assert words in scan["why"], words
-    # added at the end: the four cells the benchmark had keep their places
-    assert list(CELLS)[-2:] == [GRID, SCAN] and len(CELLS) == 6
-    assert MANIFEST["configs"][-1]["name"] == "taxi-rides-grid"
+    # added after the four cells the benchmark had, which keep their
+    # places; the two cells' own places are pinned, not the count: later
+    # PRs append cells and configurations after them
+    assert list(CELLS)[:6] == [
+        "taxi-rides.dashboard", "taxi-rides.point-rw",
+        "taxi-rides-x4.dashboard", "ssb-lineorder.brand-lookup", GRID, SCAN]
+    assert [c["name"] for c in MANIFEST["configs"]][:4] == [
+        "taxi-rides", "taxi-rides-x4", "ssb-lineorder", "taxi-rides-grid"]
 
 
 def test_cell_lookup_is_the_rotation_of_four_map_clicks():
@@ -241,13 +246,19 @@ def test_metric_entry_lists_the_grid_cell_alone(name):
 
 def test_the_five_are_appended_and_the_groupby_lists_stand():
     names = [m["name"] for m in MANIFEST["per_layer"]]
-    assert names[-5:] == ["residency_miss_ms", "residency_decode_ms",
-                          "residency_upload_ms", "residency_misses_per_read",
-                          "residency_upload_mib_s"]
-    for m in MANIFEST["per_layer"]:
-        if m["name"] not in NEW:
-            assert GRID not in m.get("workloads", [])
-            assert SCAN not in m.get("workloads", [])
+    # membership and order, not the tail: the five stand together, in
+    # ISSUE 34's order, after PR 32's last; later PRs append after them
+    at = names.index("residency_miss_ms")
+    assert names[at:at + 5] == list(NEW) == [
+        "residency_miss_ms", "residency_decode_ms", "residency_upload_ms",
+        "residency_misses_per_read", "residency_upload_mib_s"]
+    assert names[at - 1] == "candidates_per_level"
+    # the metrics the benchmark had before them keep their lists (the
+    # three GroupBy metrics' among them); one appended later may name
+    # either cell
+    for m in MANIFEST["per_layer"][:at]:
+        assert GRID not in m.get("workloads", [])
+        assert SCAN not in m.get("workloads", [])
 
 
 def test_ratio_metrics_read_the_miss_path():

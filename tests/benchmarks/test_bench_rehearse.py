@@ -214,7 +214,10 @@ def test_written_files_are_what_the_server_would_have_written(
                 got = bits[f"{index}/{f}/standard/{shard}"]
                 assert got == (datagen.SHARD_WIDTH, 0)  # one value a column
             else:
-                v = cols[f][shard << 20:(shard + 1) << 20].astype("int64")
+                # a plane holds value - min (lo_revenue's min is 90000)
+                v = cols[f][shard << 20:(shard + 1) << 20].astype(
+                    "int64") - spec["min"]
+                assert int(v.min()) >= 0
                 ones = sum(int(((v >> i) & 1).sum()) for i in range(32))
                 got = bits[f"{index}/{f}/bsig_{f}/{shard}"]
                 assert got == (ones + datagen.SHARD_WIDTH, 0)

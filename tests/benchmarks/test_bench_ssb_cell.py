@@ -127,10 +127,8 @@ def test_written_files_hold_the_columns_bits(tmp_path):
     counts in them exactly the columns' bits: one a column in each set
     field (array containers under the 1000 brands), and in the BSI field
     the existence row plus the ones of ``value - min``, which is what a
-    plane holds. (``test_bench_rehearse.py``'s case for this configuration
-    counts the ones of the raw values, which is the same thing only while
-    ``min`` is 0, as in the taxi configurations: it is red, and only a
-    ``benchmark`` PR may edit it.)"""
+    plane holds (``test_bench_rehearse.py``'s case for this configuration
+    counted the ones of the raw values until ISSUE 41 mended it)."""
     config, mix = load_config("ssb-lineorder"), load_mix("brand-lookup")
     fields = traffic.fields_read(mix, config)
     cols = datagen.make_columns(config, 3_200_000_051, 2, fields)

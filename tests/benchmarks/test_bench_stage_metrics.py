@@ -64,7 +64,14 @@ def test_the_twelve_are_listed_after_the_seven_the_benchmark_had():
                          "residency_hit_share", "compiles_in_window",
                          "fsyncs_per_write", "write_ack_p50_ms",
                          "device_idle_share"]
-    assert set(names[7:]) == STAGE_METRICS
+    # after the seven and together, in ISSUE 25's order; later PRs append
+    # past them, so the tail is theirs and no count is pinned
+    assert set(names[7:]) >= STAGE_METRICS
+    assert names[7:7 + len(STAGE_METRICS)] == [
+        "http_parse_ms", "wave_wait_ms", "dispatcher_busy_share",
+        "plan_operands_ms", "dispatch_ms", "resolve_ms", "encode_ms",
+        "wal_barrier_ms", "residency_patch_ms", "residency_lock_wait_ms",
+        "host_attributed_share", "program_compiles_in_window"]
 
 
 @pytest.mark.parametrize("name", sorted(STAGE_METRICS))

@@ -82,14 +82,31 @@ def test_group_spaces_do_not_depend_on_the_seed(cell):
 def test_constants_stay_inside_their_domains():
     cell, config = TOY_CELL, config_and_mix(TOY_CELL)[0]
     from harness.datagen import field_rows
+    seen = set()
     for c in stream(cell, SEEDS[2]):
-        for _, _, sem in c:
-            for f, r in sem.get("filter", ()):
-                assert 0 <= r < field_rows(config["fields"][f])
+        for name, _, sem in c:
+            for f, spec in sem.get("filter", ()):
+                field = config["fields"][f]
+                (op, v), = (spec.items() if isinstance(spec, dict)
+                            else [("in", [spec])])
+                seen.add((name, f, op))
+                if field["type"] == "set":
+                    assert op == "in" and v
+                    assert all(0 <= r < field_rows(field) for r in v)
+                else:
+                    lo, hi = field["uniform_int"]
+                    ends = v if op == "between" else [v]
+                    assert all(lo <= x <= hi for x in ends)
+                    assert ends == sorted(ends)
             for d in sem.get("dims", ()):
                 if d.get("previous") is not None:
                     n = field_rows(config["fields"][d["field"]])
                     assert -1 <= d["previous"] <= n - d["limit"] - 1
+    # the toy's terms of every kind were met
+    assert {("discounted_revenue", "discount", "between"),
+            ("discounted_revenue", "quantity", "lt"),
+            ("city_pair", "city", "in"), ("city_pair", "region", "in"),
+            ("nation_by_year", "brand", "in")} <= seen
 
 
 def test_a_big_seed_is_taken():
@@ -113,6 +130,65 @@ def test_render_is_the_pql_the_issue_names():
         "aggregate=Sum(field=\"lo_revenue\"))")
     assert traffic.render({"kind": "set", "column": 9, "field": "f",
                            "row": 2}) == "Set(9, f=2)"
+
+
+def test_render_writes_the_new_terms_in_the_parsers_own_forms():
+    """ISSUE 41: a Union inside the Intersect, the BSI comparisons and the
+    between form of ``pilosa_tpu/pql/parser.py``; a one-value ``in`` is the
+    plain Row, a lone term stands without an Intersect."""
+    assert traffic.render({"kind": "sum", "sum": "lo_ext_disc", "filter": [
+        ("d_year", 1), ("lo_discount", {"between": [1, 3]}),
+        ("lo_quantity", {"lt": 25})]}) == (
+        "Sum(Intersect(Row(d_year=1), Row(lo_discount >< [1, 3]), "
+        "Row(lo_quantity < 25)), field=\"lo_ext_disc\")")
+    assert traffic.render({"kind": "count", "filter": [
+        ("c_city", {"in": [221, 225]}), ("s_city", {"in": [221]})]}) == (
+        "Count(Intersect(Union(Row(c_city=221), Row(c_city=225)), "
+        "Row(s_city=221)))")
+    assert traffic.render({"kind": "count", "filter": [
+        ("p_mfgr", {"in": [0, 1]})]}) == (
+        "Count(Union(Row(p_mfgr=0), Row(p_mfgr=1)))")
+    assert traffic.render({"kind": "count", "filter": [("q", {"lt": 7})]}) \
+        == "Count(Row(q < 7))"
+    # SSB needs "lt" and "between" alone; "gte" and the like are not said
+    for bad in ({"near": 3}, {"gte": 3}, {"in": []}):
+        with pytest.raises(ValueError):
+            traffic.render({"kind": "count", "filter": [("q", bad)]})
+
+
+def test_an_int_draw_is_uniform_over_both_ends_and_moves_a_window():
+    config, mix = config_and_mix(TOY_CELL)
+    only = dict(mix["groups"][0], rotation=["discounted_revenue"])
+    client = traffic.Client(mix, config, 2, only, 0, SEEDS[1], "w")
+    windows = collections.Counter()
+    for _ in range(600):
+        _, pql, sem = client.next()
+        lo, hi = dict(sem["filter"])["discount"]["between"]
+        assert hi - lo == 2 and f"Row(discount >< [{lo}, {hi}])" in pql
+        assert "Row(quantity < 25)" in pql
+        windows[lo] += 1
+    assert sorted(windows) == [1, 2, 3, 4, 5]  # windows of one width
+    assert min(windows.values()) > 80
+
+
+def test_fields_read_and_preload_rows_learn_the_new_terms():
+    """An int field named only in a range term is materialised; the rows
+    an ``in`` names are preloaded, drawn or written out."""
+    config, mix = config_and_mix(TOY_CELL)
+    assert {"quantity", "discount", "city", "nation"} <= set(
+        traffic.fields_read(mix, config))
+    only = {"name": "m", "groups": [], "templates": {"t": {
+        "kind": "count",
+        "filter": [["quantity", {"lt": 26}], ["region", {"in": [1, "R"]}],
+                   ["category", {"in": [0, 9]}]],
+        "draw": {"R": {"row_of": "region", "top": 2}}}}}
+    assert traffic.fields_read(only, config) == ["category", "region",
+                                                 "quantity"]
+    rows = traffic.preload_rows(only, config)
+    assert ("category", 0) in rows and ("category", 9) in rows
+    assert ("region", 1) in rows
+    assert {r for f, r in rows if f == "region"} >= {1} and all(
+        f in ("category", "region") for f, _ in rows)
 
 
 def test_fields_read_are_only_what_the_mix_touches():
