@@ -845,6 +845,24 @@ def groupby_tile_plan(dim_rows: tuple, other_rows: int, slots: int,
         paged[resident.index(max(resident))] = True
 
 
+def groupby_level_plan(filt_structure, leaf_ndims, dim_rows: tuple,
+                       n_planes: int, slots: int, words: int):
+    """(filter folded first, word-tile width, which dimensions are paged)
+    of a level program over a device's ``slots`` shard slots, from static
+    shapes: what the body builds its kernel by, and what the executor
+    counts a paged program by where it dispatches one. A filter of
+    shifts, BSI comparisons or an empty tree is folded by XLA into one
+    row a slot before the kernel; row leaves under set operations are
+    combined a word at a time inside it."""
+    folds = filt_structure is not None and not (
+        leaf_ndims and all(n == 2 for n in leaf_ndims)
+        and elementwise_words(filt_structure))
+    n_filt = 1 if folds else len(leaf_ndims)
+    tw, paged = groupby_tile_plan(dim_rows, n_filt + n_planes,
+                                  min(_SUBLANES, slots), words)
+    return folds, tw, paged
+
+
 # off the TPU the same kernel body runs through Pallas' interpreter
 _pallas_interpret = pallas_interpret
 
@@ -873,9 +891,10 @@ def groupby_level_body(leaves, idxs, scalars, filt_structure, n_filt: int,
     slots, _, words = dim_mats[0].shape
     c_pad = idxs.shape[1]
 
-    if filt_structure is not None and not (
-            n_filt and all(f.ndim == 2 for f in filt_leaves)
-            and elementwise_words(filt_structure)):
+    folds, tw, paged = groupby_level_plan(
+        filt_structure, [f.ndim for f in filt_leaves],
+        tuple(m.shape[1] for m in dim_mats), n_planes, slots, words)
+    if folds:
         # shifts, BSI comparisons and empty trees are evaluated once by
         # XLA into one row a slot; the kernel sees a single leaf
         with jax.named_scope("groupby_filter"):
@@ -889,8 +908,6 @@ def groupby_level_body(leaves, idxs, scalars, filt_structure, n_filt: int,
 
     quantities = max(n_planes, 1)   # count, n_g, depth planes
     sb = min(_SUBLANES, slots)
-    tw, paged = groupby_tile_plan(tuple(m.shape[1] for m in dim_mats),
-                                  n_filt + n_planes, sb, words)
     paged_dims = [d for d in range(n_gather) if paged[d]]
     cw = min(_CHUNK_WORDS, tw)
     unroll = min(tw // cw, max(1, _CHUNK_UNROLL // quantities))

@@ -46,6 +46,7 @@ from pilosa_tpu.utils.cost import current_cost, use_node
 from pilosa_tpu.utils.tracing import (
     note_groupby_level,
     note_groupby_operand_placement,
+    note_groupby_pruned,
     note_groupby_range_dims,
     stage,
     staged,
@@ -70,11 +71,21 @@ TOPN_CANDIDATE_FACTOR = 4
 # a pipelined TopN stream still buckets into shared program shapes.
 TOPN_MATRIX_BUDGET_BYTES = 1 << 30
 
-# GroupBy cross-products at or below this size are evaluated in a single
-# level (one device sync); larger ones use per-dimension prefix pruning
+# A GroupBy of two or more dimensions is counted in a single level of
+# every group (one device sync) while its cross product is at most this
+# many groups AND that level is at most GROUPBY_DENSE_MAX_PROGRAMS
+# programs; past either the prefixes are pruned a dimension at a time
 # (one sync per dimension). No level holds its group masks in HBM
 # (batch.groupby_level_body), so memory does not bound either path.
 GROUPBY_DENSE_MAX_GROUPS = 4096
+# Two, because pruning d >= 2 dimensions dispatches at least d programs
+# and blocks on d - 1 readbacks, so a dense level of two programs never
+# dispatches more than pruning would. A program is what the level
+# kernel's accumulator block holds (batch.groupby_chunk_groups
+# candidates: 256 with a 24-bit Sum, 8,192 count-only); count-only a
+# candidate still costs its row reads, which is why the group bound
+# stands beside this one.
+GROUPBY_DENSE_MAX_PROGRAMS = 2
 
 _RESERVED_ARGS = {"_field", "_col", "from", "to", "n", "limit", "offset",
                   "previous", "column", "filter", "field", "ids", "timestamp",
@@ -1824,9 +1835,7 @@ class Executor:
             )
 
         sizes = tuple(len(row_ids) for _, row_ids in dims)
-        total_groups = 1
-        for n in sizes:
-            total_groups *= n
+        n_planes = 0 if agg_field is None else 2 + agg_field.options.bit_depth
 
         def collect(cand, counts_arr, agg_arrs) -> GroupCounts:
             return self._groupby_counts(
@@ -1834,7 +1843,7 @@ class Executor:
                 len(block.shards) * SHARD_WIDTH, limit, having=having,
             )
 
-        if total_groups <= GROUPBY_DENSE_MAX_GROUPS:
+        if _groupby_dense(sizes, n_planes):
             # small cross-product: every group in one level; the level
             # program is enqueued NOW, the readback waits for result()
             cand = _dense_candidates(sizes)
@@ -1867,29 +1876,41 @@ class Executor:
             # lossless, so reported counts — and therefore results —
             # stay byte-identical.
             quant = self._quant_ranking_active()
-            cand = np.zeros((1, 0), np.int32)
-            counts_arr, agg_arrs = None, None
-            for k in range(len(dims)):
-                cand = _index_cross(cand, sizes[k])
-                last = k == len(dims) - 1
+            note_groupby_pruned()
+            final = len(dims) - 1
+
+            def level(k: int, prefixes: np.ndarray):
+                """Prefixes extended by dimension k, counted, and the
+                non-empty ones kept: (candidates, counts, aggregates)."""
+                cand = _index_cross(prefixes, sizes[k])
                 counts_arr, agg_arrs = self._groupby_eval_level(
                     block, filt_leaves, filt_node, scalars,
                     dim_mats[: k + 1], cand,
-                    planes if last else None,
-                    agg_field if last else None,
-                    quantized=quant and not last,
+                    planes if k == final else None,
+                    agg_field if k == final else None,
+                    quantized=quant and k != final,
                     # the first level is every row of its dimension, as
                     # a dense level of one; the later ones are whatever
                     # survived the readback
                     cand_key=(sizes[0],) if k == 0 else None,
                 )
                 keep = counts_arr > 0
-                cand = cand[keep]
-                counts_arr = counts_arr[keep]
                 if agg_arrs is not None:
                     agg_arrs = (agg_arrs[0][keep], agg_arrs[1][:, keep])
+                return cand[keep], counts_arr[keep], agg_arrs
+
+            cand = np.zeros((1, 0), np.int32)
+            for k in range(final):
+                # a round trip the dense path does not make (enqueue,
+                # blocking readback, choice of survivors), timed apart
+                # from the resolve or execute it runs inside
+                with stage("executor.prune_level", level=k):
+                    cand, _, _ = level(k, cand)
                 if cand.shape[0] == 0:
                     return GroupCounts()
+            cand, counts_arr, agg_arrs = level(final, cand)
+            if cand.shape[0] == 0:
+                return GroupCounts()
             return collect(cand, counts_arr, agg_arrs)
 
         if pipeline:
@@ -1949,6 +1970,12 @@ class Executor:
         args = list(filt_leaves) + list(dim_mats)
         if has_agg:
             args.append(planes)
+        # static a program: the plan the body builds its kernel by
+        pages = any(batch.groupby_level_plan(
+            filt_node, [leaf.ndim for leaf in filt_leaves],
+            tuple(m.shape[1] for m in dim_mats), n_planes,
+            dim_mats[0].shape[0] // self.arg_shard_factor,
+            dim_mats[0].shape[2])[2])
 
         packs = []
         layout = []  # (padded, actual) per chunk
@@ -1970,7 +1997,8 @@ class Executor:
             self._note_reduce("groupby_q" if quantized else "groupby",
                               packs[-1].shape, block.padded)
             layout.append((padded, actual))
-        note_groupby_level(len(packs), c_total)
+        note_groupby_level(len(packs), c_total,
+                           paged=len(packs) if pages else 0)
 
         if len(packs) == 1:
             return packs[0], layout
@@ -2324,12 +2352,25 @@ def _index_cross(cand: np.ndarray, n: int) -> np.ndarray:
     return np.concatenate([left, right], axis=1)
 
 
+def _groupby_dense(sizes: tuple, n_planes: int) -> bool:
+    """Whether a GroupBy over dimensions of ``sizes`` rows is counted in
+    ONE level of every group rather than by prefix pruning (n_planes:
+    the aggregate's depth + 2, 0 without one). One dimension has no
+    prefix to prune; more are dense while their cross product is at most
+    GROUPBY_DENSE_MAX_GROUPS groups and GROUPBY_DENSE_MAX_PROGRAMS level
+    programs."""
+    groups = math.prod(sizes)
+    return len(sizes) == 1 or groups <= min(
+        GROUPBY_DENSE_MAX_GROUPS,
+        GROUPBY_DENSE_MAX_PROGRAMS * batch.groupby_chunk_groups(n_planes))
+
+
 @functools.lru_cache(maxsize=64)
 def _dense_candidates(sizes: tuple) -> np.ndarray:
     """Every index tuple of a cross-product of ``sizes`` rows, in
     lexicographic order: a dense level's candidates, the same for every
-    request of a query template (at most GROUPBY_DENSE_MAX_GROUPS rows).
-    Shared between callers, so read-only."""
+    request of a query template (at most GROUPBY_DENSE_MAX_GROUPS rows
+    past one dimension). Shared between callers, so read-only."""
     cand = np.zeros((1, 0), np.int32)
     for n in sizes:
         cand = _index_cross(cand, n)

@@ -540,6 +540,7 @@ STAGES = ("http.query",) + TOP_LEVEL_STAGES + (
     "residency.upload", "residency.patch", "residency.lock_wait",
     "device.upload", "device.replicate", "device.dispatch",
     "device.readback", "fragment.write", "wal.commit",
+    "executor.prune_level",
 )
 # The outermost stage of each thread role (``enter_thread_role``): its
 # exit refreshes the thread's cumulative CPU.
@@ -848,19 +849,26 @@ def thread_metrics() -> dict:
 # One says how many level programs had their packed operand placed on
 # the device(s) for them: the rest found the array an earlier level of
 # the same content had placed (Executor._level_operand), so
-# 1 - placements / programs is that memo's hit share.
+# 1 - placements / programs is that memo's hit share. Two say which
+# path the work took: the GroupBys counted by prefix pruning (a level a
+# dimension, a blocking readback between levels; the rest of
+# results_total took one dense level or had nothing to count), and the
+# level programs that paged at least one dimension (its rows copied a
+# tile a candidate from HBM: batch.groupby_tile_plan).
 
 _groupby_lock = threading.Lock()
 _groupby_stats = {"levels": 0, "programs": 0, "candidates": 0,
                   "placements": 0, "range_dims": 0, "results": 0,
-                  "materialized": 0}
+                  "materialized": 0, "pruned": 0, "paged_programs": 0}
 
 
-def note_groupby_level(programs: int, candidates: int) -> None:
+def note_groupby_level(programs: int, candidates: int,
+                       paged: int = 0) -> None:
     with _groupby_lock:
         _groupby_stats["levels"] += 1
         _groupby_stats["programs"] += programs
         _groupby_stats["candidates"] += candidates
+        _groupby_stats["paged_programs"] += paged
 
 
 def note_groupby_operand_placement() -> None:
@@ -883,6 +891,11 @@ def note_groupby_materialized() -> None:
         _groupby_stats["materialized"] += 1
 
 
+def note_groupby_pruned() -> None:
+    with _groupby_lock:
+        _groupby_stats["pruned"] += 1
+
+
 def groupby_metrics() -> dict:
     """The ``groupby`` block of /metrics and /debug/vars."""
     with _groupby_lock:
@@ -892,7 +905,9 @@ def groupby_metrics() -> dict:
                 "operand_placements_total": _groupby_stats["placements"],
                 "range_dims_total": _groupby_stats["range_dims"],
                 "results_total": _groupby_stats["results"],
-                "results_materialized_total": _groupby_stats["materialized"]}
+                "results_materialized_total": _groupby_stats["materialized"],
+                "pruned_total": _groupby_stats["pruned"],
+                "paged_programs_total": _groupby_stats["paged_programs"]}
 
 
 # ------------------------------------------------- device compiles, memory

@@ -245,7 +245,7 @@ class TestDistGroupBy:
         'too big' for a single level) and check it matches the dense path."""
         import pilosa_tpu.executor.executor as ex_mod
 
-        monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_GROUPS", 1)
+        monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_PROGRAMS", 0)
         r1, r2 = both(env, "GroupBy(Rows(f), Rows(g))")
         assert self.groups_json(r1) == self.groups_json(r2)
 

@@ -225,15 +225,17 @@ CASES = {
 }
 
 # How the final level is evaluated: every group in one dense level, the
-# pruned path (taken past GROUPBY_DENSE_MAX_GROUPS groups), and a level
+# pruned path (taken past GROUPBY_DENSE_MAX_PROGRAMS programs), and a level
 # of more candidates than one program holds.
 PATHS = ["dense", "pruned", "chunked"]
 
 
 def take_path(path, monkeypatch):
     if path == "pruned":
-        monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_GROUPS", 1)
+        monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_PROGRAMS", 0)
     elif path == "chunked":
+        # a dense level of many programs, which the rule would prune
+        monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_PROGRAMS", 10 ** 9)
         monkeypatch.setattr(batch, "groupby_chunk_groups",
                             lambda n_planes: 4)
 
@@ -271,15 +273,16 @@ def test_bytes_and_json_equal_the_per_group_reference(env, case, path,
 
 
 def test_a_cross_product_past_the_dense_bound_is_pruned(tmp_path):
-    """More than GROUPBY_DENSE_MAX_GROUPS groups with nothing patched:
-    17 x 16 x 16 = 4,352 candidates, most of them empty."""
+    """More than GROUPBY_DENSE_MAX_PROGRAMS programs with nothing patched:
+    17 x 16 x 16 = 4,352 candidates (9 programs of the 512 a 13-bit Sum
+    leaves room for), most of them empty."""
     holder = Holder(str(tmp_path / "data")).open()
     try:
         ex = Executor(holder)
         idx = holder.create_index("p", track_existence=False)
         data = Data()
         sizes = {"x": 17, "y": 16, "z": 16}
-        assert np.prod(list(sizes.values())) > ex_mod.GROUPBY_DENSE_MAX_GROUPS
+        assert not ex_mod._groupby_dense(tuple(sizes.values()), 2 + 13)
         for i, (name, n) in enumerate(sizes.items()):
             f = idx.create_field(name)
             data.cols[name] = {}

@@ -217,6 +217,8 @@ def test_each_chunk_of_a_level_is_an_entry_of_its_own(data, builder,
     nothing."""
     holder, columns = data
     monkeypatch.setattr(batch, "groupby_chunk_groups", lambda n_planes: 8)
+    # three programs would be pruned by the rule: this is the dense level
+    monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_PROGRAMS", 3)
     want = numpy_groupby(columns, ("f", "g"))
     ex = executor(holder, builder)
     with Around(builder) as first:
@@ -296,12 +298,12 @@ def test_the_bound_clears_whole_and_refills(data, builder):
 @pytest.mark.parametrize("builder", BUILDERS)
 def test_pruned_levels_after_the_first_do_not_enter(data, builder,
                                                     monkeypatch):
-    """Past GROUPBY_DENSE_MAX_GROUPS a level's candidates are what the
+    """On the pruned path a level's candidates are what the
     level before it kept: read back, one-shot, placed and forgotten. The
     first level is every row of its dimension, the entry a dense level of
     that one dimension uses too."""
     holder, columns = data
-    monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_GROUPS", 1)
+    monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_PROGRAMS", 0)
     want = numpy_groupby(columns, ("f", "g"))
     ex = executor(holder, builder)
     with Around(builder) as first:
@@ -313,7 +315,7 @@ def test_pruned_levels_after_the_first_do_not_enter(data, builder,
     assert (again.levels, again.programs, again.placements,
             again.staged) == (2, 2, 1, 1)
     assert len(ex._placed_operands) == 1
-    monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_GROUPS", 4096)
+    monkeypatch.undo()
     with Around(builder) as dense:
         assert answer(ex, "GroupBy(Rows(f))") == numpy_groupby(columns,
                                                                ("f",))

@@ -308,7 +308,7 @@ class TestQuantizedRanking:
         lossless — results byte-identical."""
         import pilosa_tpu.executor.executor as ex_mod
 
-        monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_GROUPS", 1)
+        monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_PROGRAMS", 0)
         dist = DistExecutor(qholder, make_mesh(4, groups=2),
                             quantized_ranking=True, verify_quantized=True)
         pql = "GroupBy(Rows(many), Rows(few))"

@@ -149,7 +149,7 @@ def test_every_stage_series_is_present_and_zero_on_the_first_scrape(tmp_path):
         samples = dict(line.split(" ", 1) for line in text.splitlines()
                        if line and not line.startswith("#")
                        and "{" not in line)
-        assert len(STAGES) == 25  # wal.commit joined in PR 36
+        assert len(STAGES) == 26  # executor.prune_level joined in PR 42
         for name in STAGES:
             for suffix in SUFFIXES:
                 series = f"pilosa_tpu_stage_{_key(name)}{suffix}"
