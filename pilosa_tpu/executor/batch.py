@@ -829,8 +829,10 @@ def groupby_tile_plan(dim_rows: tuple, other_rows: int, slots: int,
     The tile is as wide as twice those rows (the pipeline's two buffers)
     allow for one block of shard slots. Where that would leave less than
     one chunk of the walk, the dimension with the most rows is paged
-    instead: it stays in HBM and each candidate's row tile is copied in
-    by its index (two tiles a paged dimension, whatever its row count).
+    instead: it stays in HBM and a candidate's row tile is copied in by
+    its index where that differs from the candidate's before, else read
+    again where it lies (two tiles a paged dimension, whatever its row
+    count: the row in use and the next one arriving).
     """
     budget = 3 * GROUPBY_VMEM_BYTES // 8
     paged = [False] * len(dim_rows)
@@ -861,6 +863,19 @@ def groupby_level_plan(filt_structure, leaf_ndims, dim_rows: tuple,
     tw, paged = groupby_tile_plan(dim_rows, n_filt + n_planes,
                                   min(_SUBLANES, slots), words)
     return folds, tw, paged
+
+
+def groupby_paged_rows(cand: np.ndarray, paged: tuple, chunk: int) -> tuple:
+    """(row tiles named, row tiles copied) a grid step by the programs
+    of one level, ``chunk`` of the candidates [C, n_gather] each: every
+    candidate names a row of every paged dimension, and the kernel
+    copies one where a paged column's index differs from the
+    candidate's before (a program's first candidate always copies).
+    (0, 0) where nothing pages."""
+    cols = [d for d, p in enumerate(paged) if p]
+    copies = sum(np.count_nonzero(np.diff(cand[lo:lo + chunk, d])) + 1
+                 for lo in range(0, len(cand), chunk) for d in cols)
+    return len(cand) * len(cols), int(copies)
 
 
 # off the TPU the same kernel body runs through Pallas' interpreter
@@ -974,22 +989,35 @@ def groupby_level_body(leaves, idxs, scalars, filt_structure, n_filt: int,
 
         n_cand = idx_ref[n_gather * c_pad]
 
-        def candidate(c, _):
+        def candidate(c, halves):
+            """Candidate c's counts into its accumulator rows. halves: for
+            each paged dimension, the half of its buffer that holds the
+            row tile of the candidate before (0 where nothing pages)."""
             at = [idx_ref[d * c_pad + c] for d in range(n_gather)]
-            if paged_dims:
-                half = lax.rem(c, 2)
-                for d in paged_dims:
-                    page(d, c, half).wait()
+            half = {}
+            for d, held in zip(paged_dims, halves if paged_dims else ()):
+                # a row tile is copied where the candidate's row changes
+                # (a pruned level's candidates are prefix-major: the
+                # older dimensions repeat theirs); a new grid step is a
+                # new tile
+                fresh = (c == 0) | (
+                    at[d] != idx_ref[d * c_pad + jnp.maximum(c - 1, 0)])
+                half[d] = jnp.where(fresh, 1 - held, held)
 
-                # the next candidate's rows arrive while this one counts
-                @pl.when(c + 1 < n_cand)
+                @pl.when(fresh)
                 def _():
-                    for d in paged_dims:
-                        page(d, c + 1, 1 - half).start()
+                    page(d, c, half[d]).wait()
+
+                # the next candidate's row arrives while this one counts,
+                # over the row before this one, which nobody reads again
+                @pl.when((c + 1 < n_cand)
+                         & (idx_ref[d * c_pad + c + 1] != at[d]))
+                def _():
+                    page(d, c + 1, 1 - half[d]).start()
 
             def row(d, k):
                 if paged[d]:
-                    return page_refs[d][0][half, :, chunk(k)]
+                    return page_refs[d][0][half[d], :, chunk(k)]
                 return dim_refs[d][at[d], :, chunk(k)]
 
             def counted(k):
@@ -1022,7 +1050,7 @@ def groupby_level_body(leaves, idxs, scalars, filt_structure, n_filt: int,
                 out_row = pl.ds(q * c_pad + c, 1)
                 out_ref[out_row, :] = out_ref[out_row, :] + jnp.sum(
                     a, axis=0, keepdims=True)
-            return 0
+            return tuple(half[d] for d in paged_dims) if paged_dims else 0
 
         if paged_dims:
             @pl.when(n_cand > 0)
@@ -1030,8 +1058,10 @@ def groupby_level_body(leaves, idxs, scalars, filt_structure, n_filt: int,
                 for d in paged_dims:
                     page(d, 0, 0).start()
 
-        # the candidates after the last real one are padding
-        lax.fori_loop(0, n_cand, candidate, 0)
+        # the candidates after the last real one are padding; the first
+        # one finds its rows in half 0
+        lax.fori_loop(0, n_cand, candidate,
+                      (jnp.int32(1),) * len(paged_dims) if paged_dims else 0)
 
     def by_row(n_rows):
         return pl.BlockSpec((n_rows, sb, tw), lambda i, j, *_: (0, i, j))

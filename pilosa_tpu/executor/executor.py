@@ -1971,11 +1971,11 @@ class Executor:
         if has_agg:
             args.append(planes)
         # static a program: the plan the body builds its kernel by
-        pages = any(batch.groupby_level_plan(
+        paged = batch.groupby_level_plan(
             filt_node, [leaf.ndim for leaf in filt_leaves],
             tuple(m.shape[1] for m in dim_mats), n_planes,
             dim_mats[0].shape[0] // self.arg_shard_factor,
-            dim_mats[0].shape[2])[2])
+            dim_mats[0].shape[2])[2]
 
         packs = []
         layout = []  # (padded, actual) per chunk
@@ -1997,8 +1997,10 @@ class Executor:
             self._note_reduce("groupby_q" if quantized else "groupby",
                               packs[-1].shape, block.padded)
             layout.append((padded, actual))
+        row_visits, row_copies = batch.groupby_paged_rows(cand, paged, chunk)
         note_groupby_level(len(packs), c_total,
-                           paged=len(packs) if pages else 0)
+                           paged=len(packs) if any(paged) else 0,
+                           row_visits=row_visits, row_copies=row_copies)
 
         if len(packs) == 1:
             return packs[0], layout

@@ -853,22 +853,30 @@ def thread_metrics() -> dict:
 # path the work took: the GroupBys counted by prefix pruning (a level a
 # dimension, a blocking readback between levels; the rest of
 # results_total took one dense level or had nothing to count), and the
-# level programs that paged at least one dimension (its rows copied a
-# tile a candidate from HBM: batch.groupby_tile_plan).
+# level programs that paged at least one dimension (its rows stay in
+# HBM and the kernel copies a row tile in by the candidate's index:
+# batch.groupby_tile_plan). Two say how much those programs copy: the
+# row tiles their candidates name (candidates x paged dimensions, a grid
+# step), and the ones the kernel copies, which is one where a paged
+# index differs from the candidate's before (batch.groupby_paged_rows):
+# copies / visits is the share of copies made.
 
 _groupby_lock = threading.Lock()
 _groupby_stats = {"levels": 0, "programs": 0, "candidates": 0,
                   "placements": 0, "range_dims": 0, "results": 0,
-                  "materialized": 0, "pruned": 0, "paged_programs": 0}
+                  "materialized": 0, "pruned": 0, "paged_programs": 0,
+                  "paged_row_visits": 0, "paged_row_copies": 0}
 
 
-def note_groupby_level(programs: int, candidates: int,
-                       paged: int = 0) -> None:
+def note_groupby_level(programs: int, candidates: int, paged: int = 0,
+                       row_visits: int = 0, row_copies: int = 0) -> None:
     with _groupby_lock:
         _groupby_stats["levels"] += 1
         _groupby_stats["programs"] += programs
         _groupby_stats["candidates"] += candidates
         _groupby_stats["paged_programs"] += paged
+        _groupby_stats["paged_row_visits"] += row_visits
+        _groupby_stats["paged_row_copies"] += row_copies
 
 
 def note_groupby_operand_placement() -> None:
@@ -907,7 +915,9 @@ def groupby_metrics() -> dict:
                 "results_total": _groupby_stats["results"],
                 "results_materialized_total": _groupby_stats["materialized"],
                 "pruned_total": _groupby_stats["pruned"],
-                "paged_programs_total": _groupby_stats["paged_programs"]}
+                "paged_programs_total": _groupby_stats["paged_programs"],
+                "paged_row_visits_total": _groupby_stats["paged_row_visits"],
+                "paged_row_copies_total": _groupby_stats["paged_row_copies"]}
 
 
 # ------------------------------------------------- device compiles, memory

@@ -18,6 +18,7 @@ from pilosa_tpu.executor.executor import _groupby_level_unpack
 from pilosa_tpu.parallel import DistExecutor, make_mesh
 from pilosa_tpu.shardwidth import WORDS_PER_SHARD
 from pilosa_tpu.storage import Holder
+from pilosa_tpu.utils.tracing import groupby_metrics
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 N_SHARDS = 3          # in 4 slots on one device, 8 on the mesh
@@ -88,6 +89,13 @@ def oracle(words, n_dims, filt, cand, with_sum):
     return counts, sums
 
 
+def level_sums(agg, n):
+    """Each candidate's Sum from its aggregate partials."""
+    n_g, plane_counts = agg
+    return [sum(int(v) << b for b, v in enumerate(plane_counts[:, j]))
+            + BASE * int(n_g[j]) for j in range(n)]
+
+
 def shifted(row, n):
     """Shift(row, n) within each shard: bits move up by n columns."""
     bits = np.unpackbits(row.view(np.uint8), axis=-1, bitorder="little")
@@ -100,6 +108,9 @@ def cross(*sizes):
     grids = np.meshgrid(*[np.arange(n) for n in sizes], indexing="ij")
     return np.stack([g.ravel() for g in grids], axis=1).astype(np.int32)
 
+
+# executor._index_cross of the prefixes (2,), (5,), (9,) and 12 rows
+SURVIVORS_3X12 = cross(10, 12).reshape(10, 12, 2)[[2, 5, 9]].reshape(-1, 2)
 
 # name: (gathered dims, filter, Sum, candidates, candidate bound or None)
 CASES = {
@@ -129,6 +140,27 @@ CASES = {
     "2dims-nofilter-count-pruned-first-paged": (
         2, None, False,
         np.array([[4, 7], [4, 7], [9, 0], [0, 11], [4, 1]], np.int32), None),
+    # ISSUE 43: a paged row tile is copied only where the index changes.
+    # Prefix-major, as a pruned level sends its candidates: three
+    # survivors of the first dimension, each with the second's 12 rows
+    "2dims-leaf-count-3x12-both-paged": (2, "leaf", False, SURVIVORS_3X12,
+                                         None),
+    # the first index alternates (every candidate copies, into the half
+    # the candidate before last read), the second lasts two candidates
+    "2dims-nofilter-count-alternating-both-paged": (
+        2, None, False,
+        np.array([[4, 7], [9, 7], [4, 2], [9, 2], [4, 7], [9, 7], [4, 7]],
+                 np.int32), None),
+    # one index for the whole program: candidate 0's is the only copy
+    "2dims-leaf-count-constant-first-paged": (
+        2, "leaf", False, cross(10, 12)[48:60], None),
+    "1dim-nofilter-count-c1-paged": (1, None, False, cross(10)[3:4], None),
+    # runs of 12 over programs of 16: a run goes on in the next program,
+    # which starts with a copy
+    "2dims-leaf-count-3x12-above-bound-both-paged": (
+        2, "leaf", False, SURVIVORS_3X12, 16),
+    "2dims-leaf-sum16-3x12-first-paged": (2, "leaf", True, SURVIVORS_3X12,
+                                          None),
 }
 # the tile plan these cases force (word tile, who is paged): at the
 # test's row counts every dimension would be resident
@@ -136,6 +168,26 @@ PAGED = {
     "2dims-leaf-count-c120-last-paged": (8192, (False, True)),
     "3dims-intersect-sum16-all-paged": (2048, (True, True, True)),
     "2dims-nofilter-count-pruned-first-paged": (32768, (True, False)),
+    "2dims-leaf-count-3x12-both-paged": (8192, (True, True)),
+    "2dims-nofilter-count-alternating-both-paged": (16384, (True, True)),
+    "2dims-leaf-count-constant-first-paged": (8192, (True, False)),
+    "1dim-nofilter-count-c1-paged": (32768, (True,)),
+    "2dims-leaf-count-3x12-above-bound-both-paged": (16384, (True, True)),
+    "2dims-leaf-sum16-3x12-first-paged": (4096, (True, False)),
+}
+# name: (row tiles the candidates name, row tiles the kernel copies), a
+# grid step and summed over the level's programs
+PAGED_ROWS = {
+    "2dims-leaf-count-c120-last-paged": (120, 120),
+    "3dims-intersect-sum16-all-paged": (27, 1 + 4 + 9),
+    "2dims-nofilter-count-pruned-first-paged": (5, 4),
+    "2dims-leaf-count-3x12-both-paged": (72, 3 + 36),
+    "2dims-nofilter-count-alternating-both-paged": (14, 7 + 3),
+    "2dims-leaf-count-constant-first-paged": (12, 1),
+    "1dim-nofilter-count-c1-paged": (1, 1),
+    # programs of 16, 16 and 4 candidates: first indices 2, 1 and 1 runs
+    "2dims-leaf-count-3x12-above-bound-both-paged": (72, 2 + 2 + 1 + 36),
+    "2dims-leaf-sum16-3x12-first-paged": (36, 3),
 }
 FILTERS = {
     None: (None, 0, ()),
@@ -184,33 +236,71 @@ def run_level(ex, words, case, monkeypatch, quantized=False):
 @pytest.mark.parametrize("builder", ["local", "mesh"])
 def test_level_program_matches_numpy(executors, words, builder, case,
                                      monkeypatch):
+    before = groupby_metrics()
     (counts, agg), (want_counts, want_sums), layout = run_level(
         executors[builder], words, case, monkeypatch)
+    after = groupby_metrics()
+    assert tuple(after[k] - before[k] for k in (
+        "paged_row_visits_total", "paged_row_copies_total")) == (
+            PAGED_ROWS.get(case, (0, 0)))
     assert counts.tolist() == want_counts
     assert any(want_counts)
     if CASES[case][2]:
-        n_g, plane_counts = agg
-        sums = [sum(int(v) << b for b, v in enumerate(plane_counts[:, j]))
-                + BASE * int(n_g[j]) for j in range(len(want_sums))]
-        assert sums == want_sums
+        assert level_sums(agg, len(want_sums)) == want_sums
     if CASES[case][4] is not None:
         assert len(layout) > 1   # chunked: concat + unpack covered
 
 
-@pytest.mark.parametrize("case", ["2dims-intersect-count-c120",
-                                  "3dims-nofilter-count-pruned-list"])
+@pytest.mark.parametrize("case", [
+    "2dims-intersect-count-c120", "3dims-nofilter-count-pruned-list",
+    "2dims-leaf-count-3x12-both-paged",
+    "2dims-nofilter-count-alternating-both-paged",
+    "2dims-leaf-count-constant-first-paged", "1dim-nofilter-count-c1-paged",
+    "2dims-leaf-count-3x12-above-bound-both-paged",
+    "2dims-leaf-sum16-3x12-first-paged"])
 def test_level_program_on_the_two_level_mesh(executors, words, case,
                                              monkeypatch):
     """The hierarchical lanes post-process the same packed counts: exact
     through the narrow lossless hop, an upper bound that keeps every
     survivor through the quantized one."""
     ex = executors["mesh-2d"]
-    (counts, _), (want, _), _ = run_level(ex, words, case, monkeypatch)
+    (counts, agg), (want, want_sums), _ = run_level(ex, words, case,
+                                                    monkeypatch)
     assert counts.tolist() == want
+    if CASES[case][2]:
+        # a level with an aggregate is the last one: never quantized
+        assert level_sums(agg, len(want_sums)) == want_sums
+        return
     (bounds, _), _, _ = run_level(ex, words, case, monkeypatch,
                                   quantized=True)
     assert all(b >= w for b, w in zip(bounds.tolist(), want))
     assert all(b == 0 for b, w in zip(bounds.tolist(), want) if w == 0)
+
+
+# levels no case above sends: candidates, who is paged, candidates a
+# program, (row tiles named, copied)
+OTHER_PAGED_ROWS = {
+    # 10 surviving cities x 250: the first index changes 10 times
+    "q3_2-second-level": (
+        np.stack([np.repeat(np.arange(10) * 25, 250),
+                  np.tile(np.arange(250), 10)], axis=1).astype(np.int32),
+        (True, True), 8192, (5000, 2510)),
+    "nothing-paged": (cross(10, 12), (False, False), 8192, (0, 0)),
+    "no-candidate": (cross(10, 12)[:0], (True, True), 8192, (0, 0)),
+}
+
+
+@pytest.mark.parametrize("shape", list(PAGED_ROWS) + list(OTHER_PAGED_ROWS))
+def test_paged_row_counters_count_the_runs_of_an_index(shape):
+    """``groupby_paged_row_visits_total`` and ``_copies_total`` from the
+    candidates alone: a copy a run of equal index in a paged column of
+    a program's candidates."""
+    if shape in PAGED_ROWS:
+        cand, bound = CASES[shape][3:5]
+        level = (cand, PAGED[shape][1], bound or 8192, PAGED_ROWS[shape])
+    else:
+        level = OTHER_PAGED_ROWS[shape]
+    assert batch.groupby_paged_rows(*level[:3]) == level[3]
 
 
 @pytest.mark.parametrize("builder", ["local", "mesh"])
@@ -422,12 +512,22 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-# filter leaves, structure, dimension rows, planes, padded candidates
+# filter leaves, structure, dimension rows, planes, padded candidates,
+# shard slots a chip
 CELL_LEVELS = {
-    "q2_passengers_sum": (1, ("leaf", 0), (10,), DEPTH + 2, 16),
-    "q3_passengers_months": (1, ("leaf", 0), (10, 12), 0, 128),
+    "q2_passengers_sum": (1, ("leaf", 0), (10,), DEPTH + 2, 16, 128),
+    "q3_passengers_months": (1, ("leaf", 0), (10, 12), 0, 128, 128),
     "q4_passengers_months_dist": (
-        2, ("and", ("leaf", 0), ("leaf", 1)), (10, 12), 0, 128),
+        2, ("and", ("leaf", 0), ("leaf", 1)), (10, 12), 0, 128, 128),
+    # q-flight's Q3.2 (58 shards in 64 slots), both cities paged: its
+    # second level's 2,500 candidates, where a row tile is copied only
+    # when its index changes (ISSUE 43), and a program of its last
+    # level's 600 with lo_revenue's 26 planes
+    "q3_2_cities": (
+        2, ("and", ("leaf", 0), ("leaf", 1)), (250, 250), 0, 4096, 64),
+    "q3_2_cities_years_sum": (
+        2, ("and", ("leaf", 0), ("leaf", 1)), (250, 250, 6), 24 + 2, 256,
+        64),
 }
 # the same, then shard slots: row counts no cell has, each matrix as the
 # executor pads it
@@ -486,8 +586,12 @@ def _compile_for_one_chip(topo, level, slots, monkeypatch):
 
 @pytest.mark.parametrize("level", list(CELL_LEVELS))
 def test_cell_level_compiles_for_one_v5e_chip(topo, level, monkeypatch):
+    cell = CELL_LEVELS[level]
+    assert any(batch.groupby_tile_plan(
+        cell[2], cell[0] + cell[3], 8, WORDS_PER_SHARD)[1]) == (
+            level.startswith("q3_2"))
     _assert_reads_rows_in_place(
-        _compile_for_one_chip(topo, CELL_LEVELS[level], 128, monkeypatch))
+        _compile_for_one_chip(topo, cell, cell[5], monkeypatch))
 
 
 @pytest.mark.parametrize("level", list(OTHER_LEVELS))
@@ -509,7 +613,7 @@ def test_cell_level_compiles_for_the_four_chip_mesh(topo, level,
     monkeypatch.setattr(batch, "_pallas_interpret", lambda: False)
     monkeypatch.setattr(dist, "_DIST_JIT_CACHE", {})
     mesh = Mesh(np.asarray(topo.devices), (SHARDS_AXIS,))
-    n_filt, structure, dims, n_planes, _ = CELL_LEVELS[level]
+    n_filt, structure, dims, n_planes, _, slots = CELL_LEVELS[level]
     # on the chip the flat mesh keeps its varying-axes check
     fn = dist._dist_groupby_level_fn(mesh, structure, n_filt, 0, len(dims),
                                      n_planes)
@@ -519,7 +623,8 @@ def test_cell_level_compiles_for_the_four_chip_mesh(topo, level,
                 else replicated(mesh))
 
     compiled = fn.lower(
-        *_level_args(512, CELL_LEVELS[level], sharding_of)).compile()
+        *_level_args(mesh.size * slots, CELL_LEVELS[level],
+                     sharding_of)).compile()
     _assert_reads_rows_in_place(compiled)
     assert "all-reduce" in compiled.as_text()
 
