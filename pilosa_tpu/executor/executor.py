@@ -45,6 +45,7 @@ from pilosa_tpu.storage.heat import global_heat
 from pilosa_tpu.utils.cost import current_cost, use_node
 from pilosa_tpu.utils.tracing import (
     note_groupby_level,
+    note_groupby_marginal,
     note_groupby_operand_placement,
     note_groupby_pruned,
     note_groupby_range_dims,
@@ -74,13 +75,16 @@ TOPN_MATRIX_BUDGET_BYTES = 1 << 30
 # A GroupBy of two or more dimensions is counted in a single level of
 # every group (one device sync) while its cross product is at most this
 # many groups AND that level is at most GROUPBY_DENSE_MAX_PROGRAMS
-# programs; past either the prefixes are pruned a dimension at a time
-# (one sync per dimension). No level holds its group masks in HBM
+# programs; past either each dimension is counted alone under the
+# filter and the surviving rows' prefixes are pruned a dimension at a
+# time (run_pruned: one sync for the marginal round, one per dimension
+# crossed after it). No level holds its group masks in HBM
 # (batch.groupby_level_body), so memory does not bound either path.
 GROUPBY_DENSE_MAX_GROUPS = 4096
-# Two, because pruning d >= 2 dimensions dispatches at least d programs
-# and blocks on d - 1 readbacks, so a dense level of two programs never
-# dispatches more than pruning would. A program is what the level
+# Two, because pruning d >= 2 dimensions dispatches at least d + 1
+# programs and blocks on 1 to d - 1 readbacks before the final level,
+# so a dense level of two programs never dispatches more than pruning
+# would. A program is what the level
 # kernel's accumulator block holds (batch.groupby_chunk_groups
 # candidates: 256 with a 24-bit Sum, 8,192 count-only); count-only a
 # candidate still costs its row reads, which is why the group bound
@@ -1766,17 +1770,23 @@ class Executor:
         (executor.executeGroupByShard). Here each prefix level is ONE
         batched program — candidate prefixes are gathered out of the
         stacked dimension matrices, counted per shard, and reduced on
-        device — so the whole GroupBy costs one device sync per dimension
-        (and exactly one when the cross-product is small enough to skip
-        pruning). A level reads each operand row once and keeps every
-        candidate's accumulators on-chip (batch.groupby_level_body); it
-        is chunked only past batch.groupby_chunk_groups candidates.
+        device. A cross-product small enough to skip pruning
+        (_groupby_dense) costs exactly one device sync. A pruned GroupBy
+        first counts every dimension ALONE under the filter (the
+        marginal round: a count-only level a dimension, one sync for
+        all of them), crosses only the rows that survived, and costs one
+        sync more for the final level where the survivors' cross-product
+        is small enough by the same rule, else one more per dimension
+        from the second to the one before the last (run_pruned). A level
+        reads each operand row once and keeps every candidate's
+        accumulators on-chip (batch.groupby_level_body); it is chunked
+        only past batch.groupby_chunk_groups candidates.
 
         Pipelined (submit): the common dense single-level case enqueues
         its level program WITHOUT the blocking readback — the host sync
         moves into ``Deferred.result()``, overlapping the round trip
         with whatever the serving loop enqueues next. The pruning path
-        needs a readback per level to choose the next level's
+        needs a readback per round to choose the next level's
         candidates, so it defers the whole evaluation to ``result()``.
 
         Either way the Deferred resolves to ONE ``GroupCounts``
@@ -1866,9 +1876,20 @@ class Executor:
             return Deferred(value=finish())
 
         def run_pruned() -> GroupCounts:
-            # prefix pruning: extend one dimension at a time, dropping
-            # empty prefixes after each level (AND only shrinks groups);
-            # each level's readback gates the next level's candidates.
+            # prefix pruning over each dimension's survivors. A group
+            # (a, b, c) is non-empty under the filter F only if a & F,
+            # b & F and c & F each are, so every dimension is first
+            # counted ALONE under the filter (the marginal round: one
+            # count-only level a dimension, all enqueued before ONE
+            # blocking readback) and only the rows that survive are ever
+            # crossed. Then the dense rule is asked again with the
+            # survivors' sizes: where their cross product fits, the
+            # final level counts it at once; else prefixes are extended
+            # one dimension at a time by that dimension's survivors,
+            # dropping empty prefixes after each level (AND only shrinks
+            # groups), each level's readback gating the next level's
+            # candidates. Survivor lists ascend, so candidates stay in
+            # lexicographic order of row index, prefix-major.
             # With quantized ranking on, NON-final levels count over the
             # 8-bit lane and keep any candidate whose count+bound could
             # be nonzero (zero quantizes exactly to zero, so a true
@@ -1880,34 +1901,63 @@ class Executor:
             final = len(dims) - 1
 
             def level(k: int, prefixes: np.ndarray):
-                """Prefixes extended by dimension k, counted, and the
-                non-empty ones kept: (candidates, counts, aggregates)."""
-                cand = _index_cross(prefixes, sizes[k])
+                """Prefixes extended by dimension k's survivors,
+                counted, and the non-empty ones kept: (candidates,
+                counts, aggregates)."""
+                cand = _index_cross(prefixes, survivors[k])
                 counts_arr, agg_arrs = self._groupby_eval_level(
                     block, filt_leaves, filt_node, scalars,
                     dim_mats[: k + 1], cand,
                     planes if k == final else None,
                     agg_field if k == final else None,
                     quantized=quant and k != final,
-                    # the first level is every row of its dimension, as
-                    # a dense level of one; the later ones are whatever
-                    # survived the readback
-                    cand_key=(sizes[0],) if k == 0 else None,
                 )
                 keep = counts_arr > 0
                 if agg_arrs is not None:
                     agg_arrs = (agg_arrs[0][keep], agg_arrs[1][:, keep])
                 return cand[keep], counts_arr[keep], agg_arrs
 
-            cand = np.zeros((1, 0), np.int32)
-            for k in range(final):
-                # a round trip the dense path does not make (enqueue,
-                # blocking readback, choice of survivors), timed apart
-                # from the resolve or execute it runs inside
-                with stage("executor.prune_level", level=k):
-                    cand, _, _ = level(k, cand)
-                if cand.shape[0] == 0:
-                    return GroupCounts()
+            # a round trip the dense path does not make (enqueue,
+            # blocking readback, choice of survivors), timed apart from
+            # the resolve or execute it runs inside: the marginal round
+            # is one, whatever the number of dimensions
+            with stage("executor.prune_level", level="marginal"):
+                # a dimension alone is every row of it, as a dense level
+                # of one: its operand is placed once and found again
+                enqueued = [
+                    self._groupby_level_enqueue(
+                        block, filt_leaves, filt_node, scalars, [mat],
+                        _dense_candidates((n,)), None, None,
+                        quantized=quant, cand_key=(n,))
+                    for mat, n in zip(dim_mats, sizes)
+                ]
+                survivors = [
+                    np.flatnonzero(_groupby_level_unpack(
+                        _readback(packed), layout, n, False, 0,
+                        quantized=quant)[0]).astype(np.int32)
+                    for (packed, layout), n in zip(enqueued, sizes)
+                ]
+            kept = tuple(s.size for s in survivors)
+            alive = all(kept)
+            # the rule that chose this path, asked of what is left (two
+            # dimensions have no joint level before the final one)
+            straight = alive and (
+                final == 1 or _groupby_dense(kept, n_planes))
+            note_groupby_marginal(sum(sizes), sum(kept), straight)
+            if not alive:
+                return GroupCounts()
+            # dimension 0's level IS its marginal
+            cand = survivors[0][:, None]
+            if straight:
+                # no joint count-only level, no second round trip
+                for rows in survivors[1:final]:
+                    cand = _index_cross(cand, rows)
+            else:
+                for k in range(1, final):
+                    with stage("executor.prune_level", level=k):
+                        cand, _, _ = level(k, cand)
+                    if cand.shape[0] == 0:
+                        return GroupCounts()
             cand, counts_arr, agg_arrs = level(final, cand)
             if cand.shape[0] == 0:
                 return GroupCounts()
@@ -1919,15 +1969,14 @@ class Executor:
 
     def _groupby_eval_level(self, block, filt_leaves, filt_node,
                             scalars, dim_mats, cand: np.ndarray, planes,
-                            agg_field, quantized: bool = False,
-                            cand_key=None):
-        """Evaluate one pruning level: enqueue + blocking readback.
-        ``quantized`` levels return per-candidate count UPPER BOUNDS
-        (approx + error bound) — valid only for gating survival, never
-        for reported counts."""
+                            agg_field, quantized: bool = False):
+        """Evaluate one joint pruning level, its candidates chosen from
+        a readback: enqueue + blocking readback. ``quantized`` levels
+        return per-candidate count UPPER BOUNDS (approx + error bound) —
+        valid only for gating survival, never for reported counts."""
         packed, layout = self._groupby_level_enqueue(
             block, filt_leaves, filt_node, scalars, dim_mats, cand,
-            planes, agg_field, quantized=quantized, cand_key=cand_key,
+            planes, agg_field, quantized=quantized,
         )
         has_agg = planes is not None
         depth = agg_field.options.bit_depth if has_agg else 0
@@ -2345,12 +2394,11 @@ def pipeline_coalescable(query) -> bool:
     return calls is not None and all(one(c) for c in calls)
 
 
-def _index_cross(cand: np.ndarray, n: int) -> np.ndarray:
-    """Extend candidate index tuples [P, k] by every index of the next
-    dimension → [P·n, k+1]."""
-    p = cand.shape[0]
-    left = np.repeat(cand, n, axis=0)
-    right = np.tile(np.arange(n, dtype=np.int32), p)[:, None]
+def _index_cross(cand: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    """Extend candidate index tuples [P, k] by each of the next
+    dimension's row indices ``rows`` int32[n] → [P·n, k+1], prefix-major."""
+    left = np.repeat(cand, rows.size, axis=0)
+    right = np.tile(rows, cand.shape[0])[:, None]
     return np.concatenate([left, right], axis=1)
 
 
@@ -2375,7 +2423,7 @@ def _dense_candidates(sizes: tuple) -> np.ndarray:
     past one dimension). Shared between callers, so read-only."""
     cand = np.zeros((1, 0), np.int32)
     for n in sizes:
-        cand = _index_cross(cand, n)
+        cand = _index_cross(cand, np.arange(n, dtype=np.int32))
     cand.flags.writeable = False
     return cand
 

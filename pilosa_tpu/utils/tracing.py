@@ -859,13 +859,21 @@ def thread_metrics() -> dict:
 # row tiles their candidates name (candidates x paged dimensions, a grid
 # step), and the ones the kernel copies, which is one where a paged
 # index differs from the candidate's before (batch.groupby_paged_rows):
-# copies / visits is the share of copies made.
+# copies / visits is the share of copies made. Four say what a pruned
+# GroupBy's marginal round did (every dimension counted alone under the
+# filter, one blocking round trip, before any two are crossed): the
+# rounds run (over pruned_total: 1.0), the rows they counted and the
+# rows with a non-zero count (kept / rows is the share the round let
+# through), and the rounds whose survivors' cross product fitted the
+# dense rule, so that the final level ran at once.
 
 _groupby_lock = threading.Lock()
 _groupby_stats = {"levels": 0, "programs": 0, "candidates": 0,
                   "placements": 0, "range_dims": 0, "results": 0,
                   "materialized": 0, "pruned": 0, "paged_programs": 0,
-                  "paged_row_visits": 0, "paged_row_copies": 0}
+                  "paged_row_visits": 0, "paged_row_copies": 0,
+                  "marginal_rounds": 0, "marginal_rows": 0,
+                  "marginal_kept": 0, "marginal_dense": 0}
 
 
 def note_groupby_level(programs: int, candidates: int, paged: int = 0,
@@ -904,6 +912,14 @@ def note_groupby_pruned() -> None:
         _groupby_stats["pruned"] += 1
 
 
+def note_groupby_marginal(rows: int, kept: int, dense: bool) -> None:
+    with _groupby_lock:
+        _groupby_stats["marginal_rounds"] += 1
+        _groupby_stats["marginal_rows"] += rows
+        _groupby_stats["marginal_kept"] += kept
+        _groupby_stats["marginal_dense"] += dense
+
+
 def groupby_metrics() -> dict:
     """The ``groupby`` block of /metrics and /debug/vars."""
     with _groupby_lock:
@@ -917,7 +933,11 @@ def groupby_metrics() -> dict:
                 "pruned_total": _groupby_stats["pruned"],
                 "paged_programs_total": _groupby_stats["paged_programs"],
                 "paged_row_visits_total": _groupby_stats["paged_row_visits"],
-                "paged_row_copies_total": _groupby_stats["paged_row_copies"]}
+                "paged_row_copies_total": _groupby_stats["paged_row_copies"],
+                "marginal_rounds_total": _groupby_stats["marginal_rounds"],
+                "marginal_rows_total": _groupby_stats["marginal_rows"],
+                "marginal_kept_total": _groupby_stats["marginal_kept"],
+                "marginal_dense_total": _groupby_stats["marginal_dense"]}
 
 
 # ------------------------------------------------- device compiles, memory

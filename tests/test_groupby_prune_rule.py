@@ -6,8 +6,13 @@ product is at most ``GROUPBY_DENSE_MAX_PROGRAMS`` level programs of
 prefix to prune). Either path gives the same ``GroupCounts``, group
 for group; ``groupby_pruned_total`` and ``groupby_paged_programs_total``
 say which path and which kind of program ran, and the stage
-``executor.prune_level`` times a pruned GroupBy's non-final levels.
-Every answer here is compared with a plain numpy group-by of the columns.
+``executor.prune_level`` times a pruned GroupBy's non-final round trips.
+Since ISSUE 44 a pruned GroupBy starts with a marginal round (every
+dimension counted alone under the filter: a level a dimension, ONE
+round trip), so the level, program and ``prune_levels`` counts pinned
+here are that plan's; ``tests/test_groupby_marginal_prune.py`` holds the
+round itself. Every answer here is compared with a plain numpy group-by
+of the columns.
 """
 
 import sys
@@ -80,24 +85,25 @@ def answer(ex, pql):
 
 
 class Around:
-    """Deltas of the GroupBy counters and the pruned levels' stage."""
+    """Deltas of the GroupBy counters and the pruned levels' stage, each
+    under its attribute's name."""
 
-    NAMES = ("pruned_total", "paged_programs_total", "levels_total",
-             "level_programs_total", "results_total")
+    FIELDS = {"pruned": "pruned_total", "paged": "paged_programs_total",
+              "levels": "levels_total", "programs": "level_programs_total",
+              "results": "results_total"}
 
     def read(self):
         g = groupby_metrics()
-        s = stage_metrics()
-        return np.array([g[n] for n in self.NAMES]
-                        + [s["executor_prune_level_total"]])
+        return {**{attr: g[name] for attr, name in self.FIELDS.items()},
+                "prune_levels": stage_metrics()["executor_prune_level_total"]}
 
     def __enter__(self):
         self.before = self.read()
         return self
 
     def __exit__(self, *exc):
-        (self.pruned, self.paged, self.levels, self.programs, self.results,
-         self.prune_levels) = (self.read() - self.before).tolist()
+        for attr, after in self.read().items():
+            setattr(self, attr, after - self.before[attr])
 
 
 # ---------------------------------------------------------------- the rule
@@ -137,29 +143,35 @@ def test_dense_while_the_level_is_at_most_two_programs(case):
 
 
 # the boundary through the executor, at a candidate bound small enough
-# for the CPU: 16 a program count-only, 8 with the Sum
+# for the CPU: 16 a program count-only, 8 with the Sum. Each case:
+# (PQL, reference, levels, programs, timed round trips). A pruned
+# GroupBy's marginal round (ISSUE 44) is a level and a program a
+# dimension and ONE round trip; nothing filters here, so every row
+# survives and the two dimensions' final level follows at once
 BOUNDARY = {
     # f x g = 20 candidates
     "count-only-two-programs-dense": ("GroupBy(Rows(f), Rows(g))",
-                                      (("f", "g"), None, False), 1, 2),
-    # f x h = 45 candidates = three programs: level of 5, then 45
+                                      (("f", "g"), None, False), 1, 2, 0),
+    # f x h = 45 candidates = three programs: marginals of 5 and of 9,
+    # then 45 (before the round: a level of 5, then 45: 2 and 4)
     "count-only-three-programs-pruned": ("GroupBy(Rows(f), Rows(h))",
-                                         (("f", "h"), None, False), 2, 4),
-    # g x f with a Sum = 20 candidates, three programs of 8
+                                         (("f", "h"), None, False), 3, 5, 1),
+    # g x f with a Sum = 20 candidates, three programs of 8: marginals
+    # of 4 and of 5 (count-only), then 20 (before: 2 and 4)
     "sum-three-programs-pruned": (
         'GroupBy(Rows(g), Rows(f), aggregate=Sum(field="v"))',
-        (("g", "f"), None, True), 2, 4),
+        (("g", "f"), None, True), 3, 5, 1),
     # one dimension: dense whatever its programs (9 rows, two of 8)
     "sum-one-dimension-dense": (
         'GroupBy(Rows(h), aggregate=Sum(field="v"))',
-        (("h",), None, True), 1, 2),
+        (("h",), None, True), 1, 2, 0),
 }
 
 
 @pytest.mark.parametrize("case", list(BOUNDARY))
 def test_the_boundary_through_the_executor(data, case, monkeypatch):
     holder, columns = data
-    pql, ref, levels, programs = BOUNDARY[case]
+    pql, ref, levels, programs, round_trips = BOUNDARY[case]
     monkeypatch.setattr(batch, "groupby_chunk_groups",
                         lambda n_planes: 8 if n_planes else 16)
     ex = executor(holder, "local")
@@ -168,7 +180,7 @@ def test_the_boundary_through_the_executor(data, case, monkeypatch):
     assert got == numpy_groupby(columns, *ref) and got
     assert (d.levels, d.programs, d.results) == (levels, programs, 1)
     assert d.pruned == (levels > 1)
-    assert d.prune_levels == levels - 1
+    assert d.prune_levels == round_trips
 
 
 # ------------------------------------------------ either path, one answer
@@ -231,14 +243,21 @@ def test_either_path_answers_group_for_group(data, builder, query, paged,
         answers[path] = result_json_bytes(groups)
         pruned = path == "pruned"
         assert (d.results, d.pruned) == (1, pruned)
-        assert d.levels == d.programs == (n_dims if pruned else 1)
+        # pruned (ISSUE 44): a marginal level a dimension, a joint level
+        # from the second dimension to the one before the last (the
+        # bound of 0 keeps the survivors' product from the final level
+        # too), the final level; before the round it was one a dimension
+        assert d.levels == d.programs == (2 * n_dims - 1 if pruned else 1)
+        # the marginal round is one timed round trip, each joint level
+        # another: as many as when dimension 0 had a level of its own
         assert d.prune_levels == (n_dims - 1 if pruned else 0)
         # a program pages where h is among its dimensions: every dense
-        # one, and a pruned GroupBy's levels from h's on
+        # one; pruned, h's own marginal and the one level that reaches
+        # it (h is first of two dimensions or last of three)
         if not (paged and "Rows(h)" in pql):
             assert d.paged == 0
-        elif pruned and not pql.startswith("GroupBy(Rows(h)"):
-            assert d.paged == 1
+        elif pruned:
+            assert d.paged == 2
         else:
             assert d.paged == d.programs
     assert answers["dense"] == answers["pruned"]
@@ -277,8 +296,10 @@ def test_the_counter_asks_the_plan_the_body_builds_its_kernel_by(
 
 @pytest.mark.parametrize("builder", BUILDERS)
 def test_an_empty_level_ends_the_groupby(data, builder, monkeypatch):
-    """Nothing survives the first level: one level, one timed round
-    trip, an empty ``GroupCounts`` and no later level."""
+    """Nothing survives the filter: the marginal round (three levels,
+    one a dimension, ONE timed round trip; before ISSUE 44 the first
+    dimension's level alone), an empty ``GroupCounts`` and no later
+    level."""
     holder, _ = data
     monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_PROGRAMS", 0)
     ex = executor(holder, builder)
@@ -288,22 +309,32 @@ def test_an_empty_level_ends_the_groupby(data, builder, monkeypatch):
                 'aggregate=Sum(field="v"))')
     assert got == [] and len(groups) == 0
     assert (d.pruned, d.levels, d.programs, d.prune_levels,
-            d.results) == (1, 1, 1, 1, 1)
+            d.results) == (1, 3, 3, 1, 1)
 
 
 def test_prefixes_can_survive_to_a_final_level_that_keeps_none(
         data, monkeypatch):
-    """Prefixes survive until the final level, which keeps none."""
+    """Prefixes survive until the final level, which keeps none: one row
+    of f and one of g that each have members under the filter and none
+    together. (Before ISSUE 44 the case was a g row with no member under
+    the filter at all; the marginal round ends that one before any final
+    level, as ``test_an_empty_level_ends_the_groupby`` has it.)"""
     holder, columns = data
     monkeypatch.setattr(ex_mod, "GROUPBY_DENSE_MAX_PROGRAMS", 0)
     ex = executor(holder, "local")
-    # g=0 and g=1 never meet in a column; the first dimension (f) has
-    # members under g=0, the second (g restricted to row 1) none
+    f, g, h = (columns[n] for n in "fgh")
+    a, b, x = next(
+        (a, b, x) for x in range(ROWS["h"]) for a in range(1, ROWS["f"])
+        for b in range(1, ROWS["g"])
+        if ((f == a) & (h == x)).any() and ((g == b) & (h == x)).any()
+        and not ((f == a) & (g == b) & (h == x)).any())
     with Around() as d:
-        _, got = answer(ex, "GroupBy(Rows(f), Rows(g, previous=0, limit=1), "
-                            "filter=Row(g=0))")
+        _, got = answer(
+            ex, f"GroupBy(Rows(f, previous={a - 1}, limit=1), "
+                f"Rows(g, previous={b - 1}, limit=1), filter=Row(h={x}))")
     assert got == []
-    assert (d.pruned, d.levels, d.prune_levels) == (1, 2, 1)
+    # two marginal levels in one round trip, then the final level
+    assert (d.pruned, d.levels, d.prune_levels) == (1, 3, 1)
 
 
 # ------------------------------------------------------- the served series

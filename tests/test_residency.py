@@ -1013,15 +1013,18 @@ def test_custom_device_put_never_receives_the_sparse_form(monkeypatch):
         return jax.device_put(host)
 
     cache = residency.global_row_cache()
+    # the process's cache: a test file this worker ran before may have
+    # missed sparsely, so the counter is read as a difference
+    before = cache.sparse_misses
     block = batch.ShardBlock([0, 1, 2])
     spec = _RowSpec("f", ("standard",), 1)
     batch.stacked_leaf(NoField(), spec, block, device_put=put)
     assert asked == [False] and isinstance(received[0], np.ndarray)
     assert received[0].shape == (4, WORDS_PER_SHARD)
-    assert cache.sparse_misses == 0
+    assert cache.sparse_misses == before
     cache.clear()
     got = batch.stacked_leaf(NoField(), spec, block)  # the cache's own put
-    assert asked == [False, True] and cache.sparse_misses == 1
+    assert asked == [False, True] and cache.sparse_misses == before + 1
     assert not np.asarray(got).any()
 
 
