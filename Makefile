@@ -97,13 +97,9 @@ mp-smoke:
 multitenant-smoke:
 	$(PYTEST) tests/test_multitenant.py -m "not slow"
 
-# mesh-smoke: the hierarchical reduction plane — byte-identical results
-# vs single-device across mesh sizes 1/2/4/8 incl. 2-D groups x shards
-# factorizations at non-divisible shard counts, the narrowed-lane wire
-# model + PROFILE reduceBytes, the roaring row-frame roundtrip, the
-# quantized candidate-ranking lane (error-bound/window properties +
-# verify_quantized byte-identity + wire counters), the
-# experimental-fallback multi-mesh serialization guard, and the
+# mesh-smoke: the mesh reduction — byte-identical results vs
+# single-device across mesh sizes 1/2/4/8 at non-divisible shard
+# counts, the dist_reduce_* byte count + PROFILE reduceBytes, and the
 # query_raw vs cache-hit envelope mirror contract
 # (docs/OPERATIONS.md multi-chip mesh)
 mesh-smoke:

@@ -39,13 +39,6 @@ heartbeat-timeout = 2.0       # tight per-probe timeout for liveness
                               # stall detection of other failures
 # use-mesh = true             # force the device-mesh executor (default:
                               # auto - mesh when >1 JAX device)
-# mesh-groups = 0             # reduction groups for multi-chip meshes;
-                              # 0 = auto (flat 1-D mesh)
-# topn-quantized-ranking = false # EQuARX 8-bit TopN/GroupBy candidate
-                              # ranking on the inter-group wire; final
-                              # results stay byte-identical (exact
-                              # recount on the error-bound-widened
-                              # window)
 # device-budget-bytes = 0     # HBM residency budget PER CHIP (an entry is
                               # charged what it holds on the fullest
                               # chip: a leaf sharded over a mesh costs its

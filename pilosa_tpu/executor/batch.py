@@ -1104,7 +1104,7 @@ def groupby_level_body(leaves, idxs, scalars, filt_structure, n_filt: int,
     # a lane partial is at most 2^13 a slot, so it cannot wrap before
     # 2^18 slots; the lanes are summed in the two split channels and the
     # carry moved up, which leaves both inside the bounds a sum of
-    # per-slot splits has (reduction.split_channel_bounds)
+    # per-slot splits has (SPLIT_MASK and SHARD_WIDTH >> SPLIT_SHIFT a slot)
     lo, hi = split_sum(partials.reshape(quantities, c_pad, _LANES), axis=-1)
     out = jnp.stack([lo & SPLIT_MASK, hi + (lo >> SPLIT_SHIFT)])
     if not has_agg:

@@ -601,17 +601,14 @@ class Executor:
         the block supplies the global row count for multi-host feeding)."""
         return None
 
-    def _note_reduce(self, reduce_kind: str, out_shape: tuple,
-                     padded: int) -> None:
+    def _note_reduce(self, reduce_kind: str, out_shape: tuple) -> None:
         """Reduction-lane wire accounting hook, called once per device
-        dispatch with the packed result shape and the block's padded
-        slot count. Single-device execution has no reduction wire —
-        DistExecutor records dense-equivalent vs actual bytes here."""
+        dispatch with the packed result shape. Single-device execution
+        has no reduction wire — DistExecutor records its bytes here."""
 
     def _row_host(self, stacked, block):
-        """Row-gather readback hook: device [padded, words] result →
-        host array. DistExecutor's hierarchical mesh routes this through
-        the roaring wire simulation (parallel/reduction.py)."""
+        """Row-gather readback: device [padded, words] result → host
+        array."""
         return _readback(stacked)
 
     def _program(self, structure, reduce_kind: str, leaf_ranks: tuple,
@@ -619,11 +616,7 @@ class Executor:
         return batch.local_fn(structure, reduce_kind, leaf_ranks, n_scalars)
 
     def _groupby_level_program(self, filt_structure, n_filt: int,
-                               n_scalars: int, n_gather: int, n_planes: int,
-                               quantized: bool = False):
-        # single-device execution never quantizes (there is no wire);
-        # DistExecutor routes quantized=True pruning levels through the
-        # 8-bit ranking lane
+                               n_scalars: int, n_gather: int, n_planes: int):
         return batch.local_groupby_level_fn(
             filt_structure, n_filt, n_scalars, n_gather, n_planes
         )
@@ -685,14 +678,6 @@ class Executor:
                 self._placed_operands.clear()
             self._placed_operands[key] = operand
         return operand
-
-    # EQuARX quantized candidate-ranking lane: inert on the base
-    # executor (no inter-group wire to shrink); DistExecutor overrides
-    # the predicate behind the topn-quantized-ranking knob.
-    verify_quantized = False
-
-    def _quant_ranking_active(self) -> bool:
-        return False
 
     @staged("executor.operands")
     def _eval_operands(self, idx: Index, compiled: _Compiled, block,
@@ -784,7 +769,7 @@ class Executor:
         cost = current_cost()
         if cost is not None:
             cost.note_dispatch(site.elapsed)
-        self._note_reduce(reduce_kind, out.shape, leaves[0].shape[0])
+        self._note_reduce(reduce_kind, out.shape)
         return out
 
     def _resolve_leaves(self, idx: Index, compiled: _Compiled, block,
@@ -949,7 +934,7 @@ class Executor:
         cost = current_cost()
         if cost is not None:
             cost.note_dispatch(site.elapsed, batch=len(rows))
-        self._note_reduce(reduce_kind, group["out"].shape, shapes[0][0])
+        self._note_reduce(reduce_kind, group["out"].shape)
         if self._pending.get(key) is group:
             del self._pending[key]
 
@@ -1441,50 +1426,33 @@ class Executor:
         ) if specs else ([], tuple(int(s) for s in scalars))
         put = self._leaf_put(block)
 
-        def dispatch_chunks(cand_list, kind, use_pipeline, rows=None):
-            """One (chunk, result thunk) per candidate chunk of `kind`
-            (countrows = exact split sums; countrows_q = the quantized
-            ranking lane). Chunks pad to ``rows`` (default chunk_rows)
-            with ZERO rows — the widened-window recount passes its own
-            smaller power-of-two so the exact pass pays for the window,
-            not the full candidate set."""
-            rows = chunk_rows if rows is None else rows
-            chunk_reads = []
-            for lo in range(0, len(cand_list), rows):
-                chunk = cand_list[lo:lo + rows]
-                with stage("executor.operands"):
-                    matrix = batch.stacked_matrix(
-                        idx, field_name, view, chunk, block, put,
-                        pad_rows=rows - len(chunk),
-                    )
-                leaves = base_leaves + [matrix]
-                read = (self._microbatch_enqueue(node, kind, leaves,
-                                                 scalar_ints)
-                        if use_pipeline else None)
-                if read is None:
-                    packed = self._dispatch(node, kind, leaves,
-                                            scalar_ints)
-                    read = (lambda p: lambda: _readback(p))(packed)
-                chunk_reads.append((chunk, read))
-            return chunk_reads
-
-        def exact_totals(cand_list, chunk_reads=None, rows=None):
-            """Blocking exact recount: each chunk's packed
-            [2, rows] split sums; the slice drops the all-zero pad
-            rows (always zero counts)."""
-            if chunk_reads is None:
-                chunk_reads = dispatch_chunks(
-                    cand_list, "countrows", False, rows=rows
+        reads = []  # one (chunk_candidates, result thunk) per chunk
+        for lo in range(0, n_real, chunk_rows):
+            chunk = candidates[lo:lo + chunk_rows]
+            with stage("executor.operands"):
+                matrix = batch.stacked_matrix(
+                    idx, field_name, view, chunk, block, put,
+                    pad_rows=chunk_rows - len(chunk),
                 )
+            leaves = base_leaves + [matrix]
+            read = (self._microbatch_enqueue(node, "countrows", leaves,
+                                             scalar_ints)
+                    if pipeline else None)
+            if read is None:
+                packed = self._dispatch(node, "countrows", leaves,
+                                        scalar_ints)
+                read = (lambda p: lambda: _readback(p))(packed)
+            reads.append((chunk, read))
+
+        def finish() -> list[Pair]:
+            # each chunk's packed [2, chunk_rows] split sums; the slice
+            # drops the all-zero pad rows (always zero counts)
             totals: list[int] = []
-            for chunk, read in chunk_reads:
+            for chunk, read in reads:
                 totals.extend(
                     batch.merge_split(np.asarray(read()))[:len(chunk)]
                     .tolist()
                 )
-            return totals
-
-        def order_pairs(cand_list, totals):
             # threshold= : minimum global count to be included
             # (SURVEY-LOW surface, Appendix B — the upstream arg's exact
             # version gate is unverifiable with the mount empty;
@@ -1496,70 +1464,10 @@ class Executor:
             floor = max(1, int(call.arg("threshold", 0) or 0))
             order = sorted(
                 (int(-c), r)
-                for r, c in zip(cand_list, totals) if c >= floor
+                for r, c in zip(candidates, totals) if c >= floor
             )
             if n:
                 order = order[:n]
-            return order
-
-        # quantized candidate ranking (topn-quantized-ranking): rank ALL
-        # candidates over the 8-bit scaled inter-group lane, widen the
-        # top-n window by the transmitted error bound (any candidate the
-        # perturbed ranking could have misplaced provably stays inside),
-        # then recount ONLY the window on the lossless lanes — final
-        # pairs are byte-identical to the all-lossless path because they
-        # are computed from the same exact counts. ids= queries are
-        # already an exact recount (no ranking to approximate), and with
-        # n == 0 or nothing to cut the window is the whole set.
-        quantized = (self._quant_ranking_active() and explicit_ids is None
-                     and bool(n) and n_real > n)
-
-        if quantized:
-            from pilosa_tpu.parallel import reduction
-
-            q_reads = dispatch_chunks(candidates, "countrows_q", pipeline)
-
-            def finish_quantized() -> list[Pair]:
-                approx = np.zeros(n_real, np.int64)
-                err = np.zeros(n_real, np.int64)
-                pos = 0
-                for chunk, read in q_reads:
-                    merged = batch.merge_split(np.asarray(read()))
-                    a, e = reduction.split_quantized(merged, chunk_rows)
-                    approx[pos:pos + len(chunk)] = a[:len(chunk)]
-                    err[pos:pos + len(chunk)] = e[:len(chunk)]
-                    pos += len(chunk)
-                widx = reduction.quant_topn_window(approx, err, n)
-                reduction.global_reduce_stats().note_quant_window(
-                    len(widx), n_real
-                )
-                window = [candidates[i] for i in widx]
-                # The recount chunks size to the WINDOW, not the full
-                # candidate set — otherwise pad rows hand back the wire
-                # bytes the quantized lane just saved.
-                wrows = min(
-                    chunk_rows, 1 << max(0, len(window) - 1).bit_length()
-                ) or 1
-                order = order_pairs(
-                    window, exact_totals(window, rows=wrows)
-                )
-                if self.verify_quantized:
-                    ref = order_pairs(candidates, exact_totals(candidates))
-                    if order != ref:
-                        raise AssertionError(
-                            "quantized TopN diverged from lossless: "
-                            f"{order} != {ref}"
-                        )
-                return self._finish_pairs(
-                    idx, field, [Pair(r, -negc) for negc, r in order]
-                )
-
-            return Deferred(finish_quantized)
-
-        reads = dispatch_chunks(candidates, "countrows", pipeline)
-
-        def finish() -> list[Pair]:
-            order = order_pairs(candidates, exact_totals(candidates, reads))
             return self._finish_pairs(
                 idx, field, [Pair(r, -negc) for negc, r in order]
             )
@@ -1890,13 +1798,6 @@ class Executor:
             # groups), each level's readback gating the next level's
             # candidates. Survivor lists ascend, so candidates stay in
             # lexicographic order of row index, prefix-major.
-            # With quantized ranking on, NON-final levels count over the
-            # 8-bit lane and keep any candidate whose count+bound could
-            # be nonzero (zero quantizes exactly to zero, so a true
-            # survivor can never be pruned); the final level is always
-            # lossless, so reported counts — and therefore results —
-            # stay byte-identical.
-            quant = self._quant_ranking_active()
             note_groupby_pruned()
             final = len(dims) - 1
 
@@ -1910,7 +1811,6 @@ class Executor:
                     dim_mats[: k + 1], cand,
                     planes if k == final else None,
                     agg_field if k == final else None,
-                    quantized=quant and k != final,
                 )
                 keep = counts_arr > 0
                 if agg_arrs is not None:
@@ -1928,13 +1828,13 @@ class Executor:
                     self._groupby_level_enqueue(
                         block, filt_leaves, filt_node, scalars, [mat],
                         _dense_candidates((n,)), None, None,
-                        quantized=quant, cand_key=(n,))
+                        cand_key=(n,))
                     for mat, n in zip(dim_mats, sizes)
                 ]
                 survivors = [
                     np.flatnonzero(_groupby_level_unpack(
-                        _readback(packed), layout, n, False, 0,
-                        quantized=quant)[0]).astype(np.int32)
+                        _readback(packed), layout, n, False, 0
+                    )[0]).astype(np.int32)
                     for (packed, layout), n in zip(enqueued, sizes)
                 ]
             kept = tuple(s.size for s in survivors)
@@ -1969,26 +1869,22 @@ class Executor:
 
     def _groupby_eval_level(self, block, filt_leaves, filt_node,
                             scalars, dim_mats, cand: np.ndarray, planes,
-                            agg_field, quantized: bool = False):
+                            agg_field):
         """Evaluate one joint pruning level, its candidates chosen from
-        a readback: enqueue + blocking readback. ``quantized`` levels
-        return per-candidate count UPPER BOUNDS (approx + error bound) —
-        valid only for gating survival, never for reported counts."""
+        a readback: enqueue + blocking readback."""
         packed, layout = self._groupby_level_enqueue(
             block, filt_leaves, filt_node, scalars, dim_mats, cand,
-            planes, agg_field, quantized=quantized,
+            planes, agg_field,
         )
         has_agg = planes is not None
         depth = agg_field.options.bit_depth if has_agg else 0
         return _groupby_level_unpack(
             _readback(packed), layout, cand.shape[0], has_agg, depth,
-            quantized=quantized,
         )
 
     def _groupby_level_enqueue(self, block, filt_leaves, filt_node,
                                scalars, dim_mats, cand: np.ndarray, planes,
-                               agg_field, quantized: bool = False,
-                               cand_key=None):
+                               agg_field, cand_key=None):
         """Dispatch one level's per-candidate counts (plus BSI aggregate
         partials on the final level): one program, unless the level has
         more candidates than the kernel's accumulator block holds
@@ -2007,14 +1903,8 @@ class Executor:
         c_total = cand.shape[0]
         n_planes = 2 + depth if has_agg else 0
         chunk = batch.groupby_chunk_groups(n_planes)
-        if quantized and has_agg:
-            raise AssertionError(
-                "quantized GroupBy levels never carry aggregates "
-                "(the final level is always lossless)"
-            )
         fn = self._groupby_level_program(
             filt_node, len(filt_leaves), len(scalars), n_gather, n_planes,
-            quantized=quantized,
         )
         args = list(filt_leaves) + list(dim_mats)
         if has_agg:
@@ -2043,8 +1933,7 @@ class Executor:
             cost = current_cost()
             if cost is not None:
                 cost.note_dispatch(site.elapsed)
-            self._note_reduce("groupby_q" if quantized else "groupby",
-                              packs[-1].shape, block.padded)
+            self._note_reduce("groupby", packs[-1].shape)
             layout.append((padded, actual))
         row_visits, row_copies = batch.groupby_paged_rows(cand, paged, chunk)
         note_groupby_level(len(packs), c_total,
@@ -2182,28 +2071,9 @@ class Executor:
 
 
 def _groupby_level_unpack(host: np.ndarray, layout, c_total: int,
-                          has_agg: bool, depth: int,
-                          quantized: bool = False):
+                          has_agg: bool, depth: int):
     """Unpack a level's concatenated chunk sections (host side):
-    per-candidate counts, plus (n, plane counts) with an aggregate.
-    ``quantized`` sections are [2·(padded+blocks)] ranking-lane packs;
-    the returned counts are approx + error bound — an UPPER bound that
-    only ever gates pruning survival."""
-    if quantized:
-        from pilosa_tpu.parallel import reduction
-
-        counts = np.zeros(c_total, np.int64)
-        off = out_off = 0
-        for padded, actual in layout:
-            width = reduction.quant_total_elems(padded)
-            merged = batch.merge_split(
-                host[off:off + 2 * width].reshape(2, width)
-            )
-            approx, err = reduction.split_quantized(merged, padded)
-            counts[out_off:out_off + actual] = (approx + err)[:actual]
-            off += 2 * width
-            out_off += actual
-        return counts, None
+    per-candidate counts, plus (n, plane counts) with an aggregate."""
 
     def take2(off: int, n: int, padded: int) -> np.ndarray:
         """Merge one split-sum section [2·padded] → int64[n]."""

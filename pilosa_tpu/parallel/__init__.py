@@ -15,10 +15,8 @@ mapReduce + gossip — SURVEY.md §2 #13–17, §2.3–2.4):
 """
 
 from pilosa_tpu.parallel.mesh import (
-    GROUPS_AXIS,
     SHARDS_AXIS,
     ShardAssignment,
     make_mesh,
-    mesh_groups,
 )
 from pilosa_tpu.parallel.dist import DistExecutor

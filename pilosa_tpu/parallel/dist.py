@@ -8,15 +8,8 @@ kernel over its resident block of shards (vmapped over the block), and
 ``psum``/``pmax`` over the ``shards`` axis does the reduce on ICI. No
 serialization, no scatter/gather, no per-node re-dispatch.
 
-On a 2-D ``groups x shards`` mesh (parallel/mesh.py) every reduction
-runs hierarchically: a dense intra-group ``psum``/``pmax`` over the
-cheap axis, then a narrow inter-group lane carrying only encoded
-per-group partials (parallel/reduction.py — uint8/uint16 where the
-static SHARD_WIDTH bound proves the cast lossless, int32 otherwise, and
-roaring containers for materialized row gathers). Results are
-bit-identical to the flat 1-D path; only the wire shape changes, and the
-dispatch path measures it (dense-equivalent vs actual bytes, the
-``dist_reduce_*`` series).
+The dispatch path counts what each reduction moves from its static
+shapes (ReduceStats, the ``dist_reduce_*`` series).
 
 All mapping/result logic lives in the base Executor's batched path
 (executor/batch.py) — this class only swaps the placement/program
@@ -29,6 +22,8 @@ collective reductions.
 
 from __future__ import annotations
 
+import threading
+
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
@@ -37,10 +32,9 @@ from jax.sharding import PartitionSpec as P
 from pilosa_tpu.executor import expr
 from pilosa_tpu.executor.executor import Executor
 from pilosa_tpu.executor import batch
-from pilosa_tpu.parallel import reduction
 from pilosa_tpu.parallel.mesh import (
-    GROUPS_AXIS, SHARDS_AXIS, ShardAssignment, make_mesh, mesh_groups,
-    replicated, shards_sharding, shards_spec,
+    SHARDS_AXIS, ShardAssignment, make_mesh, replicated, shards_sharding,
+    shards_spec,
 )
 from pilosa_tpu.storage import residency
 from pilosa_tpu.utils.compile_cache import named_jit
@@ -49,22 +43,14 @@ from pilosa_tpu.utils.cost import current_cost
 _DIST_JIT_CACHE: dict = {}
 
 
-def _smap(body, mesh, in_specs, out_specs, hier):
-    # hierarchical bodies produce replicated outputs via all_gather + a
-    # local fold, which the varying-axes checker cannot infer — it is
-    # disabled for those programs only
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_vma=hier is None)
+def _reduce_split(packed_local):
+    """A per-device split-sum partial, summed over the mesh."""
+    return lax.psum(packed_local, SHARDS_AXIS)
 
 
-def _dist_body(structure, reduce_kind: str, leaf_ranks: tuple, hier=None):
+def _dist_body(structure, reduce_kind: str, leaf_ranks: tuple):
     """Uncompiled per-query SPMD evaluator body (runs inside shard_map):
-    vmap over the local shard slots, then collective reduction over the
-    mesh. ``hier`` is (groups, shards_per_group) for the 2-D mesh —
-    intra-group psum/pmax over the shards axis, then the narrow encoded
-    inter-group lane (reduction.py); None is the flat 1-D reduce. Both
-    forms return BIT-IDENTICAL packed results (integer adds are exact
-    and associative; narrowing only where the static bound proves it).
+    vmap over the local shard slots, then psum/pmax over the mesh.
     Shared by the per-query program (_dist_fn) and the micro-batched
     program (_dist_fn_batched), mirroring batch._local_body /
     batch.local_fn_batched."""
@@ -75,45 +61,23 @@ def _dist_body(structure, reduce_kind: str, leaf_ranks: tuple, hier=None):
     def body(*args):
         leaves = args[:n_leaves]
         scalars = args[n_leaves:]
-        # static per-group slot count for the lossless-narrowing bounds:
-        # local slots x group width (leaf shapes are concrete at trace)
-        group_slots = leaves[0].shape[0] * (hier[1] if hier else 1)
-
-        def reduce_split(packed_local):
-            part = lax.psum(packed_local, SHARDS_AXIS)
-            if hier is None:
-                return part
-            return reduction.hier_split_channels(
-                part, GROUPS_AXIS, group_slots
-            )
 
         if count_sub is not None:
             # elementwise count: reduce the local block flat in wide
             # chunks (batch.count_flat), then reduce the packed channels
-            return reduce_split(batch.count_flat(count_sub, leaves, scalars))
+            return _reduce_split(batch.count_flat(count_sub, leaves, scalars))
 
         def per_shard(*ls):
             return expr._go(structure, ls, scalars)
 
         out = jax.vmap(per_shard)(*leaves)
         if reduce_kind == "count":
-            return reduce_split(batch.split_sum(out))
+            return _reduce_split(batch.split_sum(out))
         if reduce_kind == "countrows":
-            return reduce_split(batch.split_sum(out, axis=0))
-        if reduce_kind == "countrows_q":
-            # quantized candidate-ranking lane: exact intra-group psum
-            # of the split channels, then the 8-bit scaled inter-group
-            # hop (reduction.hier_quantized_counts — lossless
-            # pass-through on a flat mesh). Only the executor's TopN
-            # ranking pass dispatches this kind; the exact recount of
-            # the widened window rides plain 'countrows'.
-            part = lax.psum(batch.split_sum(out, axis=0), SHARDS_AXIS)
-            return reduction.hier_quantized_counts(
-                part, GROUPS_AXIS if hier is not None else None
-            )
+            return _reduce_split(batch.split_sum(out, axis=0))
         if reduce_kind == "bsisum":
             plane_counts, n = out  # [S_loc, depth], [S_loc]
-            return reduce_split(
+            return _reduce_split(
                 jnp.concatenate(
                     [batch.split_sum(plane_counts, axis=0),
                      batch.split_sum(n)[:, None]], axis=1
@@ -128,16 +92,8 @@ def _dist_body(structure, reduce_kind: str, leaf_ranks: tuple, hier=None):
             else:
                 best = lax.pmin(jnp.min(masked), SHARDS_AXIS)
             valid_g = lax.pmax(jnp.any(valid).astype(jnp.int32), SHARDS_AXIS)
-            if hier is not None:
-                # the group best is exact int32 (sentinel-masked values
-                # can be negative — no narrowing bound); the valid flag
-                # is 0/1 and crosses as uint8
-                best = reduction.gather_extreme(best, GROUPS_AXIS, want_max)
-                valid_g = reduction.gather_extreme(
-                    valid_g, GROUPS_AXIS, True, bound=1
-                )
             any_valid = valid_g > 0
-            n = reduce_split(
+            n = _reduce_split(
                 batch.minmax_at_best(values, counts, valid, best)
             )
             return batch.minmax_finalize(best, n, any_valid)
@@ -155,7 +111,6 @@ def _dist_fn(mesh, structure, reduce_kind: str, leaf_ranks: tuple,
     if fn is not None:
         return fn
 
-    hier = mesh_groups(mesh)
     spec = shards_spec(mesh)
     leaf_specs = tuple(spec for _ in leaf_ranks)
     scalar_specs = tuple(P() for _ in range(n_scalars))
@@ -163,12 +118,11 @@ def _dist_fn(mesh, structure, reduce_kind: str, leaf_ranks: tuple,
 
     fn = named_jit(
         f"dist_{reduce_kind}",
-        _smap(
-            _dist_body(structure, reduce_kind, leaf_ranks, hier),
+        shard_map(
+            _dist_body(structure, reduce_kind, leaf_ranks),
             mesh=mesh,
             in_specs=leaf_specs + scalar_specs,
             out_specs=out_specs,
-            hier=hier,
         )
     )
     _DIST_JIT_CACHE[key] = fn
@@ -180,22 +134,20 @@ def _dist_fn_batched(mesh, structure, reduce_kind: str, leaf_ranks: tuple,
     """ONE SPMD program evaluating ``n_queries`` same-shape pipelined
     queries over the mesh (the mesh counterpart of
     batch.local_fn_batched): per query the shared per-shard body runs
-    vmapped over the local slots and reduces over the mesh (flat psum or
-    the hierarchical two-stage form — _dist_body); results come back
-    stacked [B, ...] and replicated. Only scalar reductions micro-batch
-    (count/bsisum/min/max — Executor.submit never coalesces 'row'), so
-    out_specs is always replicated. Args: B repetitions of the sharded
-    leaves, then (when the shape has scalars) ONE replicated
-    int32[B, n_scalars] array."""
+    vmapped over the local slots and reduces over the mesh (_dist_body);
+    results come back stacked [B, ...] and replicated. Only scalar
+    reductions micro-batch (count/bsisum/min/max — Executor.submit never
+    coalesces 'row'), so out_specs is always replicated. Args: B
+    repetitions of the sharded leaves, then (when the shape has scalars)
+    ONE replicated int32[B, n_scalars] array."""
     key = ("distB", mesh, structure, reduce_kind, leaf_ranks, n_scalars,
            n_queries)
     fn = _DIST_JIT_CACHE.get(key)
     if fn is not None:
         return fn
 
-    hier = mesh_groups(mesh)
     n_leaves = len(leaf_ranks)
-    body1 = _dist_body(structure, reduce_kind, leaf_ranks, hier)
+    body1 = _dist_body(structure, reduce_kind, leaf_ranks)
     in_specs = (
         tuple(shards_spec(mesh) for _ in range(n_leaves * n_queries))
         + ((P(),) if n_scalars else ())
@@ -203,12 +155,11 @@ def _dist_fn_batched(mesh, structure, reduce_kind: str, leaf_ranks: tuple,
 
     fn = named_jit(
         f"dist_{reduce_kind}_b{n_queries}",
-        _smap(
+        shard_map(
             batch.batched_body(body1, n_leaves, n_scalars, n_queries),
             mesh=mesh,
             in_specs=in_specs,
             out_specs=P(),
-            hier=hier,
         )
     )
     _DIST_JIT_CACHE[key] = fn
@@ -216,21 +167,14 @@ def _dist_fn_batched(mesh, structure, reduce_kind: str, leaf_ranks: tuple,
 
 
 def _dist_groupby_level_fn(mesh, filt_structure, n_filt: int, n_scalars: int,
-                           n_gather: int, n_planes: int,
-                           quantized: bool = False):
+                           n_gather: int, n_planes: int):
     """SPMD GroupBy level program (same per-shard body as the local
-    builder, reduced over the mesh — hierarchically on a 2-D mesh, like
-    every other split-sum lane). ``quantized`` routes the per-candidate
-    counts through the 8-bit ranking lane — only intermediate PRUNING
-    levels use it (their counts merely gate candidate survival); the
-    final level always stays lossless, so reported counts are exact."""
-    key = ("gbl", mesh, filt_structure, n_filt, n_scalars, n_gather, n_planes,
-           quantized)
+    builder, reduced over the mesh like every other split-sum lane)."""
+    key = ("gbl", mesh, filt_structure, n_filt, n_scalars, n_gather, n_planes)
     fn = _DIST_JIT_CACHE.get(key)
     if fn is not None:
         return fn
 
-    hier = mesh_groups(mesh)
     n_leaves = n_filt + n_gather + (1 if n_planes else 0)
     # the leaves, then ONE replicated int32 array
     # (batch.unpack_groupby_operand; every host argument of a mesh
@@ -241,36 +185,21 @@ def _dist_groupby_level_fn(mesh, filt_structure, n_filt: int, n_scalars: int,
         leaves = args[:n_leaves]
         idxs, scalars = batch.unpack_groupby_operand(
             args[n_leaves], n_gather, n_scalars)
-        group_slots = leaves[0].shape[0] * (hier[1] if hier else 1)
-
-        def reduce_split(packed_local):
-            part = lax.psum(packed_local, SHARDS_AXIS)
-            if hier is None:
-                return part
-            return reduction.hier_split_channels(
-                part, GROUPS_AXIS, group_slots
-            )
-
         out = batch.groupby_level_body(
             leaves, idxs, scalars, filt_structure, n_filt, n_gather, n_planes
         )
         if not n_planes:
-            if quantized:
-                part = lax.psum(out, SHARDS_AXIS)
-                return reduction.hier_quantized_counts(
-                    part, GROUPS_AXIS if hier is not None else None
-                ).ravel()
-            return reduce_split(out).ravel()
-        return jnp.concatenate([reduce_split(o).ravel() for o in out])
+            return _reduce_split(out).ravel()
+        return jnp.concatenate([_reduce_split(o).ravel() for o in out])
 
     # the kernel's partials vary over the mesh like its leaves, so the
-    # flat mesh keeps its varying-axes check; Pallas' interpreter carries
+    # program keeps its varying-axes check; Pallas' interpreter carries
     # the kernel's scratch through its grid loop without them, so the
     # check is off where the interpreter runs the body
     fn = named_jit(
         "dist_groupby_level",
         shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                  check_vma=hier is None and not batch._pallas_interpret())
+                  check_vma=not batch._pallas_interpret())
     )
     _DIST_JIT_CACHE[key] = fn
     return fn
@@ -323,13 +252,9 @@ class DistExecutor(Executor):
     """Executor whose shard map phase runs as one SPMD program on a mesh.
 
     Single-process: the mesh spans all local devices and behaves like the
-    base executor with on-device reduction. A 2-D ``groups x shards``
-    mesh (``DistExecutor(holder, groups=2)`` or an explicit
-    ``make_mesh(groups=...)``) engages the hierarchical reduction plane:
-    identical results, but cross-group traffic crosses as narrow encoded
-    lanes and row gathers as roaring containers, with per-dispatch
-    dense-vs-actual wire bytes recorded (reduction.global_reduce_stats,
-    the cost plane's reduceBytes, and the dist_reduce_* series).
+    base executor with on-device reduction, whose bytes each dispatch
+    records (global_reduce_stats, the cost plane's reduceBytes, and the
+    dist_reduce_* series).
 
     Multi-host (exercised for real by tests/test_multihost.py, two
     jax.distributed processes on the CPU backend): the same mesh spans
@@ -350,28 +275,12 @@ class DistExecutor(Executor):
     through the HTTP layer (parallel/cluster_exec.py), as the reference's
     do."""
 
-    def __init__(self, holder, mesh=None, groups: int | None = None,
-                 quantized_ranking: bool = False,
-                 verify_quantized: bool = False):
+    def __init__(self, holder, mesh=None):
         super().__init__(holder)
-        self.mesh = mesh if mesh is not None else make_mesh(groups=groups)
+        self.mesh = mesh if mesh is not None else make_mesh()
         # micro-batch argument budgeting counts per-DEVICE bytes: leaves
         # are sharded over the mesh, so each chip holds 1/size of them
         self.arg_shard_factor = self.mesh.size
-        self._hier = mesh_groups(self.mesh)
-        # EQuARX quantized candidate-ranking lane (topn-quantized-ranking
-        # knob): TopN ranking + GroupBy pruning counts cross the
-        # inter-group wire as 8-bit scaled lanes; final results stay
-        # byte-identical via the widened-window exact recount. On a flat
-        # 1-D mesh the lane is a lossless pass-through (same code path,
-        # zero error bound). verify_quantized additionally runs the
-        # lossless path per TopN and asserts identity — the dryrun
-        # certification mode, not for serving.
-        self.quantized_ranking = bool(quantized_ranking)
-        self.verify_quantized = bool(verify_quantized)
-
-    def _quant_ranking_active(self) -> bool:
-        return self.quantized_ranking
 
     def _make_block(self, shard_list):
         return ShardAssignment(shard_list, self.mesh)
@@ -412,84 +321,68 @@ class DistExecutor(Executor):
                                 n_scalars, n_queries)
 
     def _groupby_level_program(self, filt_structure, n_filt, n_scalars,
-                               n_gather, n_planes, quantized=False):
+                               n_gather, n_planes):
         return _dist_groupby_level_fn(
             self.mesh, filt_structure, n_filt, n_scalars, n_gather, n_planes,
-            quantized,
         )
 
-    # ------------------------------------------- wire-byte accounting
-
-    def _note_reduce(self, reduce_kind: str, out_shape: tuple,
-                     padded: int) -> None:
-        """Per-dispatch reduction-lane bytes, from static shapes only
-        (host side, nothing blocks on the device). dense-equivalent =
-        flat int32 ring all-reduce over the whole mesh; actual = the
-        narrow inter-group hop (equal to dense on a 1-D mesh, where the
-        plane is pass-through); intra = per-group dense traffic,
-        reported separately as the cheap-axis cost."""
+    def _note_reduce(self, reduce_kind: str, out_shape: tuple) -> None:
+        """Per-dispatch reduction bytes, from static shapes only (host
+        side, nothing blocks on the device): a ring all-reduce of the
+        packed int32 lanes over the whole mesh."""
         if reduce_kind == "row":
-            return  # row gathers are accounted in _row_host
+            return  # stays shard-sharded: nothing is reduced
         elems = 1
         for d in out_shape:
             elems *= int(d)
-        quantized = 0
-        if reduce_kind in ("countrows_q", "groupby_q"):
-            # quantized ranking dispatch: the packed section is
-            # [2, R + n_blocks] (batched: leading B; groupby: raveled,
-            # accounted per chunk). Recover R from the section width and
-            # model the 8-bit hop vs its lossless countrows equivalent.
-            width = (elems // 2 if reduce_kind == "groupby_q"
-                     else int(out_shape[-1]))
-            mult = max(elems // (2 * width), 1)
-            n_rows = reduction.quant_real_elems(width)
-            # dense equivalent: the flat ring moving the same candidate
-            # lanes as exact [2, R] int32 split channels
-            dense = reduction.dense_reduce_bytes(
-                self.mesh.size, 2 * n_rows * mult
-            )
-            if self._hier is None:
-                actual, intra, lossless = dense, 0, dense
-            else:
-                g, spg = self._hier
-                actual, intra, lossless = reduction.quant_hier_bytes(
-                    n_rows, g, spg, max(padded // g, 1)
-                )
-                actual, intra, lossless = (
-                    actual * mult, intra * mult, lossless * mult
-                )
-            reduction.global_reduce_stats().note_quant_reduce(
-                actual, lossless
-            )
-            quantized = actual
-        else:
-            dense = reduction.dense_reduce_bytes(self.mesh.size, elems)
-            if self._hier is None:
-                actual, intra = dense, 0
-            else:
-                g, spg = self._hier
-                actual, intra = reduction.hier_reduce_bytes(
-                    reduce_kind, elems, g, spg, max(padded // g, 1)
-                )
-        reduction.global_reduce_stats().note_reduce(
-            dense, actual, intra, self._hier is not None
-        )
+        dense = dense_reduce_bytes(self.mesh.size, elems)
+        _STATS.note_reduce(dense, dense)
         cost = current_cost()
         if cost is not None:
-            cost.note_reduce(dense, actual, quantized=quantized)
+            cost.note_reduce(dense, dense)
 
-    def _row_host(self, stacked, block):
-        """Row-gather readback. On the hierarchical mesh the dense
-        [padded, words] device result crosses the (simulated) wire as
-        per-slot roaring containers in block frames — the result is
-        decoded FROM those frames, so the compression is load-bearing,
-        not just counted."""
-        host = super()._row_host(stacked, block)  # stage device.readback
-        if self._hier is None or jax.process_count() > 1:
-            return host
-        frames, actual = reduction.encode_row_frames(host)
-        reduction.global_reduce_stats().note_row_gather(host.nbytes, actual)
-        cost = current_cost()
-        if cost is not None:
-            cost.note_reduce(host.nbytes, actual)
-        return reduction.decode_row_frames(frames, host.shape)
+
+def dense_reduce_bytes(n_devices: int, out_elems: int) -> int:
+    """Bytes on the wire of a ring all-reduce of ``out_elems`` int32
+    lanes over ``n_devices``."""
+    return 2 * (n_devices - 1) * out_elems * 4
+
+
+class ReduceStats:
+    """Process-wide dist_reduce_* counters (served on /metrics and
+    /debug/vars). Lock kept tiny: a handful of integer adds per device
+    dispatch, invisible next to the dispatch itself. ``actual_bytes`` is
+    what the reduction moved and ``dense_bytes`` what a dense ring
+    all-reduce moves: the one lane there is moves exactly that, and the
+    benchmark's reduce_bytes_per_dispatch reads the former."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self) -> None:
+        with getattr(self, "_lock", threading.Lock()):
+            self.dispatches = 0
+            self.dense_bytes = 0
+            self.actual_bytes = 0
+
+    def note_reduce(self, dense: int, actual: int) -> None:
+        with self._lock:
+            self.dispatches += 1
+            self.dense_bytes += dense
+            self.actual_bytes += actual
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {
+                "dispatches": self.dispatches,
+                "dense_bytes": self.dense_bytes,
+                "actual_bytes": self.actual_bytes,
+            }
+
+
+_STATS = ReduceStats()
+
+
+def global_reduce_stats() -> ReduceStats:
+    return _STATS

@@ -23,51 +23,22 @@ from pilosa_tpu.executor.batch import ShardBlock
 from pilosa_tpu.shardwidth import next_pow2
 
 SHARDS_AXIS = "shards"
-GROUPS_AXIS = "groups"
 
 
-def make_mesh(n_devices: int | None = None, devices=None,
-              groups: int | None = None) -> Mesh:
-    """Mesh over the shard axis. Default is 1-D: bitmap ops have no
-    second model axis to map, so a flat topology is just the flattened
-    device list.
-
-    ``groups`` > 1 factorizes the same devices as a 2-D ``groups x
-    shards`` mesh — device g*S+s is slot (g, s) — turning every
-    reduction into the hierarchical two-stage form (parallel/dist.py):
-    dense psum/pmax inside each group, then a narrow encoded inter-group
-    lane (parallel/reduction.py). Groups model the expensive boundary
-    (chips across DCN, or ICI superblocks); results stay bit-identical
-    to the 1-D path, only the wire traffic shape changes."""
+def make_mesh(n_devices: int | None = None, devices=None) -> Mesh:
+    """Mesh over the shard axis. It is 1-D: bitmap ops have no second
+    model axis to map, so the topology is just the flattened device
+    list."""
     if devices is None:
         devices = jax.devices()
         if n_devices is not None:
             devices = devices[:n_devices]
-    devices = np.asarray(devices)
-    if groups is not None and groups > 1:
-        if devices.size % groups:
-            raise ValueError(
-                f"groups={groups} does not divide {devices.size} devices"
-            )
-        return Mesh(devices.reshape(groups, -1),
-                    (GROUPS_AXIS, SHARDS_AXIS))
-    return Mesh(devices, (SHARDS_AXIS,))
-
-
-def mesh_groups(mesh: Mesh) -> tuple[int, int] | None:
-    """(groups, shards_per_group) for a 2-D hierarchical mesh, None for
-    the flat 1-D form."""
-    if GROUPS_AXIS in mesh.axis_names:
-        return (mesh.shape[GROUPS_AXIS], mesh.shape[SHARDS_AXIS])
-    return None
+    return Mesh(np.asarray(devices), (SHARDS_AXIS,))
 
 
 def shards_spec(mesh: Mesh) -> P:
     """PartitionSpec splitting a leading shard-slot axis over every mesh
-    device (both axes of the 2-D form — slot order matches the flattened
-    device list either way)."""
-    if GROUPS_AXIS in mesh.axis_names:
-        return P((GROUPS_AXIS, SHARDS_AXIS))
+    device."""
     return P(SHARDS_AXIS)
 
 

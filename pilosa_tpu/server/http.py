@@ -812,11 +812,10 @@ class HTTPHandler(BaseHTTPRequestHandler):
                                  seen=seen)
         text += prometheus_block(global_route_stats().metrics(), prefix,
                                  seen=seen)
-        # multi-chip reduction plane (docs/OPERATIONS.md multi-chip
-        # mesh): per-dispatch reduction-lane bytes, dense-equivalent vs
-        # actual encoded inter-group traffic plus roaring row gathers —
-        # zeros on flat 1-D meshes, where the plane is pass-through
-        from pilosa_tpu.parallel.reduction import global_reduce_stats
+        # a mesh executor's reductions (docs/OPERATIONS.md multi-chip
+        # mesh): dispatches and the bytes they move between chips, from
+        # the programs' static shapes; zeros on a one-device server
+        from pilosa_tpu.parallel.dist import global_reduce_stats
 
         text += prometheus_block(global_reduce_stats().snapshot(), prefix,
                                  "dist_reduce", seen=seen)
@@ -1141,7 +1140,7 @@ class HTTPHandler(BaseHTTPRequestHandler):
         snap["threads"] = thread_metrics()
         snap["groupby"] = groupby_metrics()
         snap["device"] = device_metrics()
-        from pilosa_tpu.parallel.reduction import global_reduce_stats
+        from pilosa_tpu.parallel.dist import global_reduce_stats
 
         snap["dist_reduce"] = global_reduce_stats().snapshot()
         from pilosa_tpu.storage.heat import global_heat

@@ -33,8 +33,6 @@ class ServerConfig:
         heartbeat_interval: float = 5.0,
         heartbeat_timeout: float = 2.0,
         use_mesh: bool | None = None,
-        mesh_groups: int = 0,
-        topn_quantized_ranking: bool = False,
         trace_sample_rate: float = 0.0,
         trace_log_dir: str = "",
         diagnostics_endpoint: str = "",
@@ -112,21 +110,6 @@ class ServerConfig:
                 "(want > 0)"
             )
         self.use_mesh = use_mesh  # None = auto (mesh when >1 device)
-        # 2-D mesh factorization (docs/OPERATIONS.md multi-chip mesh):
-        # 0/1 = flat 1-D mesh; >1 = hierarchical groups x shards
-        # reductions with the compressed inter-group lane
-        if mesh_groups < 0:
-            raise ValueError(
-                f"invalid mesh-groups {mesh_groups!r} (want >= 0)"
-            )
-        self.mesh_groups = mesh_groups
-        # EQuARX quantized TopN/GroupBy candidate ranking (default off):
-        # ranking counts cross the inter-group wire as 8-bit scaled
-        # lanes; final results stay byte-identical via the
-        # widened-window exact recount (docs/OPERATIONS.md "Multi-chip
-        # mesh"). Only meaningful with the mesh executor; harmless
-        # (lossless pass-through) on a flat mesh.
-        self.topn_quantized_ranking = bool(topn_quantized_ranking)
         # Distributed tracing (docs/OBSERVABILITY.md): `trace-sample-rate`
         # sets probabilistic sampling of the span tree (0 = off; the
         # stage counters are always on). `trace-log-dir` is where POST
@@ -442,10 +425,6 @@ class ServerConfig:
                 _parse_bool(d["use-mesh"])
                 if d.get("use-mesh") not in (None, "") else None
             ),
-            mesh_groups=int(d.get("mesh-groups", 0) or 0),
-            topn_quantized_ranking=_parse_bool(
-                d.get("topn-quantized-ranking", False)
-            ),
             qos_max_inflight=int(d.get("qos-max-inflight", 0)),
             qos_tenant_inflight=int(d.get("qos-tenant-inflight", 0)),
             qos_default_deadline=_parse_duration(
@@ -601,8 +580,6 @@ class ServerConfig:
             "tls-skip-verify": self.tls_skip_verify,
             "device-budget-bytes": self.device_budget_bytes,
             "use-mesh": self.use_mesh,
-            "mesh-groups": self.mesh_groups,
-            "topn-quantized-ranking": self.topn_quantized_ranking,
             "qos-max-inflight": self.qos_max_inflight,
             "qos-tenant-inflight": self.qos_tenant_inflight,
             "qos-default-deadline": self.qos_default_deadline,
@@ -1000,11 +977,7 @@ class Server:
         if use_mesh:
             from pilosa_tpu.parallel.dist import DistExecutor
 
-            local = DistExecutor(
-                self.holder,
-                groups=self.config.mesh_groups or None,
-                quantized_ranking=self.config.topn_quantized_ranking,
-            )
+            local = DistExecutor(self.holder)
         else:
             local = Executor(self.holder)
         self.api.executor = ClusterExecutor(

@@ -71,7 +71,7 @@ class ProfileNode:
                  "row_cache_hits", "row_cache_misses", "plan_cache_hit",
                  "operand_memo_hit", "rows_materialized", "device_bytes",
                  "reduce_dense_bytes", "reduce_actual_bytes",
-                 "reduce_quant_bytes", "children", "leaves")
+                 "children", "leaves")
 
     def __init__(self, name: str, pql: str = ""):
         self.name = name
@@ -92,7 +92,6 @@ class ProfileNode:
         self.device_bytes = 0
         self.reduce_dense_bytes = 0
         self.reduce_actual_bytes = 0
-        self.reduce_quant_bytes = 0
         # static AST skeleton (ready-to-emit dicts, shared via the
         # skeleton memo — never mutated)
         self.children: list[dict] = []
@@ -117,15 +116,11 @@ class ProfileNode:
             "bytesMoved": self.device_bytes,
         }
         if self.reduce_dense_bytes:
-            # hierarchical reduction plane engaged (parallel/reduction.py):
-            # what the flat dense path would have moved vs the encoded
-            # inter-group lane this dispatch actually paid for
+            # a mesh executor's reductions (DistExecutor._note_reduce):
+            # the bytes a ring all-reduce of the packed lanes moves, and
+            # the same figure as what the one flat lane moved
             out["reduceBytes"] = {"denseEquiv": self.reduce_dense_bytes,
                                   "actual": self.reduce_actual_bytes}
-            if self.reduce_quant_bytes:
-                # portion of `actual` that crossed on the 8-bit EQuARX
-                # ranking lane (topn-quantized-ranking)
-                out["reduceBytes"]["quantized"] = self.reduce_quant_bytes
         if self.leaves:
             out["leaves"] = self.leaves
         if self.children:
@@ -246,8 +241,7 @@ class CostContext:
                  "c_array", "c_bitmap", "c_run", "row_cache_hits",
                  "row_cache_misses", "plan_cache_hits", "plan_cache_misses",
                  "rows_materialized", "device_bytes", "reduce_dense_bytes",
-                 "reduce_actual_bytes", "reduce_quant_bytes", "profile",
-                 "current")
+                 "reduce_actual_bytes", "profile", "current")
 
     def __init__(self, tenant: str = "default", index: str = "",
                  profile: QueryProfile | None = None):
@@ -267,7 +261,6 @@ class CostContext:
         self.device_bytes = 0
         self.reduce_dense_bytes = 0
         self.reduce_actual_bytes = 0
-        self.reduce_quant_bytes = 0
         self.profile = profile
         self.current: ProfileNode | None = None
 
@@ -325,21 +318,15 @@ class CostContext:
         if node is not None:
             node.rows_materialized += n
 
-    def note_reduce(self, dense: int, actual: int,
-                    quantized: int = 0) -> None:
-        """One reduction-lane crossing on the hierarchical mesh
-        (parallel/reduction.py): flat dense-equivalent bytes vs the
-        encoded bytes actually modeled on the inter-group wire.
-        ``quantized`` marks the portion of ``actual`` that crossed on
-        the 8-bit EQuARX ranking lane."""
+    def note_reduce(self, dense: int, actual: int) -> None:
+        """One reduction on a mesh (DistExecutor._note_reduce): the
+        bytes of the dense ring all-reduce, and the bytes moved."""
         self.reduce_dense_bytes += dense
         self.reduce_actual_bytes += actual
-        self.reduce_quant_bytes += quantized
         node = self.current
         if node is not None:
             node.reduce_dense_bytes += dense
             node.reduce_actual_bytes += actual
-            node.reduce_quant_bytes += quantized
 
     def note_plan(self, hit: bool) -> None:
         if hit:
@@ -370,8 +357,6 @@ class CostContext:
         if self.reduce_dense_bytes:
             out["reduceBytes"] = {"denseEquiv": self.reduce_dense_bytes,
                                   "actual": self.reduce_actual_bytes}
-            if self.reduce_quant_bytes:
-                out["reduceBytes"]["quantized"] = self.reduce_quant_bytes
         return out
 
 

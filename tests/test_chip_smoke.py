@@ -13,8 +13,6 @@ import subprocess
 import sys
 import time
 
-from pilosa_tpu.utils import compile_cache
-
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(ROOT, "chip_smoke.py")
 
@@ -27,13 +25,8 @@ def _env(**extra):
     return env
 
 
-def _listing(path):
-    return sorted(os.listdir(path)) if os.path.isdir(path) else None
-
-
 def test_smoke_passes_on_cpu_and_keeps_the_cache_where_told(tmp_path):
     cache = tmp_path / "cache"
-    default_before = _listing(compile_cache.DEFAULT_DIR)
     proc = subprocess.run(
         [sys.executable, SMOKE, "--expect-platform", "cpu", "--shards", "4"],
         env=_env(JAX_COMPILATION_CACHE_DIR=str(cache)),
@@ -59,7 +52,9 @@ def test_smoke_passes_on_cpu_and_keeps_the_cache_where_told(tmp_path):
     assert "warm_count_intersect_rtt_ms_median" in out["info"]
     assert {"load", "first_answer_cold",
             "first_answer_after_restart"} <= set(out["seconds"])
-    # the cache is where the environment said, and nowhere else
+    # the cache is where the environment said: a process has one
+    # directory, and the child's is not the checkout's (which the other
+    # workers of a run compile into, so its listing says nothing here)
     assert out["compile_cache"]["dir"] == str(cache)
     entries = out["compile_cache"]["entries_run1"]
     assert entries > 0
@@ -67,7 +62,6 @@ def test_smoke_passes_on_cpu_and_keeps_the_cache_where_told(tmp_path):
     # the second generation's burst may add a micro-batch size after the
     # smoke's own count, so the directory holds at least that many
     assert sum(1 for f in os.listdir(cache) if f.endswith("-cache")) >= entries
-    assert _listing(compile_cache.DEFAULT_DIR) == default_before
 
 
 def test_smoke_refuses_the_cpu_unless_told(tmp_path):

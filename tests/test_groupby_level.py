@@ -58,7 +58,7 @@ def executors(tmp_path_factory):
     yield {
         "local": Executor(holder),
         "mesh": DistExecutor(holder, make_mesh()),
-        "mesh-2d": DistExecutor(holder, make_mesh(groups=2)),
+        "mesh-2": DistExecutor(holder, make_mesh(2)),
     }
     holder.close()
 
@@ -197,7 +197,7 @@ FILTERS = {
 }
 
 
-def run_level(ex, words, case, monkeypatch, quantized=False):
+def run_level(ex, words, case, monkeypatch):
     n_dims, filt_kind, with_sum, cand, bound = CASES[case]
     if bound is not None:
         monkeypatch.setattr(batch, "groupby_chunk_groups",
@@ -220,11 +220,11 @@ def run_level(ex, words, case, monkeypatch, quantized=False):
         block, [slots(f) for f in words["filt"][:n_filt]], structure,
         list(scalars), [slots(d) for d in words["dims"][:n_dims]], cand,
         slots(words["planes"]) if with_sum else None,
-        _AggField if with_sum else None, quantized=quantized,
+        _AggField if with_sum else None,
     )
     got = _groupby_level_unpack(
         np.asarray(packed), layout, cand.shape[0], with_sum,
-        DEPTH if with_sum else 0, quantized=quantized)
+        DEPTH if with_sum else 0)
     host_filt = {None: None, "leaf": words["filt"][0],
                  "and": words["filt"][0] & words["filt"][1],
                  "shift": shifted(words["filt"][0], 7)}[filt_kind]
@@ -258,23 +258,16 @@ def test_level_program_matches_numpy(executors, words, builder, case,
     "2dims-leaf-count-constant-first-paged", "1dim-nofilter-count-c1-paged",
     "2dims-leaf-count-3x12-above-bound-both-paged",
     "2dims-leaf-sum16-3x12-first-paged"])
-def test_level_program_on_the_two_level_mesh(executors, words, case,
-                                             monkeypatch):
-    """The hierarchical lanes post-process the same packed counts: exact
-    through the narrow lossless hop, an upper bound that keeps every
-    survivor through the quantized one."""
-    ex = executors["mesh-2d"]
-    (counts, agg), (want, want_sums), _ = run_level(ex, words, case,
-                                                    monkeypatch)
+def test_level_program_on_a_mesh_of_two_slots_a_device(executors, words,
+                                                       case, monkeypatch):
+    """The three shards are one slot a device on the mesh of eight; on
+    two devices each holds two slots (the kernel's grid walks both
+    before the psum), and the counts and sums are the same."""
+    (counts, agg), (want, want_sums), _ = run_level(
+        executors["mesh-2"], words, case, monkeypatch)
     assert counts.tolist() == want
     if CASES[case][2]:
-        # a level with an aggregate is the last one: never quantized
         assert level_sums(agg, len(want_sums)) == want_sums
-        return
-    (bounds, _), _, _ = run_level(ex, words, case, monkeypatch,
-                                  quantized=True)
-    assert all(b >= w for b, w in zip(bounds.tolist(), want))
-    assert all(b == 0 for b, w in zip(bounds.tolist(), want) if w == 0)
 
 
 # levels no case above sends: candidates, who is paged, candidates a

@@ -428,7 +428,7 @@ def test_groups_with_members_in_every_marginal_and_none_together_are_dropped(
         assert {tuple(c[:2]) for c in final.tolist()} == {(0, 0), (1, 1)}
 
 
-# --------------------------------------------- (g) quantized ranking on
+# ------------------------------ (g) flat meshes of 2, 6 and 8 devices
 
 
 @pytest.mark.parametrize("query", [
@@ -437,33 +437,22 @@ def test_groups_with_members_in_every_marginal_and_none_together_are_dropped(
     "GroupBy(Rows(k), Rows(h), filter=Row(s=0), limit=5)",
     f"GroupBy(Rows(f), Rows(g), Rows(h), filter=Row(s=3), {SUM})",
 ], ids=["straight", "joint", "keyed-limit", "empty"])
-@pytest.mark.parametrize("mesh", [(4, None), (4, 2), (8, 2)],
-                         ids=["4dev-g1", "4dev-g2", "8dev-g2"])
-def test_quantized_marginals_never_drop_a_row_that_has_members(
-        data, mesh, query, small_programs, monkeypatch):
-    """With quantized ranking on, the marginal round counts over the
-    8-bit lane like every non-final level and keeps a row whose upper
-    bound is not 0; the final level is lossless: the dense path's bytes."""
+@pytest.mark.parametrize("n_devices", [2, 6, 8],
+                         ids=["2dev", "6dev", "8dev"])
+def test_a_pruned_groupby_on_any_flat_mesh_answers_the_dense_paths_bytes(
+        data, n_devices, query, small_programs, monkeypatch):
+    """The tests above run a mesh of four devices. Two hold two of the
+    three shards' slots each, six and eight have devices with no shard
+    (six is a count the padded slots are not a power of two of): the
+    marginal round, the joint levels and the final one sum over each as
+    over one device, to the dense path's bytes."""
     holder, _ = data
     want = dense_bytes(holder, "local", query, monkeypatch)
-    ex = DistExecutor(holder, make_mesh(mesh[0], groups=mesh[1]),
-                      quantized_ranking=True, verify_quantized=True)
-    lane = []
-    program = ex._groupby_level_program
-
-    def spy(*args, quantized=False):
-        lane.append(quantized)
-        return program(*args, quantized=quantized)
-
-    monkeypatch.setattr(ex, "_groupby_level_program", spy)
+    ex = DistExecutor(holder, make_mesh(n_devices))
     with Around() as d:
         groups, _ = answer(ex, query)
     assert result_json_bytes(groups) == want
     assert (d.pruned, d.rounds) == (1, 1)
-    # every level rode the lane but the final one, which the empty
-    # round never reaches
-    assert all(lane[:-1]) and len(lane) >= query.count("Rows(")
-    assert lane[-1] is ("Row(s=3)" in query)
 
 
 # ------------------------------------------------- (h) the served series

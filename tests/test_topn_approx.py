@@ -136,28 +136,22 @@ def test_ids_form_is_always_exact(env):
     assert [(p.id, p.count) for p in got] == [(NEEDLE, NEEDLE_BITS)]
 
 
-def test_quantized_ranking_adds_no_approximation(env):
-    """`topn-quantized-ranking` is a WIRE optimization, not a second
-    approximation layer: the 8-bit lane only reorders the candidate
-    RANKING, and the widened window is recounted exactly — so the
-    quantized DistExecutor matches the single-device Executor
-    byte-for-byte on every TopN form, including the adversarial
-    filtered shape (both lanes share phase 1's candidate window, so
-    they share its documented bound — nothing more). verify_quantized
-    re-runs the lossless recount in-process and raises on divergence,
-    and ids= queries bypass the lane entirely (already an exact
-    recount, nothing to rank)."""
+def test_the_mesh_adds_no_approximation(env):
+    """A mesh sums the same exact per-shard counts: the DistExecutor
+    matches the single-device Executor pair for pair on every TopN form,
+    including the adversarial filtered shape (both share phase 1's
+    candidate window, so they share its documented bound — nothing
+    more), and the ids= form that bypasses phase 1."""
     holder, ex, frag = env
     from pilosa_tpu.parallel import DistExecutor, make_mesh
 
-    quant = DistExecutor(holder, make_mesh(2), quantized_ranking=True,
-                         verify_quantized=True)
+    mesh = DistExecutor(holder, make_mesh(2))
     for pql in ("TopN(f, n=5)",
                 "TopN(f, n=3)",
                 "TopN(f, Row(g=1), n=3)",
                 "TopN(f, n=4, threshold=100)",
                 f"TopN(f, Row(g=1), ids=[{NEEDLE}, 1], n=0)"):
         (want,) = ex.execute("i", pql)
-        (got,) = quant.execute("i", pql)
+        (got,) = mesh.execute("i", pql)
         assert [(p.id, p.count) for p in got] == \
             [(p.id, p.count) for p in want], pql
