@@ -15,6 +15,8 @@ START_TIMEOUT_S = 300.0
 # the server's own start-up line names the port it bound
 LISTENING = re.compile(rb"listening on https?://[^ :]+:(\d+) ")
 STOP_TIMEOUT_S = 300.0
+# after the child's own exit, how long its process group may take to empty
+GROUP_EMPTY_S = 5.0
 # a first answer pays decode + upload + compile: minutes, not seconds
 HTTP_TIMEOUT_S = 900.0
 
@@ -87,7 +89,14 @@ class ServerProc:
     touches JAX. It binds port 0 and ``wait_ready`` reads the port it was
     given from the child's own log, so two runs on one machine (the
     driver's parent and change) can never be handed the same port, and no
-    other process's server is ever mistaken for this one."""
+    other process's server is ever mistaken for this one.
+
+    The child leads a session, and so a process group, of its own: a
+    deployment whose server spawns processes (``serving-workers``) is
+    stopped as a whole. SIGTERM goes to the child alone, since the clean
+    close is the program's to make; a kill goes to the group, and a group
+    that outlives a clean stop is an error. With no knobs the group is the
+    child."""
 
     def __init__(self, root: str, data_dir: str, log_path: str,
                  knobs: dict, env_extra: dict):
@@ -96,6 +105,7 @@ class ServerProc:
         self.memory_path = os.path.join(os.path.dirname(log_path),
                                         "memory_stats.json")
         env = dict(os.environ, BENCH_MEMORY_STATS=self.memory_path,
+                   BENCH_HARNESS_PID=str(os.getpid()),
                    PYTHONPATH=root + os.pathsep
                    + os.environ.get("PYTHONPATH", ""))
         for k, v in knobs.items():
@@ -107,7 +117,10 @@ class ServerProc:
         self.proc = subprocess.Popen(
             [sys.executable, child, "-d", data_dir, "--bind", "127.0.0.1",
              "--port", "0"],
-            cwd=root, env=env, stdout=self._log, stderr=subprocess.STDOUT)
+            cwd=root, env=env, stdout=self._log, stderr=subprocess.STDOUT,
+            start_new_session=True)
+        self.pgid = self.proc.pid
+        self._group_gone = False  # its number is then free for reuse
 
     def wait_ready(self) -> None:
         t0 = time.monotonic()
@@ -138,21 +151,82 @@ class ServerProc:
             self.proc.send_signal(signal.SIGTERM)
 
     def wait_stopped(self) -> int:
-        """Wait for the clean close to end. Returns the exit code."""
+        """Wait for the clean close to end. Returns the exit code. The
+        child's exit has to leave its group empty: what is still there
+        after GROUP_EMPTY_S is killed and named in a HarnessError."""
         try:
             rc = self.proc.wait(STOP_TIMEOUT_S)
         except subprocess.TimeoutExpired:
-            self.proc.kill()
-            rc = self.proc.wait(30)
+            self.kill()
+            return self.proc.returncode
         self._log.close()
+        deadline = time.monotonic() + GROUP_EMPTY_S
+        while left := self.group_pids():
+            if time.monotonic() >= deadline:
+                self._kill_group()
+                self._group_gone = True
+                raise HarnessError(
+                    f"server exit code {rc}, and its process group still "
+                    f"held pids {left} {GROUP_EMPTY_S:.0f} s later (killed)")
+            time.sleep(0.05)
+        self._group_gone = True
         return rc
 
     def kill(self) -> None:
+        """SIGKILL to the child's whole group, the child collected."""
+        self._kill_group()
         if self.proc.poll() is None:
-            self.proc.kill()
             self.proc.wait(30)
         if not self._log.closed:
             self._log.close()
+
+    def _kill_group(self) -> None:
+        """SIGKILL to the group, and what it had mapped under /dev/shm
+        removed: a killed deployment cannot unlink its shared memory (the
+        serving tier's rings), and nothing else on the machine would."""
+        if self._group_gone:
+            return
+        shared = self._group_shm()
+        try:
+            os.killpg(self.pgid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass  # empty already
+        for path in shared:
+            try:
+                os.unlink(path)
+            except OSError:
+                pass  # unlinked by its owner meanwhile
+
+    def _group_shm(self) -> set[str]:
+        paths = set()
+        for pid in self.group_pids():
+            try:
+                with open(f"/proc/{pid}/maps") as f:
+                    # "address perms offset dev inode pathname"
+                    fields = [line.split(None, 5) for line in f]
+            except OSError:
+                continue  # gone since the listing
+            paths.update(x[5].rstrip("\n") for x in fields if len(x) == 6
+                         and x[5].startswith("/dev/shm/")
+                         and not x[5].rstrip("\n").endswith(" (deleted)"))
+        return paths
+
+    def group_pids(self) -> list[int]:
+        """The processes of the child's group that still run (a zombie
+        waiting for its parent to collect it does not)."""
+        pids = []
+        for name in os.listdir("/proc"):
+            if not name.isdigit():
+                continue
+            try:
+                with open(f"/proc/{name}/stat", "rb") as f:
+                    # "pid (comm) state ppid pgrp ...": comm may hold ")"
+                    state, _, pgrp = f.read().rpartition(b")")[2].split()[:3]
+            except (OSError, ValueError):
+                continue  # gone between the listing and the read
+            if state != b"Z" and int(pgrp) == self.pgid:
+                pids.append(int(name))
+        return pids
 
     def memory_peak_bytes(self) -> int | None:
         """Peak bytes in use on the fullest device, after a clean stop."""

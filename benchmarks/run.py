@@ -9,8 +9,10 @@ traffic``, ``benchmarks/layer_metrics``); see ``benchmarks/README.md``.
 This process never imports JAX: the one server child holds the chips.
 
 Steps: (1) the cell's data from the seed, written as fragment files;
-(2) one server child, default knobs; (3) ``/info`` must list only TPU
-devices of a kind in ``peaks.json``, as many as the cell asks for;
+(2) one server child, default knobs unless the configuration's
+``server_knobs`` names a documented deployment setting; (3) ``/info``
+must list only TPU devices of a kind in ``peaks.json``, as many as the
+cell asks for;
 (4) the rows the traffic names made resident, a sample of every
 template answered, and the mix itself run at its own and at lower
 concurrency until a pass adds no entry to the compile cache; (5) the
@@ -420,12 +422,14 @@ def main(argv=None) -> int:
         log(f"wrote {n_bytes / 2**20:.0f} MiB of fragments in "
             f"{time.monotonic() - t:.1f} s")
         t = time.monotonic()
+        knobs = config.get("server_knobs", {})
         server = ServerProc(ROOT, data_dir, os.path.join(work, "server.log"),
-                            config.get("server_knobs", {}), env_extra)
+                            knobs, env_extra)
         server.wait_ready()
         with Conn(server.port) as c:
             device = check_devices(c, cell, args.rehearse)
-        log(f"server up on {device} in {time.monotonic() - t:.1f} s")
+        log(f"server up on {device} in {time.monotonic() - t:.1f} s, "
+            f"server_knobs {json.dumps(knobs, sort_keys=True)}")
         t = time.monotonic()
         if mix.get("preload"):
             preload(server.port, index, traffic.preload_rows(mix, config))

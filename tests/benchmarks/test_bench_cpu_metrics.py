@@ -8,20 +8,18 @@ nothing and raises nothing. A traced rehearsal of ``taxi-rides.point-rw``
 prints all eleven, one of ``taxi-rides.dashboard`` the nine that move in
 every cell; in both, CPU never exceeds the wall time it lies inside. A
 stage's CPU is read inside the traced run's capture (and inside a sampled
-trace) only, so its metric divides by the entries it was read in. Each
-rehearsal runs from a checkout of its own, so no other file's rehearsal
-of the same cell shares its work directory.
+trace) only, so its metric divides by the entries it was read in. The
+two rehearsals are the traced ones every file of this directory shares.
 """
 
 import json
 import os
-import shutil
-import subprocess
 import sys
 
 import pytest
 
-from bench_helpers import BENCH, CELLS, MANIFEST, ROOT, last_line
+from bench_helpers import (BENCH, CELLS, MANIFEST, ROOT, last_line,
+                           rehearsals)
 from harness import readers
 
 sys.path.insert(0, ROOT)
@@ -196,32 +194,10 @@ def test_the_eleven_follow_the_metrics_the_benchmark_had():
 
 
 @pytest.fixture(scope="module")
-def checkout(tmp_path_factory):
-    """A checkout of its own (the benchmark as it stands, the program by
-    symlink): ``run.py`` keeps its work files in ``benchmarks/.work/<cell>``
-    of the checkout it runs from, and three other files rehearse these
-    two cells from theirs in other workers."""
-    root = tmp_path_factory.mktemp("cpu-checkout")
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(BENCH, root / "benchmarks",
-                    ignore=shutil.ignore_patterns(".work", "__pycache__"))
-    os.symlink(os.path.join(ROOT, "pilosa_tpu"), root / "pilosa_tpu")
-    return str(root)
-
-
-_lines: dict = {}
-
-
-def traced_line(checkout: str, cell: str) -> dict:
-    if cell not in _lines:
-        p = subprocess.run(
-            [sys.executable, os.path.join(checkout, "benchmarks", "run.py"),
-             "--workload", cell, "--seed", "3600000011", "--seconds", "3",
-             "--trace", "1", "--rehearse"],
-            cwd=checkout, capture_output=True, text=True, timeout=600)
-        assert p.returncode == 0, p.stderr[-3000:] + p.stdout[-2000:]
-        _lines[cell] = last_line(p.stdout)
-    return _lines[cell]
+def traced(tmp_path_factory):
+    """The two cells' traced rehearsals, which every file of this
+    directory shares (``bench_helpers.rehearsals``)."""
+    return rehearsals(tmp_path_factory, [(POINT_RW, 1), (DASHBOARD, 1)])
 
 
 @pytest.mark.parametrize("cell,printed,left_out", [
@@ -229,9 +205,11 @@ def traced_line(checkout: str, cell: str) -> dict:
     (DASHBOARD, sorted(EVERY_CELL), sorted(POINT_RW_ONLY)),
 ], ids=["point-rw", "dashboard"])
 def test_traced_rehearsal_prints_the_cpu_metrics_of_its_cell(
-        checkout, cell, printed, left_out):
+        traced, cell, printed, left_out):
     assert cell in CELLS
-    line = traced_line(checkout, cell)
+    p = traced[cell, 1]
+    assert p.returncode == 0, p.stderr[-3000:] + p.stdout[-2000:]
+    line = last_line(p.stdout)
     assert line["correct"] is True and line["failed"] == 0
     assert line["device"]["platform"] == "cpu"  # counts, never device numbers
     metrics = line["metrics"]

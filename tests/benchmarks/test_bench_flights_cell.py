@@ -12,16 +12,16 @@ pins for the 13 forms.
 
 The mix is the issue's letter for letter: a rotation of the 13 once,
 every term as the source writes it. Two older tests of this directory
-cannot take that (``conftest.py`` beside this file says which cases and
-why, and keeps them off this cell), so this file holds the cell to what
-they were written to hold: every template in its fixed proportion over
-whole rotations, and the harness's reference equal to the parent's on
-every template the parent's can tabulate, and to a filter-then-count of
-the columns on all 13.
+could not take that until PR 47 (one counted 24 requests a client, the
+other handed Q4.3 to the parent's reference: 5.5e9 cells), so this file
+holds the cell to what they were written to hold: every template in its
+fixed proportion over whole rotations, and the harness's reference equal
+to the parent's on every template the parent's can tabulate, and to a
+filter-then-count of the columns on all 13.
 
 Pins are by membership and relative order, never by tail or count. The
-cell is rehearsed by ``test_bench_rehearse.py`` (one rehearsal is ~150 s
-of CPU), not a second time here.
+cell's two rehearsals (~150 s of CPU each) are the ones every file of
+this directory shares: run here or read from the worker that ran them.
 """
 
 import collections
@@ -33,7 +33,8 @@ import sys
 import numpy as np
 import pytest
 
-from bench_helpers import BENCH, CELLS, MANIFEST, ROOT, load_config, load_mix
+from bench_helpers import (BENCH, CELLS, MANIFEST, ROOT, last_line,
+                           load_config, load_mix, rehearsals)
 from harness import datagen, readers, reference, trace, traffic
 from xplane_writer import xspace
 
@@ -333,7 +334,8 @@ def test_every_seed_draws_inside_the_hot_set(seed):
     assert categories == set(range(5))
 
 
-# -------- what the two cases conftest.py keeps off this cell would hold
+# ---- what two older tests, written for rotations that divide 24 and for
+# ---- tables the parent's reference can hold, hold of this cell
 
 SEEDS = (4_100_000_007, 41, 2_147_483_659)
 
@@ -347,14 +349,6 @@ def test_rotation_keeps_templates_in_fixed_proportions_over_whole_rotations():
     clients = traffic.clients(mix, config, config["shards"], 3, "window")
     got = collections.Counter(c.next()[0] for c in clients for _ in range(n))
     assert got == {t: n * group["clients"] // 13 for t in ORDER}
-
-
-def parents_table_cells(config: dict, template: dict) -> int:
-    """Cells of the joint table ``ParentReference._sliced`` builds for a
-    template: its dimensions' and its filter fields' row counts."""
-    fields = {d["field"] for d in template.get("dims", ())}
-    fields |= {f for f, _ in template["filter"]}
-    return math.prod(datagen.field_rows(config["fields"][f]) for f in fields)
 
 
 def filter_then_count(cols: dict, sem: dict):
@@ -392,13 +386,15 @@ def test_the_reference_agrees_with_the_parents_and_with_the_columns(seed):
     table it can hold (2^20 cells: Q2.1-Q2.3 and Q3.1; the others' terms
     it cannot say, or Q3.2's 273 M and Q4.3's 5.5e9 cells); all 13 against
     a filter-then-count of the columns."""
-    from test_bench_terms import ParentReference, plain
+    from test_bench_terms import (PARENTS_TABLE_MAX, ParentReference,
+                                  parents_table_cells, plain)
     config, mix = load_config(CONFIG), load_mix("q-flight")
     cols = datagen.make_columns(config, seed, 2,
                                 traffic.fields_read(mix, config))
     old, new = ParentReference(config, cols), reference.Reference(config, cols)
     fits = {name for name, t in mix["templates"].items()
-            if plain(t) and parents_table_cells(config, t) <= 1 << 20}
+            if plain(t)
+            and parents_table_cells(config, t) <= PARENTS_TABLE_MAX}
     assert fits == {"q2_1", "q2_2", "q2_3", "q3_1"}
     assert {name for name, t in mix["templates"].items() if plain(t)} \
         == fits | {"q3_2", "q4_3"}
@@ -561,3 +557,53 @@ def test_flight_groupby_level_share_finds_dense_and_pruned_levels(tmp_path):
                         {}) == pytest.approx(50.0)
     assert readers.read(BENCH, "device_idle_share", {}, {}, reduced,
                         {}) == pytest.approx(48.0)
+
+
+# ------------------------------------------------------- the cell rehearsed
+
+
+@pytest.fixture(scope="module")
+def both(tmp_path_factory):
+    """The cell's two rehearsals, which every file of this directory
+    shares (``bench_helpers.rehearsals``)."""
+    return rehearsals(tmp_path_factory, [(CELL, 0), (CELL, 1)])
+
+
+def test_rehearsal_is_correct_on_the_thirteen_queries_and_its_control_is_not(
+        both):
+    p = both[CELL, 0]
+    assert p.returncode == 0, p.stderr[-3000:] + p.stdout[-2000:]
+    line = last_line(p.stdout)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    assert set(line["metrics"]) == {"throughput", "read_p50_ms",
+                                    "read_p95_ms", "setup_s"}
+    checks = [l for l in p.stdout.splitlines()
+              if l.startswith("check answers.")]
+    assert [l.split()[1] for l in checks] == [f"answers.{t}:" for t in ORDER]
+    assert all(" wrong=0 limit=0" in l for l in checks)
+    # a Sum or a group's count over half the shards, doubled, is neither
+    assert "control[sampled]: correct=False" in p.stdout
+    wrong = [l for l in p.stdout.splitlines()
+             if l.startswith("control[sampled] ") and " wrong=0 " not in l]
+    assert len(wrong) >= 10
+
+
+def test_traced_rehearsal_prints_the_plan_metrics_of_the_flights(both):
+    p = both[CELL, 1]
+    assert p.returncode == 0, p.stderr[-3000:] + p.stdout[-2000:]
+    line = last_line(p.stdout)
+    assert line["correct"] is True and line["failed"] == 0
+    metrics = line["metrics"]
+    listed = {m["name"] for m in MANIFEST["per_layer"]
+              if CELL in m.get("workloads", [CELL])}
+    assert set(metrics) <= listed
+    # the counters every GroupBy moves are read at one shard too; the
+    # prune stage's twins are left out where no level was pruned
+    for name in ("flight_pruned_groupby_share", "flight_levels_per_groupby",
+                 "flight_level_programs_per_level",
+                 "flight_candidates_per_level", "flight_paged_program_share"):
+        assert metrics[name]["unit"] == NEW[name][0], name
+        assert metrics[name]["value"] >= 0, name
+    assert metrics["flight_levels_per_groupby"]["value"] >= 1.0
+    assert metrics["residency_evictions_in_window"]["value"] == 0.0

@@ -13,15 +13,13 @@ take every cell of the manifest."""
 import collections
 import json
 import os
-import shutil
-import subprocess
 import sys
 
 import numpy as np
 import pytest
 
 from bench_helpers import (BENCH, CELLS, MANIFEST, ROOT, last_line,
-                           load_config, load_mix)
+                           load_config, load_mix, rehearsals)
 from harness import datagen, readers, traffic
 from harness.reference import Reference
 
@@ -335,36 +333,22 @@ def test_the_program_exports_the_series_the_ratios_name():
 
 
 @pytest.fixture(scope="module")
-def checkout(tmp_path_factory):
-    """A checkout of its own (the benchmark as it stands, the program by
-    symlink): ``test_bench_rehearse.py`` and ``test_bench_stage_metrics.py``
-    rehearse the same cell from theirs in other workers, and ``run.py``
-    keeps its work files inside the checkout it runs from."""
-    root = tmp_path_factory.mktemp("grid-checkout")
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(BENCH, root / "benchmarks",
-                    ignore=shutil.ignore_patterns(".work", "__pycache__"))
-    os.symlink(os.path.join(ROOT, "pilosa_tpu"), root / "pilosa_tpu")
-    return str(root)
-
-
-def _rehearse(checkout: str, *extra: str):
-    return subprocess.run(
-        [sys.executable, os.path.join(checkout, "benchmarks", "run.py"),
-         "--workload", GRID, "--seed", "3400000029", "--seconds", "3",
-         "--rehearse", *extra],
-        cwd=checkout, capture_output=True, text=True, timeout=900)
+def both(tmp_path_factory):
+    """The cell's two rehearsals, which every file of this directory
+    shares (``bench_helpers.rehearsals``): run here or read from the
+    worker that ran them."""
+    return rehearsals(tmp_path_factory, [(GRID, 0), (GRID, 1)])
 
 
 @pytest.fixture(scope="module")
-def untraced(checkout):
+def untraced(both):
     """The end-to-end run, with the control compared after it."""
-    return _rehearse(checkout, "--trace", "0", "--control", "sampled")
+    return both[GRID, 0]
 
 
 @pytest.fixture(scope="module")
-def traced(checkout):
-    return _rehearse(checkout, "--trace", "1")
+def traced(both):
+    return both[GRID, 1]
 
 
 def test_rehearsal_is_correct_on_the_four_queries(untraced):

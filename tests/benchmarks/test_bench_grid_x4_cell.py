@@ -7,22 +7,19 @@ readers the harness has, and return nothing (and raise nothing) against
 a program that lacks their series or their kernel, as the parent does on
 a mesh; and the four-chip cell rehearsed (``--rehearse``: 8 shards on
 four virtual CPU devices) is ``correct``, prints the seven when traced,
-and comes out not correct under ``--control sampled``. The rehearsals
-run from a checkout of their own (``run.py`` keeps its work files in the
-checkout it runs from, and other files rehearse every cell from theirs).
+and comes out not correct under ``--control sampled``. The two
+rehearsals are the ones every file of this directory shares.
 """
 
 import collections
 import json
 import os
-import shutil
-import subprocess
 import sys
 
 import pytest
 
 from bench_helpers import (BENCH, CELLS, MANIFEST, ROOT, last_line,
-                           load_config, load_mix)
+                           load_config, load_mix, rehearsals)
 from harness import datagen, readers, trace, traffic
 from xplane_writer import xspace
 
@@ -75,7 +72,7 @@ def test_configuration_is_the_grid_schema_at_the_x4_scale():
     assert config["reduced"] == ["shards"] == list(config["reduced_why"])
     assert config["assumed"][:-1] == base["assumed"]
     assert config["assumed"][-1] == x4["assumed"][-1]
-    assert "flat 1-D mesh of four chips, mesh-groups unset" in (
+    assert "flat 1-D mesh of four chips, no knob set" in (
         config["assumed"][-1])
     entry = {c["name"]: c for c in MANIFEST["configs"]}["taxi-rides-grid-x4"]
     assert entry["source"] == config["source"] and len(entry["source"]) < 200
@@ -281,34 +278,22 @@ def test_expand_rows_share_finds_the_kernel_on_four_device_planes(tmp_path):
 
 
 @pytest.fixture(scope="module")
-def checkout(tmp_path_factory):
-    """A checkout of its own: the benchmark as it stands, the program by
-    symlink."""
-    root = tmp_path_factory.mktemp("grid-x4-checkout")
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(BENCH, root / "benchmarks",
-                    ignore=shutil.ignore_patterns(".work", "__pycache__"))
-    os.symlink(os.path.join(ROOT, "pilosa_tpu"), root / "pilosa_tpu")
-    return str(root)
-
-
-def _rehearse(checkout: str, *extra: str):
-    return subprocess.run(
-        [sys.executable, os.path.join(checkout, "benchmarks", "run.py"),
-         "--workload", X4, "--seed", "3900000029", "--seconds", "3",
-         "--rehearse", *extra],
-        cwd=checkout, capture_output=True, text=True, timeout=900)
+def both(tmp_path_factory):
+    """The cell's two rehearsals, which every file of this directory
+    shares (``bench_helpers.rehearsals``): run here or read from the
+    worker that ran them."""
+    return rehearsals(tmp_path_factory, [(X4, 0), (X4, 1)])
 
 
 @pytest.fixture(scope="module")
-def untraced(checkout):
+def untraced(both):
     """The end-to-end run, with the control compared after it."""
-    return _rehearse(checkout, "--trace", "0", "--control", "sampled")
+    return both[X4, 0]
 
 
 @pytest.fixture(scope="module")
-def traced(checkout):
-    return _rehearse(checkout, "--trace", "1")
+def traced(both):
+    return both[X4, 1]
 
 
 def test_rehearsal_on_four_virtual_devices_is_correct(untraced):
@@ -361,3 +346,18 @@ def test_traced_rehearsal_prints_the_seven(traced):
               if X4 in m.get("workloads", [X4])}
     assert set(NEW) <= set(metrics) <= listed
     assert "collective_share" not in metrics  # that list is the x4 dashboard's
+
+
+def test_the_sweep_rehearsed_is_correct_on_the_three_queries(tmp_path_factory):
+    """``ssb-lineorder.brand-sweep``, the other cell of ISSUE 39, from the
+    rehearsals every file of this directory shares."""
+    p = rehearsals(tmp_path_factory, [(SWEEP, 0)])[SWEEP, 0]
+    assert p.returncode == 0, p.stderr[-3000:] + p.stdout[-2000:]
+    line = last_line(p.stdout)
+    assert line["correct"] is True and line["failed"] == 0
+    checks = [l for l in p.stdout.splitlines()
+              if l.startswith("check answers.")]
+    assert [l.split()[1] for l in checks] == [
+        "answers.q2_1:", "answers.q2_2:", "answers.q2_3:"]
+    assert all(" wrong=0 limit=0" in l for l in checks)
+    assert "control[sampled]: correct=False" in p.stdout

@@ -6,6 +6,7 @@ timed path come out not correct, and no TPU without ``--rehearse`` is an
 error before anything is loaded."""
 
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -13,27 +14,31 @@ import time
 
 import pytest
 
-from bench_helpers import (BENCH, CELLS, CONTROL, MANIFEST, ROOT, RUN,
-                           import_run, last_line, load_config, load_mix,
-                           rehearse)
+from bench_helpers import (BENCH, CELLS, CONTROL, KINDS, MANIFEST, ROOT, RUN,
+                           SHARED_SEED, import_run, last_line, load_config,
+                           load_mix, rehearsals, rehearse, shared_dir,
+                           shared_key)
 from harness import datagen, traffic
 
-# one rehearsal per cell, shared by the tests below; traced for the cells
-# with metrics no other cell reads
+# the traced line is held to the contract too in the cells with metrics
+# no other cell reads
 TRACED = {"taxi-rides.point-rw"}
-_runs: dict = {}
 
 
-def run_of(name: str):
-    if name not in _runs:
-        _runs[name] = rehearse(name, "--control", CONTROL[name],
-                               trace=int(name in TRACED))
-    return _runs[name]
+@pytest.fixture(scope="module")
+def runs(tmp_path_factory):
+    """Every cell's untraced rehearsal with its control, and the traced
+    one of TRACED: the runs every file of this directory shares. The taxi
+    cells first: the SSB cells, 100-150 s each, are made meanwhile by the
+    workers that run those cells' own files."""
+    return rehearsals(
+        tmp_path_factory, [(name, 0) for name in sorted(CELLS, reverse=True)]
+        + [(name, 1) for name in sorted(TRACED & set(CELLS))])
 
 
 @pytest.mark.parametrize("name", sorted(CELLS))
-def test_rehearsal_is_correct_on_every_template(name):
-    p = run_of(name)
+def test_rehearsal_is_correct_on_every_template(runs, name):
+    p = runs[name, 0]
     assert p.returncode == 0, p.stderr[-3000:] + p.stdout[-2000:]
     line = last_line(p.stdout)
     assert line["correct"] is True and line["failed"] == 0
@@ -50,9 +55,14 @@ def test_rehearsal_is_correct_on_every_template(name):
 
 
 @pytest.mark.parametrize("name", sorted(CELLS))
-def test_last_line_has_exactly_the_contracts_keys(name):
-    line = last_line(run_of(name).stdout)
-    traced = name in TRACED
+def test_last_line_has_exactly_the_contracts_keys(runs, name):
+    line_keeps_to_the_contract(name, last_line(runs[name, 0].stdout), False)
+    if name in TRACED:
+        line_keeps_to_the_contract(name, last_line(runs[name, 1].stdout),
+                                   True)
+
+
+def line_keeps_to_the_contract(name: str, line: dict, traced: bool) -> None:
     want = {"correct", "attempted", "failed", "metrics", "device"}
     assert set(line) == want | ({"breakdown"} if traced else set())
     device = {"platform", "kind", "count", "memory_peak_bytes"}
@@ -81,11 +91,11 @@ def test_last_line_has_exactly_the_contracts_keys(name):
 
 
 @pytest.mark.parametrize("name", sorted(CELLS))
-def test_control_comes_out_not_correct(name):
+def test_control_comes_out_not_correct(runs, name):
     """The reference with one stated guarantee broken (answers sampled
     and scaled instead of exact; an acknowledged write lost) has to fail
     at least one of the cell's numbers. run.py exits 3 if it passes."""
-    p = run_of(name)
+    p = runs[name, 0]
     kind = CONTROL[name]
     assert f"control[{kind}]: correct=False" in p.stdout
     wrong = [l for l in p.stdout.splitlines()
@@ -93,10 +103,53 @@ def test_control_comes_out_not_correct(name):
     assert wrong
 
 
-def test_every_acknowledged_write_is_read_back():
+# the files that start a rehearsal of their own, and why the shared runs
+# would not do
+OWN_REHEARSALS = {
+    "bench_helpers.py": "the one place that spells the command",
+    "test_bench_rehearse.py": "the timed path broken in this process; a "
+                              "cell that is not there",
+    "test_bench_rehearse_knobs.py": "the work directories, watched from "
+                                    "inside the run",
+    "test_bench_mesh_cell.py": "its own seed (ISSUE 27's)",
+    "test_bench_server_group.py": "a scratch configuration",
+}
+
+
+def test_a_cell_is_rehearsed_in_two_kinds_and_no_third(runs, tmp_path_factory):
+    """Tier-1 rehearses a shipped cell twice: untraced with its control,
+    and traced, both with the shared seed. The helper names no other
+    kind, what the session has kept is of those kinds, and a file that
+    starts a rehearsal of its own is on the list above."""
+    keys = {shared_key(cell, trace) for cell in CELLS for trace in KINDS}
+    assert len(keys) == 2 * len(CELLS)
+    assert shared_key("taxi-rides.point-rw", 0) == (
+        f"taxi-rides.point-rw.t0.lost-write.{SHARED_SEED}")
+    assert shared_key("taxi-rides.dashboard", 1) == (
+        f"taxi-rides.dashboard.t1.none.{SHARED_SEED}")
+    for cell, trace in (("taxi-rides.dashboard", 2), ("no-such.cell", 0)):
+        with pytest.raises(ValueError, match="no shared rehearsal"):
+            shared_key(cell, trace)
+    kept = {name for name in os.listdir(shared_dir(tmp_path_factory))
+            if not name.endswith(".lock")}
+    assert {shared_key(cell, trace) for cell, trace in runs} <= kept <= keys
+    here = os.path.dirname(os.path.abspath(__file__))
+    own = set()
+    for name in sorted(os.listdir(here)):
+        if name.endswith(".py"):
+            with open(os.path.join(here, name)) as f:
+                text = f.read()
+            # the flag as an argument, or a call of the plain helper
+            if '"--rehearse"' in text or re.search(r"(?<![\w.`])rehearse\(",
+                                                   text):
+                own.add(name)
+    assert own == set(OWN_REHEARSALS)
+
+
+def test_every_acknowledged_write_is_read_back(runs):
     if "taxi-rides.point-rw" not in CELLS:
         pytest.skip("no cell with writes")
-    out = run_of("taxi-rides.point-rw").stdout
+    out = runs["taxi-rides.point-rw", 1].stdout
     acked = [l for l in out.splitlines()
              if l.startswith("check writes.acknowledged_bits_read_back")][0]
     window = [l for l in out.splitlines() if l.startswith("window:")][0]
@@ -164,7 +217,7 @@ def test_refuses_a_directory_with_only_the_benchmark(tmp_path):
     assert p.returncode != 0 and p.stdout.strip() == ""
 
 
-def test_the_port_is_the_one_the_server_says_it_bound():
+def test_the_port_is_the_one_the_server_says_it_bound(runs):
     """The child binds port 0; the harness reads the port from the
     server's own start-up line, whatever else the log holds."""
     from harness.serving import LISTENING
@@ -173,8 +226,9 @@ def test_the_port_is_the_one_the_server_says_it_bound():
            b"(data-dir /x/data, node node-43817, devices 1 x tpu TPU v5 lite)\n")
     assert int(LISTENING.search(log).group(1)) == 43817
     assert LISTENING.search(b"listening soon\n") is None
-    p = run_of(sorted(CELLS)[0])
-    assert "server up on" in p.stderr
+    p = runs[sorted(CELLS)[0], 0]
+    # and the run says what it handed the child: the default knobs
+    assert "server up on" in p.stderr and ", server_knobs {}\n" in p.stderr
 
 
 def test_unknown_workload_is_refused():

@@ -10,14 +10,13 @@ assertions: one costs over a minute on the CPU."""
 import collections
 import json
 import os
-import shutil
 import subprocess
 import sys
 
 import pytest
 
 from bench_helpers import (BENCH, CELLS, MANIFEST, ROOT, last_line,
-                           load_config, load_mix)
+                           load_config, load_mix, rehearsals)
 from harness import datagen, readers, trace, traffic
 from xplane_writer import xspace
 
@@ -230,36 +229,22 @@ def test_groupby_level_share_finds_the_kernel_of_a_written_plane(tmp_path):
 
 
 @pytest.fixture(scope="module")
-def checkout(tmp_path_factory):
-    """A checkout of its own (the benchmark as it stands, the program by
-    symlink): ``test_bench_rehearse.py`` and ``test_bench_stage_metrics.py``
-    rehearse the same cell from theirs in other workers, and ``run.py``
-    keeps its work files inside the checkout it runs from."""
-    root = tmp_path_factory.mktemp("ssb-checkout")
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(BENCH, root / "benchmarks",
-                    ignore=shutil.ignore_patterns(".work", "__pycache__"))
-    os.symlink(os.path.join(ROOT, "pilosa_tpu"), root / "pilosa_tpu")
-    return str(root)
-
-
-def _rehearse(checkout: str, *extra: str):
-    return subprocess.run(
-        [sys.executable, os.path.join(checkout, "benchmarks", "run.py"),
-         "--workload", CELL, "--seed", "3200000029", "--seconds", "3",
-         "--rehearse", *extra],
-        cwd=checkout, capture_output=True, text=True, timeout=900)
+def both(tmp_path_factory):
+    """The cell's two rehearsals, which every file of this directory
+    shares (``bench_helpers.rehearsals``): run here or read from the
+    worker that ran them."""
+    return rehearsals(tmp_path_factory, [(CELL, 0), (CELL, 1)])
 
 
 @pytest.fixture(scope="module")
-def untraced(checkout):
+def untraced(both):
     """The end-to-end run, with the control compared after it."""
-    return _rehearse(checkout, "--trace", "0", "--control", "sampled")
+    return both[CELL, 0]
 
 
 @pytest.fixture(scope="module")
-def traced(checkout):
-    return _rehearse(checkout, "--trace", "1")
+def traced(both):
+    return both[CELL, 1]
 
 
 def test_rehearsal_is_correct_on_the_three_queries(untraced):

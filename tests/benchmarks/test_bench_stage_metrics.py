@@ -5,13 +5,12 @@ new metrics its ``workloads`` key allows, each with a value."""
 
 import json
 import os
-import shutil
-import subprocess
 import sys
 
 import pytest
 
-from bench_helpers import BENCH, CELLS, MANIFEST, ROOT, last_line
+from bench_helpers import (BENCH, CELLS, MANIFEST, ROOT, last_line,
+                           rehearsals)
 
 sys.path.insert(0, ROOT)
 
@@ -23,33 +22,16 @@ STAGE_METRICS = {
 }
 ENTRIES = {m["name"]: m for m in MANIFEST["per_layer"]
            if m["name"] in STAGE_METRICS}
-_runs: dict = {}
 
 
 @pytest.fixture(scope="module")
-def checkout(tmp_path_factory):
-    """A second checkout for this file's rehearsals: the benchmark as it
-    stands, the program by symlink. ``run.py`` keeps its work files and
-    the compile cache inside its checkout, and ``test_bench_rehearse.py``
-    rehearses the same cells from the real one in another worker."""
-    root = tmp_path_factory.mktemp("checkout")
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    shutil.copytree(BENCH, root / "benchmarks",
-                    ignore=shutil.ignore_patterns(".work", "__pycache__"))
-    os.symlink(os.path.join(ROOT, "pilosa_tpu"), root / "pilosa_tpu")
-    return str(root)
-
-
-def traced_line(checkout: str, cell: str) -> dict:
-    if cell not in _runs:
-        p = subprocess.run(
-            [sys.executable, os.path.join(checkout, "benchmarks", "run.py"),
-             "--workload", cell, "--seed", "2600000011", "--seconds", "3",
-             "--trace", "1", "--rehearse"],
-            cwd=checkout, capture_output=True, text=True, timeout=600)
-        assert p.returncode == 0, p.stderr[-3000:] + p.stdout[-2000:]
-        _runs[cell] = last_line(p.stdout)
-    return _runs[cell]
+def traced(tmp_path_factory):
+    """Every cell's traced rehearsal: the runs every file of this
+    directory shares, each with a compile cache of its own. The taxi cells
+    first: the SSB cells, 100-150 s each, are made meanwhile by the
+    workers that run those cells' own files."""
+    return rehearsals(tmp_path_factory,
+                      [(cell, 1) for cell in sorted(CELLS, reverse=True)])
 
 
 def spec_of(name: str) -> dict:
@@ -104,8 +86,10 @@ def test_host_attributed_share_sums_the_nine_top_level_stages():
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_traced_rehearsal_carries_the_metrics_its_workloads_key_allows(
-        checkout, cell):
-    line = traced_line(checkout, cell)
+        traced, cell):
+    p = traced[cell, 1]
+    assert p.returncode == 0, p.stderr[-3000:] + p.stdout[-2000:]
+    line = last_line(p.stdout)
     assert line["correct"] is True and line["failed"] == 0
     allowed = {n for n, m in ENTRIES.items()
                if cell in m.get("workloads", [cell])}

@@ -17,6 +17,7 @@ at the size of the dimensions (``reference.py``).
 """
 
 import collections
+import math
 import os
 import tracemalloc
 import types
@@ -200,10 +201,37 @@ def plain(template: dict) -> bool:
                    for _, spec in template.get("filter", ()))
 
 
+# the parent's reference tabulates a template by the joint table of its
+# dimensions and its filter fields; past this many cells it is not asked
+# (SSB Q3.2: 273 M cells; Q4.3: 5.5e9, 44 GB)
+PARENTS_TABLE_MAX = 1 << 20
+
+
+def parents_table_cells(config: dict, template: dict) -> int:
+    """Cells of the joint table ``ParentReference._sliced`` builds for a
+    template: its dimensions' and its filter fields' row counts (a field
+    the seed draws counts as the configuration's largest set field)."""
+    fields = {d["field"] for d in template.get("dims", ())}
+    fields |= {f for f, _ in template.get("filter", ())}
+    drawn = max(field_rows(spec) for spec in config["fields"].values()
+                if spec["type"] == "set")
+    return math.prod(field_rows(config["fields"][f])
+                     if f in config["fields"] else drawn for f in fields)
+
+
 @pytest.mark.parametrize("seed", SEEDS)
 @pytest.mark.parametrize("cell", PAIRS, ids=lambda c: c["name"])
 def test_old_and_new_reference_agree(cell, seed):
     config, mix = config_and_mix(cell)
+    sayable = {name for name, t in mix["templates"].items()
+               if t["kind"] not in traffic.WRITE_KINDS and plain(t)}
+    fits = {name for name in sayable if parents_table_cells(
+        config, mix["templates"][name]) <= PARENTS_TABLE_MAX}
+    for name in sorted(sayable - fits):
+        print(f"{cell['name']}: template {name} left out for size: the "
+              f"parent's joint table would have "
+              f"{parents_table_cells(config, mix['templates'][name]):,} "
+              f"cells, over 2^20")
     cols = datagen.make_columns(config, seed, 2,
                                 traffic.fields_read(mix, config))
     old, new = ParentReference(config, cols), Reference(config, cols)
@@ -214,7 +242,7 @@ def test_old_and_new_reference_agree(cell, seed):
             name, _pql, sem = client.next()
             if sem["kind"] in traffic.WRITE_KINDS:
                 writes.append(sem)
-            elif plain(mix["templates"][name]):
+            elif name in fits:
                 reads.append((name, sem))
     if writes:
         # beside the mix's own writes (random columns, which seldom meet a
@@ -252,9 +280,8 @@ def test_old_and_new_reference_agree(cell, seed):
         for acked_only in (True, False):
             assert (new.row_count(w["field"], w["row"], acked_only)
                     == old.row_count(w["field"], w["row"], acked_only))
-    want = {name for name, t in mix["templates"].items()
-            if t["kind"] not in traffic.WRITE_KINDS and plain(t)}
-    assert set(compared) == want and min(compared.values()) >= 2
+    # every template the parent could say and could hold was compared
+    assert set(compared) == fits and min(compared.values()) >= 2
     if cell["traffic"] == "point-rw":
         assert len(writes) >= 200
         moved = [sem for _, sem in reads if new.count(

@@ -1,6 +1,7 @@
 """The traffic generator: the seed draws constants, never work."""
 
 import collections
+import math
 
 import pytest
 
@@ -51,12 +52,15 @@ def test_other_seed_other_constants_same_shapes_and_counts(cell):
 @pytest.mark.parametrize("cell", PAIRS, ids=lambda c: c["name"])
 def test_rotation_keeps_templates_in_fixed_proportions(cell):
     mix = config_and_mix(cell)[1]
-    run = stream(cell, SEEDS[0], n=24 * sum(g["clients"] for g in mix["groups"]))
-    got = collections.Counter(r[0] for c in run for r in c[:24])
+    # whole rotations of every group: a rotation's length need not divide
+    # 24 (the 13 SSB queries do not)
+    n = math.lcm(24, *(len(g["rotation"]) for g in mix["groups"]))
+    run = stream(cell, SEEDS[0], n=n * sum(g["clients"] for g in mix["groups"]))
+    got = collections.Counter(r[0] for c in run for r in c[:n])
     want = collections.Counter()
     for g in mix["groups"]:
         for t in g["rotation"]:
-            want[t] += 24 * g["clients"] // len(g["rotation"])
+            want[t] += n * g["clients"] // len(g["rotation"])
     assert got == want
 
 
