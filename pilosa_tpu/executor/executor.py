@@ -24,6 +24,7 @@ import math
 import threading
 import time
 import weakref
+from bisect import bisect_right
 from itertools import repeat
 from typing import Callable
 
@@ -1381,15 +1382,12 @@ class Executor:
             if explicit_ids is not None:
                 candidates = sorted(int(i) for i in explicit_ids)
             else:
-                # phase 1: per-shard candidates from the ranked caches
+                # phase 1: per-shard candidates from the ranked caches,
+                # folded by the view once per version of it
                 overfetch = max(n * TOPN_CANDIDATE_FACTOR, n + 10)
-                cand: set[int] = set()
-                for shard in shard_list:
-                    frag = view.fragment(shard) if view else None
-                    if frag is None:
-                        continue
-                    cand.update(r for r, _ in frag.top(overfetch))
-                candidates = sorted(cand)
+                candidates = list(view.topn_fold(
+                    shard_list, overfetch, keep=shards is None,
+                )) if view else []
             candidates = self._filter_topn_candidates(field, call, candidates)
             if not candidates:
                 return Deferred(value=[])
@@ -1533,26 +1531,20 @@ class Executor:
         view = field.view(VIEW_STANDARD)
         if view is None:
             return []
-        rows: set[int] = set()
         if column is not None:
-            shard = shard_of(int(column))
-            pos = position(int(column))
-            frag = view.fragment(shard)
-            if frag is not None:
-                rows.update(frag.rows_containing(pos))
+            frag = view.fragment(shard_of(int(column)))
+            out = (sorted(set(frag.rows_containing(position(int(column)))))
+                   if frag is not None else [])
         else:
-            # one O(#containers) metadata pass per fragment — exact
-            # non-empty rows with no per-row count loop (fragment.row_counts)
-            for shard in self._shards(idx, shards):
-                frag = view.fragment(shard)
-                if frag is not None:
-                    rows.update(frag.row_counts()[0].tolist())
-        out = sorted(rows)
+            # the view folds its fragments' non-empty rows once per
+            # version of it; the tuple is its own, sliced into a new list
+            out = view.rows_fold(self._shards(idx, shards),
+                                 keep=shards is None)
         if previous is not None:
-            out = [r for r in out if r > int(previous)]
+            out = out[bisect_right(out, int(previous)):]
         if limit:
             out = out[: int(limit)]
-        return out
+        return list(out)
 
     # -------------------------------------------------------------- GroupBy
 

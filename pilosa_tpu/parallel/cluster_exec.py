@@ -203,6 +203,13 @@ class ClusterExecutor:
         shard_list = shards if shards is not None else self._all_shards(idx.name)
         local, groups = self._route(idx.name, shard_list)
         if not groups:
+            if shards is None and local == idx.available_shards():
+                # the whole of this node's index and no shard named: say
+                # so. The local executor then works from
+                # Index.available_shards' one list object, which its
+                # block memo and the views' folds are kept by; a list
+                # made here is new to them every request
+                local = None
             return self.local.submit(idx.name, call, shards=local,
                                      deadline=deadline)[0]
         if name == "TopN":
@@ -275,6 +282,10 @@ class ClusterExecutor:
     def _route(self, index_name: str, shards: list[int]):
         """Group shards by executing node (primary live replica; self
         preferred when we are any replica)."""
+        if self.cluster.nodes.keys() == {self.cluster.local.id}:
+            # a cluster of this node alone: shard_nodes can name no other
+            # owner, so there is nothing to ask it a shard, a request
+            return list(shards), []
         local: list[int] = []
         remote: dict[str, tuple[Node, list[int]]] = {}
         for shard in shards:

@@ -304,19 +304,11 @@ class Scrubber:
                         return  # concurrently deleted: deletion wins
                     stale.close(discard=True)
                     quarantine_paths(frag.path, reason=str(err))
-                    from pilosa_tpu.storage.fragment import Fragment
-
-                    fresh = Fragment(
-                        frag.path, iname, fname, view.name, shard,
-                        cache_type=view.cache_type,
-                        cache_size=view.cache_size, scope=view.scope,
-                        wal=view.wal,
-                        verify_on_load=view.verify_on_load,
-                    ).open()
+                    fresh = view.new_fragment(shard).open()
                     fresh.import_roaring_bitmap(copy)
                     fresh.snapshot()  # durable + fresh sidecar
                     fresh.recalculate_cache()
-                    view.fragments[shard] = fresh
+                    view.adopt(shard, fresh)
             except OSError as e:
                 self.unrepaired += 1
                 out["unrepaired"] += 1

@@ -838,6 +838,7 @@ class HTTPHandler(BaseHTTPRequestHandler):
             device_memory_by_device,
             device_metrics,
             groupby_metrics,
+            plan_metrics,
             stage_metrics,
             thread_metrics,
         )
@@ -850,6 +851,7 @@ class HTTPHandler(BaseHTTPRequestHandler):
         text += prometheus_block(thread_metrics(), prefix, seen=seen)
         text += prometheus_block(groupby_metrics(), prefix, "groupby",
                                  seen=seen)
+        text += prometheus_block(plan_metrics(), prefix, "plan", seen=seen)
         text += prometheus_block(device_metrics(), prefix, "device",
                                  seen=seen)
         for d in device_memory_by_device():
@@ -1132,6 +1134,7 @@ class HTTPHandler(BaseHTTPRequestHandler):
         from pilosa_tpu.utils.tracing import (
             device_metrics,
             groupby_metrics,
+            plan_metrics,
             stage_metrics,
             thread_metrics,
         )
@@ -1139,6 +1142,7 @@ class HTTPHandler(BaseHTTPRequestHandler):
         snap["stages"] = stage_metrics()
         snap["threads"] = thread_metrics()
         snap["groupby"] = groupby_metrics()
+        snap["plan"] = plan_metrics()
         snap["device"] = device_metrics()
         from pilosa_tpu.parallel.dist import global_reduce_stats
 

@@ -80,6 +80,10 @@ def _group_by_row(rows: np.ndarray, positions: np.ndarray):
         yield int(r), sorted_pos[bounds[i]:bounds[i + 1]]
 
 
+def _nobody() -> None:
+    """``Fragment._on_change`` of a fragment no view owns."""
+
+
 class Fragment:
     def __init__(
         self,
@@ -94,6 +98,7 @@ class Fragment:
         scope: str = "",
         wal=None,
         verify_on_load: bool = False,
+        on_change=None,
     ):
         self.path = path
         self.index = index
@@ -128,6 +133,10 @@ class Fragment:
         # monotonic content version: bumped on every mutation (see
         # _log_op); validates the row_counts memo
         self.mutations = 0
+        # the owning view's ``touch`` (View.new_fragment), called when a
+        # change to the bitmap or to row_cache is complete (_publish,
+        # recalculate_cache); a fragment built alone has nobody to tell
+        self._on_change = on_change or _nobody
         self._row_counts_memo: tuple | None = None
         self._blocks_memo: tuple | None = None
         self.snapshot_threshold = snapshot_threshold
@@ -498,8 +507,7 @@ class Fragment:
             from pilosa_tpu.utils.stats import global_stats
 
             global_stats().count("fragment_row_writes", n_rows)
-            rescache.invalidate_write(self.scope, self.index, self.field,
-                                      self.shard)
+            self._publish()
             if current_cost() is not None:
                 bits = sum(len(p) for _, p in rows_added)
                 bits += sum(len(p) for _, p in rows_removed)
@@ -697,8 +705,9 @@ class Fragment:
                 self.index, self.field, self.view, self.shard, row,
                 scope=self.scope,
             ))
-        rescache.invalidate_write(self.scope, self.index, self.field,
-                                  self.shard)
+        # row_cache is as it was: the caller recounts it afterwards
+        # (recalculate_cache publishes again)
+        self._publish()
 
     def snapshot(self) -> None:
         """Compact: rewrite the file as a clean snapshot, dropping the log
@@ -807,8 +816,7 @@ class Fragment:
         # ONE result-cache write event per batch (the per-row calls
         # above pass count_stat=False and skip theirs) — unconditional:
         # the cost kill switch gates accounting, never correctness
-        rescache.invalidate_write(self.scope, self.index, self.field,
-                                  self.shard)
+        self._publish()
         if current_cost() is not None:
             # one heat record per batch, weighted by written bits — same
             # lock-amortization reasoning as the counter above. Gated on
@@ -849,8 +857,7 @@ class Fragment:
             # write can never be masked by stale cached bytes.
             # Batch paths (count_stat=False, from _after_rows_added)
             # invalidate once per batch instead of once per row.
-            rescache.invalidate_write(self.scope, self.index, self.field,
-                                      self.shard)
+            self._publish()
             from pilosa_tpu.utils.stats import global_stats
 
             global_stats().count("fragment_row_writes", 1)
@@ -861,6 +868,18 @@ class Fragment:
                 # nothing (see _after_rows_added)
                 global_heat().record_write(self.index, self.field,
                                            self.shard, scope=self.scope)
+
+    def _publish(self) -> None:
+        """The last step of every change to the bitmap, after the ranked
+        cache has it too (``_log_op`` moved ``mutations`` before
+        ``row_cache.add`` ran, and ``top`` takes no lock, so that counter
+        cannot vouch for the cache) and before the caller's
+        acknowledgement: what is kept ABOVE the fragment turns over here,
+        the cached results of its (index, field, shard) and the owning
+        view's version (``View.touch``: the folds the plan stage reads)."""
+        rescache.invalidate_write(self.scope, self.index, self.field,
+                                  self.shard)
+        self._on_change()
 
     def _check_pos(self, pos: int) -> None:
         if not 0 <= pos < SHARD_WIDTH:
@@ -942,6 +961,7 @@ class Fragment:
             for r, c in zip(rows.tolist(), counts.tolist()):
                 fresh.bulk_add(r, c)
             self.row_cache = fresh
+            self._on_change()  # what top() answers may have changed
             self.row_cache.save(self._cache_path())
 
     def top(self, n: int = 10, row_ids=None):

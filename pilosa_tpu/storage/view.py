@@ -22,6 +22,7 @@ from pilosa_tpu.storage.integrity import (
     global_integrity,
     quarantine_paths,
 )
+from pilosa_tpu.utils.tracing import note_plan_view_fold
 
 _LOG = logging.getLogger("pilosa_tpu.storage.view")
 
@@ -134,6 +135,44 @@ class View:
         # the loser's acknowledged bits vanish (caught by the mixed
         # read/write serving load test, ~1-in-10 runs)
         self._create_lock = threading.Lock()
+        # what the plan stage asks of every fragment, kept while nothing
+        # changed (see touch, rows_fold, topn_fold)
+        self.version = 0
+        self._version_lock = threading.Lock()
+        # kind -> (version, shard list, parameter, sorted ids): two entries
+        self._folds: dict[str, tuple] = {}
+
+    def new_fragment(self, shard: int) -> Fragment:
+        """A fragment of this view, not opened and not yet in
+        ``fragments`` (``adopt`` publishes it): its writes move this
+        view's version."""
+        return Fragment(
+            os.path.join(self.path, "fragments", str(shard)),
+            self.index,
+            self.field,
+            self.name,
+            shard,
+            cache_type=self.cache_type,
+            cache_size=self.cache_size,
+            scope=self.scope,
+            wal=self.wal,
+            verify_on_load=self.verify_on_load,
+            on_change=self.touch,
+        )
+
+    def adopt(self, shard: int, frag: Fragment) -> None:
+        """Publish ``frag`` as this view's fragment of ``shard`` (a first
+        write's creation, the open, the scrubber's repaired copy)."""
+        self.fragments[shard] = frag
+        self.touch()
+
+    def discard(self, shard: int) -> Fragment | None:
+        """Take a fragment out of ``fragments`` (the caller closes it and
+        deletes its files)."""
+        frag = self.fragments.pop(shard, None)
+        if frag is not None:
+            self.touch()
+        return frag
 
     def open(self) -> "View":
         frag_dir = os.path.join(self.path, "fragments")
@@ -142,18 +181,7 @@ class View:
             if not entry.isdigit():
                 continue
             shard = int(entry)
-            frag = Fragment(
-                os.path.join(frag_dir, entry),
-                self.index,
-                self.field,
-                self.name,
-                shard,
-                cache_type=self.cache_type,
-                cache_size=self.cache_size,
-                scope=self.scope,
-                wal=self.wal,
-                verify_on_load=self.verify_on_load,
-            )
+            frag = self.new_fragment(shard)
             try:
                 frag.open()
             except CorruptFragmentError as e:
@@ -167,7 +195,7 @@ class View:
                 _LOG.error("startup quarantine of %s/%s/%s/%d: %s",
                            self.index, self.field, self.name, shard, e)
                 continue
-            self.fragments[shard] = frag
+            self.adopt(shard, frag)
         return self
 
     def close(self, discard: bool = False) -> None:
@@ -180,19 +208,8 @@ class View:
             with self._create_lock:
                 frag = self.fragments.get(shard)
                 if frag is None:
-                    frag = Fragment(
-                        os.path.join(self.path, "fragments", str(shard)),
-                        self.index,
-                        self.field,
-                        self.name,
-                        shard,
-                        cache_type=self.cache_type,
-                        cache_size=self.cache_size,
-                        scope=self.scope,
-                        wal=self.wal,
-                        verify_on_load=self.verify_on_load,
-                    ).open()
-                    self.fragments[shard] = frag
+                    frag = self.new_fragment(shard).open()
+                    self.adopt(shard, frag)
         return frag
 
     def remove_fragment(self, shard: int, invalidate_derived: bool = True
@@ -230,7 +247,7 @@ class View:
         frags = []
         with self._create_lock:
             for shard in shards:
-                frag = self.fragments.pop(shard, None)
+                frag = self.discard(shard)
                 if frag is not None:
                     frags.append(frag)
         if not frags:
@@ -272,3 +289,78 @@ class View:
 
     def available_shards(self) -> list[int]:
         return sorted(self.fragments)
+
+    # ------------------------------------------------- the plan stage's folds
+    #
+    # A request's plan asks every fragment of a view two things: which
+    # rows are non-empty (Rows(), a GroupBy dimension) and which rows rank
+    # (TopN's phase 1). Each fragment memoises its own answer; the fold
+    # over the fragments is what grew with the shard count, in Python, on
+    # the dispatcher thread, and nothing in it changes between two writes.
+    # The view keeps the last fold of each kind under the version it was
+    # made at.
+
+    def touch(self) -> None:
+        """Move the version. Called by whoever changed what a fragment of
+        this view answers to either question, AFTER the bitmap and the
+        ranked cache hold the change and before the write is acknowledged
+        (``Fragment._publish`` at the end of every mutator, under the
+        fragment's lock; ``recalculate_cache``; ``adopt``; ``discard``).
+
+        Fragments of one view have locks of their own, so two writers get
+        here at once, and ``version += 1`` is a read and a store: bare,
+        writer A could read 5, B and C move it to 7 and A's late store
+        put 6 back, the very value a reader took between B's and C's
+        writes, whose walk missed C's: a stale fold under a current
+        version. Under this lock the version only rises and no two
+        touches leave the same value, so a fold made at snapshot ``s`` is
+        served only while no touch has returned since ``s`` was read, and
+        every write that had touched by then was in the bitmap and the
+        ranked cache before the walk began."""
+        with self._version_lock:
+            self.version += 1
+
+    def _fold(self, kind: str, shard_list: list[int], param, of_fragment,
+              keep: bool) -> tuple[int, ...]:
+        """Sorted union of ``of_fragment(frag)`` over ``shard_list``.
+        ``keep``: the list is ``Index.available_shards``' own (one object
+        while the index has the fragments it has, what
+        ``Executor._shard_block`` keys on), so the fold is looked up and
+        kept; a request that names its shards walks and leaves the
+        entries alone. The version is read BEFORE the walk, as
+        ``Fragment.row_counts`` reads ``mutations``: a write that lands
+        during the walk has not touched yet, touches a higher version
+        afterwards, and forces one more walk."""
+        version = self.version
+        if keep:
+            entry = self._folds.get(kind)
+            if (entry is not None and entry[0] == version
+                    and entry[1] is shard_list and entry[2] == param):
+                note_plan_view_fold(walked=False)
+                return entry[3]
+        ids: set[int] = set()
+        for shard in shard_list:
+            frag = self.fragment(shard)
+            if frag is not None:
+                ids.update(of_fragment(frag))
+        out = tuple(sorted(ids))
+        if keep:
+            self._folds[kind] = (version, shard_list, param, out)
+        note_plan_view_fold(walked=True)
+        return out
+
+    def rows_fold(self, shard_list: list[int], keep: bool) -> tuple[int, ...]:
+        """Sorted ids of the rows with a bit in any of ``shard_list``'s
+        fragments: one O(#containers) metadata pass per fragment (exact,
+        no per-row count loop), memoised there by ``row_counts``."""
+        return self._fold("rows", shard_list, None,
+                          lambda frag: frag.row_counts()[0].tolist(), keep)
+
+    def topn_fold(self, shard_list: list[int], overfetch: int,
+                  keep: bool) -> tuple[int, ...]:
+        """TopN's phase-1 candidates: the sorted union of every
+        fragment's ``overfetch`` best-ranked rows. One entry: a second
+        ``overfetch`` on the same view replaces it."""
+        return self._fold("topn", shard_list, overfetch,
+                          lambda frag: [r for r, _ in frag.top(overfetch)],
+                          keep)

@@ -881,7 +881,7 @@ class WriteAheadLog:
             view = fld.views.get(parts[2]) if fld is not None else None
             if view is None:
                 continue
-            stale = view.fragments.pop(int(parts[3]), None)
+            stale = view.discard(int(parts[3]))
             if stale is not None:
                 stale.close(discard=True)
             frag_path = os.path.join(view.path, "fragments", parts[3])

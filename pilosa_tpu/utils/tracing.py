@@ -866,6 +866,12 @@ def thread_metrics() -> dict:
 # rows with a non-zero count (kept / rows is the share the round let
 # through), and the rounds whose survivors' cross product fitted the
 # dense rule, so that the final level ran at once.
+# Beside them, a block of their own (``plan``): the folds over a view's
+# fragments that the plan stage asked for (the non-empty rows of a
+# Rows() or a GroupBy dimension, TopN's phase-1 candidates) and those
+# that walked the fragments, because the view's version or the shard
+# list had changed or the request named its shards (storage/view.py
+# View._fold): 1 - walks / folds is the share a view answered at once.
 
 _groupby_lock = threading.Lock()
 _groupby_stats = {"levels": 0, "programs": 0, "candidates": 0,
@@ -873,7 +879,8 @@ _groupby_stats = {"levels": 0, "programs": 0, "candidates": 0,
                   "materialized": 0, "pruned": 0, "paged_programs": 0,
                   "paged_row_visits": 0, "paged_row_copies": 0,
                   "marginal_rounds": 0, "marginal_rows": 0,
-                  "marginal_kept": 0, "marginal_dense": 0}
+                  "marginal_kept": 0, "marginal_dense": 0,
+                  "view_folds": 0, "view_walks": 0}
 
 
 def note_groupby_level(programs: int, candidates: int, paged: int = 0,
@@ -918,6 +925,19 @@ def note_groupby_marginal(rows: int, kept: int, dense: bool) -> None:
         _groupby_stats["marginal_rows"] += rows
         _groupby_stats["marginal_kept"] += kept
         _groupby_stats["marginal_dense"] += dense
+
+
+def note_plan_view_fold(walked: bool) -> None:
+    with _groupby_lock:
+        _groupby_stats["view_folds"] += 1
+        _groupby_stats["view_walks"] += walked
+
+
+def plan_metrics() -> dict:
+    """The ``plan`` block of /metrics and /debug/vars."""
+    with _groupby_lock:
+        return {"view_folds_total": _groupby_stats["view_folds"],
+                "view_walks_total": _groupby_stats["view_walks"]}
 
 
 def groupby_metrics() -> dict:
